@@ -60,18 +60,6 @@ transport_option = click.option(
 @click.group()
 def main() -> None:
     """aiko_services_tpu: TPU-native distributed service framework."""
-    # some accelerator plugins force-set jax_platforms at import,
-    # clobbering the env var; honour an explicit JAX_PLATFORMS ask
-    # (e.g. =cpu with xla_force_host_platform_device_count for a
-    # virtual mesh) the way tests/conftest.py does
-    import os
-    requested = os.environ.get("JAX_PLATFORMS")
-    if requested:
-        try:
-            import jax
-            jax.config.update("jax_platforms", requested)
-        except Exception:
-            pass          # jax optional for pure control-plane commands
 
 
 @main.command()
@@ -224,9 +212,14 @@ def create(definition_pathname, name, stream_id, stream_parameters,
     Every element parameter is additionally a flag:
     `--PE_Element.param VALUE` or `--pe-element-param VALUE`
     (see `pipeline params DEFINITION` for the list)."""
-    from .compute import ComputeRuntime
+    from .compute import ComputeRuntime, enable_compile_cache
     from .pipeline import Pipeline, load_pipeline_definition
 
+    # the one command that compiles device programs: control-plane
+    # commands (registrar, dashboard, system start, ...) never import
+    # jax, so a parent that spawns chip-owning children stays off the
+    # chip
+    enable_compile_cache()
     definition = load_pipeline_definition(definition_pathname)
     parameters = json.loads(stream_parameters)
     parameters |= parse_element_flags(definition, element_flags)

@@ -16,6 +16,7 @@
 
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass
 from typing import Any, Callable
@@ -26,7 +27,30 @@ from .actor import Actor
 from .utils import get_logger
 
 __all__ = ["ComputeRuntime", "CompiledProgram", "PROTOCOL_COMPUTE",
-           "resolve_pipelined"]
+           "resolve_pipelined", "enable_compile_cache"]
+
+# the persistent compile cache's home when the environment names none:
+# fixed inside the checkout, because the directory is part of every
+# cache key — a path that moves between runs never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache before the first
+    compile; returns the directory in use.  JAX_COMPILATION_CACHE_DIR,
+    when set, is honoured as jax itself reads it and no directory is
+    set in code (so whoever runs the program can place the cache where
+    it survives); otherwise the cache lives at COMPILE_CACHE_DIR.
+    Entry points call this (chip_smoke.py, bench.py, `aiko_tpu
+    pipeline create`); the test suite does not."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def resolve_pipelined(pipelined, mode: str) -> bool:

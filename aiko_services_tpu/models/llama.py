@@ -272,8 +272,7 @@ def llama_forward_sp(params, config: LlamaConfig, tokens, mesh,
     batch = batch_axis if batch_axis in mesh.axis_names else None
     token_spec = P(batch, axis_name)
     param_specs = jax.tree.map(lambda _: P(), params)   # replicated
-    from ..parallel.collectives import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(param_specs, token_spec),
         out_specs=P(batch, axis_name, None))(params, tokens)
 

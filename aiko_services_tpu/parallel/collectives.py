@@ -12,38 +12,7 @@ from __future__ import annotations
 
 __all__ = ["psum", "pmean", "pmax", "all_gather", "ppermute_ring",
            "reduce_scatter", "axis_index", "axis_size", "device_transfer",
-           "ring_neighbours", "shard_map"]
-
-
-def shard_map(fn, mesh, in_specs, out_specs, check_vma=None):
-    """Version-tolerant shard_map: jax.shard_map on current jax (its
-    own defaults preserved), the jax.experimental spelling on older
-    toolchains — there with the replication checker OFF, because old
-    checkers lack the varying-manifest ops (pcast/pvary) this code
-    marks loop carries with."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs,
-                      check_rep=bool(check_vma))
-
-
-def pcast_varying(x, axis_name):
-    """Mark x device-varying for the shard_map type system.  No-op on
-    jax versions without the varying-manifest checker (their shard_map
-    runs with the replication check off — see shard_map above)."""
-    import jax
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_name, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis_name)
-    return x
+           "ring_neighbours"]
 
 
 def psum(x, axis_name):

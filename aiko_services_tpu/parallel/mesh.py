@@ -81,22 +81,15 @@ def create_mesh(axes: dict | MeshSpec | None = None, devices=None):
 
 
 def _make_mesh(shape: tuple, names: tuple, devices):
-    """Version-tolerant mesh construction.  Auto axis types: shardings
-    propagate from annotations (with_sharding_constraint) rather than
-    the explicit-sharding type system — the classic pjit programming
-    model.  Older jax (< AxisType) defaults to exactly that, so the
-    argument is simply omitted there."""
+    """Auto axis types: shardings propagate from annotations
+    (with_sharding_constraint) rather than the explicit-sharding type
+    system jax.make_mesh defaults to — the classic pjit programming
+    model."""
     import jax
 
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, names, devices=devices,
-                             axis_types=(axis_type.Auto,) * len(names))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, names, devices=devices)
-    import numpy as np
-    return jax.sharding.Mesh(
-        np.asarray(devices).reshape(shape), names)
+    return jax.make_mesh(
+        shape, names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def single_device_mesh(axis: str = AXIS_DATA):

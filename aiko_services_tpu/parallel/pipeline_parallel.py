@@ -130,8 +130,7 @@ def gpipe_spmd(stage_fn, mesh, num_microbatches: int,
 
         # mark the loop state stage-varying up front (shard_map type
         # system: the fori_loop carry type must match its output)
-        from .collectives import pcast_varying
-        buffer = pcast_varying(microbatches, axis_name)
+        buffer = jax.lax.pcast(microbatches, axis_name, to="varying")
         carry = jnp.zeros_like(buffer[0])
 
         def step_fn(t, state):
@@ -159,8 +158,7 @@ def gpipe_spmd(stage_fn, mesh, num_microbatches: int,
             axis_name)
         return result
 
-    from .collectives import shard_map
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P()))

@@ -114,9 +114,8 @@ def ring_attention(q, k, v, mesh, axis_name: str = AXIS_SEQUENCE,
     spec = P(batch, None, axis_name, None)
     body = functools.partial(ring_attention_sharded, axis_name=axis_name,
                              causal=causal, scale=scale)
-    from .collectives import shard_map
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
 
 
 def attention_reference(q, k, v, causal: bool = False,

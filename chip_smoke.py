@@ -1,0 +1,739 @@
+#!/usr/bin/env python3
+# chip smoke CLI: the console lines and the final JSON are the product
+# graft: disable-file=lint-print
+"""The quickest proof that the two serving paths still start on the chip.
+
+    python3 chip_smoke.py              one TPU chip: kernels, speech, llama
+    python3 chip_smoke.py --chips 4    four chips: TP=4 llama vs one chip, only
+    python3 chip_smoke.py --rehearse   tiny presets on whatever jax finds
+                                       (the CPU rehearsal; never says "ok")
+
+One process, because one process owns the chip.  Inputs and weights are
+made from --seed; nothing is read from the network or written outside
+the checkout.  Every phase raises on a wrong result, so a non-zero exit
+means a phase failed; on success the LAST stdout line is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Phases of the default run:
+  kernels  every pallas kernel of the main path, compiled
+           (interpret=False), against its XLA oracle
+  speech   examples/speech/pipeline_transcription.json built as `aiko_tpu
+           pipeline create` builds it — Whisper-small, bf16, batched through
+           BatchingScheduler -> ComputeRuntime — fed seeded audio that lands
+           in every mel bucket; tokens equal a direct jit of greedy_decode
+  llama    ContinuousDecoder on LLAMA_PRESETS["1b"] with paged KV, eight
+           seeded requests (64..1024-token prompts, chunked extend on the
+           long ones) against llama_greedy_decode, then the same requests
+           through the fused paged-attention kernel
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import os
+import sys
+import time
+import zlib
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+SPEECH_DEFINITION = os.path.join(
+    ROOT, "examples", "speech", "pipeline_transcription.json")
+
+# the whole run, compilation included, must end well inside the
+# driver's 1200 s; a hung device call otherwise outlives the caller
+WATCHDOG_SECONDS = 1150
+
+
+def say(message: str) -> None:
+    print(f"[chip_smoke] {message}", flush=True)
+
+
+def require(condition, message: str) -> None:
+    if not condition:
+        raise AssertionError(message)
+
+
+class CompileClock:
+    """Sums jax's own backend-compile durations and persistent-cache
+    hit/miss events, so each phase can report compile seconds apart
+    from run seconds (and a warm cache shows as fewer of them)."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.seconds = 0.0
+        self.hits = 0
+        self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += seconds
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self) -> tuple:
+        return self.seconds, self.hits, self.misses
+
+
+class Phase:
+    """`with Phase("speech", clock):` — prints wall, compile and run
+    seconds, cache hits/misses and HBM in use when the block ends; an
+    exception inside propagates (a failed phase fails the run)."""
+
+    def __init__(self, name: str, clock: CompileClock):
+        self.name, self.clock = name, clock
+
+    def __enter__(self):
+        say(f"phase {self.name}: start")
+        self.start = time.perf_counter()
+        self.before = self.clock.snapshot()
+        return self
+
+    def __exit__(self, exc_type, exc, _tb):
+        wall = time.perf_counter() - self.start
+        seconds, hits, misses = (
+            now - then for now, then in zip(self.clock.snapshot(),
+                                            self.before))
+        outcome = "FAILED" if exc_type else "ok"
+        say(f"phase {self.name}: {outcome} wall={wall:.1f}s "
+            f"compile={seconds:.1f}s run={max(0.0, wall - seconds):.1f}s "
+            f"cache_hits={hits} cache_misses={misses} hbm={hbm_in_use()}")
+        return False
+
+
+def hbm_in_use() -> str:
+    import jax
+    parts = []
+    for device in jax.devices():
+        stats = device.memory_stats() or {}
+        if "bytes_in_use" in stats:
+            parts.append(f"{stats['bytes_in_use'] / 2**30:.2f}"
+                         f"/{stats.get('peak_bytes_in_use', 0) / 2**30:.2f}")
+    return ("GiB in-use/peak " + " ".join(parts)) if parts \
+        else "not reported by this backend"
+
+
+# -- kernels -----------------------------------------------------------------
+
+def lowered_has_kernel(fn, *args, **kwargs) -> bool:
+    """True when fn's lowering holds a compiled pallas kernel — the
+    proof that the interpret/XLA branch was NOT taken."""
+    return "tpu_custom_call" in fn.lower(*args, **kwargs).as_text()
+
+
+def check_kernel(label: str, kernel, oracle, args, on_chip: bool) -> None:
+    """Run a jitted kernel path and its XLA oracle on the same inputs.
+    Both round to bf16 along different routes, so each element may
+    differ by a few bf16 ulps of its own size (2^-6 relative) plus
+    what the bf16 softmax weights accumulate (2^-7 absolute, for
+    outputs of order one); a wrong block or mask is off by the
+    output's whole spread."""
+    import jax.numpy as jnp
+    if on_chip:
+        require(lowered_has_kernel(kernel, *args),
+                f"{label} lowered without a tpu_custom_call")
+    out = kernel(*args).astype(jnp.float32)
+    ref = oracle(*args).astype(jnp.float32)
+    diff = jnp.abs(out - ref)
+    worst = float(jnp.max(diff))
+    say(f"  {label}: max|kernel-oracle|={worst:.4f}, oracle std "
+        f"{float(jnp.std(ref)):.3f}")
+    require(float(jnp.max(diff - 2.0 ** -6 * jnp.abs(ref))) <= 2.0 ** -7,
+            f"{label} off by {worst}")
+
+
+def llama_config(shape: dict):
+    from aiko_services_tpu.models.llama import LLAMA_PRESETS
+    return dataclasses.replace(LLAMA_PRESETS[shape["llama_preset"]],
+                               dtype=shape["llama_dtype"],
+                               max_seq_len=shape["max_seq"])
+
+
+def phase_kernels(shape: dict, seed: int, on_chip: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from aiko_services_tpu import serving, serving_paged
+    from aiko_services_tpu.models import layers as L
+    from aiko_services_tpu.models.llama import _layer_init
+    from aiko_services_tpu.ops.attention import flash_attention
+    from aiko_services_tpu.parallel import attention_reference
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    # flash attention at the 3000-frame bucket's padded context
+    b, h, s, d = shape["flash"]
+    qkv = [jax.random.normal(key, (b, h, s, d), jnp.bfloat16)
+           for key in keys[:3]]
+    for causal in (False, True):
+        check_kernel(
+            f"flash_attention b{b} h{h} s{s} d{d} causal={causal}",
+            jax.jit(functools.partial(flash_attention, causal=causal,
+                                      interpret=not on_chip)),
+            jax.jit(functools.partial(attention_reference,
+                                      causal=causal)),
+            qkv, on_chip)
+
+    # paged decode attention at the llama phase's geometry, through the
+    # same layer wrappers the decode scan calls, against the gather path
+    config = llama_config(shape)
+    layer = _layer_init(keys[3], config)
+    slots, block = shape["slots"], 32
+    t_cap, side_len = shape["max_seq"], shape["steps_per_sync"]
+    nb = -(-t_cap // block)
+    cos, sin = L.rope_frequencies(config.head_dim, config.max_seq_len,
+                                  config.rope_theta)
+    x = jax.random.normal(keys[4], (slots, 1, config.dim), config.dtype)
+    pool_shape = (slots * nb + 1, config.num_kv_heads, block,
+                  config.head_dim)
+    native = [jax.random.normal(key, pool_shape, config.dtype)
+              for key in keys[5:7]]
+    tables = 1 + jnp.arange(slots * nb, dtype=jnp.int32).reshape(slots, nb)
+    entry = jnp.linspace(1, t_cap - side_len, slots).astype(jnp.int32)
+    sides = jnp.zeros((slots, config.num_kv_heads, side_len,
+                       config.head_dim), config.dtype)
+
+    # the layer's weights are an ARGUMENT: closed over, 120 MB of them
+    # would be baked into each executable (and its cache entry)
+    def kernel_path(layer, x, k_pool, v_pool):
+        return serving_paged._kernel_attention_block(
+            tables, layer, config, x, cos, sin, k_pool, v_pool,
+            sides, sides, entry, entry, 0)[0]
+
+    def gather_path(layer, x, k_pool, v_pool):
+        k_cache, v_cache = (
+            serving_paged._gather_views([pool], tables, t_cap)[0]
+            for pool in (k_pool, v_pool))
+        return serving._slot_attention_block(
+            layer, config, x, cos, sin, k_cache, v_cache, sides,
+            sides, entry, entry, 0)[0]
+
+    for kv, pools in (
+            (str(jnp.dtype(config.dtype)), native),
+            ("int8", [L.quantize_kv_cache(pool) for pool in native])):
+        check_kernel(
+            f"paged_decode_attention S{slots} Hkv{config.num_kv_heads} "
+            f"G{config.num_heads // config.num_kv_heads} "
+            f"D{config.head_dim} B{block} nb{nb} P{side_len} {kv}",
+            jax.jit(kernel_path), jax.jit(gather_path),
+            [layer, x, *pools], on_chip)
+
+
+# -- speech ------------------------------------------------------------------
+
+def seeded_microphone(seed: int):
+    """PE_MicrophoneSim's stand-in: the same source seam, but each
+    posted frame yields `chunk_seconds` of noise made from (seed,
+    stream, frame) instead of a timer-driven tone — the smoke decides
+    how many frames of which length reach each mel bucket."""
+    import numpy as np
+
+    from aiko_services_tpu.pipeline import FrameOutput, PipelineElement
+
+    class PE_SeededMicrophone(PipelineElement):
+        contracts = {"out:audio": "f32[*]"}
+
+        def process_frame(self, frame, **_) -> FrameOutput:
+            seconds, _found = self.get_parameter("chunk_seconds", 1.0,
+                                                 frame.stream)
+            rng = np.random.default_rng(
+                [seed, zlib.crc32(frame.stream_id.encode()),
+                 frame.frame_id])
+            audio = 0.1 * rng.standard_normal(int(float(seconds) * 16000))
+            return FrameOutput(True, {"audio": audio.astype(np.float32)})
+
+    return PE_SeededMicrophone
+
+
+def phase_speech(shape: dict, seed: int, on_chip: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu.compute import ComputeRuntime
+    from aiko_services_tpu.models.whisper import (greedy_decode_scored,
+                                                  sot_sequence_for)
+    from aiko_services_tpu.ops import attention as attention_ops
+    from aiko_services_tpu.pipeline import (Pipeline,
+                                            parse_pipeline_definition)
+    from aiko_services_tpu.process import ProcessRuntime
+
+    with open(SPEECH_DEFINITION) as f:
+        data = json.load(f)
+    data["parameters"] |= {
+        "PE_WhisperASR.preset": shape["whisper_preset"],
+        # random weights decode at ~log(1/vocab) per token: the
+        # hallucination gates would blank every transcript
+        "PE_WhisperASR.logprob_threshold": -1e9,
+        "PE_WhisperASR.compression_ratio_threshold": 1e9,
+    }
+    definition = parse_pipeline_definition(data)
+    max_tokens = int(data["parameters"]["PE_WhisperASR.max_tokens"])
+
+    # what `aiko_tpu pipeline create` does (cli.create), minus the
+    # endless runtime.run(): the smoke drives the engine itself
+    runtime = ProcessRuntime(name="chip_smoke").initialize()
+    ComputeRuntime(runtime, "compute")
+    pipe = Pipeline(runtime, definition,
+                    definition_pathname=SPEECH_DEFINITION,
+                    element_classes={
+                        "PE_MicrophoneSim": seeded_microphone(seed)})
+    done: list = []
+    pipe.add_frame_handler(done.append)
+    asr = next(node.element for node in pipe.graph.nodes()
+               if node.name == "PE_WhisperASR")
+    stats = attention_ops.dispatch_stats
+    stats.update(flash=0, xla=0)
+
+    def feed(stream_id: str, chunk_seconds: float, frames: int) -> list:
+        """Post `frames` chunks to one stream; PE_AudioFraming's
+        3-chunk window makes the mel lengths 1x, 2x, 3x the chunk."""
+        if stream_id not in pipe.streams:
+            pipe.create_stream(stream_id, lease_time=0, parameters={
+                "PE_MicrophoneSim.chunk_seconds": chunk_seconds})
+        before = len(done)
+        for _ in range(frames):
+            pipe.post("process_frame", stream_id, {})
+        finished = runtime.event.run_until(
+            lambda: len(done) >= before + frames, timeout=600.0)
+        require(finished and pipe.recovery_stats["frames_failed"] == 0,
+                f"speech stream {stream_id}: {len(done) - before}/"
+                f"{frames} frames completed, "
+                f"{pipe.recovery_stats['frames_failed']} failed")
+        return done[before:]
+
+    try:
+        short = feed("short", 1.5, 3)       # 150/300/450 mel frames
+        require(stats["xla"] > 0 and stats["flash"] == 0,
+                f"the 500-frame bucket must take XLA attention: {stats}")
+        long = feed("long", 7.0, 3)         # 700/1400/2100 mel frames
+        if on_chip:
+            require(stats["flash"] > 0,
+                    f"the 3000-frame bucket (n_audio_ctx 1536) must "
+                    f"take the flash kernel: {stats}")
+        say(f"  attention dispatch after both streams: {dict(stats)}")
+        # a second, already-compiled round: run seconds without compile
+        short += feed("short", 1.5, 2)
+        long += feed("long", 7.0, 2)
+        program = asr.compute.programs[asr._program]
+        buckets = sorted(program.first_call_times)
+        require(len(buckets) >= 2 and buckets[0] == 500
+                and buckets[-1] >= 3000,
+                f"expected the 500- and 3000-frame buckets, got {buckets}")
+        for bucket in buckets:
+            later = [s for b, s in program.recent_service if b == bucket]
+            say(f"  bucket {bucket}: first call (compile+run) "
+                f"{program.first_call_times[bucket]:.1f}s, later calls "
+                f"{[round(s, 3) for s in later] or 'none'}")
+
+        vocab = asr.config.n_vocab
+        for frame in short + long:
+            tokens = np.asarray(frame.swag["tokens"])
+            require(tokens.ndim == 1 and 0 < tokens.size <= max_tokens
+                    and tokens.min() >= 0 and tokens.max() < vocab,
+                    f"frame {frame.stream_id}/{frame.frame_id}: bad "
+                    f"tokens {tokens}")
+
+        # one batch, decoded by a direct jit of the model on the same
+        # mel on the same device, padded exactly as the element pads
+        bucket = buckets[0]
+        config = dataclasses.replace(asr.config, n_audio_ctx=bucket // 2)
+        rows, _found = asr.get_parameter("max_batch", 32)
+        batch = np.zeros((int(rows), bucket, config.n_mels), np.float32)
+        for row, frame in enumerate(short):
+            mel = np.asarray(frame.swag["mel"])
+            batch[row, :mel.shape[0]] = mel
+        direct = jax.jit(lambda params, mel: greedy_decode_scored(
+            params, config, mel, max_tokens=max_tokens,
+            sot_sequence=sot_sequence_for(config, timestamps=False),
+            suppress_timestamps=True))
+        tokens, lengths, _ = direct(asr.params,
+                                    jnp.asarray(batch, jnp.bfloat16))
+        tokens, lengths = np.asarray(tokens), np.asarray(lengths)
+        for row, frame in enumerate(short):
+            served = np.asarray(frame.swag["tokens"])
+            expect = tokens[row, :lengths[row]]
+            require(np.array_equal(served, expect),
+                    f"pipeline tokens differ from the direct decode for "
+                    f"frame {frame.frame_id}: {served} vs {expect}")
+        say(f"  {len(short)} frames of bucket {bucket} equal the direct "
+            f"greedy_decode; {len(short) + len(long)} frames completed, "
+            f"e.g. {np.asarray(long[-1].swag['tokens'])[:8]}")
+    finally:
+        runtime.terminate()
+
+
+# -- llama -------------------------------------------------------------------
+
+def llama_setup(shape: dict, seed: int):
+    import jax
+    import numpy as np
+
+    from aiko_services_tpu.models.llama import llama_init
+
+    config = llama_config(shape)
+    params = llama_init(jax.random.PRNGKey(seed), config)
+    rng = np.random.default_rng(seed)
+    requests = {
+        f"r{i}": (rng.integers(1, config.vocab, size=length).tolist(),
+                  shape["new_tokens"])
+        for i, length in enumerate(shape["prompt_lengths"])}
+    return config, params, requests
+
+
+def llama_decoder(params, config, shape: dict, kernel: bool = False):
+    """A paged ContinuousDecoder with the constructor's own paged-KV
+    defaults (kv_block 32).  The attention implementation is read once,
+    at construction — the AIKO_DECODE_ATTENTION seam."""
+    from aiko_services_tpu import serving
+
+    before = serving.ATTENTION_IMPL
+    serving.ATTENTION_IMPL = "paged_kernel" if kernel else "two_pass"
+    try:
+        return serving.ContinuousDecoder(
+            params, config, paged_kv=True, max_slots=shape["slots"],
+            max_seq=shape["max_seq"], t_block=shape["max_seq"],
+            prefill_buckets=shape["prefill_buckets"],
+            prefill_chunk=shape["prefill_chunk"],
+            steps_per_sync=shape["steps_per_sync"],
+            name="kernel" if kernel else "gather")
+    finally:
+        serving.ATTENTION_IMPL = before
+
+
+def serve(decoder, requests: dict, limit: float = 600.0) -> dict:
+    """The normal submit/pump path, to completion."""
+    done: dict = {}
+    for request_id, (prompt, new_tokens) in requests.items():
+        require(decoder.submit(request_id, prompt, new_tokens,
+                               lambda rid, tokens: done.update(
+                                   {rid: [int(t) for t in tokens]})),
+                f"request {request_id} refused")
+    deadline = time.perf_counter() + limit
+    while len(done) < len(requests):
+        require(time.perf_counter() < deadline,
+                f"{len(done)}/{len(requests)} requests after {limit}s")
+        decoder.pump()
+    for request_id, (_, new_tokens) in requests.items():
+        tokens = done[request_id]
+        require(len(tokens) == new_tokens and
+                all(0 <= t < decoder.config.vocab for t in tokens),
+                f"request {request_id}: bad tokens {tokens}")
+    return done
+
+
+class TeacherForced:
+    """The plain reference every served token is held to: one causal
+    forward of the model over prompt + served tokens, then for each
+    served token the gap between the reference's best logit at that
+    position and the served token's.  Zero when the server emitted the
+    reference argmax.  bf16 programs that associate their sums
+    differently (bucketed prefill, blockwise kernels, TP all-reduces)
+    legitimately flip NEAR-TIES — random weights make them common —
+    so a token passes when its gap is within `tolerance` standard
+    deviations of that position's logits: a wrong KV row or position
+    picks an unrelated token, several deviations down."""
+
+    def __init__(self, params, config, length: int):
+        import jax
+        import jax.numpy as jnp
+
+        from aiko_services_tpu.models import layers as L
+        from aiko_services_tpu.models.llama import (init_llama_caches,
+                                                    llama_hidden)
+
+        self.length = length
+        # rounding error of ~50 chained bf16 ops (16 layers) is a few
+        # percent of a logit's spread; float32 leaves none to speak of
+        self.tolerance = 0.125 if config.dtype == jnp.bfloat16 else 1e-3
+
+        def gaps(params, tokens, positions, served):
+            hidden, _ = llama_hidden(
+                params, config, tokens,
+                init_llama_caches(config, 1, length))
+            logits = L.linear_logits(params["lm_head"],
+                                     hidden[0, positions])
+            chosen = jnp.take_along_axis(logits, served[:, None], 1)[:, 0]
+            return ((jnp.max(logits, axis=-1) - chosen) /
+                    jnp.std(logits, axis=-1))
+
+        self._gaps = jax.jit(gaps)
+        self.params = params
+
+    def check(self, label: str, requests: dict, served: dict) -> float:
+        import numpy as np
+        worst = 0.0
+        for request_id, (prompt, _) in requests.items():
+            tokens = served[request_id]
+            full = np.zeros((1, self.length), np.int32)
+            sequence = prompt + tokens[:-1]
+            full[0, :len(sequence)] = sequence
+            positions = len(prompt) - 1 + np.arange(len(tokens))
+            gaps = np.asarray(self._gaps(
+                self.params, full, positions.astype(np.int32),
+                np.asarray(tokens, np.int32)))
+            require(np.all(np.isfinite(gaps)) and
+                    gaps.max() <= self.tolerance,
+                    f"{label} {request_id}: token {int(gaps.argmax())} "
+                    f"is {gaps.max():.3f} logit-std below the "
+                    f"reference's best (tolerance {self.tolerance})")
+            worst = max(worst, float(gaps.max()))
+        return worst
+
+
+def compare(label: str, requests: dict, ours: dict, theirs: dict) -> None:
+    """Exact-identity census between two token streams (reported, and
+    written into CHANGES.md; TeacherForced decides pass or fail)."""
+    exact = 0
+    for request_id, (prompt, _) in requests.items():
+        a, b = ours[request_id], theirs[request_id]
+        if a == b:
+            exact += 1
+            continue
+        first = next(i for i, (x, y) in enumerate(zip(a, b)) if x != y)
+        say(f"  {label} {request_id} (prompt {len(prompt)}): first "
+            f"divergence at token {first}: {a[first]} vs {b[first]}")
+    say(f"  {label}: {exact}/{len(requests)} requests token-identical")
+
+
+def greedy_oracle(params, config, requests: dict) -> dict:
+    """llama_greedy_decode — the oracle the repo's tests hold the
+    decoder to; the jit retraces once per distinct prompt length."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu.models.llama import llama_greedy_decode
+
+    new_tokens = {n for _, n in requests.values()}.pop()
+    decode = jax.jit(lambda params, prompt: llama_greedy_decode(
+        params, config, prompt, max_tokens=new_tokens))
+    return {
+        request_id: [int(t) for t in np.asarray(
+            decode(params, jnp.asarray([prompt], jnp.int32)))[0]]
+        for request_id, (prompt, _) in requests.items()}
+
+
+def timed_serve(label: str, decoder, requests: dict,
+                clock: CompileClock) -> dict:
+    start, compiled = time.perf_counter(), clock.seconds
+    served = serve(decoder, requests)
+    say(f"  {label}: {len(served)} requests in "
+        f"{time.perf_counter() - start:.1f}s, of which compile "
+        f"{clock.seconds - compiled:.1f}s")
+    return served
+
+
+def decode_step_has_kernel(decoder) -> bool:
+    import jax.numpy as jnp
+    slots = decoder.max_slots
+    nb = -(-decoder._cache_t // decoder.kv_block)
+    return lowered_has_kernel(
+        decoder._step, decoder.params, jnp.ones((slots,), jnp.int32),
+        jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), bool),
+        jnp.ones((slots,), jnp.int32), decoder.pool.k_pools,
+        decoder.pool.v_pools, jnp.zeros((slots, nb), jnp.int32),
+        num_steps=decoder.steps_per_sync, eos=-1,
+        t_cap=decoder._cache_t)
+
+
+def phase_llama(shape: dict, seed: int, on_chip: bool,
+                clock: CompileClock) -> None:
+    config, params, requests = llama_setup(shape, seed)
+    reference = TeacherForced(params, config, shape["max_seq"])
+
+    gather = llama_decoder(params, config, shape)
+    cold = timed_serve("gather path, first pass", gather, requests,
+                       clock)
+    warm = timed_serve("gather path, second pass", gather, requests,
+                       clock)
+    require(cold == warm, "the same requests served twice differ")
+    require(gather.stats["prefill_chunks"] > 0,
+            "no prompt took the chunked extend")
+    say(f"  gather path: prefill_chunks="
+        f"{gather.stats['prefill_chunks']} rounds="
+        f"{gather.stats['rounds']} pool_blocks="
+        f"{gather.pool.num_blocks - 1}x{gather.kv_block} tokens")
+    worst = reference.check("gather", requests, cold)
+    say(f"  gather path: every token within {reference.tolerance} "
+        f"logit-std of the teacher-forced reference (worst {worst:.4f})")
+    compare("gather vs llama_greedy_decode", requests, cold,
+            greedy_oracle(params, config, requests))
+
+    kernel = llama_decoder(params, config, shape, kernel=True)
+    require(kernel.paged_kernel and not gather.paged_kernel,
+            "attention implementation was not latched at construction")
+    if on_chip:
+        require(decode_step_has_kernel(kernel),
+                "the kernel decoder's decode step lowered without a "
+                "tpu_custom_call")
+        require(not decode_step_has_kernel(gather),
+                "the gather decoder's decode step holds a kernel")
+    fused = timed_serve("paged kernel, first pass", kernel, requests,
+                        clock)
+    timed_serve("paged kernel, second pass", kernel, requests, clock)
+    require(kernel.stats["prefill_chunks"] > 0,
+            "no prompt took the kernel's chunked extend")
+    worst = reference.check("kernel", requests, fused)
+    say(f"  paged kernel: every token within {reference.tolerance} "
+        f"logit-std of the reference (worst {worst:.4f})")
+    compare("kernel vs gather", requests, fused, cold)
+
+
+# -- four chips --------------------------------------------------------------
+
+def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
+                          clock: CompileClock) -> None:
+    """Llama over create_mesh({"model": 4}) against the one-chip
+    decoder, same process, same requests."""
+    import jax
+
+    from aiko_services_tpu.models.llama import llama_axes
+    from aiko_services_tpu.parallel import create_mesh, shard_pytree
+
+    devices = jax.devices()
+    require(len(devices) >= 4, f"--chips 4 needs four devices, jax "
+                               f"sees {len(devices)}")
+    config, params, requests = llama_setup(shape, seed)
+    mesh = create_mesh({"model": 4}, devices=devices[:4])
+    placed = shard_pytree(params, llama_axes(config), mesh)
+
+    # are the weights really spread?  Code that has only ever seen one
+    # chip may leave everything on the first
+    sharded = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(placed):
+        if leaf.sharding.is_fully_replicated:
+            continue
+        sharded += 1
+        sizes = {shard.device.id: shard.data.nbytes
+                 for shard in leaf.addressable_shards}
+        require(len(sizes) == 4 and
+                all(size * 4 == leaf.nbytes for size in sizes.values()),
+                f"{jax.tree_util.keystr(path)}: shard bytes {sizes} are "
+                f"not a quarter of {leaf.nbytes}")
+    total = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(placed))
+    require(sharded > 0, "no leaf is sharded over the model axis")
+    say(f"  {sharded} TP-sharded leaves, each device holds a quarter of "
+        f"each; params {total / 2**30:.2f} GiB")
+    if on_chip:
+        for device in devices[1:4]:
+            in_use = device.memory_stats()["bytes_in_use"]
+            require(in_use > total // 16,
+                    f"device {device.id} holds {in_use} bytes: the "
+                    f"weights are not on it")
+
+    reference = TeacherForced(params, config, shape["max_seq"])
+    single = timed_serve("one chip", llama_decoder(params, config, shape),
+                         requests, clock)
+    tp_decoder = llama_decoder(placed, config, shape)
+    tp = timed_serve("TP=4, first pass", tp_decoder, requests, clock)
+    # the first pass returns state with the compiler's shardings, so
+    # the second may compile again; the third is the compiled path
+    timed_serve("TP=4, second pass", tp_decoder, requests, clock)
+    timed_serve("TP=4, third pass", tp_decoder, requests, clock)
+    for label, served in (("one chip", single), ("TP=4", tp)):
+        worst = reference.check(label, requests, served)
+        say(f"  {label}: every token within {reference.tolerance} "
+            f"logit-std of the reference (worst {worst:.4f})")
+    compare("TP=4 vs one chip", requests, tp, single)
+    pools = jax.tree_util.tree_leaves(tp_decoder.pool.k_pools)
+    say(f"  TP=4 KV pool leaf sharding: {pools[0].sharding}")
+
+
+# -- entry -------------------------------------------------------------------
+
+def shapes(rehearse: bool) -> dict:
+    import jax.numpy as jnp
+    if rehearse:
+        # the CPU rehearsal: same code paths, toy widths
+        return {"whisper_preset": "test", "llama_preset": "tiny",
+                "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
+                "prefill_buckets": (8, 32), "prefill_chunk": 32,
+                "steps_per_sync": 4, "new_tokens": 8,
+                "prompt_lengths": (8, 8, 20, 20, 44, 44, 100, 100),
+                "flash": (1, 2, 256, 64)}
+    return {"whisper_preset": "small", "llama_preset": "1b",
+            "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,
+            "prefill_buckets": (64, 256), "prefill_chunk": 256,
+            "steps_per_sync": 4, "new_tokens": 32,
+            "prompt_lengths": (64, 64, 200, 200, 448, 448, 1024, 1024),
+            "flash": (4, 12, 1536, 64)}
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                        help="4 runs ONLY the TP=4 llama phase and the "
+                             "one-chip decoder it is compared with")
+    parser.add_argument("--rehearse", action="store_true",
+                        help="tiny presets on any backend (CPU "
+                             "rehearsal); never prints ok")
+    args = parser.parse_args(argv)
+
+    import faulthandler
+    faulthandler.dump_traceback_later(WATCHDOG_SECONDS, exit=True)
+
+    import jax
+
+    device = jax.devices()[0]
+    found = {"platform": device.platform, "kind": device.device_kind,
+             "count": len(jax.devices())}
+    on_chip = device.platform == "tpu"
+    if not on_chip and not args.rehearse:
+        print(f"chip_smoke: jax found no TPU ({found})", file=sys.stderr)
+        return 2
+
+    from aiko_services_tpu.compute import enable_compile_cache
+    # a rehearsal's CPU executables are no use to a chip run
+    cache_dir = "off (rehearsal)" if args.rehearse \
+        else enable_compile_cache()
+    clock = CompileClock()
+    say(f"device {found}, jax {jax.__version__}, compile cache "
+        f"{cache_dir}")
+    if on_chip:
+        # an unknown device kind is an error wherever the peak tables
+        # are used — fail before spending the run, not in the benchmark
+        import bench
+        say(f"peaks for {device.device_kind!r}: "
+            f"{bench.device_peak_flops()[0] / 1e12:.0f} TFLOP/s bf16, "
+            f"{bench.device_peak_membw() / 1e9:.0f} GB/s HBM")
+    require(len(jax.devices()) >= args.chips,
+            f"--chips {args.chips} but jax sees {found['count']}")
+    from aiko_services_tpu.native import NATIVE_AVAILABLE
+    say(f"NATIVE_AVAILABLE={NATIVE_AVAILABLE}")
+
+    shape = shapes(args.rehearse)
+    if args.chips == 4:
+        phases = {"tensor_parallel": functools.partial(
+            phase_tensor_parallel, clock=clock)}
+    else:
+        phases = {"kernels": phase_kernels, "speech": phase_speech,
+                  "llama": functools.partial(phase_llama, clock=clock)}
+    for name, phase in phases.items():
+        with Phase(name, clock):
+            phase(shape, args.seed, on_chip)
+    seconds, hits, misses = clock.snapshot()
+    say(f"total compile={seconds:.1f}s cache_hits={hits} "
+        f"cache_misses={misses} (a second run with the same cache "
+        f"directory should show hits and less compile)")
+    faulthandler.cancel_dump_traceback_later()
+    if args.rehearse:
+        print(json.dumps({"rehearsal": "passed", "device": found}))
+    else:
+        print(json.dumps({"ok": True, "device": found}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,149 @@
+# Chip-compile rehearsal kept as tests (guide on-chip-measurement §2 step 3):
+# every pallas kernel of the main path, compiled with interpret=False for a
+# DESCRIBED v5e — the TPU compiler is installed here though no chip is — at
+# the shapes the serving stack gives it.  Interpret mode cannot see what
+# these see: PR 16's paged kernel passed every interpret-mode test and was
+# refused by mosaic at every serving geometry (a dynamic lane-dimension
+# slice at kv_block=32).  Nothing runs, so these say nothing about results
+# or times.  Plus the compile-cache helper's placement contract.
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+# Llama-3.2-1B attention geometry, the serving stack's pool block
+HKV, GROUPS, HEAD_DIM, BLOCK = 8, 4, 64, 32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """SingleDeviceSharding on one described v5e chip; the persistent
+    compilation cache is off around the module (an executable compiled
+    for a described device cannot be read back without one — a warm
+    cache would only add warnings)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:           # no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e: {exc!r}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topology.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _flash(causal):
+    from aiko_services_tpu.ops.attention import flash_attention
+    # the 3000-frame whisper bucket: n_audio_ctx 1536, 12 heads
+    shape = ((4, 12, 1536, 64), jnp.bfloat16)
+    return (lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            interpret=False),
+            [shape, shape, shape])
+
+
+def _cross_decode():
+    from aiko_services_tpu.ops.attention import cross_decode_attention
+    kv = ((32, 12, 250, 64), jnp.bfloat16)
+    return (lambda q, k, v: cross_decode_attention(q, k, v,
+                                                   interpret=False),
+            [((32, 12, 1, 64), jnp.bfloat16), kv, kv])
+
+
+def _paged(int8, fold, width, side, slots=8, t_cap=1280):
+    """side = steps_per_sync * width for a decode/spec round, or the
+    chunk length for a chunked-prefill extend (width = chunk)."""
+    from aiko_services_tpu.ops.paged_attention import \
+        paged_decode_attention
+    nb = t_cap // BLOCK
+    pool_shape = (slots * nb + 1, HKV, BLOCK, HEAD_DIM)
+    pool = {"q": (pool_shape, jnp.int8),
+            "s": (pool_shape[:3], jnp.float32)} if int8 \
+        else (pool_shape, jnp.bfloat16)
+    side_kv = ((slots, HKV, side, HEAD_DIM), jnp.bfloat16)
+    return (lambda q, k_pool, v_pool, tables, k_side, v_side, valid,
+            entry: paged_decode_attention(
+                q, k_pool, v_pool, tables, k_side, v_side, valid, entry,
+                groups=GROUPS, fold_scales=fold, interpret=False),
+            [((slots, HKV, GROUPS * width, HEAD_DIM), jnp.bfloat16),
+             pool, pool, ((slots, nb), jnp.int32), side_kv, side_kv,
+             ((slots, width, side), jnp.bool_), ((slots,), jnp.int32)])
+
+
+CASES = {
+    "flash-s1536": lambda: _flash(False),
+    "flash-s1536-causal": lambda: _flash(True),
+    "cross-decode-t250": _cross_decode,
+    # decode scan (W=1) and speculative verify (W=1+k, k=4)
+    "paged-bf16-fold-w1": lambda: _paged(False, True, 1, 4),
+    "paged-bf16-nofold-w1": lambda: _paged(False, False, 1, 4),
+    "paged-bf16-fold-w5": lambda: _paged(False, True, 5, 20),
+    "paged-bf16-nofold-w5": lambda: _paged(False, False, 5, 20),
+    "paged-int8-fold-w1": lambda: _paged(True, True, 1, 4),
+    "paged-int8-nofold-w1": lambda: _paged(True, False, 1, 4),
+    "paged-int8-fold-w5": lambda: _paged(True, True, 5, 20),
+    "paged-int8-nofold-w5": lambda: _paged(True, False, 5, 20),
+    # bench.py's llama rung: 256 slots, 64 steps per sync, int8 KV
+    "paged-int8-bench-s256": lambda: _paged(True, True, 1, 64,
+                                            slots=256, t_cap=1024),
+    # chunked-prefill extend: the chunk rides as the side buffer and
+    # the G*chunk query rows tile to fit VMEM (_row_tile)
+    "paged-bf16-extend-c256": lambda: _paged(False, False, 256, 256,
+                                             slots=4),
+    "paged-int8-extend-c64": lambda: _paged(True, False, 64, 64,
+                                            slots=4, t_cap=1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, shapes = CASES[case]()
+    args = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf[0], leaf[1], sharding=chip),
+        shapes, is_leaf=lambda leaf: isinstance(leaf, tuple))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_row_tile_stays_inside_vmem_budget():
+    from aiko_services_tpu.ops import paged_attention as pa
+    # a decode row fits whole; an extend's G*chunk rows are tiled
+    assert pa._row_tile(4, 40, HKV, BLOCK) == 4
+    rows = pa._row_tile(1024, 40, HKV, BLOCK)
+    assert rows < 1024 and 1024 % rows == 0 and rows % 8 == 0
+    assert rows * 40 * HKV * 128 * 4 <= pa._SCORES_VMEM_BUDGET
+    with pytest.raises(ValueError, match="VMEM"):
+        pa._row_tile(8, 1 << 16, HKV, BLOCK)
+
+
+class TestCompileCachePlacement:
+    def test_environment_places_the_cache(self, monkeypatch, tmp_path):
+        from aiko_services_tpu import compute
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compute.enable_compile_cache() == str(tmp_path)
+        # no directory is set in code when the environment names one
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_inside_the_checkout(self, monkeypatch):
+        from aiko_services_tpu import compute
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            checkout = os.path.dirname(os.path.dirname(
+                os.path.abspath(compute.__file__)))
+            expected = os.path.join(checkout, ".jax_cache")
+            assert compute.enable_compile_cache() == expected
+            assert compute.enable_compile_cache() == expected   # stable
+            assert jax.config.jax_compilation_cache_dir == expected
+        finally:
+            # the suite itself runs cache-free
+            jax.config.update("jax_compilation_cache_dir", before)
