@@ -17,7 +17,22 @@ from . import layers as L
 
 __all__ = ["LlamaConfig", "llama_init", "llama_axes", "llama_forward",
            "llama_forward_sp", "llama_decode_step", "llama_greedy_decode",
-           "llama_ffn", "init_llama_caches", "LLAMA_PRESETS"]
+           "llama_ffn", "init_llama_caches", "LLAMA_PRESETS",
+           "SCOPE_KV_VIEW", "SCOPE_ATTN_PROJ", "SCOPE_ATTN_CORE",
+           "SCOPE_MLP", "SCOPE_HEAD", "SCOPE_KV_MERGE"]
+
+
+# jax.named_scope regions of the serving programs (ISSUE 24): HLO
+# metadata only, never the computation.  A device trace charges each
+# operation to the region its root carries (PERF.md, "How a trace names
+# things"); the benchmark's region metrics read these names, so they
+# are part of its yardstick.  Nothing finer than these six.
+SCOPE_KV_VIEW = "aiko.kv_view"       # slot-major K and V views of the pool
+SCOPE_ATTN_PROJ = "aiko.attn_proj"   # attention's norm, q/k/v/o, rotary
+SCOPE_ATTN_CORE = "aiko.attn_core"   # scores, mask, softmax, weights x V
+SCOPE_MLP = "aiko.mlp"               # the MLP and its norm
+SCOPE_HEAD = "aiko.head"             # final norm, output head, argmax
+SCOPE_KV_MERGE = "aiko.kv_merge"     # the round's K and V into the pool
 
 
 @dataclass(frozen=True)
@@ -200,14 +215,19 @@ def llama_hidden(params, config: LlamaConfig, tokens, caches,
         mask = (k_pos <= q_pos)[None, None]
 
     new_caches = []
+    # attention goes through layers.mha, which Whisper shares: it is not
+    # split into SCOPE_ATTN_PROJ and SCOPE_ATTN_CORE here
     for layer, cache in zip(params["layers"], caches):
         attn_out, cache = _attention(
             layer, config, L.rms_norm(layer["ln_attn"], x), cos, sin,
             cache, position_offset, mask)
         x = x + attn_out
-        x = x + llama_ffn(layer, config, L.rms_norm(layer["ln_mlp"], x))
+        with jax.named_scope(SCOPE_MLP):
+            x = x + llama_ffn(layer, config,
+                              L.rms_norm(layer["ln_mlp"], x))
         new_caches.append(cache)
-    return L.rms_norm(params["ln_out"], x), new_caches
+    with jax.named_scope(SCOPE_HEAD):
+        return L.rms_norm(params["ln_out"], x), new_caches
 
 
 def llama_decode_step(params, config: LlamaConfig, tokens, caches,

@@ -30,7 +30,7 @@ from .journey import (                                      # noqa: F401
 from .ledger import (                                       # noqa: F401
     KVMemoryLedger, assert_ledger_clean, seed_ledger_leak,
 )
-from .profiler import PhaseProfiler, arm_trace              # noqa: F401
+from .profiler import PhaseProfiler, arm_trace, round_log   # noqa: F401
 from .flight import (                                       # noqa: F401
     DumpOnAlert, FLIGHT_TOPIC_SUFFIX, FlightLogHandler, FlightRecorder,
 )

@@ -1,10 +1,9 @@
 # Fleet health plane, part 2: the decode-round phase profiler
-# (ISSUE 11).
+# (ISSUE 11; a record of every round and the device trace's clock,
+# ISSUE 24).
 #
-# BENCH_r05 measured the decode round at 11.38 ms against a 5.64 ms
-# HBM roofline and could only call the difference "overhead".  This
-# module ATTRIBUTES it: ContinuousDecoder.pump() marks the boundary of
-# every phase of a serving round —
+# ContinuousDecoder.pump() tells a PhaseProfiler where every phase of
+# a serving round begins —
 #
 #   plan           host-side round planning (active mask, budgets,
 #                  cache fit)
@@ -13,28 +12,30 @@
 #                  program is the widened verify step)
 #   admit_dispatch bucketed prefill admits queued behind the scan
 #   extend_dispatch chunked-prefill extends queued behind the scan
-#   host_sync      the device_get wall — where the device actually
-#                  executes everything dispatched above (THIS is the
-#                  phase the HBM-bytes model explains)
+#   host_sync      the device_get wall: the host waits here while the
+#                  device executes everything dispatched above
 #   wave_resolve   resolving earlier rounds' deferred admit firsts
 #   deliver        walking emissions into callbacks / retirements
-#   other          whatever the marks did not cover (bookkeeping,
-#                  EWMA) — 1 - other/wall is the attribution fraction
-#                  the bench reports
+#   other          whatever no phase covered
 #
-# and a PhaseProfiler accumulates wall time per phase.  The mark API
-# costs one perf_counter read per boundary (~9 per round against
-# millisecond-scale rounds), so it is ALWAYS ON — the bench's
-# lat_llama_phase_* fields and the serving_phase_seconds_total registry
-# family read the same accumulators.
+# and the profiler keeps three things from that ONE set of boundaries
+# (enter() costs one perf_counter read, so it is ALWAYS ON):
 #
-# The HBM-bytes model rides the same phases: the decoder feeds each
-# round's modeled device bytes (weights + sized KV read for the scan,
-# prefill writes for admits/extends) into the phase that explains
-# them, so phase_stats() can report an implied GB/s per phase and the
-# roofline gap decomposes into "device streaming at X% of spec
-# bandwidth" vs "host-side dispatch/walk time" instead of one opaque
-# number.
+#   sums     wall seconds per phase (phase_stats(), the bench's
+#            lat_llama_phase_* fields, serving_phase_seconds_total)
+#   a ring   one plain tuple per committed round (ROUND_FIELDS), the
+#            newest RING_ROUNDS of them: a stalled round dissolves in
+#            a mean and stands out here.  round_log() hands the ring
+#            to a reader that holds no reference to the decoder.
+#   spans    jax.profiler.TraceAnnotations (SPAN_ROUND around the
+#            round, SPANS[phase] inside it), which record only while a
+#            profiler session runs and then sit on /host:CPU on the
+#            clock of the device planes: a trace can say which phase
+#            the device waited for.
+#
+# Host wall time says nothing about device bytes: no phase carries a
+# bandwidth.  What the device did inside host_sync is the trace's to
+# say (PERF.md, layers).
 #
 # Opt-in deep capture: arm_trace() opens a jax.profiler trace window
 # (XLA-level timeline) for `duration` seconds, armed by environment
@@ -46,16 +47,91 @@
 
 from __future__ import annotations
 
+import collections
 import os
 import time
+import weakref
 
 from .metrics import MetricsRegistry, default_registry
 
-__all__ = ["PhaseProfiler", "PHASES", "arm_trace", "trace_state"]
+__all__ = ["PhaseProfiler", "PHASES", "ROUND_FIELDS", "SPAN_ROUND", "SPANS",
+           "arm_trace", "round_log", "slow_round", "trace_state"]
 
 PHASES = ("plan", "scan_dispatch", "spec_verify", "admit_dispatch",
           "extend_dispatch", "host_sync", "wave_resolve", "deliver",
           "other")
+
+# the same phases as a profiler session sees them: admit and extend are
+# one span, and so are the wave resolve and the walk
+SPAN_ROUND = "aiko.decoder.round"
+SPANS = {"plan": "aiko.decoder.plan",
+         "scan_dispatch": "aiko.decoder.dispatch_step",
+         "spec_verify": "aiko.decoder.dispatch_step",
+         "admit_dispatch": "aiko.decoder.dispatch_prefill",
+         "extend_dispatch": "aiko.decoder.dispatch_prefill",
+         "host_sync": "aiko.decoder.sync",
+         "wave_resolve": "aiko.decoder.deliver",
+         "deliver": "aiko.decoder.deliver"}
+
+# one ring record, a plain tuple in this order: `rounds` is the decoder's
+# stats["rounds"] at commit, `t0` the round's begin on perf_counter,
+# `gap_s` the time since the previous committed round ended, and
+# `idle_before` says that the decoder had nothing to do at that end
+ROUND_FIELDS = ("seq", "rounds", "t0", "gap_s", "idle_before", "wall_s") \
+    + PHASES + ("num_steps", "slots", "prefill_tokens", "pending")
+RING_ROUNDS = 8192              # over ten minutes of 100 ms rounds
+
+# a round is slow when it and the gap before it took longer than both
+SLOW_ROUND_FLOOR_S = 0.5
+SLOW_ROUND_FACTOR = 5.0
+
+_profilers: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
+
+
+def round_log(name: str | None = None) -> list:
+    """The ring of the profiler registered under `name`, oldest record
+    first; with no name, of the one profiler the process has."""
+    if name is None:
+        if len(_profilers) != 1:
+            raise LookupError(f"round_log() needs a name: the process has "
+                              f"profilers {sorted(_profilers)}")
+        name = next(iter(_profilers))
+    profiler = _profilers.get(name)
+    if profiler is None:
+        raise LookupError(f"no profiler named {name!r}; "
+                          f"there are {sorted(_profilers)}")
+    return list(profiler.ring)
+
+
+def slow_round(record: tuple, mean_s: float | None) -> str | None:
+    """What to log about a round that stood still, or None: its
+    sequence number and every phase's milliseconds.  `mean_s` is the
+    running mean round BEFORE this one; a round that follows an idle
+    decoder is nobody's stall."""
+    seq, _, _, gap_s, idle_before, wall_s = record[:6]
+    took = gap_s + wall_s
+    if idle_before or mean_s is None or took <= max(
+            SLOW_ROUND_FLOOR_S, SLOW_ROUND_FACTOR * mean_s):
+        return None
+    phases = " ".join(f"{phase}={1e3 * seconds:.1f}" for phase, seconds
+                      in zip(PHASES, record[6:6 + len(PHASES)]))
+    return (f"slow round seq={seq}: {1e3 * took:.1f} ms against a mean of "
+            f"{1e3 * mean_s:.1f} ms (gap before it {1e3 * gap_s:.1f} ms); "
+            f"phase ms: {phases}")
+
+
+_annotation = None
+
+
+def _annotation_class():
+    """jax.profiler.TraceAnnotation, imported at the first round and
+    not with this module."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
 
 # -- jax.profiler capture window ---------------------------------------------
 
@@ -116,17 +192,20 @@ class PhaseProfiler:
 
     Usage (the pump loop's shape):
 
-        profiler.begin_round()
-        ...planning...          ; profiler.mark("plan")
-        ...dispatch scan...     ; profiler.mark("scan_dispatch")
+        profiler.begin_round()             # the round begins in "plan"
+        ...planning...
+        profiler.enter("scan_dispatch")    # "plan" ends here
+        ...dispatch scan...
+        profiler.enter("admit_dispatch")
         ...
-        profiler.commit_round()    # or abandon_round() for idle ticks
+        profiler.commit_round(...)  # or abandon_round() for idle ticks
 
-    mark(name) charges the time since the previous boundary to `name`;
-    commit folds the staged marks into the accumulators and charges
-    the unmarked remainder to "other".  abandon_round() discards the
-    staged marks — idle pump ticks must not dilute the attribution the
-    bench asserts on."""
+    enter(name) charges the time since the previous boundary to the
+    phase that was open and opens `name`, as a sum, as a field of the
+    round's record and as a span of a profiler session; commit folds
+    the staged phases into the accumulators and appends the record to
+    the ring.  abandon_round() discards them — idle pump ticks must not
+    dilute the attribution, and leave no record."""
 
     def __init__(self, name: str = "decoder",
                  registry: MetricsRegistry | None = None):
@@ -134,44 +213,62 @@ class PhaseProfiler:
         self.rounds = 0
         self.wall_s = 0.0
         self.phase_s = {phase: 0.0 for phase in PHASES}
-        self.phase_bytes = {phase: 0 for phase in PHASES}
+        self.ring: collections.deque = collections.deque(maxlen=RING_ROUNDS)
+        self.idle = True        # nothing was live when the last round ended
+        self._seq = 0
         self._t0 = 0.0
         self._last = 0.0
-        self._staged: list = []
-        self._staged_bytes: dict = {}
+        self._end = None        # when the last committed round ended
+        self._phase = "plan"
+        self._staged = dict.fromkeys(PHASES, 0.0)
+        self._round_span = self._span = self._span_name = None
         registry = registry or default_registry()
-        labels = {"decoder": name}
         self._seconds_counters = {
             phase: registry.counter(
                 "serving_phase_seconds_total",
                 "decode-round wall seconds by phase",
-                labels={**labels, "phase": phase})
+                labels={"decoder": name, "phase": phase})
             for phase in PHASES}
-        self._bytes_counters = {
-            phase: registry.counter(
-                "serving_phase_bytes_total",
-                "modeled device HBM bytes by phase",
-                labels={**labels, "phase": phase})
-            for phase in PHASES}
+        _profilers[name] = self
 
-    # -- the hot-path mark API (one perf_counter read each) ----------------
+    # -- the hot-path API (one perf_counter read each) ---------------------
     def begin_round(self) -> None:
+        """Open the round; it begins in "plan"."""
+        self._close_spans()             # of a round that raised half way
+        self._round_span = _annotation_class()(SPAN_ROUND)
+        self._round_span.__enter__()
         self._t0 = self._last = time.perf_counter()
-        self._staged = []
-        self._staged_bytes = {}
+        for name in PHASES:
+            self._staged[name] = 0.0
+        self._open("plan")
 
-    def mark(self, phase: str) -> None:
+    def enter(self, phase: str) -> None:
         now = time.perf_counter()
-        self._staged.append((phase, now - self._last))
+        self._staged[self._phase] += now - self._last
         self._last = now
+        self._open(phase)
 
-    def add_bytes(self, phase: str, nbytes: int) -> None:
-        self._staged_bytes[phase] = \
-            self._staged_bytes.get(phase, 0) + int(nbytes)
+    def _open(self, phase: str) -> None:
+        self._phase = phase
+        span_name = SPANS.get(phase)
+        if span_name != self._span_name:
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+            self._span = None
+            if span_name is not None:
+                self._span = _annotation_class()(span_name)
+                self._span.__enter__()
+            self._span_name = span_name
+
+    def _close_spans(self) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        if self._round_span is not None:
+            self._round_span.__exit__(None, None, None)
+        self._round_span = self._span = self._span_name = None
 
     def abandon_round(self) -> None:
-        self._staged = []
-        self._staged_bytes = {}
+        self._close_spans()
         # idle ticks still advance the capture window: a trace armed
         # by an alert must STOP on schedule even if decode work
         # ceases right after the breach (load shed/collapsed) —
@@ -179,38 +276,39 @@ class PhaseProfiler:
         # never finalizes
         _trace_tick()
 
-    def commit_round(self) -> None:
-        total = time.perf_counter() - self._t0
-        marked = 0.0
-        for phase, dt in self._staged:
-            self.phase_s[phase] = self.phase_s.get(phase, 0.0) + dt
-            counter = self._seconds_counters.get(phase)
-            if counter is not None:
-                counter.inc(dt)
-            marked += dt
-        other = max(0.0, total - marked)
-        self.phase_s["other"] += other
-        self._seconds_counters["other"].inc(other)
-        for phase, nbytes in self._staged_bytes.items():
-            self.phase_bytes[phase] = \
-                self.phase_bytes.get(phase, 0) + nbytes
-            counter = self._bytes_counters.get(phase)
-            if counter is not None:
-                counter.inc(nbytes)
+    def commit_round(self, rounds: int = 0, num_steps: int = 0,
+                     slots: int = 0, prefill_tokens: int = 0,
+                     pending: int = 0) -> tuple:
+        """Fold the round into the sums and the ring; returns its
+        record (ROUND_FIELDS)."""
+        now = time.perf_counter()
+        staged = self._staged
+        staged[self._phase] += now - self._last
+        self._close_spans()
+        total = now - self._t0
+        for phase, dt in staged.items():
+            if dt:
+                self.phase_s[phase] += dt
+                self._seconds_counters[phase].inc(dt)
         self.rounds += 1
         self.wall_s += total
-        self._staged = []
-        self._staged_bytes = {}
+        self._seq += 1
+        record = (self._seq, rounds, self._t0,
+                  0.0 if self._end is None else self._t0 - self._end,
+                  self.idle, total, *staged.values(),
+                  num_steps, slots, prefill_tokens, pending)
+        self.ring.append(record)
+        self._end, self.idle = now, False
         _trace_tick()
+        return record
 
     # -- reporting ----------------------------------------------------------
     def reset(self) -> None:
+        """Zero the sums; the ring keeps its records."""
         self.rounds = 0
         self.wall_s = 0.0
         for phase in self.phase_s:
             self.phase_s[phase] = 0.0
-        for phase in self.phase_bytes:
-            self.phase_bytes[phase] = 0
 
     def attributed_fraction(self) -> float:
         """Fraction of committed round wall time carrying a NAMED
@@ -221,26 +319,21 @@ class PhaseProfiler:
 
     def phase_stats(self) -> dict:
         """{"rounds", "wall_s", "attributed_frac", "phases": {name:
-        {"s", "frac", "ms_per_round", "bytes", "gb_per_s"?}}} — phases
-        with no time AND no bytes are omitted (speculative vs plain
-        mode each uses its own dispatch phase)."""
+        {"s", "frac", "ms_per_round"}}} — phases with no time are
+        omitted (speculative vs plain mode each uses its own dispatch
+        phase)."""
         phases = {}
         for phase in PHASES:
             seconds = self.phase_s[phase]
-            nbytes = self.phase_bytes[phase]
-            if seconds <= 0.0 and nbytes <= 0:
+            if seconds <= 0.0:
                 continue
-            entry = {
+            phases[phase] = {
                 "s": seconds,
                 "frac": seconds / self.wall_s if self.wall_s > 0
                 else 0.0,
                 "ms_per_round": seconds * 1000.0 / self.rounds
                 if self.rounds else 0.0,
-                "bytes": nbytes,
             }
-            if nbytes and seconds > 0:
-                entry["gb_per_s"] = nbytes / seconds / 1e9
-            phases[phase] = entry
         return {"rounds": self.rounds, "wall_s": self.wall_s,
                 "attributed_frac": self.attributed_fraction(),
                 "phases": phases}

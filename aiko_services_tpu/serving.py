@@ -57,8 +57,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .models import layers as L
-from .models.llama import LlamaConfig, llama_ffn
+from .models.llama import (SCOPE_ATTN_CORE, SCOPE_ATTN_PROJ, SCOPE_HEAD,
+                           SCOPE_MLP, LlamaConfig, llama_ffn)
+from .observe.profiler import ROUND_FIELDS, PhaseProfiler, slow_round
 from .utils import get_logger
+
+_WALL_S = ROUND_FIELDS.index("wall_s")    # of a PhaseProfiler round record
 
 __all__ = ["ContinuousDecoder", "DecodeRequest", "PrefixKVCache",
            "prefix_chain_keys", "check_block_geometry",
@@ -1189,44 +1193,51 @@ def _grouped_block_attention(layer, config: LlamaConfig, x, cos, sin,
     dtype (they are one round wide — quantizing them would save
     nothing and cost an int8 round-trip every step)."""
     num_heads, num_kv = config.num_heads, config.num_kv_heads
-    q, k, v = _project_qkv(layer, config, x)
-    q = L.apply_rope(q, cos, sin, lengths)
-    k = L.apply_rope(k, cos, sin, lengths)
-    k_side = jax.lax.dynamic_update_slice_in_dim(k_side, k, write_index,
-                                                 axis=2)
-    v_side = jax.lax.dynamic_update_slice_in_dim(v_side, v, write_index,
-                                                 axis=2)
+    with jax.named_scope(SCOPE_ATTN_PROJ):
+        q, k, v = _project_qkv(layer, config, x)
+        q = L.apply_rope(q, cos, sin, lengths)
+        k = L.apply_rope(k, cos, sin, lengths)
+        k_side = jax.lax.dynamic_update_slice_in_dim(k_side, k,
+                                                     write_index, axis=2)
+        v_side = jax.lax.dynamic_update_slice_in_dim(v_side, v,
+                                                     write_index, axis=2)
 
     slots_n, num_q, head_dim = q.shape[0], q.shape[2], q.shape[3]
     group = num_heads // num_kv
-    q_grouped = q.reshape(slots_n, num_kv, group, num_q, head_dim)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-    k_main, k_fold = _kv_planes(k_cache, x.dtype)
-    v_main, v_fold = _kv_planes(v_cache, x.dtype)
-    main_t = k_main.shape[2]
-    main_valid = (jnp.arange(main_t)[None] <
-                  entry_lengths[:, None])[:, None, None, None]
-    scores_main = jnp.einsum("skgqd,sktd->skgqt", q_grouped, k_main,
-                             preferred_element_type=jnp.float32) * scale
-    if k_fold is not None:
-        scores_main = scores_main * k_fold[:, :, None, None, :]
-    scores_side = jnp.einsum("skgqd,sktd->skgqt", q_grouped, k_side,
-                             preferred_element_type=jnp.float32) * scale
-    scores = jnp.concatenate(
-        [jnp.where(main_valid, scores_main, -1e30),
-         jnp.where(side_valid, scores_side, -1e30)], axis=-1)
-    weights = jax.nn.softmax(scores, axis=-1)
-    w_main = weights[..., :main_t]
-    if v_fold is not None:
-        w_main = w_main * v_fold[:, :, None, None, :]
-    out = jnp.einsum("skgqt,sktd->skgqd", w_main.astype(v_main.dtype),
-                     v_main, preferred_element_type=jnp.float32) + \
-        jnp.einsum("skgqt,sktd->skgqd",
-                   weights[..., main_t:].astype(v_side.dtype), v_side,
-                   preferred_element_type=jnp.float32)
-    out = out.reshape(slots_n, num_heads, num_q, head_dim).astype(x.dtype)
-    return (L.linear(layer["attn"]["o"], L._merge_heads(out)),
-            k_side, v_side)
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        q_grouped = q.reshape(slots_n, num_kv, group, num_q, head_dim)
+        scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
+        k_main, k_fold = _kv_planes(k_cache, x.dtype)
+        v_main, v_fold = _kv_planes(v_cache, x.dtype)
+        main_t = k_main.shape[2]
+        main_valid = (jnp.arange(main_t)[None] <
+                      entry_lengths[:, None])[:, None, None, None]
+        scores_main = jnp.einsum(
+            "skgqd,sktd->skgqt", q_grouped, k_main,
+            preferred_element_type=jnp.float32) * scale
+        if k_fold is not None:
+            scores_main = scores_main * k_fold[:, :, None, None, :]
+        scores_side = jnp.einsum(
+            "skgqd,sktd->skgqt", q_grouped, k_side,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.concatenate(
+            [jnp.where(main_valid, scores_main, -1e30),
+             jnp.where(side_valid, scores_side, -1e30)], axis=-1)
+        weights = jax.nn.softmax(scores, axis=-1)
+        w_main = weights[..., :main_t]
+        if v_fold is not None:
+            w_main = w_main * v_fold[:, :, None, None, :]
+        out = jnp.einsum("skgqt,sktd->skgqd",
+                         w_main.astype(v_main.dtype), v_main,
+                         preferred_element_type=jnp.float32) + \
+            jnp.einsum("skgqt,sktd->skgqd",
+                       weights[..., main_t:].astype(v_side.dtype), v_side,
+                       preferred_element_type=jnp.float32)
+        out = out.reshape(slots_n, num_heads, num_q,
+                          head_dim).astype(x.dtype)
+    with jax.named_scope(SCOPE_ATTN_PROJ):
+        return (L.linear(layer["attn"]["o"], L._merge_heads(out)),
+                k_side, v_side)
 
 
 def _slot_attention_block(layer, config: LlamaConfig, x, cos, sin,
@@ -1346,14 +1357,18 @@ def _token_block_argmax(params, config: LlamaConfig, token_block,
     the plain decode step and 1 + speculate_k for the verify step."""
     x = L.embedding(params["embed"], token_block).astype(config.dtype)
     for i, layer in enumerate(params["layers"]):
-        x = x + attend(i, layer, L.rms_norm(layer["ln_attn"], x))
-        normed = L.rms_norm(layer["ln_mlp"], x)
-        # dense SwiGLU or MoE per the config — MoE llama serves
-        # through the same continuous-batching step
-        x = x + llama_ffn(layer, config, normed)
-    x = L.rms_norm(params["ln_out"], x)
-    logits = L.linear_logits(params["lm_head"], x)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope(SCOPE_ATTN_PROJ):
+            normed = L.rms_norm(layer["ln_attn"], x)
+        x = x + attend(i, layer, normed)
+        with jax.named_scope(SCOPE_MLP):
+            normed = L.rms_norm(layer["ln_mlp"], x)
+            # dense SwiGLU or MoE per the config — MoE llama serves
+            # through the same continuous-batching step
+            x = x + llama_ffn(layer, config, normed)
+    with jax.named_scope(SCOPE_HEAD):
+        x = L.rms_norm(params["ln_out"], x)
+        logits = L.linear_logits(params["lm_head"], x)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _build_step(config: LlamaConfig):
@@ -1845,6 +1860,9 @@ class ContinuousDecoder:
         self.logger = get_logger(f"serving.{name}")
         self.on_idle = None          # hook: fires when the last slot
                                      # retires and nothing is pending
+        self.on_token = None         # hook: on_token(request_id, slot,
+                                     # token, now) for every token as it
+                                     # is delivered, before retirement
 
         # paged KV (ISSUE 15): the slot caches become ONE refcounted
         # block pool plus per-slot int32 block tables — a prefix hit
@@ -2038,16 +2056,14 @@ class ContinuousDecoder:
         # decode-scan emissions vs prompt tokens prefilled — the
         # overhead ISSUE 7 moves off the decode round is exactly their
         # ratio.
-        # decode-round phase profiler (ISSUE 11): every pump round's
+        # decode-round phase profiler (ISSUE 11, 24): every pump round's
         # wall time attributed to named phases (plan / scan dispatch /
         # admit+extend dispatch / host sync / wave resolve / deliver),
-        # with the modeled HBM bytes charged to the phase that explains
-        # them — the roofline gap decomposes instead of being one
-        # opaque overhead number.  Always on: the mark API is one
-        # perf_counter read per boundary.
+        # as sums, as one record a round in a bounded ring, and as
+        # spans of a profiler session.  Always on: one perf_counter
+        # read per boundary.
         from .observe.journey import JourneyLog
         from .observe.metrics import MirroredStats, default_registry
-        from .observe.profiler import PhaseProfiler
         self.profiler = PhaseProfiler(name)
         self._registry = registry or default_registry()
         # request journeys + mergeable SLO sketches (ISSUE 12): every
@@ -2726,14 +2742,6 @@ class ContinuousDecoder:
                 jnp.asarray(slots + pad_slots, jnp.int32),
                 jnp.asarray(valid), jnp.asarray(finish_arr),
                 jnp.asarray(final_idx))
-        # HBM model for the extend program: weight stream + per-row
-        # prefix read (dequantize up to offset) + chunk write
-        row_bytes = self._kv_bytes_per_t // self.max_slots
-        self.profiler.add_bytes(
-            "extend_dispatch",
-            self._param_bytes + sum(
-                (offset + chunk) * row_bytes
-                for _, _, offset, _ in batch))
         wave = []
         for j, (slot, request, offset, finish) in enumerate(batch):
             new_pos = len(request.prompt) if finish else offset + chunk
@@ -3238,7 +3246,6 @@ class ContinuousDecoder:
         # the copy writes t_write rows of K+V per layer — bytes, the
         # whole point: no weight stream, no FLOPs
         copy_bytes = t_write * self._kv_bytes_per_t // self.max_slots
-        self.profiler.add_bytes("admit_dispatch", copy_bytes)
         self.stats["prefix_copy_bytes"] += copy_bytes
         request.slot = slot
         request.prefilling = True
@@ -3281,7 +3288,6 @@ class ContinuousDecoder:
             self._context = _paged_ctx_fn_for(t_write)(
                 self._context, jnp.asarray(slot, jnp.int32),
                 jnp.asarray(ctx))
-            self.profiler.add_bytes("admit_dispatch", t_write * 4)
         request.slot = slot
         request.prefilling = True
         request.prefill_pos = request.prefix_hit
@@ -3448,14 +3454,6 @@ class ContinuousDecoder:
         # with its first token OWED; the stashed wave resolves it at
         # the NEXT round's sync, by which point the admit program has
         # run in the gap between scans.
-        # HBM model for the admit program (executes in the sync gap
-        # behind the scan; bytes attributed to the dispatching phase):
-        # one weight stream plus the quantized/raw K+V rows written
-        # for `width` slots over `bucket` positions
-        self.profiler.add_bytes(
-            "admit_dispatch",
-            self._param_bytes +
-            width * bucket * self._kv_bytes_per_t // self.max_slots)
         wave = []
         admit_t = time.monotonic()
         for j, request in enumerate(chunk):
@@ -3596,9 +3594,11 @@ class ContinuousDecoder:
             if self.idle:
                 self._drain_finish()
         self._round_prefill_tokens = 0
+        # ONE set of phase boundaries: each profiler.enter() below is a
+        # sum, a field of the round's record and (while a profiler
+        # session runs) a span on the device trace's clock
         profiler = self.profiler
-        profiler.begin_round()
-        round_start = time.perf_counter()
+        profiler.begin_round()                    # the round opens in "plan"
         # mid-prefill slots hold a slot but don't decode yet
         active = self._active_np                  # preallocated (hot)
         any_active = False
@@ -3610,6 +3610,7 @@ class ContinuousDecoder:
         waves_due = self._admit_waves
         self._admit_waves = []
         scanned = False
+        num_steps = scanned_slots = 0
         if any_active:
             occupied = [s for s in range(self.max_slots) if active[s]]
             num_steps, required_t, budgets = self._round_plan(occupied)
@@ -3623,9 +3624,11 @@ class ContinuousDecoder:
             # token) needs no decode: masking it out of the scan keeps
             # its discarded emissions out of useful_steps
             scan_active = active & (budgets > 0)
-            scanned = bool(scan_active.any())
-        profiler.mark("plan")
+            scanned_slots = int(scan_active.sum())
+            scanned = scanned_slots > 0
         if scanned:
+            profiler.enter("spec_verify" if self.speculate_k
+                           else "scan_dispatch")
             self.stats["rounds"] += 1
             self.stats["occupancy_sum"] += float(active.mean())
             decode_start = time.perf_counter()
@@ -3669,16 +3672,15 @@ class ContinuousDecoder:
                     jnp.array(scan_active), jnp.array(budgets),
                     self._k, self._v, num_steps=num_steps, eos=eos)
             self.stats["steps"] += num_steps
-            profiler.mark("spec_verify" if self.speculate_k
-                          else "scan_dispatch")
         # prefill rides BETWEEN decode scans: dispatched after the scan,
         # it runs on device while the host below waits out the scan
         # sync and walks the emissions — off the decode critical path,
         # rationed by prefill_budget
+        profiler.enter("admit_dispatch")
         self._admit_pending()
-        profiler.mark("admit_dispatch")
+        profiler.enter("extend_dispatch")
         self._advance_prefills()
-        profiler.mark("extend_dispatch")
+        profiler.enter("host_sync")
         if self._round_prefill_tokens > \
                 self.stats["round_prefill_tokens_max"]:
             self.stats["round_prefill_tokens_max"] = \
@@ -3699,12 +3701,9 @@ class ContinuousDecoder:
             round_bytes = num_steps * (
                 self._param_bytes + self._kv_bytes_per_t * self._cache_t)
             self.stats["bytes_moved"] += round_bytes
-            # the scan's device bytes execute under the sync wall —
-            # host_sync is the phase whose duration they explain
-            profiler.add_bytes("host_sync", round_bytes)
         elif wave_firsts:
             wave_firsts = jax.device_get(wave_firsts)
-        profiler.mark("host_sync")
+        profiler.enter("wave_resolve")
         # resolve deferred admits from EARLIER rounds: their prefill
         # programs ran before this round's scan on the in-order device
         # stream, so the fetch never waits on fresh work
@@ -3714,8 +3713,8 @@ class ContinuousDecoder:
                 if self._slots[request.slot] is request and \
                         not request.generated:
                     self._deliver(request.slot, int(firsts[j]), now)
-        profiler.mark("wave_resolve")
         if scanned:
+            profiler.enter("deliver")
             if self.speculate_k:
                 self._deliver_spec(emitted, emit_mask, occupied,
                                    num_steps, now)
@@ -3738,16 +3737,22 @@ class ContinuousDecoder:
                         self._deliver(slot, int(emitted[k, slot]), now)
                         delivered += 1
                 self.stats["tokens_decode"] += delivered
-            profiler.mark("deliver")
         if scanned or wave_firsts or self._round_prefill_tokens:
             # working rounds only: idle pump ticks would drag the EWMA
             # toward the timer period and break the admission estimate
             # (and would dilute the profiler's phase attribution the
             # same way — idle ticks are abandoned, not committed)
-            elapsed = time.perf_counter() - round_start
+            record = profiler.commit_round(
+                self.stats["rounds"], num_steps if scanned else 0,
+                scanned_slots, self._round_prefill_tokens,
+                len(self._pending))
+            # what remains of a stall in a run nobody traced
+            slow = slow_round(record, self._round_ewma)
+            if slow is not None:
+                self.logger.warning("%s", slow)
+            elapsed = record[_WALL_S]
             self._round_ewma = elapsed if self._round_ewma is None \
                 else 0.7 * self._round_ewma + 0.3 * elapsed
-            profiler.commit_round()
         else:
             profiler.abandon_round()
             if self.pool is not None and self.idle:
@@ -3756,8 +3761,10 @@ class ContinuousDecoder:
                 # only ever fires on an idle tick — never inside a
                 # serving window
                 self.pool.maybe_shrink()
-        if self.idle and self.on_idle is not None:
-            self.on_idle()
+        if self.idle:
+            profiler.idle = True      # the next round follows an idle decoder
+            if self.on_idle is not None:
+                self.on_idle()
 
     def _deliver_spec(self, emitted, emit_mask, occupied,
                       num_steps: int, now: float) -> None:
@@ -3850,6 +3857,12 @@ class ContinuousDecoder:
             journey.token(now)
         request.generated.append(token)
         request.last_time = now
+        if self.on_token is not None:
+            try:
+                self.on_token(request.request_id, slot, token, now)
+            except Exception:
+                self.logger.exception("on_token failed for %s",
+                                      request.request_id)
         if self._finished(request, token):
             self._retire(slot)
 

@@ -46,7 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .models import layers as L
-from .models.llama import LlamaConfig, llama_ffn
+from .models.llama import (SCOPE_ATTN_CORE, SCOPE_ATTN_PROJ, SCOPE_HEAD,
+                           SCOPE_KV_MERGE, SCOPE_KV_VIEW, SCOPE_MLP,
+                           LlamaConfig, llama_ffn)
 from .utils import get_logger
 
 __all__ = ["BlockPool"]
@@ -484,23 +486,26 @@ def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
     from .ops.paged_attention import paged_decode_attention
     from .serving import _project_qkv
     num_heads, num_kv = config.num_heads, config.num_kv_heads
-    q, k, v = _project_qkv(layer, config, x)
-    q = L.apply_rope(q, cos, sin, lengths)
-    k = L.apply_rope(k, cos, sin, lengths)
-    k_side = jax.lax.dynamic_update_slice_in_dim(k_side, k,
-                                                 write_index, axis=2)
-    v_side = jax.lax.dynamic_update_slice_in_dim(v_side, v,
-                                                 write_index, axis=2)
+    with jax.named_scope(SCOPE_ATTN_PROJ):
+        q, k, v = _project_qkv(layer, config, x)
+        q = L.apply_rope(q, cos, sin, lengths)
+        k = L.apply_rope(k, cos, sin, lengths)
+        k_side = jax.lax.dynamic_update_slice_in_dim(k_side, k,
+                                                     write_index, axis=2)
+        v_side = jax.lax.dynamic_update_slice_in_dim(v_side, v,
+                                                     write_index, axis=2)
     slots_n, num_q, head_dim = q.shape[0], q.shape[2], q.shape[3]
     group = num_heads // num_kv
-    q_grouped = q.reshape(slots_n, num_kv, group * num_q, head_dim)
-    out = paged_decode_attention(q_grouped, k_pool, v_pool, tables,
-                                 k_side, v_side, side_valid,
-                                 entry_lengths, groups=group)
-    out = out.reshape(slots_n, num_heads, num_q,
-                      head_dim).astype(x.dtype)
-    return (L.linear(layer["attn"]["o"], L._merge_heads(out)),
-            k_side, v_side)
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        q_grouped = q.reshape(slots_n, num_kv, group * num_q, head_dim)
+        out = paged_decode_attention(q_grouped, k_pool, v_pool, tables,
+                                     k_side, v_side, side_valid,
+                                     entry_lengths, groups=group)
+        out = out.reshape(slots_n, num_heads, num_q,
+                          head_dim).astype(x.dtype)
+    with jax.named_scope(SCOPE_ATTN_PROJ):
+        return (L.linear(layer["attn"]["o"], L._merge_heads(out)),
+                k_side, v_side)
 
 
 def _kernel_attention_block(tables, layer, config: LlamaConfig, x,
@@ -581,8 +586,10 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
             k_caches = v_caches = None
             cap_tables = _table_cap(tables, block_tokens, t_cap)
         else:
-            k_caches = _gather_views(k_pools, tables, t_cap)
-            v_caches = _gather_views(v_pools, tables, t_cap)
+            # once a round, before the scan: the views are read-only in it
+            with jax.named_scope(SCOPE_KV_VIEW):
+                k_caches = _gather_views(k_pools, tables, t_cap)
+                v_caches = _gather_views(v_pools, tables, t_cap)
         entry_lengths = lengths
         entry_active = active
         slots_n = tokens.shape[0]
@@ -633,14 +640,18 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
         # as the dense merge's garbage rows.  Slots inactive at round
         # entry drop entirely (their stale lengths point into prompt
         # regions their extends are writing).
-        positions = entry_lengths[:, None] + jnp.arange(num_steps)[None]
-        live = entry_active[:, None]
-        k_pools = _paged_scatter(k_pools, tables, positions, live,
-                                 k_sides, isinstance(k_pools[0], dict),
-                                 block_tokens)
-        v_pools = _paged_scatter(v_pools, tables, positions, live,
-                                 v_sides, isinstance(v_pools[0], dict),
-                                 block_tokens)
+        with jax.named_scope(SCOPE_KV_MERGE):
+            positions = entry_lengths[:, None] + \
+                jnp.arange(num_steps)[None]
+            live = entry_active[:, None]
+            k_pools = _paged_scatter(k_pools, tables, positions, live,
+                                     k_sides,
+                                     isinstance(k_pools[0], dict),
+                                     block_tokens)
+            v_pools = _paged_scatter(v_pools, tables, positions, live,
+                                     v_sides,
+                                     isinstance(v_pools[0], dict),
+                                     block_tokens)
         return (emitted, emitted_active, tokens, lengths,
                 k_pools, v_pools)
 
@@ -748,26 +759,30 @@ def _paged_admit_fn_for(config: LlamaConfig, bucket: int, width: int,
             jax.tree_util.tree_leaves(k_pools[0])[0].shape[0]
         caches = init_llama_caches(config, width, bucket)
         hidden, caches = llama_hidden(params, config, prompts, caches)
-        idx = jnp.maximum(true_lens - 1, 0)
-        last_hidden = jnp.take_along_axis(
-            hidden, idx[:, None, None], axis=1)[:, 0]
-        last = L.linear_logits(params["lm_head"], last_hidden)
-        firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        with jax.named_scope(SCOPE_HEAD):
+            idx = jnp.maximum(true_lens - 1, 0)
+            last_hidden = jnp.take_along_axis(
+                hidden, idx[:, None, None], axis=1)[:, 0]
+            last = L.linear_logits(params["lm_head"], last_hidden)
+            firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
         nbb = tables_rows.shape[1]
         padded_t = nbb * block_tokens
         dest = jnp.where(valid[:, None], tables_rows, num_total)
         pad = padded_t - bucket
-        for i, cache in enumerate(caches):
-            k_rows, v_rows = cache["k"], cache["v"]
-            if pad:
-                spec = [(0, 0), (0, 0), (0, pad), (0, 0)]
-                k_rows = jnp.pad(k_rows, spec)
-                v_rows = jnp.pad(v_rows, spec)
-            if kv_int8:
-                k_rows = L.quantize_kv_cache(k_rows)
-                v_rows = L.quantize_kv_cache(v_rows)
-            k_pools[i] = L.write_paged_blocks(k_pools[i], dest, k_rows)
-            v_pools[i] = L.write_paged_blocks(v_pools[i], dest, v_rows)
+        with jax.named_scope(SCOPE_KV_MERGE):
+            for i, cache in enumerate(caches):
+                k_rows, v_rows = cache["k"], cache["v"]
+                if pad:
+                    spec = [(0, 0), (0, 0), (0, pad), (0, 0)]
+                    k_rows = jnp.pad(k_rows, spec)
+                    v_rows = jnp.pad(v_rows, spec)
+                if kv_int8:
+                    k_rows = L.quantize_kv_cache(k_rows)
+                    v_rows = L.quantize_kv_cache(v_rows)
+                k_pools[i] = L.write_paged_blocks(k_pools[i], dest,
+                                                  k_rows)
+                v_pools[i] = L.write_paged_blocks(v_pools[i], dest,
+                                                  v_rows)
         tokens = tokens.at[slots].set(
             jnp.where(valid, firsts, tokens[slots]))
         lengths = lengths.at[slots].set(
@@ -846,71 +861,85 @@ def _paged_extend_fn_for(config: LlamaConfig, chunk_len: int,
                     row, kv, (0, off, 0)))(rows, chunk_kv, offs)
 
         for i, layer in enumerate(params["layers"]):
-            normed = L.rms_norm(layer["ln_attn"], x)
-            q = L._split_heads(L.linear(layer["attn"]["q"], normed),
-                               num_heads)
-            k = L._split_heads(L.linear(layer["attn"]["k"], normed),
-                               num_kv)
-            v = L._split_heads(L.linear(layer["attn"]["v"], normed),
-                               num_kv)
-            q = L.apply_rope(q, cos, sin, offsets)
-            k = L.apply_rope(k, cos, sin, offsets)
+            with jax.named_scope(SCOPE_ATTN_PROJ):
+                normed = L.rms_norm(layer["ln_attn"], x)
+                q = L._split_heads(L.linear(layer["attn"]["q"], normed),
+                                   num_heads)
+                k = L._split_heads(L.linear(layer["attn"]["k"], normed),
+                                   num_kv)
+                v = L._split_heads(L.linear(layer["attn"]["v"], normed),
+                                   num_kv)
+                q = L.apply_rope(q, cos, sin, offsets)
+                k = L.apply_rope(k, cos, sin, offsets)
             if kernel:
                 from .ops.paged_attention import \
                     paged_decode_attention
-                q_grouped = q.reshape(q.shape[0], num_kv,
-                                      group * chunk_len,
-                                      config.head_dim)
-                out = paged_decode_attention(
-                    q_grouped, k_pools[i], v_pools[i], cap_tables,
-                    k, v, tri, offsets, groups=group,
-                    fold_scales=False)
-                out = out.reshape(out.shape[0], num_heads, chunk_len,
-                                  config.head_dim).astype(x.dtype)
+                with jax.named_scope(SCOPE_ATTN_CORE):
+                    q_grouped = q.reshape(q.shape[0], num_kv,
+                                          group * chunk_len,
+                                          config.head_dim)
+                    out = paged_decode_attention(
+                        q_grouped, k_pools[i], v_pools[i], cap_tables,
+                        k, v, tri, offsets, groups=group,
+                        fold_scales=False)
+                    out = out.reshape(out.shape[0], num_heads,
+                                      chunk_len,
+                                      config.head_dim).astype(x.dtype)
             else:
-                gathered_k = _slice_time(
-                    L.gather_paged_kv(k_pools[i], tables_rows), t_cap)
-                gathered_v = _slice_time(
-                    L.gather_paged_kv(v_pools[i], tables_rows), t_cap)
+                with jax.named_scope(SCOPE_KV_VIEW):
+                    gathered_k = _slice_time(
+                        L.gather_paged_kv(k_pools[i], tables_rows),
+                        t_cap)
+                    gathered_v = _slice_time(
+                        L.gather_paged_kv(v_pools[i], tables_rows),
+                        t_cap)
+                    if kv_int8:
+                        k_rows = write_rows(
+                            L.dequantize_kv_cache(gathered_k, x.dtype),
+                            k, offsets)
+                        v_rows = write_rows(
+                            L.dequantize_kv_cache(gathered_v, x.dtype),
+                            v, offsets)
+                    else:
+                        k_rows = write_rows(gathered_k, k, offsets)
+                        v_rows = write_rows(gathered_v, v, offsets)
+                with jax.named_scope(SCOPE_ATTN_CORE):
+                    q_grouped = q.reshape(q.shape[0], num_kv, group,
+                                          chunk_len, config.head_dim)
+                    scores = jnp.einsum(
+                        "akgcd,aktd->akgct", q_grouped, k_rows,
+                        preferred_element_type=jnp.float32) * scale
+                    scores = jnp.where(mask, scores, -1e30)
+                    weights = jax.nn.softmax(
+                        scores, axis=-1).astype(v_rows.dtype)
+                    out = jnp.einsum(
+                        "akgct,aktd->akgcd", weights, v_rows,
+                        preferred_element_type=jnp.float32)
+                    out = out.reshape(out.shape[0], num_heads,
+                                      chunk_len,
+                                      config.head_dim).astype(x.dtype)
+            with jax.named_scope(SCOPE_ATTN_PROJ):
+                x = x + L.linear(layer["attn"]["o"],
+                                 L._merge_heads(out))
+            with jax.named_scope(SCOPE_MLP):
+                x = x + llama_ffn(layer, config,
+                                  L.rms_norm(layer["ln_mlp"], x))
+            with jax.named_scope(SCOPE_KV_MERGE):
                 if kv_int8:
-                    k_rows = write_rows(
-                        L.dequantize_kv_cache(gathered_k, x.dtype), k,
-                        offsets)
-                    v_rows = write_rows(
-                        L.dequantize_kv_cache(gathered_v, x.dtype), v,
-                        offsets)
+                    k_store = L.quantize_kv_cache(k)
+                    v_store = L.quantize_kv_cache(v)
                 else:
-                    k_rows = write_rows(gathered_k, k, offsets)
-                    v_rows = write_rows(gathered_v, v, offsets)
-                q_grouped = q.reshape(q.shape[0], num_kv, group,
-                                      chunk_len, config.head_dim)
-                scores = jnp.einsum(
-                    "akgcd,aktd->akgct", q_grouped, k_rows,
-                    preferred_element_type=jnp.float32) * scale
-                scores = jnp.where(mask, scores, -1e30)
-                weights = jax.nn.softmax(
-                    scores, axis=-1).astype(v_rows.dtype)
-                out = jnp.einsum("akgct,aktd->akgcd", weights, v_rows,
-                                 preferred_element_type=jnp.float32)
-                out = out.reshape(out.shape[0], num_heads, chunk_len,
-                                  config.head_dim).astype(x.dtype)
-            x = x + L.linear(layer["attn"]["o"], L._merge_heads(out))
-            x = x + llama_ffn(layer, config,
-                              L.rms_norm(layer["ln_mlp"], x))
-            if kv_int8:
-                k_store = L.quantize_kv_cache(k)
-                v_store = L.quantize_kv_cache(v)
-            else:
-                k_store, v_store = k, v
-            k_pools[i] = L.scatter_paged_rows(k_pools[i], dest,
-                                              block_offsets, k_store)
-            v_pools[i] = L.scatter_paged_rows(v_pools[i], dest,
-                                              block_offsets, v_store)
-        x = L.rms_norm(params["ln_out"], x)
-        last_hidden = jnp.take_along_axis(
-            x, final_idx[:, None, None], axis=1)[:, 0]
-        last = L.linear_logits(params["lm_head"], last_hidden)
-        firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                    k_store, v_store = k, v
+                k_pools[i] = L.scatter_paged_rows(
+                    k_pools[i], dest, block_offsets, k_store)
+                v_pools[i] = L.scatter_paged_rows(
+                    v_pools[i], dest, block_offsets, v_store)
+        with jax.named_scope(SCOPE_HEAD):
+            x = L.rms_norm(params["ln_out"], x)
+            last_hidden = jnp.take_along_axis(
+                x, final_idx[:, None, None], axis=1)[:, 0]
+            last = L.linear_logits(params["lm_head"], last_hidden)
+            firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
         apply = valid & finish
         tokens = tokens.at[slots].set(
             jnp.where(apply, firsts, tokens[slots]))

@@ -1383,9 +1383,6 @@ def bench_llama(window: float):
     for phase_name, entry in sorted(phase["phases"].items()):
         phase_fields[f"lat_llama_phase_{phase_name}_ms"] = \
             round(entry["ms_per_round"], 3)
-        if "gb_per_s" in entry:
-            phase_fields[f"lat_llama_phase_{phase_name}_gbps"] = \
-                round(entry["gb_per_s"], 2)
     return phase_fields | {
         "llama_tokens_per_sec": round(tokens_per_sec, 1),
         "llama_occupancy": round(decoder.mean_occupancy(), 3),

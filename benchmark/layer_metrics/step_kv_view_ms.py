@@ -1,0 +1,10 @@
+from benchmark.trace import regions
+
+
+def read(run):
+    """Device ms a decode step spends under `aiko.kv_view` (building the
+    slot-major K and V views of the pool, once a round, before the scan): the
+    own time of the device operations that carry the scope inside `jit_step`,
+    and of the scopeless ones they adopt (trace/regions.py), over the steps
+    run in the traced span."""
+    return regions.step_region_ms(run, "aiko.kv_view")
