@@ -7,7 +7,9 @@
 # slice at kv_block=32).  Nothing runs, so these say nothing about results
 # or times.  Plus the compile-cache helper's placement contract.
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,14 +22,13 @@ HKV, GROUPS, HEAD_DIM, BLOCK = 8, 4, 64, 32
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """SingleDeviceSharding on one described v5e chip; the persistent
-    compilation cache is off around the module (an executable compiled
-    for a described device cannot be read back without one — a warm
-    cache would only add warnings)."""
+def v5e():
+    """A described v5e 2x2; the persistent compilation cache is off
+    around the module (an executable compiled for a described device
+    cannot be read back without one — a warm cache would only add
+    warnings)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topology = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
@@ -36,9 +37,16 @@ def chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topology.devices[0])
+    yield topology
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(v5e):
+    """SingleDeviceSharding on one chip of it."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _flash(causal):
@@ -111,6 +119,86 @@ def test_kernel_compiles_for_v5e(chip, case):
         shapes, is_leaf=lambda leaf: isinstance(leaf, tuple))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# mistral-7b-v0.3-d16's pool leaf in the benchmark's cells: 24 slots x 64
+# blocks + the null block, 8 KV heads, 32 tokens a block, head 128
+POOL = (1537, 8, 32, 128)
+POOL_LEAVES = {"bf16": (POOL, jnp.bfloat16), "s8": (POOL, jnp.int8),
+               "scale-f32": (POOL[:3], jnp.float32)}
+
+
+def _compiled_donating(fn, sharding, *leaves):
+    """`fn` compiled for `sharding` with its first argument donated;
+    every leaf is (shape, dtype)."""
+    return jax.jit(fn, donate_argnums=(0,)).lower(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in leaves)).compile()
+
+
+def _assert_in_place(compiled, shape, dtype):
+    """No `copy` in the optimized HLO has a result of the pool leaf's
+    shape (in whatever layout), and the temporaries are under a leaf."""
+    result = re.escape("[" + ",".join(map(str, shape)) + "]")
+    assert re.findall(rf"= \w+{result}\S* copy\(.*", compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("leaf", sorted(POOL_LEAVES))
+@pytest.mark.parametrize("dest", [(24, 4), (1, 512)],
+                         ids=["step-24x4", "extend-1x512"])
+def test_row_scatter_updates_the_donated_pool_in_place(chip, dest, leaf):
+    """The decode step's merge (24 slots x 4 steps) and the chunked
+    extend's (1 x 512) write a few rows into a 100 MB leaf.  With the
+    heads axis sliced between two indexed axes, XLA copies the whole
+    leaf before the scatter and back after it: a fifth of the decode
+    step (PR 25)."""
+    from aiko_services_tpu.models import layers
+    shape, dtype = POOL_LEAVES[leaf]
+    slots, width = dest
+    compiled = _compiled_donating(
+        layers.scatter_paged_rows, chip, (shape, dtype), (dest, jnp.int32),
+        (dest, jnp.int32), ((slots, shape[1], width) + shape[3:], dtype))
+    assert "scatter" in compiled.as_text()
+    _assert_in_place(compiled, shape, dtype)
+
+
+def test_row_scatter_stays_on_its_shard_of_a_heads_sharded_pool(v5e):
+    """Tensor parallel serving shards a pool leaf over its heads axis
+    (`create_mesh({"model": 4})`): the scatter keeps that axis an axis
+    of its own, so each chip writes its two heads' rows into its own
+    quarter of the leaf, in place, and no collective moves a leaf."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from aiko_services_tpu.models import layers
+    mesh = Mesh(np.array(v5e.devices).reshape(4), ("model",))
+    heads = NamedSharding(mesh, PartitionSpec(None, "model"))
+    whole = NamedSharding(mesh, PartitionSpec())
+    compiled = jax.jit(layers.scatter_paged_rows, donate_argnums=(0,),
+                       out_shardings=heads).lower(
+        jax.ShapeDtypeStruct(POOL, jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct((24, 4), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((24, 4), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((24, POOL[1], 4, POOL[3]), jnp.bfloat16,
+                             sharding=heads)).compile()
+    text = compiled.as_text()
+    shard = (POOL[0], POOL[1] // 4) + POOL[2:]
+    assert "scatter" in text
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text
+    _assert_in_place(compiled, shard, jnp.bfloat16)
+
+
+def test_block_write_updates_the_donated_pool_in_place(chip):
+    """The admit's whole-block write at 512 x 1 (16 blocks a row)."""
+    from aiko_services_tpu.models import layers
+    compiled = _compiled_donating(
+        layers.write_paged_blocks, chip, (POOL, jnp.bfloat16),
+        ((1, 512 // POOL[2]), jnp.int32),
+        ((1, POOL[1], 512, POOL[3]), jnp.bfloat16))
+    _assert_in_place(compiled, POOL, jnp.bfloat16)
 
 
 def test_paged_row_tile_stays_inside_vmem_budget():
