@@ -23,7 +23,7 @@
 #
 #   sums     wall seconds per phase (phase_stats(), the bench's
 #            lat_llama_phase_* fields, serving_phase_seconds_total)
-#   a ring   one plain tuple per committed round (ROUND_FIELDS), the
+#   a ring   one plain tuple per committed round (ROUND_RECORD), the
 #            newest RING_ROUNDS of them: a stalled round dissolves in
 #            a mean and stands out here.  round_log() hands the ring
 #            to a reader that holds no reference to the decoder.
@@ -54,7 +54,8 @@ import weakref
 
 from .metrics import MetricsRegistry, default_registry
 
-__all__ = ["PhaseProfiler", "PHASES", "ROUND_FIELDS", "SPAN_ROUND", "SPANS",
+__all__ = ["PhaseProfiler", "PHASES", "ROUND_FIELDS", "ROUND_RECORD",
+           "SPAN_ROUND", "SPANS",
            "arm_trace", "round_log", "slow_round", "trace_state"]
 
 PHASES = ("plan", "scan_dispatch", "spec_verify", "admit_dispatch",
@@ -79,6 +80,14 @@ SPANS = {"plan": "aiko.decoder.plan",
 # `idle_before` says that the decoder had nothing to do at that end
 ROUND_FIELDS = ("seq", "rounds", "t0", "gap_s", "idle_before", "wall_s") \
     + PHASES + ("num_steps", "slots", "prefill_tokens", "pending")
+# the whole record: ROUND_FIELDS, then what later PRs added.  The
+# benchmark's reader zips a record with ROUND_FIELDS and its test
+# builds records of exactly that length (neither file is this repo's
+# to edit outside a benchmark PR), so ROUND_FIELDS stays what PR 24
+# made it and a new field goes behind it: `attend_width`, the
+# positions the round's step built its views and attended at (the
+# dense cache's time extent; 0 for a round that ran no step)
+ROUND_RECORD = ROUND_FIELDS + ("attend_width",)
 RING_ROUNDS = 8192              # over ten minutes of 100 ms rounds
 
 # a round is slow when it and the gap before it took longer than both
@@ -278,9 +287,9 @@ class PhaseProfiler:
 
     def commit_round(self, rounds: int = 0, num_steps: int = 0,
                      slots: int = 0, prefill_tokens: int = 0,
-                     pending: int = 0) -> tuple:
+                     pending: int = 0, attend_width: int = 0) -> tuple:
         """Fold the round into the sums and the ring; returns its
-        record (ROUND_FIELDS)."""
+        record (ROUND_RECORD)."""
         now = time.perf_counter()
         staged = self._staged
         staged[self._phase] += now - self._last
@@ -296,7 +305,8 @@ class PhaseProfiler:
         record = (self._seq, rounds, self._t0,
                   0.0 if self._end is None else self._t0 - self._end,
                   self.idle, total, *staged.values(),
-                  num_steps, slots, prefill_tokens, pending)
+                  num_steps, slots, prefill_tokens, pending,
+                  attend_width)
         self.ring.append(record)
         self._end, self.idle = now, False
         _trace_tick()

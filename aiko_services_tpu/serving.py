@@ -64,6 +64,26 @@ from .utils import get_logger
 
 _WALL_S = ROUND_FIELDS.index("wall_s")    # of a PhaseProfiler round record
 
+# no paged step attends at fewer positions than this: below it the views
+# are a tenth of the step, and a decoder whose max_seq is no larger
+# keeps exactly one width (every test geometry; tests of the ladder
+# patch it down to theirs)
+_ATTEND_FLOOR = 512
+
+
+def _attend_ladder(max_seq: int, kv_block: int) -> tuple:
+    """The widths a paged decoder's step may build its views and attend
+    at, ascending: the cap, half of it and a quarter of it, each
+    rounded up to whole blocks, none under _ATTEND_FLOOR.  Halving
+    wastes under 2x of the views and bounds the programs at three a
+    step count."""
+    widths = {max_seq}
+    for divisor in (2, 4):
+        width = -(-max_seq // (divisor * kv_block)) * kv_block
+        if _ATTEND_FLOOR <= width < max_seq:
+            widths.add(width)
+    return tuple(sorted(widths))
+
 __all__ = ["ContinuousDecoder", "DecodeRequest", "PrefixKVCache",
            "prefix_chain_keys", "check_block_geometry",
            "measure_device_step"]
@@ -92,7 +112,7 @@ def measure_device_step(decoder, steps_per_sync: int = 64,
         # paged probe: fresh zero pools at the pool's CURRENT capacity
         # (shape-identical to the serving pool, so the compiled
         # executable is the one serving runs) and round-robin distinct
-        # tables at the serving gather width
+        # tables at the cap, the widest the serving step attends at
         nb = -(-decoder._cache_t // decoder.kv_block)
         k_probe = decoder.pool._zero_pools(decoder.pool.num_blocks)
         v_probe = decoder.pool._zero_pools(decoder.pool.num_blocks)
@@ -1848,10 +1868,14 @@ class ContinuousDecoder:
         # has dispatched this much prefill work.  None = unbounded.
         self.prefill_budget = int(prefill_budget) if prefill_budget \
             else None
-        # granularity of the attention time-axis cap: each round reads
+        # granularity of the DENSE cache's time axis: each round reads
         # cache[:, :, :t_cap] with t_cap the smallest multiple of
-        # t_block covering the longest active context (one compiled
-        # program per distinct t_cap — max_seq/t_block variants)
+        # t_block covering the longest active context (a resize is a
+        # device copy and one compiled program per distinct t_cap —
+        # max_seq/t_block variants).  In paged mode it sizes the pool's
+        # first allocation and its growth and NOTHING ELSE: no paged
+        # program's width follows it (tables, extends and admits keep
+        # max_seq; the step takes its width from _attend_ladder).
         self.t_block = max(1, int(t_block))
         # buckets beyond the cache's time axis would blow up the admit
         # scatter — clamp, dedupe, keep sorted
@@ -1887,14 +1911,19 @@ class ContinuousDecoder:
             raise ValueError(
                 f"kv_block must be >= 1, got {kv_block}")
 
-        # the cache TIME axis is allocated at the workload, not at
-        # max_seq: it grows/shrinks in t_block steps to cover the
+        # the dense cache's TIME axis is allocated at the workload, not
+        # at max_seq: it grows/shrinks in t_block steps to cover the
         # longest active context (_fit_caches).  HBM capacity AND
         # per-step bandwidth then scale with actual occupancy — a
         # max_seq allocation makes every decode step stream max_seq
         # worth of cache (an in-program slice doesn't help: it
-        # materializes, measured 3× attention bytes).
-        self._cache_t = min(self.t_block, self.max_seq)
+        # materializes, measured 3× attention bytes).  A paged decoder
+        # holds no such axis: _cache_t is then the constant width of
+        # its tables, extends and admits, max_seq, and what follows
+        # the workload is the width its step attends at, round by
+        # round (_attend_width).
+        start_t = min(self.t_block, self.max_seq)
+        self._cache_t = self.max_seq if self.paged else start_t
 
         # prefix/KV reuse cache (ISSUE 13): hash-addressed block
         # sharing across requests and sessions.  The cache stores rows
@@ -1921,11 +1950,12 @@ class ContinuousDecoder:
         if self.paged:
             from .serving_paged import BlockPool
             block = self.kv_block
-            # table width covers the worst-case extent _fit_caches can
-            # reach (max_seq + block-mode merge headroom)
+            # table width covers the worst-case extent a round's merge
+            # can write (max_seq + block-mode merge headroom: cells
+            # that nobody attends, so no step's views reach them)
             headroom = 0 if self.speculate_k else steps_per_sync
             self._table_blocks = -(-(self.max_seq + headroom) // block)
-            initial = max_slots * (-(-self._cache_t // block))
+            initial = max_slots * (-(-start_t // block))
             if prefix_cache is not None and prefix_cache.paged:
                 # a decoder sharing an already-attached cache ADOPTS
                 # its pool (attach_pool's one-pool-per-cache contract;
@@ -1964,7 +1994,9 @@ class ContinuousDecoder:
             self._tables_scratch = np.zeros_like(self._tables_np)
             self._tables_dirty = True
             self._tables_dev = None
-            self._tables_dev_nb = -1
+            self._attend_widths = _attend_ladder(self.max_seq, block)
+            # (num_steps, width, state's placement) -> executable
+            self._step_programs: dict = {}
             # per-slot owned/aliased pool block ids, in table order
             self._slot_blocks: list[list] = \
                 [[] for _ in range(max_slots)]
@@ -2835,8 +2867,8 @@ class ContinuousDecoder:
         """Round prologue for the paged scan: extend every scanned
         slot's table to cover the positions this round's merge can
         write (entry length + num_steps tokens — per verify-block
-        width in speculative mode), then hand back the device table
-        slice at the current gather width."""
+        width in speculative mode), then hand back the device tables,
+        whole: the step cuts them to its width itself."""
         per_step = 1 + self.speculate_k
         cap = self.max_seq if self.speculate_k \
             else self.max_seq + self.steps_per_sync
@@ -2847,17 +2879,53 @@ class ContinuousDecoder:
                 + owed
             self._ensure_coverage(
                 slot, min(current + num_steps * per_step, cap))
-        return self._tables_device(-(-self._cache_t // self.kv_block))
+        return self._tables_device()
 
-    def _tables_device(self, nb: int):
-        """The device block-table slice [S, nb] the compiled programs
-        gather through; rebuilt only when the host tables changed or
-        the gather width moved (one small int32 transfer)."""
-        if self._tables_dirty or nb != self._tables_dev_nb:
-            self._tables_dev = jnp.asarray(self._tables_np[:, :nb])
-            self._tables_dev_nb = nb
+    def _tables_device(self):
+        """The device block tables [S, table width] the step gathers
+        and merges through, at their full and constant shape: uploaded
+        again only when the host tables changed (one small int32
+        transfer), never because a round attends at another width."""
+        if self._tables_dirty:
+            self._tables_dev = jnp.asarray(self._tables_np)
             self._tables_dirty = False
         return self._tables_dev
+
+    def _attend_width(self, required_t: int) -> int:
+        """The smallest width of the ladder that covers `required_t`,
+        the cap where none does (a context within a round of max_seq:
+        the positions past the cap are merge headroom, written through
+        the table and attended by nobody)."""
+        for width in self._attend_widths:
+            if required_t <= width:
+                return width
+        return self._attend_widths[-1]
+
+    def _step_program(self, num_steps: int, width: int, eos: int,
+                      args: tuple):
+        """The executable of the paged step at `num_steps` and `width`
+        for `args` as they are placed now.  The first dispatch of a
+        step count compiles it at EVERY width of the ladder, from the
+        live arguments' types (nothing is allocated), so that no later
+        round compiles: a context that grows into the next width meets
+        its program.  (The plain step has one step count, the longest
+        round's: pump cuts a shorter round by its budgets.  The
+        speculative step has one a round length.)  State that comes
+        back placed otherwise (a tensor-parallel program returns it
+        sharded) or a pool that grew is another key, as it is another
+        trace of the jitted step."""
+        from .serving_paged import compiled_step, placements
+        # the weights (args[0]) stay as they were placed at construction
+        key = (num_steps, width, self.pool.num_blocks,
+               placements(args[1:]))
+        program = self._step_programs.get(key)
+        if program is None:
+            for each in self._attend_widths:
+                self._step_programs[(num_steps, each) + key[2:]] = \
+                    compiled_step(self._step, args, num_steps=num_steps,
+                                  eos=eos, t_cap=each)
+            program = self._step_programs[key]
+        return program
 
     def _release_slot_blocks(self, slot: int,
                              tenant: str | None = None) -> None:
@@ -2959,18 +3027,17 @@ class ContinuousDecoder:
         needs no headroom).  A grow pads with zeros, a shrink slices —
         one whole-cache copy, amortized over the many rounds run at
         the new size.  No-op when already sized."""
+        if self.paged:
+            # nothing to resize: the pool allocates by the block, the
+            # tables, extends and admits keep max_seq, and the step
+            # takes its width round by round (_attend_width)
+            return
         if self.speculate_k or KV_WRITE != "block":
             cap = self.max_seq
         else:
             cap = self.max_seq + self.steps_per_sync
         new_t = min(cap, -(-required_t // self.t_block) * self.t_block)
         if new_t == self._cache_t:
-            return
-        if self.paged:
-            # the pool allocates per block on demand; only the gather
-            # width (and with it the step's streamed bytes) tracks the
-            # workload here — no device copy at all
-            self._cache_t = new_t
             return
         key = (self._cache_t, new_t)
         if key not in self._resize_fns:
@@ -3610,16 +3677,23 @@ class ContinuousDecoder:
         waves_due = self._admit_waves
         self._admit_waves = []
         scanned = False
-        num_steps = scanned_slots = 0
+        num_steps = scanned_slots = attend_width = 0
         if any_active:
             occupied = [s for s in range(self.max_slots) if active[s]]
             num_steps, required_t, budgets = self._round_plan(occupied)
-            # never shrink the cache below a mid-prefill slot's written
-            # extent — the decode slots alone may need less
-            for request in self._slots:
-                if request is not None and request.prefilling:
-                    required_t = max(required_t, request.prefill_pos)
-            self._fit_caches(required_t)
+            if self.paged:
+                # the width need cover only the slots this round scans:
+                # a slot in mid-prefill is masked out of the step and
+                # dropped by its merge, and no resize can cut its rows
+                attend_width = self._attend_width(required_t)
+            else:
+                # never shrink the cache below a mid-prefill slot's
+                # written extent — the decode slots alone may need less
+                for request in self._slots:
+                    if request is not None and request.prefilling:
+                        required_t = max(required_t, request.prefill_pos)
+                self._fit_caches(required_t)
+                attend_width = self._cache_t
             # a slot with budget 0 (request satisfied by its owed first
             # token) needs no decode: masking it out of the scan keeps
             # its discarded emissions out of useful_steps
@@ -3640,23 +3714,26 @@ class ContinuousDecoder:
                 # int32 transfer refreshes the device tables if dirty
                 tables = self._prepare_round_tables(occupied,
                                                     num_steps)
+                program_steps = num_steps
+                if not self.speculate_k:
+                    # every round runs the program of the longest one,
+                    # cut to its own length by the budgets: the step's
+                    # loop ends when no slot has a token left to emit,
+                    # so a round length is no program of its own
+                    np.minimum(budgets, num_steps, out=budgets)
+                    program_steps = self.steps_per_sync
+                args = (self.params, self._tokens, self._lengths,
+                        jnp.array(scan_active), jnp.array(budgets)) + \
+                    ((self._context,) if self.speculate_k else ()) + \
+                    (self.pool.k_pools, self.pool.v_pools, tables)
+                step = self._step_program(program_steps, attend_width,
+                                          eos, args)
                 if self.speculate_k:
                     (emitted, emit_mask, self._tokens, self._lengths,
-                     self._context, k_pools, v_pools) = self._step(
-                        self.params, self._tokens, self._lengths,
-                        jnp.array(scan_active), jnp.array(budgets),
-                        self._context, self.pool.k_pools,
-                        self.pool.v_pools, tables,
-                        num_steps=num_steps, eos=eos,
-                        t_cap=self._cache_t)
+                     self._context, k_pools, v_pools) = step(*args)
                 else:
                     (emitted, emitted_active, self._tokens,
-                     self._lengths, k_pools, v_pools) = self._step(
-                        self.params, self._tokens, self._lengths,
-                        jnp.array(scan_active), jnp.array(budgets),
-                        self.pool.k_pools, self.pool.v_pools, tables,
-                        num_steps=num_steps, eos=eos,
-                        t_cap=self._cache_t)
+                     self._lengths, k_pools, v_pools) = step(*args)
                 self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
             elif self.speculate_k:
                 (emitted, emit_mask, self._tokens, self._lengths,
@@ -3699,7 +3776,7 @@ class ContinuousDecoder:
                     (emitted, emitted_active, wave_firsts))
             self.stats["decode_s"] += time.perf_counter() - decode_start
             round_bytes = num_steps * (
-                self._param_bytes + self._kv_bytes_per_t * self._cache_t)
+                self._param_bytes + self._kv_bytes_per_t * attend_width)
             self.stats["bytes_moved"] += round_bytes
         elif wave_firsts:
             wave_firsts = jax.device_get(wave_firsts)
@@ -3745,7 +3822,7 @@ class ContinuousDecoder:
             record = profiler.commit_round(
                 self.stats["rounds"], num_steps if scanned else 0,
                 scanned_slots, self._round_prefill_tokens,
-                len(self._pending))
+                len(self._pending), attend_width if scanned else 0)
             # what remains of a stall in a run nobody traced
             slow = slow_round(record, self._round_ewma)
             if slow is not None:

@@ -25,11 +25,16 @@
 #
 # Device-side discipline: the compiled step GATHERS a slot-major
 # [S, H, T, D] view from the pool once per round (the main cache is
-# read-only through the scan, so the gather hoists out of it), slices
-# it to the dense path's exact time extent, and runs the SAME attention
-# bodies (_slot_attention_block / _slot_attention_spec) — the gathered
-# view is value- and shape-identical to the dense slot cache, so paged
-# greedy output is BIT-IDENTICAL to dense by construction.  Round-end
+# read-only through the scan, so the gather hoists out of it) and runs
+# the SAME attention bodies (_slot_attention_block /
+# _slot_attention_spec) — the gathered view is value-identical to the
+# dense slot cache over every live position and the tail past them is
+# masked to exact zeros, so paged greedy output is identical to dense
+# (the parity matrix of tests/test_paged_kv.py) at whatever width T
+# the view is built: the decoder takes T from a short ladder of widths,
+# the smallest that covers the round's longest live context (ISSUE 28;
+# serving.ContinuousDecoder._attend_width), so the views and the
+# attention over them move what is live and not max_seq.  Round-end
 # side-buffer merges scatter to (block, offset) pairs computed from the
 # tables, with out-of-range ids dropping exactly like the dense path's
 # _POS_INVALID entries.  This module owns the pool allocator and the
@@ -40,6 +45,7 @@
 from __future__ import annotations
 
 import functools
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -440,17 +446,33 @@ def _write_blocks_fn(config: LlamaConfig, kv_int8: bool):
 # -- compiled paged programs --------------------------------------------------
 
 def _slice_time(cache, t_cap: int):
-    """Slice a gathered slot-major view to the dense path's exact time
-    extent — shape-identical programs are how paged stays bit-identical
-    to dense (an extra masked tail could re-pair the f32 reductions)."""
+    """Slice a gathered slot-major view to exactly t_cap positions: a
+    width that ends inside a block (a max_seq that is no multiple of
+    the block) gathers whole blocks and trims here."""
     if isinstance(cache, dict):
         return {"q": cache["q"][:, :, :t_cap],
                 "s": cache["s"][:, :, :t_cap]}
     return cache[:, :, :t_cap]
 
 
+def _table_cap(tables, block_tokens: int, t_cap: int):
+    """Slice a round table to the blocks covering t_cap — an int32
+    table slice, not a KV gather.  The gather path cuts its table with
+    it BEFORE gathering; the kernel masks positions against
+    entry_lengths natively, so this is the only t_cap handling the
+    kernel path needs."""
+    return tables[:, :-(-t_cap // block_tokens)]
+
+
 def _gather_views(pools, tables, t_cap: int) -> list:
-    return [_slice_time(L.gather_paged_kv(pool, tables), t_cap)
+    """Slot-major views of positions [0, t_cap) of every slot.  The
+    table is cut to the blocks that cover t_cap FIRST, so the gather
+    itself moves t_cap positions whatever the table's width (a step
+    at half the cap builds half the views); the time slice after it
+    only trims a t_cap that ends inside a block."""
+    block_tokens = jax.tree_util.tree_leaves(pools[0])[0].shape[2]
+    capped = _table_cap(tables, block_tokens, t_cap)
+    return [_slice_time(L.gather_paged_kv(pool, capped), t_cap)
             for pool in pools]
 
 
@@ -463,14 +485,6 @@ def _gather_views(pools, tables, t_cap: int) -> list:
 # (int8 × chunked × spec × block size) combination, and the kernel
 # builders key their lru caches on the toggle so both variants coexist
 # in one process (tools/ab_decode_attention.py flips per case).
-
-def _table_cap(tables, block_tokens: int, t_cap: int):
-    """Slice a round table to the blocks covering t_cap — an int32
-    table slice, not a KV gather; the kernel masks positions against
-    entry_lengths natively, so this is the only t_cap handling the
-    kernel path needs."""
-    return tables[:, :-(-t_cap // block_tokens)]
-
 
 def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
                               k_pool, v_pool, tables, k_side, v_side,
@@ -565,9 +579,15 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
     the slot-major KV views from the pool (once — the main cache is
     read-only through the scan), run the IDENTICAL scan body
     (_slot_attention_block owns the numerics), and merge the round's
-    side buffers back by (block, offset) scatter.  t_cap is static and
-    equals the dense path's cache time extent, so every einsum shape
-    matches the dense program exactly.
+    side buffers back by (block, offset) scatter.  t_cap is static:
+    the width the views are built and attended at, which the decoder
+    picks every round from its ladder of widths to cover the longest
+    live context (ContinuousDecoder._attend_width).  `tables` comes at
+    its full, constant shape and is cut to t_cap in here, so a change
+    of width between two rounds uploads nothing; the merge scatters
+    through the whole table.  Any t_cap that covers every scanned
+    slot's entry length gives the same tokens: the tail is masked to
+    exact zeros.
 
     kernel=True swaps the gather + shared attention body for the
     fused pallas kernel reading pool blocks through the table
@@ -629,10 +649,32 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
             return ((next_tokens, lengths, still, budgets, new_k,
                      new_v), (next_tokens, active))
 
-        (tokens, lengths, active, budgets, k_sides, v_sides), \
-            (emitted, emitted_active) = jax.lax.scan(
-                body, (tokens, lengths, active, budgets, k_sides,
-                       v_sides), jnp.arange(num_steps))
+        # a loop that ends with the round and not a scan of num_steps:
+        # the decoder runs every round through the ONE program of its
+        # longest round (steps_per_sync) and cuts a shorter one by its
+        # budgets, so a round length is no program of its own (each
+        # cost 2 s of set-up, a width and a length 9 programs).  An
+        # iteration with no slot active would change nothing but the
+        # side rows of slots that the merge writes as dead cells, so
+        # leaving it out leaves the same tokens and the same pool.
+        def unfinished(loop):
+            index, carry, _ = loop
+            return (index < num_steps) & carry[2].any()
+
+        def iterate(loop):
+            index, carry, (emitted, emitted_active) = loop
+            carry, (next_tokens, was_active) = body(carry, index)
+            return (index + 1, carry,
+                    (emitted.at[index].set(next_tokens),
+                     emitted_active.at[index].set(was_active)))
+
+        _, (tokens, lengths, active, budgets, k_sides, v_sides), \
+            (emitted, emitted_active) = jax.lax.while_loop(
+                unfinished, iterate,
+                (jnp.int32(0),
+                 (tokens, lengths, active, budgets, k_sides, v_sides),
+                 (jnp.zeros((num_steps, slots_n), tokens.dtype),
+                  jnp.zeros((num_steps, slots_n), bool))))
 
         # merge: each slot's side rows land at their absolute positions
         # [entry_length, entry_length + num_steps) — rows past a slot's
@@ -734,6 +776,43 @@ def _build_paged_spec_step(config: LlamaConfig, k_spec: int,
                    donate_argnames=("context", "k_pools", "v_pools"))
 
 
+# -- step programs compiled ahead of time -------------------------------------
+# A decoder compiles a step count at EVERY width of its ladder the first
+# time it dispatches that count, and dispatches through the executables:
+# a context that grows into the next width in the middle of serving
+# meets a program that is already there (a jitted call would compile it
+# then, with every slot standing still).  The executables are shared
+# process-wide like the jitted builders they come from, by everything a
+# jitted call's own cache would tell apart.
+
+_step_programs: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+
+
+def placements(args) -> tuple:
+    """What a jitted call reads of its arguments beside their shapes
+    and dtypes, leaf by leaf: the sharding of a committed array (one
+    that a program returned onto a mesh, or that was put on a device);
+    an uncommitted one goes wherever the program wants it (None)."""
+    return tuple(leaf.sharding if leaf.committed else None
+                 for leaf in jax.tree_util.tree_leaves(args))
+
+
+def compiled_step(step, args: tuple, **static):
+    """`step` (a jitted paged step) lowered and compiled for `args` as
+    they are: their shapes, dtypes and placements, so a pool that grew
+    or state that a tensor-parallel program returned sharded gets an
+    executable of its own, as it would from the jitted call.  Nothing
+    is allocated: lowering reads the live arrays' types only."""
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    key = (tuple(sorted(static.items())), treedef,
+           tuple((leaf.shape, leaf.dtype) for leaf in leaves),
+           placements(leaves))
+    programs = _step_programs.setdefault(step, {})
+    if key not in programs:
+        programs[key] = step.lower(*args, **static).compile()
+    return programs[key]
+
+
 @functools.lru_cache(maxsize=16)
 def _paged_spec_step_for(config: LlamaConfig, k_spec: int, ngram: int,
                          kernel: bool = False):
@@ -809,6 +888,11 @@ def _paged_extend_fn_for(config: LlamaConfig, chunk_len: int,
     back.  int8 prefixes dequantize for the attention read and the
     chunk stores quantized, exactly like dense — untouched positions
     are never re-rounded because they are never rewritten at all.
+
+    t_cap here is always the decoder's cap (max_seq), never a width
+    of the step's ladder: an extend reads one row a chunk, so a
+    narrower view wins nothing and every width would be one more
+    program for each (chunk, rows) pair.
 
     kernel=True reads the prefix through the pallas kernel instead of
     gathering: the chunk's own K/V ride as the kernel's side buffer

@@ -201,6 +201,54 @@ def test_block_write_updates_the_donated_pool_in_place(chip):
     _assert_in_place(compiled, POOL, jnp.bfloat16)
 
 
+@pytest.mark.parametrize("width, blocks, temporaries", [
+    (1024, 32, 2.3e9),      # the width of every decode_saturated round
+    (2048, 64, 4.56e9),     # the cap: what it was before the ladder
+], ids=["half", "cap"])
+def test_step_views_follow_the_attend_width(chip, width, blocks,
+                                            temporaries):
+    """`jit_step` x 4 of mistral-7b-v0.3-d16 as the benchmark's cells
+    run it, whole: 24 slots, a full pool, the table at its constant 65
+    blocks (64 and the merge's headroom).  The views are gathered at
+    the width's blocks and no wider, whatever the table holds, and the
+    temporaries shrink with them; the cap's program is not widened to
+    the table's 65."""
+    import json
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models.llama import LlamaConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mistral-7b-v0.3-d16.json")) as f:
+        sizes = json.load(f)
+    serve = sizes["serving"]
+    config = LlamaConfig(
+        vocab=sizes["vocab_size"], dim=sizes["hidden_size"],
+        ffn_dim=sizes["intermediate_size"],
+        num_layers=sizes["num_hidden_layers"],
+        num_heads=sizes["num_attention_heads"],
+        num_kv_heads=sizes["num_key_value_heads"],
+        max_seq_len=serve["max_seq"], rope_theta=sizes["rope_theta"],
+        dtype=jnp.bfloat16)
+    slots, table = serve["max_slots"], 65
+
+    def shaped(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    from aiko_services_tpu.models.llama import llama_init
+    params = jax.tree.map(
+        lambda leaf: shaped(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), config)))
+    pool = [shaped(POOL, jnp.bfloat16) for _ in range(config.num_layers)]
+    compiled = serving_paged._paged_step_for(config, False).lower(
+        params, shaped((slots,), jnp.int32), shaped((slots,), jnp.int32),
+        shaped((slots,), bool), shaped((slots,), jnp.int32), pool, pool,
+        shaped((slots, table), jnp.int32), num_steps=4, eos=-1,
+        t_cap=width).compile()
+    views = set(re.findall(r"bf16\[24,(\d+),8,32,128\]", compiled.as_text()))
+    assert views == {str(blocks)}, views
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
 def test_paged_row_tile_stays_inside_vmem_budget():
     from aiko_services_tpu.ops import paged_attention as pa
     # a decode row fits whole; an extend's G*chunk rows are tiled

@@ -94,7 +94,8 @@ class TestRing:
         serve(decoder, {"a": (PROMPT[:12], 9), "b": (PROMPT[:30], 5),
                         "c": (PROMPT[:7], 3)})
         log = P.round_log("ring_b")
-        assert all(len(r) == len(P.ROUND_FIELDS) for r in log)
+        assert all(len(r) == len(P.ROUND_RECORD) for r in log)
+        assert P.ROUND_RECORD[:len(P.ROUND_FIELDS)] == P.ROUND_FIELDS
         # `rounds` is the decoder's counter when the round was committed
         assert [r[F["rounds"]] for r in log] == counted
         assert log[-1][F["rounds"]] == decoder.stats["rounds"]
@@ -171,9 +172,9 @@ class TestRing:
             P.round_log()
         profiler = P.PhaseProfiler("only")
         profiler.begin_round()
-        profiler.commit_round(7, 4, 3, 64, 2)
+        profiler.commit_round(7, 4, 3, 64, 2, 1024)
         (record,) = P.round_log()
-        assert record[F["rounds"]] == 7 and record[-4:] == (4, 3, 64, 2)
+        assert record[F["rounds"]] == 7 and record[-5:] == (4, 3, 64, 2, 1024)
 
 
 class TestSlowRound:
