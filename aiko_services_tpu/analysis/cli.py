@@ -3,7 +3,7 @@
 #   --pipeline DEF.json    contract-check pipeline definitions (repeat)
 #   --lint PATH            lint files/directories (repeat)
 #   --self-check           the repo's own CI gate: lint the package +
-#                          bench.py + scripts/ + tools/, run the
+#                          scripts/ + tools/, run the
 #                          interprocedural effect analysis, the
 #                          metric-drift and wire-schema checkers, the
 #                          bundled example pipelines, and the stale-
@@ -59,9 +59,9 @@ def _looks_like_pipeline(pathname: Path) -> bool:
 
 
 def _self_check_paths() -> list:
-    """The repo's own lint surface: the package, bench.py, and the
-    scripts/ and tools/ trees (soaks and A/B harnesses used to escape
-    analysis entirely)."""
+    """The repo's own lint surface: the package and the scripts/ and
+    tools/ trees (soaks and converters used to escape analysis
+    entirely), and a root bench.py where a checkout has one."""
     root = _repo_root()
     paths = [_package_root()]
     for extra in ("bench.py", "scripts", "tools"):

@@ -2,7 +2,7 @@
 #
 # Ten PRs of growth created two unchecked surfaces:
 #
-#   * ~80 metric/bench family names consumed by bench.py, scripts/,
+#   * ~80 metric/bench family names consumed by scripts/, tools/,
 #     the autoscaler, and the dashboard with no cross-check against
 #     their registry creation sites — a renamed
 #     `serving_itl_seconds` ships silently and every consumer reads 0
@@ -188,9 +188,10 @@ class _MetricScan(ast.NodeVisitor):
 
 
 def _is_consumer_path(path: Path, root: Path) -> bool:
-    """Files whose metric-name strings count as CONSUMPTION: bench,
-    scripts/, tools/, the autoscaler, the dashboard, and observe/
-    (journey merging, export)."""
+    """Files whose metric-name strings count as CONSUMPTION: a
+    bench.py (this repo has none since PR 29; tests/test_effects.py
+    brings its own), scripts/, tools/, the autoscaler, the dashboard,
+    and observe/ (journey merging, export)."""
     try:
         rel = path.resolve().relative_to(root.resolve())
     except ValueError:

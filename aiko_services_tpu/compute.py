@@ -43,8 +43,8 @@ def enable_compile_cache() -> str:
     when set, is honoured as jax itself reads it and no directory is
     set in code (so whoever runs the program can place the cache where
     it survives); otherwise the cache lives at COMPILE_CACHE_DIR.
-    Entry points call this (chip_smoke.py, bench.py, `aiko_tpu
-    pipeline create`); the test suite does not."""
+    Entry points call this (chip_smoke.py, `aiko_tpu pipeline
+    create`); the test suite does not."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

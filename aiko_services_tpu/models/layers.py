@@ -95,13 +95,8 @@ def quantize_linear(params):
     the bf16 matmul up to int8 rounding of the weights — activations
     stay full precision (W8A16).
 
-    Measured r5 at the llama 1b/256-slot serving shape
-    (tools/ab_w8.py): device step 11.32 → 11.02 ms (−2.6%) and a
-    closed-loop wash — the weight-byte halving does NOT buy the ~3 ms
-    its share of a bandwidth-bound step would predict, so the step is
-    scheduling-bound there (or XLA hoists the converted weights out
-    of the decode scan; undiagnosed).  Treat W8 as a MEMORY lever: it
-    frees 1.24 GB of the 1b weight set for more KV slots.  Returns
+    Half the weights' bytes in memory; what it does to a decode
+    step's time no cell has measured on this installation.  Returns
     {"w8": int8 [in,out], "s": f32 [out]} (+"b" passthrough), which
     linear() consumes transparently."""
     w = params["w"]
@@ -304,11 +299,7 @@ def quantize_kv(tensor, mode: str = "position"):
     The scale is constant along the head/position/feature axes, so
     the dequant is a bare int8→bf16 convert as the dot operand (mha
     folds the scale into the softmax scale / output as a per-batch
-    broadcast), which XLA fuses instead of materializing — measured
-    r5 at the whisper decode shape: 38% faster per step than the
-    bf16 read in isolation (tools/diag_attn_patterns.py: 1334 vs
-    2156 us/rep), −14% whole-round in the fused program (a global
-    scalar measured −17% but couples co-batched streams).  Coarser
+    broadcast), which XLA fuses instead of materializing.  Coarser
     scale than "position", so slightly larger error.
 
     Returns {"q": int8, "s": scale} — dequantize_kv handles both

@@ -170,15 +170,6 @@ def _attention(layer, config: LlamaConfig, x, cos, sin, cache,
 
 
 def _swiglu(layer, x):
-    if "gate_up" in layer:
-        # serving._fuse_decode_projections form: one [dim, 2*ffn]
-        # matmul, split after — halves the FFN's projection op count
-        # for tiny-M decode steps
-        gate_up = L.linear(layer["gate_up"], x)
-        ffn = gate_up.shape[-1] // 2
-        return L.linear(layer["down"],
-                        jax.nn.silu(gate_up[..., :ffn]) *
-                        gate_up[..., ffn:])
     return L.linear(layer["down"],
                     jax.nn.silu(L.linear(layer["gate"], x)) *
                     L.linear(layer["up"], x))

@@ -85,174 +85,19 @@ def _attend_ladder(max_seq: int, kv_block: int) -> tuple:
     return tuple(sorted(widths))
 
 __all__ = ["ContinuousDecoder", "DecodeRequest", "PrefixKVCache",
-           "prefix_chain_keys", "check_block_geometry",
-           "measure_device_step"]
+           "prefix_chain_keys", "check_block_geometry"]
 
-
-def measure_device_step(decoder, steps_per_sync: int = 64,
-                        chains: int = 4) -> float:
-    """Chained pure-device decode-step milliseconds for `decoder`'s
-    compiled step at its serving shape: fresh zero caches, `chains`
-    back-to-back rounds, ONE host sync at the end — separates device
-    compute from the host's per-round dispatch+sync.  The single
-    methodology behind the bench's llama_device_step_ms and
-    tools/ab_w8.py, so the two cannot drift.  Probes the decoder's OWN
-    configuration (int8 KV layout, speculative step) — in speculative
-    mode the number is per VERIFY iteration, which emits up to
-    1 + speculate_k tokens."""
-    config = decoder.config
-    slots = decoder.max_slots
-    tokens = jnp.ones((slots,), jnp.int32)
-    lengths = jnp.zeros((slots,), jnp.int32)
-    active = jnp.ones((slots,), bool)
-    budgets = jnp.full((slots,), 1 << 30, jnp.int32)
-    context = jnp.zeros((slots, decoder.max_seq), jnp.int32) \
-        if decoder.speculate_k else None
-    if decoder.paged:
-        # paged probe: fresh zero pools at the pool's CURRENT capacity
-        # (shape-identical to the serving pool, so the compiled
-        # executable is the one serving runs) and round-robin distinct
-        # tables at the cap, the widest the serving step attends at
-        nb = -(-decoder._cache_t // decoder.kv_block)
-        k_probe = decoder.pool._zero_pools(decoder.pool.num_blocks)
-        v_probe = decoder.pool._zero_pools(decoder.pool.num_blocks)
-        ids = 1 + (np.arange(slots * nb) %
-                   max(1, decoder.pool.num_blocks - 1))
-        tables = jnp.asarray(ids.reshape(slots, nb).astype(np.int32))
-    else:
-        k_probe = decoder._zero_caches()
-        v_probe = decoder._zero_caches()
-
-    def chain(rounds):
-        nonlocal k_probe, v_probe, tokens, lengths, context
-        out = None
-        for _ in range(rounds):
-            if decoder.paged and decoder.speculate_k:
-                out = decoder._step(decoder.params, tokens, lengths,
-                                    active, budgets, context, k_probe,
-                                    v_probe, tables,
-                                    num_steps=steps_per_sync, eos=-1,
-                                    t_cap=decoder._cache_t)
-                (_, _, tokens, lengths, context, k_probe,
-                 v_probe) = out
-            elif decoder.paged:
-                out = decoder._step(decoder.params, tokens, lengths,
-                                    active, budgets, k_probe, v_probe,
-                                    tables, num_steps=steps_per_sync,
-                                    eos=-1, t_cap=decoder._cache_t)
-                _, _, tokens, lengths, k_probe, v_probe = out
-            elif decoder.speculate_k:
-                out = decoder._step(decoder.params, tokens, lengths,
-                                    active, budgets, context, k_probe,
-                                    v_probe, num_steps=steps_per_sync,
-                                    eos=-1)
-                (_, _, tokens, lengths, context, k_probe,
-                 v_probe) = out
-            else:
-                out = decoder._step(decoder.params, tokens, lengths,
-                                    active, budgets, k_probe, v_probe,
-                                    num_steps=steps_per_sync, eos=-1)
-                _, _, tokens, lengths, k_probe, v_probe = out
-        np.asarray(out[0][-1])          # one sync for the chain
-    chain(1)                             # warm (compile cache hit)
-    start = time.perf_counter()
-    chain(chains)
-    return ((time.perf_counter() - start) * 1000.0
-            / (chains * steps_per_sync))
-
-
-# decode attention inner loop for the "select" KV mode: "two_pass"
-# (scores einsum + softmax + weights einsum), "online" (flash-style
-# single sweep over time blocks with running max/sum — measured a
-# wash, -1%), or "vpu" (broadcast-multiply reductions — measured 70%
-# SLOWER; kept as the recorded dead end).  The "block" KV mode (the
-# default) hardcodes the two-pass einsums — ATTENTION_IMPL has no
-# effect there; tools/ab_decode_attention.py pins KV mode per case so
-# the labels stay meaningful.
-# "paged_kernel" (ISSUE 16) applies to PAGED decoders only: the
-# decode/spec/extend attentions run the fused pallas kernel
-# (ops.paged_attention) reading pool blocks straight through the
-# block table — no slot-major gather materializes.  The gather path
-# stays the bit-parity oracle; dense decoders ignore the value (it
-# falls through to two_pass).  Read at decoder CONSTRUCTION (stashed
-# as self.paged_kernel), so flipping the module global never switches
-# a live decoder's compiled programs mid-stream.
+# how a PAGED decoder's step, speculative step and extend attend:
+# "two_pass" gathers slot-major views of the pool through the block
+# table and runs the scores / softmax / weights einsums over them (the
+# default, and the bit-parity oracle); "paged_kernel" runs the fused
+# pallas kernel (ops.paged_attention), which reads pool blocks straight
+# through the table and builds no views.  A dense decoder attends its
+# own cache and takes no kernel.  Read at decoder CONSTRUCTION (stashed
+# as self.paged_kernel), so flipping the module global never switches a
+# live decoder's compiled programs mid-stream; any other value is
+# refused there.
 ATTENTION_IMPL = os.environ.get("AIKO_DECODE_ATTENTION", "two_pass")
-# KV write strategy inside the decode scan:
-#   "select" — masked full-cache select per step (r4 design);
-#   "block"  — new tokens land in a small [S, H, num_steps, D] side
-#              buffer at the SCAN index (uniform across slots, so XLA
-#              updates in place) and merge into the main cache once per
-#              round.  The main cache is READ-ONLY inside the scan.
-# Measured motivation: step time vs cache size has a 37.9 us/T slope
-# where the read-only floor is 10.2 us/T — the functional full-cache
-# select makes XLA touch the KV ~4x per step (read for the select,
-# write the full result, read again for attention, x K and V).  The
-# side buffer removes every full-cache write from the hot loop:
-# measured 14.6 -> 11.4 ms/step at the 1b/256-slot/cache-256 serving
-# shape (slope 37.9 -> 16.1 us/T), identical tokens vs the oracle
-# across the whole serving suite.  "select" remains available; it
-# measures slightly better only below ~cache 180 (the merge+side
-# fixed cost), where steps are cheap anyway.
-KV_WRITE = os.environ.get("AIKO_DECODE_KV", "block")
-_ONLINE_BLOCK = 256         # time-block per online-softmax sweep step
-
-
-def _online_decode_attention(q_grouped, k_cache, v_cache, lengths,
-                             scale):
-    """Single-pass GQA decode attention: lax.scan over time blocks
-    with a running (max, sum, accumulator) — the flash-attention
-    recurrence expressed in plain XLA, so K and V stream through HBM
-    exactly once instead of once per einsum pass.
-
-    q_grouped: [S, Hkv, G, 1, D]; caches [S, Hkv, T, D]; lengths [S].
-    Returns [S, Hkv, G, 1, D] f32."""
-    slots_n, num_kv, group, num_q, head_dim = q_grouped.shape
-    t_total = k_cache.shape[2]
-    block = min(_ONLINE_BLOCK, t_total)
-    num_blocks = -(-t_total // block)
-    pad = num_blocks * block - t_total
-    if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    # [blocks, S, Hkv, block, D]: scan carries one block per step
-    k_blocks = jnp.moveaxis(
-        k_cache.reshape(slots_n, num_kv, num_blocks, block, head_dim),
-        2, 0)
-    v_blocks = jnp.moveaxis(
-        v_cache.reshape(slots_n, num_kv, num_blocks, block, head_dim),
-        2, 0)
-    positions = jnp.arange(block)
-
-    def body(carry, inputs):
-        running_max, running_sum, acc = carry
-        index, k_blk, v_blk = inputs
-        t0 = index * block
-        valid = ((t0 + positions)[None, :] <=
-                 lengths[:, None])[:, None, None, None]   # [S,1,1,1,B]
-        scores = jnp.einsum("skgqd,skbd->skgqb", q_grouped, k_blk,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid, scores, -jnp.inf)
-        blk_max = jnp.max(scores, axis=-1, keepdims=True)
-        new_max = jnp.maximum(running_max, blk_max)
-        # rescale the old accumulator into the new max's frame
-        correction = jnp.exp(running_max - new_max)
-        probs = jnp.exp(scores - new_max)
-        new_sum = running_sum * correction + \
-            jnp.sum(probs, axis=-1, keepdims=True)
-        acc = acc * correction + jnp.einsum(
-            "skgqb,skbd->skgqd", probs.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32)
-        return (new_max, new_sum, acc), None
-
-    init = (jnp.full((slots_n, num_kv, group, num_q, 1), -jnp.inf,
-                     jnp.float32),
-            jnp.zeros((slots_n, num_kv, group, num_q, 1), jnp.float32),
-            jnp.zeros((slots_n, num_kv, group, num_q, head_dim),
-                      jnp.float32))
-    (final_max, final_sum, acc), _ = jax.lax.scan(
-        body, init, (jnp.arange(num_blocks), k_blocks, v_blocks))
-    return acc / jnp.maximum(final_sum, 1e-30)
 
 
 @dataclasses.dataclass
@@ -1096,86 +941,6 @@ class PrefixKVCache:
         return evicted
 
 
-def _slot_attention(layer, config: LlamaConfig, x, cos, sin,
-                    k_cache, v_cache, lengths, write_mask):
-    """One-token attention for all slots at per-slot positions.
-
-    x: [S, 1, dim]; k_cache/v_cache: [S, H_kv, T, D]; lengths: [S] —
-    tokens already in each slot's context (the new token's position).
-    write_mask: [S] bool — only these slots commit their K/V write.  A
-    mid-prefill slot's stale `lengths` entry points INTO the prompt
-    region its extend chunks are writing; an unmasked write would
-    corrupt it from the decode scan running between chunks.
-
-    The cache's time axis T is NOT max_seq: the decoder allocates the
-    smallest block multiple covering the longest active context and
-    grows/shrinks the allocation between rounds (see
-    ContinuousDecoder._fit_caches).  Decode is HBM-bound, so the step
-    streams exactly the bytes the workload needs — an in-program
-    slice of a max_seq cache was measured to MATERIALIZE the slice
-    per layer per step (scatter output feeding a dot can't fuse),
-    tripling the attention bytes."""
-    num_heads, num_kv = config.num_heads, config.num_kv_heads
-    q, k, v = _project_qkv(layer, config, x)
-    q = L.apply_rope(q, cos, sin, lengths)
-    k = L.apply_rope(k, cos, sin, lengths)
-
-    # write this token's K/V at each slot's own cursor — as a masked
-    # select, not a scatter: a per-slot-index scatter defeats XLA's
-    # in-place/fusion analysis inside the scan, and the full-cache
-    # select was measured ~12% faster per step at the serving shape
-    hit = (jnp.arange(k_cache.shape[2])[None, None, :, None] ==
-           lengths[:, None, None, None]) & \
-        write_mask[:, None, None, None]             # [S,1,T,1]
-    k_cache = jnp.where(hit, k[:, :, 0][:, :, None], k_cache)
-    v_cache = jnp.where(hit, v[:, :, 0][:, :, None], v_cache)
-
-    # attend over each slot's valid prefix (inclusive of the new token).
-    # GQA via a grouped einsum against the SHARED KV — materializing
-    # repeated caches (jnp.repeat) costs group× HBM and halves the slot
-    # capacity that fits on a chip.  Scores run as bf16×bf16 MXU
-    # matmuls with f32 ACCUMULATION (preferred_element_type) — an
-    # explicit f32 upcast of the cache would double the HBM bytes of
-    # the read, which is the dominant cost of the step.
-    slots_n, num_q, head_dim = q.shape[0], q.shape[2], q.shape[3]
-    group = num_heads // num_kv
-    q_grouped = q.reshape(slots_n, num_kv, group, num_q, head_dim)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-    if ATTENTION_IMPL == "online":
-        out = _online_decode_attention(q_grouped, k_cache, v_cache,
-                                       lengths, scale)
-    elif ATTENTION_IMPL == "vpu":
-        # broadcast-multiply + reduce instead of MXU matmuls: the
-        # per-(slot, kv-head) matmul is M=group (tiny) — issue-rate
-        # bound on the MXU; the VPU variant streams the same bytes as
-        # fused elementwise reductions
-        valid = (jnp.arange(k_cache.shape[2])[None] <=
-                 lengths[:, None])[:, None, None]        # [S,1,1,T]
-        q_sq = q_grouped[:, :, :, 0]                     # [S,kv,G,D]
-        scores = jnp.sum(
-            q_sq[:, :, :, None, :].astype(jnp.float32) *
-            k_cache[:, :, None, :, :].astype(jnp.float32),
-            axis=-1) * scale                             # [S,kv,G,T]
-        scores = jnp.where(valid, scores, -1e30)
-        weights = jax.nn.softmax(scores, axis=-1)
-        out = jnp.sum(
-            weights[..., None] *
-            v_cache[:, :, None, :, :].astype(jnp.float32),
-            axis=3)[:, :, :, None, :]                    # [S,kv,G,1,D]
-    else:
-        valid = (jnp.arange(k_cache.shape[2])[None] <=
-                 lengths[:, None])[:, None, None, None]  # [S,1,1,1,T]
-        scores = jnp.einsum("skgqd,sktd->skgqt", q_grouped, k_cache,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid, scores, -1e30)
-        weights = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-        out = jnp.einsum("skgqt,sktd->skgqd", weights, v_cache,
-                         preferred_element_type=jnp.float32)
-    out = out.reshape(slots_n, num_heads, num_q, head_dim).astype(x.dtype)
-    return (L.linear(layer["attn"]["o"], L._merge_heads(out)),
-            k_cache, v_cache)
-
-
 def _kv_planes(cache, dtype):
     """(dot-operand values, fold scale or None) for a main-cache leaf.
     int8 caches (layers.quantize_kv_cache) keep the int8 buffer as the
@@ -1211,7 +976,14 @@ def _grouped_block_attention(layer, config: LlamaConfig, x, cos, sin,
     and fold their per-(slot, head, position) scales into the main
     scores (K) and weights (V); the side buffers stay in the compute
     dtype (they are one round wide — quantizing them would save
-    nothing and cost an int8 round-trip every step)."""
+    nothing and cost an int8 round-trip every step).
+
+    GQA runs as a grouped einsum against the SHARED KV: materializing
+    repeated caches (jnp.repeat) multiplies the cache's bytes by the
+    group and divides the slots that fit on a chip by it.  Scores and
+    outputs are dots in the cache's own dtype with f32 ACCUMULATION
+    (preferred_element_type): an explicit f32 upcast of the cache
+    would double the bytes of the step's dominant read."""
     num_heads, num_kv = config.num_heads, config.num_kv_heads
     with jax.named_scope(SCOPE_ATTN_PROJ):
         q, k, v = _project_qkv(layer, config, x)
@@ -1299,69 +1071,13 @@ def _slot_attention_spec(layer, config: LlamaConfig, x, cos, sin,
                                     side_valid)
 
 
-def _fuse_decode_projections(params):
-    """Opt-in serving transform: concatenate each layer's q/k/v weight
-    matrices into one [dim, (Hq+2Hkv)*D] matmul and gate/up into one
-    [dim, 2*ffn].  The decode step's activations are [S, 1, dim], so
-    its ~14 projections per layer are tiny-M matmuls whose cost is
-    issue/scheduling, not FLOPs — the W8 wash (see quantize_linear)
-    showed weight BYTES aren't the binding constraint, so this halves
-    the op COUNT instead.  Measured r5 at the 1b/256-slot shape
-    (tools/ab_w8.py AB_MODE=fuse): device step 11.27 → 11.68 ms,
-    +3.6% — a DEAD END on this toolchain (XLA already schedules the
-    separate matmuls; the fused output's split costs more than the
-    saved issues).  Kept opt-in as the recorded negative result, like
-    serving's other measured dead ends.
-
-    Tree shape after the transform: attn gains a "qkv" copy while
-    q/k/v REMAIN (the prefill/extend attention goes through
-    layers.mha, which needs them; _param_bytes excludes the duplicate
-    so traffic stats stay honest); gate/up are REPLACED by "gate_up"
-    outright, because every FFN path routes through llama_ffn →
-    _swiglu, which prefers the fused form.  Biases are asserted
-    absent — silently dropping one would corrupt outputs.  Outputs
-    are not bit-identical to the unfused step (different f32
-    accumulation tiling), so this stays opt-in and A/B-gated."""
-    new_layers = []
-    for layer in params["layers"]:
-        layer = dict(layer)
-        attn = dict(layer["attn"])
-        # hard errors, not asserts: python -O strips asserts and a
-        # silently-dropped bias corrupts every output (ADVICE r5)
-        if any("b" in attn[k] for k in ("q", "k", "v")):
-            raise ValueError(
-                "fuse_projections drops linear biases; refusing")
-        attn["qkv"] = {"w": jnp.concatenate(
-            [attn["q"]["w"], attn["k"]["w"], attn["v"]["w"]], axis=1)}
-        layer["attn"] = attn
-        if "gate" in layer:
-            if "b" in layer["gate"] or "b" in layer["up"]:
-                raise ValueError(
-                    "fuse_projections drops FFN biases; refusing")
-            layer["gate_up"] = {"w": jnp.concatenate(
-                [layer["gate"]["w"], layer["up"]["w"]], axis=1)}
-            del layer["gate"], layer["up"]
-        new_layers.append(layer)
-    return {**params, "layers": new_layers}
-
-
 def _project_qkv(layer, config: LlamaConfig, x):
-    """q/k/v for the decode step: one fused matmul when the layer
-    carries the _fuse_decode_projections form, else the canonical
-    three."""
-    num_heads, num_kv = config.num_heads, config.num_kv_heads
+    """q/k/v of a [S, W] block, split into heads: the one place the
+    decode, verify and paged programs project them."""
     attn = layer["attn"]
-    if "qkv" in attn:
-        qkv = L.linear(attn["qkv"], x)
-        q_dim = num_heads * config.head_dim
-        kv_dim = num_kv * config.head_dim
-        q = L._split_heads(qkv[..., :q_dim], num_heads)
-        k = L._split_heads(qkv[..., q_dim:q_dim + kv_dim], num_kv)
-        v = L._split_heads(qkv[..., q_dim + kv_dim:], num_kv)
-    else:
-        q = L._split_heads(L.linear(attn["q"], x), num_heads)
-        k = L._split_heads(L.linear(attn["k"], x), num_kv)
-        v = L._split_heads(L.linear(attn["v"], x), num_kv)
+    q = L._split_heads(L.linear(attn["q"], x), config.num_heads)
+    k = L._split_heads(L.linear(attn["k"], x), config.num_kv_heads)
+    v = L._split_heads(L.linear(attn["v"], x), config.num_kv_heads)
     return q, k, v
 
 
@@ -1403,60 +1119,26 @@ def _build_step(config: LlamaConfig):
     cos, sin = L.rope_frequencies(config.head_dim, config.max_seq_len,
                                   config.rope_theta)
 
-    def one_token(params, tokens, lengths, active, k_caches, v_caches):
-        new_k, new_v = [], []
-
-        def attend(i, layer, normed):
-            attn_out, k_c, v_c = _slot_attention(
-                layer, config, normed, cos, sin, k_caches[i],
-                v_caches[i], lengths, active)
-            new_k.append(k_c)
-            new_v.append(v_c)
-            return attn_out
-
-        next_tokens = _token_block_argmax(params, config,
-                                          tokens[:, None], attend)[:, 0]
-        return next_tokens, new_k, new_v
-
-    def step_k(params, tokens, lengths, active, budgets, k_caches,
-               v_caches, num_steps, eos):
+    def step_k_block(params, tokens, lengths, active, budgets,
+                     k_caches, v_caches, num_steps, eos):
         """lax.scan of `num_steps` iterations; returns tokens emitted
         [K, S] plus the per-step active mask [K, S] (True where the
         emitted token is real output).  A slot retires INSIDE the scan
         the moment it emits `eos` or exhausts its `budgets` entry —
         retired slots stop growing their context and their later
         emissions are discarded by the host, so a request finishing at
-        step 1 of a 32-step round no longer pollutes its cache or
-        miscounts as useful work."""
-        def body(carry, _):
-            tokens, lengths, active, budgets, k_caches, v_caches = carry
-            next_tokens, k_caches, v_caches = one_token(
-                params, tokens, lengths, active, k_caches, v_caches)
-            next_tokens = jnp.where(active, next_tokens, tokens)
-            lengths = jnp.where(active, lengths + 1, lengths)
-            budgets = jnp.where(active, budgets - 1, budgets)
-            still = active & (budgets > 0) & (next_tokens != eos)
-            return ((next_tokens, lengths, still, budgets, k_caches,
-                     v_caches), (next_tokens, active))
+        step 1 of a 32-step round neither pollutes its cache nor
+        miscounts as useful work.
 
-        (tokens, lengths, active, budgets, k_caches, v_caches), \
-            (emitted, emitted_active) = jax.lax.scan(
-                body, (tokens, lengths, active, budgets, k_caches,
-                       v_caches), None, length=num_steps)
-        return (emitted, emitted_active, tokens, lengths,
-                k_caches, v_caches)
-
-    def step_k_block(params, tokens, lengths, active, budgets,
-                     k_caches, v_caches, num_steps, eos):
-        """Block-KV variant of step_k: the main caches stay READ-ONLY
-        through the scan (closed over, never carried), this round's
-        K/V land in [S, H, num_steps, D] side buffers at the scan
-        index, and one per-slot merge runs after the scan.  Removes
-        the per-step full-cache writes that made each step touch the
-        KV ~4x (measured slope 37.9 us/T vs a 10.2 read-only floor).
-        int8 main caches (kv_cache_dtype="int8") are read via the
-        scale fold and the merge quantizes the side rows ONCE per
-        round — the scan itself never touches int8 encode."""
+        The main caches stay READ-ONLY through the scan (closed over,
+        never carried): this round's K/V land in [S, H, num_steps, D]
+        side buffers at the scan index — uniform across slots, so XLA
+        updates them in place, where a functional write at per-slot
+        cursors makes every step rewrite the whole cache — and one
+        per-slot merge runs after the scan.  int8 main caches
+        (kv_cache_dtype="int8") are read via the scale fold and the
+        merge quantizes the side rows ONCE per round — the scan itself
+        never touches int8 encode."""
         entry_lengths = lengths
         entry_active = active
         slots_n = tokens.shape[0]
@@ -1501,8 +1183,8 @@ def _build_step(config: LlamaConfig):
         # attended (same invariant as the admit scatter's padding).
         # Slots INACTIVE at round entry must not merge at all: a
         # mid-prefill slot's stale length points INTO the prompt its
-        # extend chunks are writing (the same corruption the select
-        # mode's write_mask guards against).
+        # extend chunks are writing, and the decode scan runs between
+        # those chunks.
         merge_at = jnp.minimum(entry_lengths,
                                _cache_time(k_caches[0]) - num_steps)
         keep = entry_active[:, None, None, None]
@@ -1533,21 +1215,18 @@ def _build_step(config: LlamaConfig):
         return (emitted, emitted_active, tokens, lengths,
                 new_k_caches, new_v_caches)
 
-    return jax.jit(step_k_block if KV_WRITE == "block" else step_k,
+    return jax.jit(step_k_block,
                    static_argnames=("num_steps", "eos"),
                    donate_argnames=("k_caches", "v_caches"))
 
 
 @functools.lru_cache(maxsize=16)
-def _step_for(config: LlamaConfig, kv_write: str, attention_impl: str):
+def _step_for(config: LlamaConfig):
     """Process-wide cache of compiled step builders: decoders sharing
     a config share ONE jit object, so the XLA executables inside it
     (keyed by shapes / static args) are reused across instances —
     rebuilding a decoder, or building several in one process (tests,
-    A/B tools, multi-tenant serving), pays no recompile.  Keyed on the
-    module toggles too, so tools that flip serving.KV_WRITE /
-    ATTENTION_IMPL (ab_decode_attention) still get the variant they
-    set."""
+    multi-tenant serving), pays no recompile."""
     return _build_step(config)
 
 
@@ -1753,11 +1432,9 @@ def _build_spec_step(config: LlamaConfig, k_spec: int, ngram: int):
 
 
 @functools.lru_cache(maxsize=16)
-def _spec_step_for(config: LlamaConfig, k_spec: int, ngram: int,
-                   kv_write: str):
+def _spec_step_for(config: LlamaConfig, k_spec: int, ngram: int):
     """Same process-wide sharing as _step_for, for the speculative
-    variant (kv_write in the key for symmetry — the builder requires
-    block mode, enforced at construction)."""
+    variant."""
     return _build_spec_step(config, k_spec, ngram)
 
 
@@ -1772,8 +1449,7 @@ class ContinuousDecoder:
     admit outputs, retire EOS/max-length slots through their
     callbacks.  Opt-in levers: kv_cache_dtype="int8" (half the cache
     read of the HBM-bound step), speculate_k=k (multi-token decoding
-    via self-drafted prompt lookup, greedy-equivalent), weight_quant,
-    fuse_projections."""
+    via self-drafted prompt lookup, greedy-equivalent), weight_quant."""
 
     def __init__(self, params, config: LlamaConfig, max_slots: int = 8,
                  max_seq: int | None = None, eos_token: int | None = None,
@@ -1781,13 +1457,16 @@ class ContinuousDecoder:
                  t_block: int = 256, prefill_chunk: int | None = None,
                  prefill_budget: int | None = None,
                  weight_quant: bool = False,
-                 fuse_projections: bool = False,
                  kv_cache_dtype: str | None = None,
                  speculate_k: int = 0, speculate_ngram: int = 2,
                  name: str = "decoder", registry=None,
                  prefix_cache: PrefixKVCache | None = None,
                  paged_kv: bool = False, kv_block: int = 32):
         self.config = config
+        if ATTENTION_IMPL not in ("two_pass", "paged_kernel"):
+            raise ValueError(
+                f"AIKO_DECODE_ATTENTION / serving.ATTENTION_IMPL must be "
+                f"'two_pass' or 'paged_kernel', got {ATTENTION_IMPL!r}")
         # int8 KV cache (ISSUE 7): the slot caches store int8 values
         # with per-(slot, head, position) f32 scales
         # (layers.quantize_kv_cache).  Admits/extends write quantized
@@ -1803,12 +1482,6 @@ class ContinuousDecoder:
                 f"kv_cache_dtype must be None/'native'/'int8', got "
                 f"{kv_cache_dtype!r}")
         self.kv_int8 = dtype_norm == "int8"
-        if self.kv_int8 and KV_WRITE != "block":
-            raise ValueError(
-                "kv_cache_dtype='int8' requires the block KV write "
-                "mode (AIKO_DECODE_KV=block): the select mode rewrites "
-                "the whole cache per step, which would re-encode int8 "
-                "every iteration")
         # self-speculative decoding (ISSUE 7): each scan iteration
         # drafts speculate_k tokens by prompt lookup over a device-side
         # context buffer and verifies the widened block in one forward;
@@ -1824,25 +1497,16 @@ class ContinuousDecoder:
         self.speculate_ngram = int(speculate_ngram)
         if self.speculate_k and self.speculate_ngram < 1:
             raise ValueError("speculate_ngram must be >= 1")
-        if self.speculate_k and KV_WRITE != "block":
-            raise ValueError(
-                "speculate_k requires the block KV write mode "
-                "(AIKO_DECODE_KV=block)")
         # weight-only int8 (W8A16): every linear's weight tree-rewritten
         # to {w8, s} once here — linear()/linear_logits consume it
         # transparently across prefill, chunked extends, and the
-        # decode scan.  Measured r5 (tools/ab_w8.py, 1b/256 slots):
-        # device step −2.6%, closed loop a wash — a MEMORY lever
-        # (1.24 GB of weights freed for more KV slots), not a speed
-        # lever; see layers.quantize_linear for the numbers.  Greedy
-        # outputs are NOT bit-identical to bf16 (int8 rounding), and
-        # MoE routers are excluded (top-k flips).
-        if fuse_projections:
-            params = _fuse_decode_projections(params)
+        # decode scan.  Half the weights' bytes; not measured in any
+        # cell on this installation.  Greedy outputs are NOT
+        # bit-identical to bf16 (int8 rounding), and MoE routers are
+        # excluded (top-k flips).
         if weight_quant:
             params = L.quantize_linear_tree(params)
         self.weight_quant = bool(weight_quant)
-        self.fuse_projections = bool(fuse_projections)
         self.params = params
         self.max_slots = max_slots
         self.max_seq = max_seq or config.max_seq_len
@@ -1896,15 +1560,9 @@ class ContinuousDecoder:
         # runs the SAME attention bodies at the same shapes, so greedy
         # output is bit-identical to the dense cache (the parity
         # matrix in tests/test_paged_kv.py asserts it across int8 /
-        # chunked / spec / mid-stream / disagg).  Dense stays the A/B
-        # behind AIKO_BENCH_LLAMA_PAGED=off.
+        # chunked / spec / mid-stream / disagg).  Dense stays the
+        # default and the parity oracle.
         self.paged = bool(paged_kv)
-        if self.paged and KV_WRITE != "block":
-            raise ValueError(
-                "paged_kv requires the block KV write mode "
-                "(AIKO_DECODE_KV=block): the select mode rewrites the "
-                "whole cache inside the scan, which a block pool "
-                "cannot express")
         self.kv_block = int(prefix_cache.block_tokens) \
             if prefix_cache is not None else int(kv_block)
         if self.paged and self.kv_block < 1:
@@ -1951,7 +1609,7 @@ class ContinuousDecoder:
             from .serving_paged import BlockPool
             block = self.kv_block
             # table width covers the worst-case extent a round's merge
-            # can write (max_seq + block-mode merge headroom: cells
+            # can write (max_seq + the merge's headroom: cells
             # that nobody attends, so no step's views reach them)
             headroom = 0 if self.speculate_k else steps_per_sync
             self._table_blocks = -(-(self.max_seq + headroom) // block)
@@ -2039,10 +1697,8 @@ class ContinuousDecoder:
                 else _paged_step_for(config, self.paged_kernel)
         else:
             self._step = _spec_step_for(config, self.speculate_k,
-                                        self.speculate_ngram,
-                                        KV_WRITE) \
-                if self.speculate_k else _step_for(config, KV_WRITE,
-                                                   ATTENTION_IMPL)
+                                        self.speculate_ngram) \
+                if self.speculate_k else _step_for(config)
         # in-flight prefix dedup window (ISSUE 14 satellite): leading
         # block key -> the request currently prefilling that chain.
         # Bounded by the slot pool: entries unregister at early
@@ -2062,22 +1718,13 @@ class ContinuousDecoder:
         # per-step hot path (graft-check lint-hot-alloc polices them)
         self._active_np = np.zeros((max_slots,), bool)
         self._budgets_np = np.zeros((max_slots,), np.int32)
-        # HBM-traffic model for roofline reporting: every decode step
-        # streams the full weight set (embed excluded — it's a gather
-        # of S rows) plus the capped KV read
-        itemsize = jnp.dtype(config.dtype).itemsize
-        # fused qkv copies (fuse_projections) duplicate q/k/v byte-for
-        # -byte — exclude them so bytes_moved counts what one step
-        # actually streams, not both forms
-        self._param_bytes = int(sum(
-            int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-            for path, leaf in jax.tree_util.tree_leaves_with_path(params)
-            if "embed" not in str(path[0]) and
-            not any("qkv" in str(part) for part in path)))
-        # int8 cache: D int8 values + one f32 scale per (slot, head,
-        # position) — ~(D+4)/(2D) of the bf16 bytes
+        # bytes of K and V one cache position holds over all layers
+        # and slots (what the prefix copy-in and harvest counters
+        # charge a position).  int8 cache: D int8 values + one f32
+        # scale per (slot, head, position) — ~(D+4)/(2D) of the bf16
+        # bytes
         per_position = (config.head_dim + 4) if self.kv_int8 \
-            else config.head_dim * itemsize
+            else config.head_dim * jnp.dtype(config.dtype).itemsize
         self._kv_bytes_per_t = (2 * config.num_layers * max_slots *
                                 config.num_kv_heads * per_position)
         # cumulative decode-loop counters, mirrored onto the process
@@ -2116,7 +1763,7 @@ class ContinuousDecoder:
              "tokens_decode": 0, "tokens_prefill": 0,
              "spec_proposed": 0, "spec_accepted": 0,
              "accepted_per_step": 0.0,
-             "bytes_moved": 0, "prefill_chunks": 0,
+             "prefill_chunks": 0,
              "chunk_admits": 0, "prefix_admits": 0,
              "round_prefill_tokens_max": 0,
              "admission_shed": 0,
@@ -3020,11 +2667,11 @@ class ContinuousDecoder:
     def _fit_caches(self, required_t: int) -> None:
         """Resize the cache time axis to the t_block multiple covering
         `required_t` (clamped to max_seq — plus steps_per_sync scratch
-        headroom in block-KV mode, so a round-end side-buffer merge
-        near the seq cap never clamps into a misaligned overwrite;
-        the headroom cells are never attended.  The speculative merge
-        scatters at absolute positions with out-of-bounds drop, so it
-        needs no headroom).  A grow pads with zeros, a shrink slices —
+        headroom, so a round-end side-buffer merge near the seq cap
+        never clamps into a misaligned overwrite; the headroom cells
+        are never attended.  The speculative merge scatters at
+        absolute positions with out-of-bounds drop, so it needs no
+        headroom).  A grow pads with zeros, a shrink slices —
         one whole-cache copy, amortized over the many rounds run at
         the new size.  No-op when already sized."""
         if self.paged:
@@ -3032,7 +2679,7 @@ class ContinuousDecoder:
             # tables, extends and admits keep max_seq, and the step
             # takes its width round by round (_attend_width)
             return
-        if self.speculate_k or KV_WRITE != "block":
+        if self.speculate_k:
             cap = self.max_seq
         else:
             cap = self.max_seq + self.steps_per_sync
@@ -3775,9 +3422,6 @@ class ContinuousDecoder:
                 emitted, emitted_active, wave_firsts = jax.device_get(
                     (emitted, emitted_active, wave_firsts))
             self.stats["decode_s"] += time.perf_counter() - decode_start
-            round_bytes = num_steps * (
-                self._param_bytes + self._kv_bytes_per_t * attend_width)
-            self.stats["bytes_moved"] += round_bytes
         elif wave_firsts:
             wave_firsts = jax.device_get(wave_firsts)
         profiler.enter("wave_resolve")

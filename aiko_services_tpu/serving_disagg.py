@@ -1,12 +1,10 @@
 # Disaggregated prefill/decode serving plane (ISSUE 14, ROADMAP item 2).
 #
-# BENCH_r05 measured prefill riding the decode host gap (~9.2 ms/step of
-# deferred-admit prefill per round before PR 7) and MULTICHIP_r0x shows
-# multi-chip capacity idle for serving.  Production LLM serving converged
-# on the fix (DistServe, Splitwise): split prefill and decode into
-# separately-scaled pools so prompt bursts never dilate inter-token
-# latency.  Every building block already exists in this repo — this
-# module is the composition:
+# Prefill that rides between decode rounds delays every live slot's
+# next token.  Production LLM serving converged on the fix (DistServe,
+# Splitwise): split prefill and decode into separately-scaled pools so
+# prompt bursts never dilate inter-token latency.  Every building
+# block already exists in this repo — this module is the composition:
 #
 #   * PrefillRuntime — a role-tagged actor owning a ContinuousDecoder +
 #     PrefixKVCache pair whose ONLY job is computing prompt KV: each

@@ -39,8 +39,8 @@
 # tables, with out-of-range ids dropping exactly like the dense path's
 # _POS_INVALID entries.  This module owns the pool allocator and the
 # paged compiled-program builders; serving.ContinuousDecoder(
-# paged_kv=True) is the integration point and keeps the dense path as
-# the A/B (AIKO_BENCH_LLAMA_PAGED=off).
+# paged_kv=True) is the integration point; the dense path stays the
+# default and the parity oracle.
 
 from __future__ import annotations
 
@@ -484,7 +484,7 @@ def _gather_views(pools, tables, t_cap: int) -> list:
 # stays the bit-parity ORACLE — tests prove greedy token identity per
 # (int8 × chunked × spec × block size) combination, and the kernel
 # builders key their lru caches on the toggle so both variants coexist
-# in one process (tools/ab_decode_attention.py flips per case).
+# in one process (chip_smoke.py runs one after the other).
 
 def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
                               k_pool, v_pool, tables, k_side, v_side,
@@ -705,7 +705,7 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
 def _paged_step_for(config: LlamaConfig, kernel: bool = False):
     """Process-wide builder cache, like serving._step_for.  Keyed on
     the kernel toggle so the pallas variant and the gather oracle
-    coexist in one process (parity tests, ab_decode_attention)."""
+    coexist in one process (parity tests, chip_smoke.py)."""
     return _build_paged_step(config, kernel)
 
 

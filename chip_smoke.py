@@ -701,13 +701,6 @@ def main(argv=None) -> int:
     clock = CompileClock()
     say(f"device {found}, jax {jax.__version__}, compile cache "
         f"{cache_dir}")
-    if on_chip:
-        # an unknown device kind is an error wherever the peak tables
-        # are used — fail before spending the run, not in the benchmark
-        import bench
-        say(f"peaks for {device.device_kind!r}: "
-            f"{bench.device_peak_flops()[0] / 1e12:.0f} TFLOP/s bf16, "
-            f"{bench.device_peak_membw() / 1e9:.0f} GB/s HBM")
     require(len(jax.devices()) >= args.chips,
             f"--chips {args.chips} but jax sees {found['count']}")
     from aiko_services_tpu.native import NATIVE_AVAILABLE

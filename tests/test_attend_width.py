@@ -115,6 +115,21 @@ def test_same_tokens_as_the_cap_across_two_widths(monkeypatch, params, kv,
     assert laddered.pool.used_blocks() == 0
 
 
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_decoders_of_one_config_share_one_step(params, paged):
+    """The builder caches are keyed by the config alone (and, paged, by
+    the kernel toggle): a second decoder finds the first one's jit
+    object, and with it every executable already compiled."""
+    from aiko_services_tpu import serving_paged
+    kwargs = dict(max_slots=2, prefill_buckets=(16,))
+    one = ContinuousDecoder(params, CONFIG, paged_kv=paged, **kwargs)
+    other = ContinuousDecoder(params, dataclasses.replace(CONFIG),
+                              paged_kv=paged, steps_per_sync=2, **kwargs)
+    shared = serving_paged._paged_step_for(CONFIG, False) if paged \
+        else serving._step_for(CONFIG)
+    assert one._step is other._step is shared
+
+
 class Compiles:
     """jax's own backend-compile events, as benchmark/run.py's CompileClock
     counts them (a hit of the persistent cache fires one too)."""

@@ -99,7 +99,7 @@ CASES = {
     "paged-int8-nofold-w1": lambda: _paged(True, False, 1, 4),
     "paged-int8-fold-w5": lambda: _paged(True, True, 5, 20),
     "paged-int8-nofold-w5": lambda: _paged(True, False, 5, 20),
-    # bench.py's llama rung: 256 slots, 64 steps per sync, int8 KV
+    # a wide serving shape: 256 slots, 64 steps per sync, int8 KV
     "paged-int8-bench-s256": lambda: _paged(True, True, 1, 64,
                                             slots=256, t_cap=1024),
     # chunked-prefill extend: the chunk rides as the side buffer and

@@ -499,12 +499,6 @@ class TestBlockPool:
         assert leaf["q"].dtype == jnp.int8
         assert leaf["s"].shape == leaf["q"].shape[:3]
 
-    def test_measure_device_step_probes_paged(self, params):
-        from aiko_services_tpu.serving import measure_device_step
-        _, paged = pair(params)
-        assert measure_device_step(paged, steps_per_sync=2,
-                                   chains=1) > 0.0
-
 
 # -- direct slot-table install (cacheless disagg landing) -------------------
 

@@ -520,12 +520,15 @@ def test_tenant_slo_rows_split_ttft_by_prefill():
 
 def test_conversation_cached_ttft_near_decode_floor(params):
     """The ISSUE 13 acceptance shape at test scale: multi-turn
-    sessions re-submitting a deep history every turn.  Cached turns
-    must come in with TTFT p50 >= 3x lower than cold turns and the
-    block hit rate above 0.5 — cached-prefix TTFT rides the
-    decode-round floor instead of the history length.  (Token parity
-    of the warm path is proven by the tests above; this one scores the
-    latency shape, so it skips the per-length oracle compiles.)"""
+    sessions re-submitting a deep history every turn.  A cached turn
+    prefills only what its history does not already hold — under a
+    third of a cold turn's prompt tokens, in one or two extend chunks
+    where the cold turn takes ceil(156 / 8) — and the block hit rate stays
+    above 0.5: cached-prefix TTFT rides the decode-round floor instead
+    of the history length.  Scored on the decoder's own counters, not
+    on a clock: the shape holds whatever else the host is running.
+    (Token parity of the warm path is proven by the tests above, so
+    this one skips the per-length oracle compiles.)"""
     config = dataclasses.replace(LLAMA_PRESETS["tiny"], max_seq_len=256)
     cache = PrefixKVCache(block_tokens=8, max_bytes=64 << 20,
                           name="conv")
@@ -534,6 +537,7 @@ def test_conversation_cached_ttft_near_decode_floor(params):
                                 prefill_chunk=8, prefix_cache=cache)
     rng = np.random.default_rng(5)
     done = {}
+    prefilled = {"cold": [], "cached": []}      # (tokens, chunks) a turn
 
     def run_session(session, turns=3):
         # a deep restored transcript: turn 1 re-prefills it COLD,
@@ -543,6 +547,8 @@ def test_conversation_cached_ttft_near_decode_floor(params):
             rid = f"s{session}.t{turn}"
             prompt = history + rng.integers(1, config.vocab,
                                             size=6).tolist()
+            tokens = decoder.stats["tokens_prefill"]
+            chunks = decoder.stats["prefill_chunks"]
             decoder.submit(rid, prompt, 6,
                            lambda rid, t: done.update({rid: t}))
             for _ in range(400):
@@ -550,18 +556,20 @@ def test_conversation_cached_ttft_near_decode_floor(params):
                 if rid in done:
                     break
             assert rid in done and len(done[rid]) == 6
+            prefilled["cached" if turn else "cold"].append(
+                (decoder.stats["tokens_prefill"] - tokens,
+                 decoder.stats["prefill_chunks"] - chunks))
             history = prompt + done[rid]
 
-    # warmup session: every session follows the same turn schedule, so
-    # one full generation compiles the cold admit, the prefix-copy
-    # widths, and the cached extends — measured percentiles must not
-    # carry compile stalls (the bench rung's discipline)
-    run_session("warm")
-    decoder.clear_slo_sketches()
     for session in range(3):
         run_session(session)
-    cached = decoder.slo_sketch_stats(prefill="cached")["ttft_p50_ms"]
-    cold = decoder.slo_sketch_stats(prefill="cold")["ttft_p50_ms"]
-    assert cached is not None and cold is not None
-    assert cold >= 3.0 * cached, (cold, cached)
+    cold_tokens = sum(tokens for tokens, _ in prefilled["cold"])
+    cached_tokens = sum(tokens for tokens, _ in prefilled["cached"])
+    # twice as many cached turns as cold ones, and still under a third
+    assert 0 < 3 * cached_tokens <= cold_tokens, prefilled
+    assert all(chunks == -(-156 // 8)
+               for _, chunks in prefilled["cold"]), prefilled
+    # what a cached turn still owes: the last answer's 6 tokens, the 6
+    # new ones, and whatever falls short of a whole cache block
+    assert all(chunks <= 2 for _, chunks in prefilled["cached"]), prefilled
     assert cache.hit_rate() > 0.5, cache.hit_rate()

@@ -741,8 +741,7 @@ def test_int8_kv_engine_parity_multichunk(params):
 def test_int8_kv_cache_bytes_halved(params):
     """The allocation the mode exists for: int8 values + f32
     per-(slot, head, position) scales vs full-precision values —
-    ~(D+4)/(4D) of the f32 cache here, well under the 'halved' bar
-    the bench's llama_kv_cache_bytes field scores."""
+    ~(D+4)/(4D) of the f32 cache here, well under half."""
     kwargs = dict(max_slots=4, prefill_buckets=(16,), steps_per_sync=4)
     full = ContinuousDecoder(params, CONFIG, **kwargs)
     i8 = ContinuousDecoder(params, CONFIG, kv_cache_dtype="int8",
@@ -880,28 +879,21 @@ def test_offpath_prefill_stats_split(params):
                               {"kind": kind}) >= decoder.stats[kind]
 
 
-def test_fused_projections_match_oracle(params):
-    """fuse_projections=True (one qkv matmul + one gate_up matmul per
-    layer, serving._fuse_decode_projections) must serve the oracle's
-    tokens: the fused matmul contracts the same [dim] axis per output
-    column, so on the test geometry the greedy outputs match the
-    unfused engine exactly (larger geometries may differ in f32
-    accumulation tiling — the mode stays opt-in and A/B-gated)."""
-    decoder = ContinuousDecoder(params, CONFIG, max_slots=4,
-                                prefill_buckets=(16,), steps_per_sync=4,
-                                fuse_projections=True)
-    done = {}
-    prompts = {f"r{i}": [i + 2, (i * 13) % 50 + 1, 9] for i in range(5)}
-    for rid, prompt in prompts.items():
-        decoder.submit(rid, prompt, 10,
-                       lambda rid, t: done.update({rid: t}))
-    for _ in range(80):
-        decoder.pump()
-        if len(done) == len(prompts):
-            break
-    assert len(done) == len(prompts)
-    for rid, prompt in prompts.items():
-        assert done[rid] == oracle(params, prompt, 10), rid
+@pytest.mark.parametrize("impl", ["online", "vpu", "two-pass"])
+def test_unknown_attention_impl_is_refused(params, impl):
+    """serving.ATTENTION_IMPL has two values; anything else (the
+    removed "online" and "vpu", a typo) is refused where a decoder is
+    built, dense or paged, and never silently served as two_pass."""
+    from aiko_services_tpu import serving
+    before = serving.ATTENTION_IMPL
+    serving.ATTENTION_IMPL = impl
+    try:
+        for paged in (False, True):
+            with pytest.raises(ValueError, match="two_pass.*paged_kernel"):
+                ContinuousDecoder(params, CONFIG, max_slots=2,
+                                  prefill_buckets=(16,), paged_kv=paged)
+    finally:
+        serving.ATTENTION_IMPL = before
 
 
 def test_deadline_admission_sheds_doomed_request(params):
