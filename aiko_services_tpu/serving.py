@@ -90,14 +90,17 @@ __all__ = ["ContinuousDecoder", "DecodeRequest", "PrefixKVCache",
 # how a PAGED decoder's step, speculative step and extend attend:
 # "two_pass" gathers slot-major views of the pool through the block
 # table and runs the scores / softmax / weights einsums over them (the
-# default, and the bit-parity oracle); "paged_kernel" runs the fused
+# bit-parity oracle, and the CPU's path); "paged_kernel" runs the fused
 # pallas kernel (ops.paged_attention), which reads pool blocks straight
-# through the table and builds no views.  A dense decoder attends its
-# own cache and takes no kernel.  Read at decoder CONSTRUCTION (stashed
-# as self.paged_kernel), so flipping the module global never switches a
-# live decoder's compiled programs mid-stream; any other value is
-# refused there.
-ATTENTION_IMPL = os.environ.get("AIKO_DECODE_ATTENTION", "two_pass")
+# through the table and builds no views.  Unset (None), all three
+# gather but the PLAIN step of a decoder that the kernel suits, which
+# attends each slot's live blocks through it (ContinuousDecoder's
+# constructor says where).  A dense decoder attends its own
+# cache and takes no kernel.  Read at decoder CONSTRUCTION (stashed as
+# self.paged_kernel and self.step_kernel), so flipping the module
+# global never switches a live decoder's compiled programs mid-stream;
+# any other value is refused there.
+ATTENTION_IMPL = os.environ.get("AIKO_DECODE_ATTENTION")
 
 
 @dataclasses.dataclass
@@ -1463,10 +1466,11 @@ class ContinuousDecoder:
                  prefix_cache: PrefixKVCache | None = None,
                  paged_kv: bool = False, kv_block: int = 32):
         self.config = config
-        if ATTENTION_IMPL not in ("two_pass", "paged_kernel"):
+        if ATTENTION_IMPL not in (None, "two_pass", "paged_kernel"):
             raise ValueError(
                 f"AIKO_DECODE_ATTENTION / serving.ATTENTION_IMPL must be "
-                f"'two_pass' or 'paged_kernel', got {ATTENTION_IMPL!r}")
+                f"'two_pass' or 'paged_kernel' (or unset), got "
+                f"{ATTENTION_IMPL!r}")
         # int8 KV cache (ISSUE 7): the slot caches store int8 values
         # with per-(slot, head, position) f32 scales
         # (layers.quantize_kv_cache).  Admits/extends write quantized
@@ -1652,7 +1656,33 @@ class ContinuousDecoder:
             self._tables_scratch = np.zeros_like(self._tables_np)
             self._tables_dirty = True
             self._tables_dev = None
-            self._attend_widths = _attend_ladder(self.max_seq, block)
+            # the paged pallas-kernel toggle is latched here — builder
+            # cache keys include it, so oracle and kernel decoders
+            # coexist in one process (parity tests build one of each).
+            # paged_kernel: the kernel was ASKED for (the plain and the
+            # speculative step and the extend take it).  step_kernel:
+            # the step this decoder runs takes it, asked for, or told
+            # nothing where the kernel suits the decoder: on a TPU
+            # (elsewhere it would run in the interpreter), at a pool
+            # whose live blocks it walks by hand (its table body reads
+            # every entry of every slot: no gain over views), with
+            # weights that sit on one device
+            from .ops.paged_attention import walks_live_blocks
+            on_tpu = jax.default_backend() == "tpu"
+            walks = walks_live_blocks(config.head_dim, self.kv_int8,
+                                      interpret=not on_tpu)
+            self.paged_kernel = ATTENTION_IMPL == "paged_kernel"
+            self.step_kernel = self.paged_kernel or (
+                ATTENTION_IMPL is None and not self.speculate_k
+                and on_tpu and walks and self._weights_on_one_device())
+            # a step whose kernel walks each slot's own live blocks has
+            # no width: the table goes in whole, ONE program a step
+            # count.  The gather step builds its views at a width of
+            # the ladder, and the kernel's table body (a head of 64, an
+            # int8 pool) follows the table as far as it is cut
+            self._walks_live = self.step_kernel and walks
+            self._attend_widths = (self.max_seq,) if self._walks_live \
+                else _attend_ladder(self.max_seq, block)
             # (num_steps, width, state's placement) -> executable
             self._step_programs: dict = {}
             # per-slot owned/aliased pool block ids, in table order
@@ -1662,6 +1692,8 @@ class ContinuousDecoder:
             self._v = None
         else:
             self.pool = None
+            self.paged_kernel = self.step_kernel = False
+            self._walks_live = False
             self._k = self._zero_caches()
             self._v = self._zero_caches()
         self._tokens = jnp.zeros((max_slots,), jnp.int32)
@@ -1682,19 +1714,21 @@ class ContinuousDecoder:
         # credit away (ISSUE 13 satellite)
         self._prefill_token_ewma: float | None = None
 
-        # the paged pallas-kernel toggle is latched here — builder
-        # cache keys include it, so oracle and kernel decoders coexist
-        # in one process (parity tests build one of each)
-        self.paged_kernel = bool(self.paged and
-                                 ATTENTION_IMPL == "paged_kernel")
         if self.paged:
             from .serving_paged import (_paged_spec_step_for,
                                         _paged_step_for)
             self._step = _paged_spec_step_for(
                 config, self.speculate_k, self.speculate_ngram,
-                self.paged_kernel) \
+                self.step_kernel) \
                 if self.speculate_k \
-                else _paged_step_for(config, self.paged_kernel)
+                else _paged_step_for(config, self.step_kernel)
+            if self._walks_live:
+                how = "the paged kernel, each slot's live blocks"
+            else:
+                how = "%s at widths %s" % (
+                    "the paged kernel's table body" if self.step_kernel
+                    else "gathered views", self._attend_widths)
+            self.logger.info("decode step attends through %s", how)
         else:
             self._step = _spec_step_for(config, self.speculate_k,
                                         self.speculate_ngram) \
@@ -1718,6 +1752,9 @@ class ContinuousDecoder:
         # per-step hot path (graft-check lint-hot-alloc polices them)
         self._active_np = np.zeros((max_slots,), bool)
         self._budgets_np = np.zeros((max_slots,), np.int32)
+        # each slot's length on the device at round entry, for a kernel
+        # round's record (_round_plan fills it)
+        self._entry_np = np.zeros((max_slots,), np.int64)
         # bytes of K and V one cache position holds over all layers
         # and slots (what the prefix copy-in and harvest counters
         # charge a position).  int8 cache: D int8 values + one f32
@@ -2538,6 +2575,15 @@ class ContinuousDecoder:
             self._tables_dirty = False
         return self._tables_dev
 
+    def _weights_on_one_device(self) -> bool:
+        """The decoder holds no mesh: a tensor-parallel decoder is one
+        whose weights came in sharded, and a pallas_call over the
+        heads-sharded pool such a decoder's programs make needs a
+        shard_map of its own."""
+        return all(len(leaf.sharding.device_set) == 1
+                   for leaf in jax.tree_util.tree_leaves(self.params)
+                   if isinstance(leaf, jax.Array))
+
     def _attend_width(self, required_t: int) -> int:
         """The smallest width of the ladder that covers `required_t`,
         the cap where none does (a context within a round of max_seq:
@@ -3259,6 +3305,7 @@ class ContinuousDecoder:
         each a full dispatch+sync round trip."""
         budgets = self._budgets_np                # preallocated (hot)
         budgets.fill(0)
+        entry = self._entry_np                    # a fixed array a slot
         max_len = 0
         # tokens one scan iteration can yield: 1, or the whole
         # speculative block when every draft lands
@@ -3280,6 +3327,9 @@ class ContinuousDecoder:
                 request.max_new_tokens - generated,
                 self.max_seq - 1 - current))
             max_len = max(max_len, current)
+            # the device's length of the slot: the last token is the
+            # step's input, not yet a row of the pool
+            entry[slot] = current - 1
         remaining = budgets[occupied]
         cap = int(remaining.min()) if self._pending \
             else int(remaining.max())
@@ -3324,7 +3374,7 @@ class ContinuousDecoder:
         waves_due = self._admit_waves
         self._admit_waves = []
         scanned = False
-        num_steps = scanned_slots = attend_width = 0
+        num_steps = scanned_slots = attend_width = attended = 0
         if any_active:
             occupied = [s for s in range(self.max_slots) if active[s]]
             num_steps, required_t, budgets = self._round_plan(occupied)
@@ -3347,6 +3397,14 @@ class ContinuousDecoder:
             scan_active = active & (budgets > 0)
             scanned_slots = int(scan_active.sum())
             scanned = scanned_slots > 0
+            # what the round's record says the step attended at: the
+            # width of its views, or what the kernel walks of the
+            # scanned slots (their mean: it has no width)
+            attended = attend_width
+            if scanned and self._walks_live:
+                from .ops.paged_attention import walk_positions
+                attended = float(walk_positions(
+                    self._entry_np[scan_active], self.kv_block).mean())
         if scanned:
             profiler.enter("spec_verify" if self.speculate_k
                            else "scan_dispatch")
@@ -3466,7 +3524,7 @@ class ContinuousDecoder:
             record = profiler.commit_round(
                 self.stats["rounds"], num_steps if scanned else 0,
                 scanned_slots, self._round_prefill_tokens,
-                len(self._pending), attend_width if scanned else 0)
+                len(self._pending), attended if scanned else 0)
             # what remains of a stall in a run nobody traced
             slow = slow_round(record, self._round_ewma)
             if slow is not None:

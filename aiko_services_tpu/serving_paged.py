@@ -23,7 +23,11 @@
 #     mutation.  At most one partial block copies per such write; the
 #     common hit path copies nothing.
 #
-# Device-side discipline: the compiled step GATHERS a slot-major
+# Device-side discipline, the gather path (the CPU's, the parity
+# oracle, a tensor-parallel decoder's; a decoder on one chip whose pool
+# the paged kernel walks by hand attends its step through the kernel
+# instead and builds no views, see "pallas kernel attention" below):
+# the compiled step GATHERS a slot-major
 # [S, H, T, D] view from the pool once per round (the main cache is
 # read-only through the scan, so the gather hoists out of it) and runs
 # the SAME attention bodies (_slot_attention_block /
@@ -476,19 +480,24 @@ def _gather_views(pools, tables, t_cap: int) -> list:
             for pool in pools]
 
 
-# -- pallas kernel attention (ISSUE 16) ---------------------------------------
-# AIKO_DECODE_ATTENTION=paged_kernel swaps the gather+shared-body
-# attention for ops.paged_attention.paged_decode_attention: the pool
-# leaves and the round table go to the kernel directly, so the
-# slot-major [S, H, T, D] gather never materializes.  The gather path
-# stays the bit-parity ORACLE — tests prove greedy token identity per
-# (int8 × chunked × spec × block size) combination, and the kernel
-# builders key their lru caches on the toggle so both variants coexist
-# in one process (chip_smoke.py runs one after the other).
+# -- pallas kernel attention (ISSUE 16, ISSUE 30) -----------------------------
+# kernel=True swaps the gather+shared-body attention for
+# ops.paged_attention.paged_decode_attention: the pool leaves and the
+# round table go to the kernel directly, so the slot-major [S, H, T, D]
+# gather never materializes and each slot's attention reads its own
+# live blocks.  The plain step takes it where the decoder chose it
+# (ContinuousDecoder.step_kernel: asked for by
+# AIKO_DECODE_ATTENTION=paged_kernel, or unasked on a TPU where the
+# kernel suits the decoder); the speculative step and the extend only
+# where it was asked for.  The gather path stays the bit-parity ORACLE
+# — tests prove greedy token identity per (int8 × chunked × spec ×
+# block size) combination, and the kernel builders key their lru
+# caches on the toggle so both variants coexist in one process
+# (chip_smoke.py runs one after the other).
 
 def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
                               k_pool, v_pool, tables, k_side, v_side,
-                              entry_lengths, lengths, write_index,
+                              walk_lengths, lengths, write_index,
                               side_valid):
     """Kernel-path sibling of serving._grouped_block_attention: the
     same QKV projection / rope / side-buffer write, then the fused
@@ -496,7 +505,8 @@ def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
     is the caller's per-query mask in its compact [S, W, P] form (the
     kernel broadcasts it over heads and groups) — one kernel serves
     the plain scan (W=1) and the widened speculative verify
-    (W=1+k)."""
+    (W=1+k).  `walk_lengths` is how far the kernel reads each slot's
+    pool rows: the entry length, or 0 for a slot it is to skip."""
     from .ops.paged_attention import paged_decode_attention
     from .serving import _project_qkv
     num_heads, num_kv = config.num_heads, config.num_kv_heads
@@ -514,7 +524,7 @@ def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
         q_grouped = q.reshape(slots_n, num_kv, group * num_q, head_dim)
         out = paged_decode_attention(q_grouped, k_pool, v_pool, tables,
                                      k_side, v_side, side_valid,
-                                     entry_lengths, groups=group)
+                                     walk_lengths, groups=group)
         out = out.reshape(slots_n, num_heads, num_q,
                           head_dim).astype(x.dtype)
     with jax.named_scope(SCOPE_ATTN_PROJ):
@@ -524,16 +534,21 @@ def _kernel_grouped_attention(layer, config: LlamaConfig, x, cos, sin,
 
 def _kernel_attention_block(tables, layer, config: LlamaConfig, x,
                             cos, sin, k_pool, v_pool, k_side, v_side,
-                            entry_lengths, lengths, step_index):
+                            entry_lengths, lengths, step_index,
+                            entry_active=None):
     """Kernel sibling of serving._slot_attention_block — the same side
-    mask, in [S, 1, P] form."""
+    mask, in [S, 1, P] form.  A slot that was not active at round entry
+    (`entry_active`, all active when None) walks nothing of the pool:
+    its token is discarded and its stale length may point anywhere."""
     side_positions = jnp.arange(k_side.shape[2])
     side_valid = ((side_positions[None] <= step_index) &
                   (side_positions[None] <
                    (lengths - entry_lengths + 1)[:, None]))[:, None, :]
+    walk_lengths = entry_lengths if entry_active is None \
+        else jnp.where(entry_active, entry_lengths, 0)
     return _kernel_grouped_attention(layer, config, x, cos, sin,
                                      k_pool, v_pool, tables, k_side,
-                                     v_side, entry_lengths, lengths,
+                                     v_side, walk_lengths, lengths,
                                      step_index, side_valid)
 
 
@@ -591,9 +606,11 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
 
     kernel=True swaps the gather + shared attention body for the
     fused pallas kernel reading pool blocks through the table
-    (_kernel_attention_block); the scan structure, side buffers, and
-    merge are unchanged, and the gather path remains the parity
-    oracle."""
+    (_kernel_attention_block): no views are built, a slot that was
+    inactive at round entry reads nothing of the pool, and t_cap only
+    cuts the table (a decoder whose kernel walks live blocks hands the
+    cap, always: one program).  The loop, side buffers and merge are
+    unchanged, and the gather path remains the parity oracle."""
     from .serving import _slot_attention_block, _token_block_argmax
     cos, sin = L.rope_frequencies(config.head_dim, config.max_seq_len,
                                   config.rope_theta)
@@ -630,7 +647,7 @@ def _build_paged_step(config: LlamaConfig, kernel: bool = False):
                         cap_tables, layer, config, normed, cos, sin,
                         k_pools[i], v_pools[i], k_sides[i],
                         v_sides[i], entry_lengths, lengths,
-                        step_index)
+                        step_index, entry_active)
                 else:
                     attn_out, k_s, v_s = _slot_attention_block(
                         layer, config, normed, cos, sin, k_caches[i],

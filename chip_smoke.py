@@ -22,10 +22,12 @@ Phases of the default run:
            pipeline create` builds it — Whisper-small, bf16, batched through
            BatchingScheduler -> ComputeRuntime — fed seeded audio that lands
            in every mel bucket; tokens equal a direct jit of greedy_decode
-  llama    ContinuousDecoder on LLAMA_PRESETS["1b"] with paged KV, eight
-           seeded requests (64..1024-token prompts, chunked extend on the
-           long ones) against llama_greedy_decode, then the same requests
-           through the fused paged-attention kernel
+  llama    ContinuousDecoder on LLAMA_PRESETS["1b"] at 16 heads of 128 (a
+           pool the paged kernel walks by hand) with paged KV, eight seeded
+           requests (64..1024-token prompts, chunked extend on the long
+           ones): the gather path forced, against llama_greedy_decode; the
+           paged kernel forced (step and extend); and a decoder that was
+           told nothing, which on the chip must have taken the kernel
 """
 
 from __future__ import annotations
@@ -152,20 +154,23 @@ def check_kernel(label: str, kernel, oracle, args, on_chip: bool) -> None:
             f"{label} off by {worst}")
 
 
-def llama_config(shape: dict):
+def llama_config(shape: dict, preset_heads: bool = False):
+    """The llama phases' model: the preset at shape["llama_heads"]
+    heads (1b: 16 heads of 128, a pool whose live blocks the paged
+    kernel walks by hand), or with the preset's own heads (1b: 32 of
+    64, which keep the kernel's table body)."""
     from aiko_services_tpu.models.llama import LLAMA_PRESETS
-    return dataclasses.replace(LLAMA_PRESETS[shape["llama_preset"]],
-                               dtype=shape["llama_dtype"],
-                               max_seq_len=shape["max_seq"])
+    preset = LLAMA_PRESETS[shape["llama_preset"]]
+    return dataclasses.replace(
+        preset, dtype=shape["llama_dtype"], max_seq_len=shape["max_seq"],
+        num_heads=preset.num_heads if preset_heads
+        else shape["llama_heads"])
 
 
 def phase_kernels(shape: dict, seed: int, on_chip: bool) -> None:
     import jax
     import jax.numpy as jnp
 
-    from aiko_services_tpu import serving, serving_paged
-    from aiko_services_tpu.models import layers as L
-    from aiko_services_tpu.models.llama import _layer_init
     from aiko_services_tpu.ops.attention import flash_attention
     from aiko_services_tpu.parallel import attention_reference
 
@@ -184,22 +189,37 @@ def phase_kernels(shape: dict, seed: int, on_chip: bool) -> None:
                                       causal=causal)),
             qkv, on_chip)
 
-    # paged decode attention at the llama phase's geometry, through the
-    # same layer wrappers the decode scan calls, against the gather path
-    config = llama_config(shape)
-    layer = _layer_init(keys[3], config)
+    # paged decode attention at the llama phase's geometry and at the
+    # preset's own heads, through the same layer wrappers the decode
+    # scan calls, against the gather path: both of the kernel's bodies
+    for config in dict.fromkeys((llama_config(shape),
+                                 llama_config(shape, preset_heads=True))):
+        check_paged_attention(config, shape, keys[3:7], on_chip)
+
+
+def check_paged_attention(config, shape: dict, keys, on_chip: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from aiko_services_tpu import serving, serving_paged
+    from aiko_services_tpu.models import layers as L
+    from aiko_services_tpu.models.llama import _layer_init
+
+    layer = _layer_init(keys[0], config)
     slots, block = shape["slots"], 32
     t_cap, side_len = shape["max_seq"], shape["steps_per_sync"]
     nb = -(-t_cap // block)
     cos, sin = L.rope_frequencies(config.head_dim, config.max_seq_len,
                                   config.rope_theta)
-    x = jax.random.normal(keys[4], (slots, 1, config.dim), config.dtype)
+    x = jax.random.normal(keys[1], (slots, 1, config.dim), config.dtype)
     pool_shape = (slots * nb + 1, config.num_kv_heads, block,
                   config.head_dim)
     native = [jax.random.normal(key, pool_shape, config.dtype)
-              for key in keys[5:7]]
+              for key in keys[2:4]]
     tables = 1 + jnp.arange(slots * nb, dtype=jnp.int32).reshape(slots, nb)
-    entry = jnp.linspace(1, t_cap - side_len, slots).astype(jnp.int32)
+    # ragged: a slot that holds nothing, one token, and up to the cap
+    entry = jnp.linspace(1, t_cap - side_len, slots).astype(
+        jnp.int32).at[1].set(0)
     sides = jnp.zeros((slots, config.num_kv_heads, side_len,
                        config.head_dim), config.dtype)
 
@@ -391,14 +411,17 @@ def llama_setup(shape: dict, seed: int):
     return config, params, requests
 
 
-def llama_decoder(params, config, shape: dict, kernel: bool = False):
+def llama_decoder(params, config, shape: dict,
+                  attention: str | None = "two_pass"):
     """A paged ContinuousDecoder with the constructor's own paged-KV
     defaults (kv_block 32).  The attention implementation is read once,
-    at construction — the AIKO_DECODE_ATTENTION seam."""
+    at construction — the AIKO_DECODE_ATTENTION seam: "two_pass" (the
+    gather path), "paged_kernel", or None (told nothing: the decoder
+    chooses by what it observes)."""
     from aiko_services_tpu import serving
 
     before = serving.ATTENTION_IMPL
-    serving.ATTENTION_IMPL = "paged_kernel" if kernel else "two_pass"
+    serving.ATTENTION_IMPL = attention
     try:
         return serving.ContinuousDecoder(
             params, config, paged_kv=True, max_slots=shape["slots"],
@@ -406,7 +429,8 @@ def llama_decoder(params, config, shape: dict, kernel: bool = False):
             prefill_buckets=shape["prefill_buckets"],
             prefill_chunk=shape["prefill_chunk"],
             steps_per_sync=shape["steps_per_sync"],
-            name="kernel" if kernel else "gather")
+            name={"two_pass": "gather", "paged_kernel": "kernel",
+                  None: "unset"}[attention])
     finally:
         serving.ATTENTION_IMPL = before
 
@@ -570,8 +594,9 @@ def phase_llama(shape: dict, seed: int, on_chip: bool,
     compare("gather vs llama_greedy_decode", requests, cold,
             greedy_oracle(params, config, requests))
 
-    kernel = llama_decoder(params, config, shape, kernel=True)
-    require(kernel.paged_kernel and not gather.paged_kernel,
+    kernel = llama_decoder(params, config, shape, "paged_kernel")
+    require(kernel.paged_kernel and kernel.step_kernel and
+            not gather.paged_kernel and not gather.step_kernel,
             "attention implementation was not latched at construction")
     if on_chip:
         require(decode_step_has_kernel(kernel),
@@ -588,6 +613,30 @@ def phase_llama(shape: dict, seed: int, on_chip: bool,
     say(f"  paged kernel: every token within {reference.tolerance} "
         f"logit-std of the reference (worst {worst:.4f})")
     compare("kernel vs gather", requests, fused, cold)
+
+    # told nothing: on the chip the plain step takes the kernel (this
+    # pool's live blocks are walked by hand) and the extend gathers;
+    # anywhere else (the rehearsal) it is the gather decoder again
+    unset = llama_decoder(params, config, shape, None)
+    require(not unset.paged_kernel,
+            "a decoder that was told nothing says it was asked for the "
+            "kernel")
+    if on_chip:
+        require(unset.step_kernel and unset._walks_live and
+                decode_step_has_kernel(unset),
+                "a decoder built with AIKO_DECODE_ATTENTION unset on the "
+                "chip did not take the paged kernel for its step")
+    else:
+        require(not unset.step_kernel,
+                "off the chip a decoder that was told nothing took the "
+                "kernel (it would run in the interpreter)")
+    chosen = timed_serve("attention unset, first pass", unset, requests,
+                         clock)
+    worst = reference.check("unset", requests, chosen)
+    say(f"  attention unset ({'kernel' if unset.step_kernel else 'gather'}"
+        f" step): every token within {reference.tolerance} logit-std of "
+        f"the reference (worst {worst:.4f})")
+    compare("unset vs gather", requests, chosen, cold)
 
 
 # -- four chips --------------------------------------------------------------
@@ -635,7 +684,11 @@ def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
     reference = TeacherForced(params, config, shape["max_seq"])
     single = timed_serve("one chip", llama_decoder(params, config, shape),
                          requests, clock)
-    tp_decoder = llama_decoder(placed, config, shape)
+    # told nothing: weights sharded over four devices keep the gather
+    # path (a pallas_call over a heads-sharded pool needs a shard_map)
+    tp_decoder = llama_decoder(placed, config, shape, None)
+    require(not tp_decoder.step_kernel,
+            "the tensor-parallel decoder took the paged kernel")
     tp = timed_serve("TP=4, first pass", tp_decoder, requests, clock)
     # the first pass returns state with the compiler's shardings, so
     # the second may compile again; the third is the compiled path
@@ -657,12 +710,14 @@ def shapes(rehearse: bool) -> dict:
     if rehearse:
         # the CPU rehearsal: same code paths, toy widths
         return {"whisper_preset": "test", "llama_preset": "tiny",
+                "llama_heads": 4,
                 "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
                 "prefill_buckets": (8, 32), "prefill_chunk": 32,
                 "steps_per_sync": 4, "new_tokens": 8,
                 "prompt_lengths": (8, 8, 20, 20, 44, 44, 100, 100),
                 "flash": (1, 2, 256, 64)}
     return {"whisper_preset": "small", "llama_preset": "1b",
+            "llama_heads": 16,
             "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,
             "prefill_buckets": (64, 256), "prefill_chunk": 256,
             "steps_per_sync": 4, "new_tokens": 32,

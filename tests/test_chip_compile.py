@@ -66,25 +66,28 @@ def _cross_decode():
             [((32, 12, 1, 64), jnp.bfloat16), kv, kv])
 
 
-def _paged(int8, fold, width, side, slots=8, t_cap=1280):
+def _paged(int8, fold, width, side, slots=8, t_cap=1280,
+           head_dim=HEAD_DIM):
     """side = steps_per_sync * width for a decode/spec round, or the
     chunk length for a chunked-prefill extend (width = chunk)."""
     from aiko_services_tpu.ops.paged_attention import \
         paged_decode_attention
     nb = t_cap // BLOCK
-    pool_shape = (slots * nb + 1, HKV, BLOCK, HEAD_DIM)
+    pool_shape = (slots * nb + 1, HKV, BLOCK, head_dim)
     pool = {"q": (pool_shape, jnp.int8),
             "s": (pool_shape[:3], jnp.float32)} if int8 \
         else (pool_shape, jnp.bfloat16)
-    side_kv = ((slots, HKV, side, HEAD_DIM), jnp.bfloat16)
+    side_kv = ((slots, HKV, side, head_dim), jnp.bfloat16)
     return (lambda q, k_pool, v_pool, tables, k_side, v_side, valid,
             entry: paged_decode_attention(
                 q, k_pool, v_pool, tables, k_side, v_side, valid, entry,
                 groups=GROUPS, fold_scales=fold, interpret=False),
-            [((slots, HKV, GROUPS * width, HEAD_DIM), jnp.bfloat16),
+            [((slots, HKV, GROUPS * width, head_dim), jnp.bfloat16),
              pool, pool, ((slots, nb), jnp.int32), side_kv, side_kv,
              ((slots, width, side), jnp.bool_), ((slots,), jnp.int32)])
 
+
+MISTRAL = {"slots": 24, "t_cap": 2048, "head_dim": 128}
 
 CASES = {
     "flash-s1536": lambda: _flash(False),
@@ -108,6 +111,19 @@ CASES = {
                                              slots=4),
     "paged-int8-extend-c64": lambda: _paged(True, False, 64, 64,
                                             slots=4, t_cap=1024),
+    # mistral-7b-v0.3-d16 as the benchmark's cells serve it: head 128,
+    # 24 slots, 64 table entries.  A native pool whose minor axis is
+    # whole lanes takes the body that walks live blocks by hand (the
+    # cases above, head 64, and every int8 pool keep the table body:
+    # mosaic slices no block out of an HBM operand narrower than 128)
+    "paged-bf16-d128-step-w1": lambda: _paged(False, True, 1, 4, **MISTRAL),
+    "paged-bf16-d128-spec-w5": lambda: _paged(False, True, 5, 20,
+                                              **MISTRAL),
+    "paged-bf16-d128-extend-c512": lambda: _paged(
+        False, False, 512, 512, **(MISTRAL | {"slots": 1})),
+    "paged-int8-d128-step-w1": lambda: _paged(True, True, 1, 4, **MISTRAL),
+    "paged-int8-d128-extend-c512": lambda: _paged(
+        True, False, 512, 512, **(MISTRAL | {"slots": 1})),
 }
 
 
@@ -201,18 +217,10 @@ def test_block_write_updates_the_donated_pool_in_place(chip):
     _assert_in_place(compiled, POOL, jnp.bfloat16)
 
 
-@pytest.mark.parametrize("width, blocks, temporaries", [
-    (1024, 32, 2.3e9),      # the width of every decode_saturated round
-    (2048, 64, 4.56e9),     # the cap: what it was before the ladder
-], ids=["half", "cap"])
-def test_step_views_follow_the_attend_width(chip, width, blocks,
-                                            temporaries):
+def _mistral_step(chip, kernel, width):
     """`jit_step` x 4 of mistral-7b-v0.3-d16 as the benchmark's cells
-    run it, whole: 24 slots, a full pool, the table at its constant 65
-    blocks (64 and the merge's headroom).  The views are gathered at
-    the width's blocks and no wider, whatever the table holds, and the
-    temporaries shrink with them; the cap's program is not widened to
-    the table's 65."""
+    run it, whole, compiled for `chip`: 24 slots, a full pool, the
+    table at its constant 65 blocks (64 and the merge's headroom)."""
     import json
     from aiko_services_tpu import serving_paged
     from aiko_services_tpu.models.llama import LlamaConfig
@@ -239,14 +247,48 @@ def test_step_views_follow_the_attend_width(chip, width, blocks,
         lambda leaf: shaped(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), config)))
     pool = [shaped(POOL, jnp.bfloat16) for _ in range(config.num_layers)]
-    compiled = serving_paged._paged_step_for(config, False).lower(
+    return serving_paged._paged_step_for(config, kernel).lower(
         params, shaped((slots,), jnp.int32), shaped((slots,), jnp.int32),
         shaped((slots,), bool), shaped((slots,), jnp.int32), pool, pool,
         shaped((slots, table), jnp.int32), num_steps=4, eos=-1,
         t_cap=width).compile()
-    views = set(re.findall(r"bf16\[24,(\d+),8,32,128\]", compiled.as_text()))
+
+
+VIEW = r"bf16\[24,(\d+),8,32,128\]"       # a slot-major K or V view
+
+
+@pytest.mark.parametrize("width, blocks, temporaries", [
+    (1024, 32, 2.3e9),      # the width of every decode_saturated round
+    (2048, 64, 4.56e9),     # the cap: what it was before the ladder
+], ids=["half", "cap"])
+def test_step_views_follow_the_attend_width(chip, width, blocks,
+                                            temporaries):
+    """The gather step's views are gathered at the width's blocks and
+    no wider, whatever the table holds, and the temporaries shrink with
+    them; the cap's program is not widened to the table's 65."""
+    compiled = _mistral_step(chip, False, width)
+    views = set(re.findall(VIEW, compiled.as_text()))
     assert views == {str(blocks)}, views
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+def test_kernel_step_builds_no_views_and_copies_no_pool(chip, monkeypatch):
+    """The step a cell runs on the chip (PR 30): attention through the
+    pallas kernel, lowered with mosaic (this host's backend is the CPU,
+    where the kernel would pick the interpreter, whose loops copy every
+    pool leaf: the test says "tpu" for it).  Sixteen kernels, one a
+    layer; no slot-major view; no `copy` of a pool leaf's shape (a
+    pool-shaped operand handed to the kernel by value would be one);
+    temporaries a twentieth of the gather step's at the cap."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _mistral_step(chip, True, 2048)
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 16
+    assert re.findall(VIEW, text) == []
+    result = re.escape("[" + ",".join(map(str, POOL)) + "]")
+    assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 def test_paged_row_tile_stays_inside_vmem_budget():

@@ -296,6 +296,123 @@ class TestPagedKernelParity:
         assert calls                         # oracle still gathers
 
 
+# -- the kernel's ragged walk (ISSUE 30) ------------------------------------
+# A slot walks its OWN live blocks, a chunk of them a time, and reads no
+# dead cell into a result.  At a size a test holds: blocks of 8, a chunk
+# patched down to 32 positions (4 blocks), a table of 12 blocks (3 chunks).
+
+WALK_BLOCK, WALK_CHUNK, WALK_TABLE = 8, 32, 12
+WALK_CASES = {              # slot -> (entry length, active at round entry)
+    "inactive": (50, False), "empty": (0, True), "one": (1, True),
+    "one-short-of-a-block": (7, True), "a-block": (8, True),
+    "mid-chunk": (45, True), "a-chunk": (32, True),
+    "a-chunk-and-one": (33, True), "cap": (96, True)}
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """The walk's chunk at WALK_CHUNK positions.  The jitted step
+    builders are shared process-wide and would hand back a program
+    traced at another chunk: they are dropped around the test."""
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.ops import paged_attention
+    monkeypatch.setattr(paged_attention, "_CHUNK", WALK_CHUNK)
+    serving_paged._paged_step_for.cache_clear()
+    yield
+    serving_paged._paged_step_for.cache_clear()
+
+
+@pytest.fixture(scope="module")
+def walked():
+    """One layer's attention for a slot of every WALK_CASES length:
+    (kernel over a POISONED pool, gather oracle over a clean one).
+    Poisoned: every dead cell of a last live block and every block past
+    it is NaN, table entries past the live ones point at such blocks;
+    the clean pool holds zeros there (the oracle's exact-zero weights
+    times NaN would be NaN)."""
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import layers as L
+    from aiko_services_tpu.models.llama import _layer_init
+    from aiko_services_tpu.ops import paged_attention
+    slots, block, nb = len(WALK_CASES), WALK_BLOCK, WALK_TABLE
+    keys = jax.random.split(jax.random.PRNGKey(30), 6)
+    layer = _layer_init(keys[0], CONFIG)
+    # one position past max_seq: the new token of the slot at the cap
+    cos, sin = L.rope_frequencies(CONFIG.head_dim, CONFIG.max_seq_len + 1,
+                                  CONFIG.rope_theta)
+    entry = jnp.asarray([case[0] for case in WALK_CASES.values()],
+                        jnp.int32)
+    active = jnp.asarray([case[1] for case in WALK_CASES.values()])
+    shape = (slots * nb + 1, CONFIG.num_kv_heads, block, CONFIG.head_dim)
+    tables = 1 + jnp.arange(slots * nb, dtype=jnp.int32).reshape(slots, nb)
+    # position of every pool cell in its slot (the null block: dead)
+    position = (jnp.arange(nb * block)[None] <
+                jnp.where(active, entry, 0)[:, None])
+    live = jnp.concatenate([
+        jnp.zeros((1, block), bool), position.reshape(slots * nb, block)]
+    )[:, None, :, None]
+    pools = [jax.random.normal(key, shape, jnp.float32)
+             for key in keys[1:3]]
+    clean = [jnp.where(live, pool, 0.0) for pool in pools]
+    poisoned = [jnp.where(live, pool, jnp.nan) for pool in pools]
+    x = jax.random.normal(keys[3], (slots, 1, CONFIG.dim), jnp.float32)
+    sides = jnp.zeros((slots, CONFIG.num_kv_heads, 4, CONFIG.head_dim),
+                      jnp.float32)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_attention, "_CHUNK", WALK_CHUNK)
+        kernel = jax.jit(
+            lambda k_pool, v_pool:
+            serving_paged._kernel_attention_block(
+                tables, layer, CONFIG, x, cos, sin, k_pool, v_pool, sides,
+                sides, entry, entry, 0, active)[0])(*poisoned)
+    views = [serving_paged._gather_views([pool], tables, nb * block)[0]
+             for pool in clean]
+    gathered = serving._slot_attention_block(
+        layer, CONFIG, x, cos, sin, views[0], views[1], sides, sides,
+        jnp.where(active, entry, 0), entry, 0)[0]
+    return np.asarray(kernel), np.asarray(gathered)
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_ragged_walk_reads_no_dead_cell(walked, case):
+    kernel, gathered = walked
+    slot = list(WALK_CASES).index(case)
+    assert np.isfinite(kernel[slot]).all()
+    np.testing.assert_allclose(kernel[slot], gathered[slot],
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_walk_positions_are_whole_live_blocks():
+    from aiko_services_tpu.ops.paged_attention import (walk_positions,
+                                                       walks_live_blocks)
+    assert walk_positions(np.array([0, 1, 31, 32, 33, 450]), 32).tolist() \
+        == [0, 32, 32, 32, 64, 480]
+    # what mosaic lets a kernel slice out of HBM by hand; the
+    # interpreter has no lanes
+    assert walks_live_blocks(128, False) and walks_live_blocks(256, False)
+    assert not walks_live_blocks(64, False)
+    assert not walks_live_blocks(128, True)
+    assert walks_live_blocks(16, False, interpret=True)
+    assert not walks_live_blocks(16, True, interpret=True)
+
+
+@pytest.mark.parametrize("kwargs", [{}, {"prefill_chunk": 16}],
+                         ids=["bucketed", "chunked"])
+def test_ragged_lengths_in_one_round_keep_token_identity(
+        params, small_chunks, kwargs):
+    """A whole run, kernel against oracle, with slots of very different
+    lengths in every round: 3 to 70 tokens of prompt, so one slot walks
+    three chunks while its neighbour walks one block."""
+    requests = {"tiny": (PROMPT[:3], 12), "short": (PROMPT[:9], 20),
+                "block": (PROMPT[:16], 9),
+                "long": ((PROMPT * 2)[:70], 20)}
+    oracle_d, kernel_d = kernel_pair(params, **kwargs)
+    assert kernel_d.step_kernel and kernel_d._walks_live
+    assert not oracle_d.step_kernel
+    assert run(oracle_d, requests) == run(kernel_d, requests)
+    assert kernel_d.pool.used_blocks() == 0
+
+
 # -- zero-copy prefix hits --------------------------------------------------
 
 class TestPagedPrefixReuse:

@@ -896,6 +896,106 @@ def test_unknown_attention_impl_is_refused(params, impl):
         serving.ATTENTION_IMPL = before
 
 
+# a pool geometry whose live blocks the kernel walks by hand on a chip:
+# a head of 128 (ops.paged_attention.walks_live_blocks)
+HEAD128 = dataclasses.replace(LLAMA_PRESETS["tiny"], dim=256, num_heads=2,
+                              num_kv_heads=1, max_seq_len=96)
+
+
+def _paged_as(impl, params, config, **kwargs):
+    """A paged decoder built with serving.ATTENTION_IMPL at `impl`."""
+    from aiko_services_tpu import serving
+    before = serving.ATTENTION_IMPL
+    serving.ATTENTION_IMPL = impl
+    try:
+        return ContinuousDecoder(params, config, max_slots=4,
+                                 prefill_buckets=(16,), steps_per_sync=4,
+                                 paged_kv=True, kv_block=8, **kwargs)
+    finally:
+        serving.ATTENTION_IMPL = before
+
+
+@pytest.mark.parametrize("impl, backend, kwargs, step_kernel, asked", [
+    # told nothing: the gather path on the CPU (the kernel would run in
+    # the interpreter), the kernel on a chip
+    (None, "cpu", {}, False, False),
+    (None, "tpu", {}, True, False),
+    # the speculative step takes the kernel only where it was asked for
+    (None, "tpu", {"speculate_k": 2}, False, False),
+    # an int8 pool's scales are nothing mosaic slices out of HBM: the
+    # kernel's table body reads every entry, no gain over views
+    (None, "tpu", {"kv_cache_dtype": "int8"}, False, False),
+    # both names, said out loud, mean what they mean wherever
+    ("two_pass", "tpu", {}, False, False),
+    ("paged_kernel", "cpu", {}, True, True),
+    ("paged_kernel", "tpu", {"speculate_k": 2}, True, True),
+])
+def test_attention_choice_follows_what_the_decoder_observes(
+        monkeypatch, impl, backend, kwargs, step_kernel, asked):
+    params = llama_init(jax.random.PRNGKey(0), HEAD128)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    decoder = _paged_as(impl, params, HEAD128, **kwargs)
+    assert decoder.step_kernel == step_kernel
+    assert decoder.paged_kernel == asked        # the extend's, the spec's
+    # a step that walks live blocks has no width: one program
+    walks = step_kernel and not kwargs.get("kv_cache_dtype")
+    assert decoder._walks_live == walks
+    assert decoder._attend_widths == (96,)      # max_seq under the floor
+
+
+def test_attention_choice_on_the_tiny_head_and_sharded_weights(monkeypatch):
+    """What else the choice reads: a head of 16 is no geometry the
+    kernel walks by hand on a chip, and a decoder whose weights came in
+    sharded over several devices (tensor parallel: the decoder holds no
+    mesh, leaf placements are what it can see) stays on the gather
+    path."""
+    from aiko_services_tpu.models.llama import llama_axes
+    from aiko_services_tpu.parallel import create_mesh, shard_pytree
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tiny = llama_init(jax.random.PRNGKey(0), CONFIG)
+    assert not _paged_as(None, tiny, CONFIG).step_kernel
+    params = llama_init(jax.random.PRNGKey(0), HEAD128)
+    assert _paged_as(None, params, HEAD128).step_kernel
+    mesh = create_mesh({"model": 2}, devices=jax.devices()[:2])
+    placed = shard_pytree(params, llama_axes(HEAD128), mesh)
+    assert any(len(leaf.sharding.device_set) > 1
+               for leaf in jax.tree_util.tree_leaves(placed))
+    decoder = _paged_as(None, placed, HEAD128)
+    assert not decoder.step_kernel and not decoder._walks_live
+
+
+def test_kernel_decoder_has_one_step_program_and_records_its_walk(
+        params, monkeypatch):
+    """A decoder whose step walks live blocks compiles ONE program a
+    step count (the gather decoder: one a width of its ladder) and its
+    rounds record, as attend_width, the mean over the scanned slots of
+    what the kernel walks for them: the length at round entry in whole
+    blocks."""
+    from aiko_services_tpu import serving
+    from aiko_services_tpu.observe import profiler
+    monkeypatch.setattr(serving, "_ATTEND_FLOOR", 24)
+    gather = _paged_as("two_pass", params, CONFIG, name="choice-gather")
+    kernel = _paged_as("paged_kernel", params, CONFIG, name="choice-kernel")
+    assert gather._attend_widths == (24, 48, 96)
+    assert kernel._attend_widths == (96,)
+    requests = {"a": ([3 + i for i in range(3)], 10),
+                "b": ([5 + i for i in range(14)], 10)}
+    assert _run_decoder(gather, requests) == _run_decoder(kernel, requests)
+    assert {key[:2] for key in kernel._step_programs} == {(4, 96)}
+    assert {key[:2] for key in gather._step_programs} == \
+        {(4, 24), (4, 48), (4, 96)}
+    width = profiler.ROUND_RECORD.index("attend_width")
+    steps = profiler.ROUND_RECORD.index("num_steps")
+    walked = [record[width] for record in kernel.profiler.ring
+              if record[steps]]
+    # both admitted in one wave: the first scanned round enters at the
+    # prompts' lengths, 3 and 14 -> 8 and 16 walked, the next rounds
+    # four tokens later each: 7 and 18 -> 8 and 24, 11 and 22 -> 16, 24
+    assert walked[:3] == [12.0, 16.0, 20.0]
+    assert all(record[width] in (24, 48, 96)
+               for record in gather.profiler.ring if record[steps])
+
+
 def test_deadline_admission_sheds_doomed_request(params):
     """Deadline-aware admission (ISSUE 9): a request whose first-token
     deadline cannot survive the estimated admit wait is refused at
