@@ -57,6 +57,18 @@ class LlamaConfig:
     def head_dim(self):
         return self.dim // self.num_heads
 
+    @property
+    def cache_leaves(self) -> tuple:
+        """(heads, lanes) of each leaf a layer keeps of a token in the
+        paged pool: a K and a V leaf (serving_paged.BlockPool)."""
+        return ((self.num_kv_heads, self.head_dim),) * 2
+
+    def paged_model(self):
+        """The layer functions the paged decoder serves this model
+        through (serving_paged.PagedModel)."""
+        from ..serving_paged import GQA_PAGED_MODEL
+        return GQA_PAGED_MODEL
+
     def moe_config(self):
         from .moe import MoeConfig
         return MoeConfig(dim=self.dim, ffn_dim=self.ffn_dim,

@@ -41,6 +41,15 @@
 # what an earlier chunk left behind it): no dead cell reaches a result,
 # whatever it holds.
 #
+# A LATENT pool (ISSUE 31: multi-head latent attention, absorbed) has
+# ONE leaf, [N, 1, B, lanes]: one shared row a token, and V is the
+# leading lanes of the row that K is.  The walk takes it as it is
+# (v_pool=None): the V items copy the same blocks again and the dots
+# read the buffer's leading v_side.shape[-1] lanes, so the ring, the
+# chunking and the two-pass softmax are the ones above, at one "KV
+# head" and 64 query rows a slot.  The row has to be whole lanes (a
+# 576-lane row is refused like a head of 64): the pool pads it to 640.
+#
 # THE TABLE BODY (_table_kernel: a head of 64, every int8 pool).
 # Mosaic slices a block out of an HBM operand only where the operand's
 # minor axis is whole lanes: it pads the memref of a [.., B, 64] pool
@@ -217,6 +226,12 @@ def _unit_pv(e, row_sum, v, v_scale, first, length, dtype, *,
         preferred_element_type=jnp.float32)
 
 
+def _leading_lanes(rows, lanes: int):
+    """The V rows of a chunk held in the ring: the rows themselves, or
+    for a latent pool the leading `lanes` of the rows that K is."""
+    return rows if rows.shape[-1] == lanes else rows[:, :, :lanes]
+
+
 def _walk_kernel(tables_ref, entry_ref, q_ref, k_hbm, v_hbm, k_side_ref,
                  v_side_ref, valid_ref, o_ref, ring, sems, scores, acc,
                  chained, *, scale: float, chunk_blocks: int):
@@ -335,8 +350,9 @@ def _walk_kernel(tables_ref, entry_ref, q_ref, k_hbm, v_hbm, k_side_ref,
             ring[at] = jnp.where(pos < length, held,
                                  jnp.zeros_like(held))
 
-        acc[...] += _unit_pv(scores[j], row_sum, ring[at], None,
-                             j * chunk, length, q.dtype, fold=True)
+        acc[...] += _unit_pv(scores[j], row_sum,
+                             _leading_lanes(ring[at], acc.shape[-1]),
+                             None, j * chunk, length, q.dtype, fold=True)
         return 0
 
     jax.lax.fori_loop(0, n, v_item, 0)
@@ -432,7 +448,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, k_side, v_side,
                   (group, width) axes flattened)
     k/v_pool:     per-layer pool leaf [N, Hkv, B, D], or the int8
                   serving dict {"q" i8 [N, Hkv, B, D], "s" f32
-                  [N, Hkv, B]}
+                  [N, Hkv, B]}.  v_pool=None is a LATENT pool: V is
+                  the leading v_side.shape[-1] lanes of the K rows
+                  (the walk only: a native leaf of whole lanes)
     tables:       [S, nb] int32 block ids (nb * B >= the slot's
                   readable extent; the walk follows only the entries
                   of blocks that hold a live position, the table body
@@ -496,13 +514,19 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
     from ..models.layers import paged_pool_planes
 
     kq, k_scales = paged_pool_planes(k_pool)
-    vq, v_scales = paged_pool_planes(v_pool)
+    vq, v_scales = paged_pool_planes(k_pool if v_pool is None else v_pool)
     int8 = k_scales is not None
     slots_n, num_kv, gw, head_dim = q.shape
+    v_dim = v_side.shape[3]
     nb = tables.shape[1]
     block_tokens = kq.shape[2]
     side_len = k_side.shape[2]
     walk = walks_live_blocks(head_dim, int8, interpret)
+    if v_dim != head_dim and not (walk and v_pool is None):
+        raise ValueError(
+            "paged_decode_attention: values narrower than keys are a "
+            "latent pool's (v_pool=None), which only the walk reads: a "
+            "native leaf whose rows are whole lanes")
     c = chunk_blocks if walk else 1
     unit = c * block_tokens
     units = -(-nb // c)
@@ -517,7 +541,9 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
                           nb * block_tokens)
     side_block = (1, num_kv, side_len, head_dim)
     row_block = (1, num_kv, rows, head_dim)
-    out_shape = jax.ShapeDtypeStruct((slots_n, num_kv, gw, head_dim),
+    v_side_block = (1, num_kv, side_len, v_dim)
+    out_block = (1, num_kv, rows, v_dim)
+    out_shape = jax.ShapeDtypeStruct((slots_n, num_kv, gw, v_dim),
                                      jnp.float32)
     state = [pltpu.VMEM((units, num_kv, rows, unit), jnp.float32)]
 
@@ -537,15 +563,15 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
             grid=(slots_n, gw // rows),
             in_specs=[pl.BlockSpec(row_block, q_map), in_pool, in_pool,
                       pl.BlockSpec(side_block, side_map),
-                      pl.BlockSpec(side_block, side_map),
+                      pl.BlockSpec(v_side_block, side_map),
                       pl.BlockSpec((1, rows, side_len), valid_map)],
-            out_specs=pl.BlockSpec(row_block, q_map),
+            out_specs=pl.BlockSpec(out_block, q_map),
             scratch_shapes=[
                 # the two-deep ring that K and V chunks pass through
                 pltpu.VMEM((2, num_kv, unit, head_dim), kq.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 *state,
-                pltpu.VMEM((num_kv, rows, head_dim), jnp.float32),
+                pltpu.VMEM((num_kv, rows, v_dim), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32)])
         return pl.pallas_call(
             functools.partial(_walk_kernel, scale=scale, chunk_blocks=c),

@@ -1085,10 +1085,12 @@ def _project_qkv(layer, config: LlamaConfig, x):
 
 
 def _token_block_argmax(params, config: LlamaConfig, token_block,
-                        attend):
+                        attend, ffn=None):
     """Shared transformer pass over a [S, W] token block: `attend(i,
     layer, normed)` supplies each layer's attention output (and owns
-    the cache-write strategy).  Returns the per-position argmax
+    the cache-write strategy); `ffn(layer, x)`, where a model brings
+    its own feed-forward (its norm, residual and scopes with it),
+    stands in for the SwiGLU of llama_ffn.  Returns the per-position argmax
     [S, W] — bf16 operand reads (an f32 UPCAST of the [dim, vocab]
     head would double the step's largest weight read), f32
     accumulation KEPT f32 into the argmax: rounding the logits to
@@ -1099,6 +1101,9 @@ def _token_block_argmax(params, config: LlamaConfig, token_block,
         with jax.named_scope(SCOPE_ATTN_PROJ):
             normed = L.rms_norm(layer["ln_attn"], x)
         x = x + attend(i, layer, normed)
+        if ffn is not None:
+            x = ffn(layer, x)
+            continue
         with jax.named_scope(SCOPE_MLP):
             normed = L.rms_norm(layer["ln_mlp"], x)
             # dense SwiGLU or MoE per the config — MoE llama serves
@@ -1466,6 +1471,10 @@ class ContinuousDecoder:
                  prefix_cache: PrefixKVCache | None = None,
                  paged_kv: bool = False, kv_block: int = 32):
         self.config = config
+        # what the model declares for the paged path: its cache's
+        # leaves, its layer functions, the serving paths its pool is
+        # carried through (serving_paged.PagedModel)
+        self._model = config.paged_model()
         if ATTENTION_IMPL not in (None, "two_pass", "paged_kernel"):
             raise ValueError(
                 f"AIKO_DECODE_ATTENTION / serving.ATTENTION_IMPL must be "
@@ -1512,6 +1521,22 @@ class ContinuousDecoder:
             params = L.quantize_linear_tree(params)
         self.weight_quant = bool(weight_quant)
         self.params = params
+        # a path that this model's pool is not carried through refuses
+        # HERE, by name: none runs another model's code on its cache
+        for asked, path, what in (
+                (not paged_kv, "dense_cache",
+                 "the dense slot cache (paged_kv=False)"),
+                (self.kv_int8, "int8_kv", "an int8 KV cache"),
+                (self.speculate_k, "speculation",
+                 "speculative decoding (speculate_k)"),
+                (prefix_cache is not None, "prefix_cache",
+                 "a prefix cache"),
+                (weight_quant, "weight_quant",
+                 "weight-only int8 (weight_quant)"),
+                (not self._weights_on_one_device(), "tensor_parallel",
+                 "tensor-parallel (sharded) weights")):
+            if asked:
+                self._require(path, what)
         self.max_slots = max_slots
         self.max_seq = max_seq or config.max_seq_len
         self.eos_token = eos_token
@@ -1598,10 +1623,12 @@ class ContinuousDecoder:
         item = jnp.dtype(config.dtype).itemsize
         # the layout tuple is the geometry handshake for binding AND
         # for the disaggregated wire — a cacheless paged decoder still
-        # needs it for the direct slot-table install (ISSUE 15)
-        self._kv_layout = (config.num_layers, config.num_kv_heads,
-                           config.head_dim, str(config.dtype),
-                           self.kv_int8, self.kv_block, item)
+        # needs it for the direct slot-table install (ISSUE 15).  It
+        # speaks of K and V leaves of one shape: the first leaf's
+        heads, lanes = config.cache_leaves[0]
+        self._kv_layout = (config.num_layers, heads, lanes,
+                           str(config.dtype), self.kv_int8,
+                           self.kv_block, item)
         if prefix_cache is not None:
             prefix_cache.bind(self._kv_layout, paged=self.paged)
             if not self.paged and prefix_cache.paged:
@@ -1667,10 +1694,8 @@ class ContinuousDecoder:
             # whose live blocks it walks by hand (its table body reads
             # every entry of every slot: no gain over views), with
             # weights that sit on one device
-            from .ops.paged_attention import walks_live_blocks
             on_tpu = jax.default_backend() == "tpu"
-            walks = walks_live_blocks(config.head_dim, self.kv_int8,
-                                      interpret=not on_tpu)
+            walks = self._model.walks(config, self.kv_int8, not on_tpu)
             self.paged_kernel = ATTENTION_IMPL == "paged_kernel"
             self.step_kernel = self.paged_kernel or (
                 ATTENTION_IMPL is None and not self.speculate_k
@@ -1760,10 +1785,10 @@ class ContinuousDecoder:
         # charge a position).  int8 cache: D int8 values + one f32
         # scale per (slot, head, position) — ~(D+4)/(2D) of the bf16
         # bytes
-        per_position = (config.head_dim + 4) if self.kv_int8 \
-            else config.head_dim * jnp.dtype(config.dtype).itemsize
-        self._kv_bytes_per_t = (2 * config.num_layers * max_slots *
-                                config.num_kv_heads * per_position)
+        self._kv_bytes_per_t = config.num_layers * max_slots * sum(
+            heads * ((lanes + 4) if self.kv_int8
+                     else lanes * jnp.dtype(config.dtype).itemsize)
+            for heads, lanes in config.cache_leaves)
         # cumulative decode-loop counters, mirrored onto the process
         # metrics registry (serving_decoder_total{kind=...}) so the
         # bench and the dashboard metrics pane read the SAME numbers
@@ -1818,7 +1843,10 @@ class ContinuousDecoder:
              # deadline checkpoints that harvested a live slot's
              # chain instead of letting it finish
              "drain_refused": 0, "drain_evacuated": 0,
-             "drain_checkpoints": 0},
+             "drain_checkpoints": 0}
+            # what the model's step counts of itself (an expert
+            # layer's routing), brought back in the round's one fetch
+            | {name: 0 for name in self._model.counters},
             metric="serving_decoder_total",
             help="continuous-decoder events by kind",
             # levels and time-sums stay dict-only: a high-water mark or
@@ -2137,6 +2165,16 @@ class ContinuousDecoder:
     def drained(self) -> bool:
         return self._drained
 
+    def _require(self, path: str, what: str) -> None:
+        """Refuse, by name, a serving path that the model's pool is
+        not carried through (PagedModel.supports)."""
+        if path not in self._model.supports:
+            raise ValueError(
+                f"{type(self.config).__name__}: {what} is not carried "
+                f"for this model's cache (leaves "
+                f"{self.config.cache_leaves} a layer); its paged "
+                f"decoder serves native rows, unshared, on one device")
+
     def drain(self, deadline: float | None = None,
               on_evacuate=None, on_complete=None) -> list:
         """Arm a graceful wind-down: stop admitting, let in-flight
@@ -2152,6 +2190,7 @@ class ContinuousDecoder:
         is invoked with whatever generated so far — degraded, never
         silently dropped.  Idempotent: re-arming tightens the deadline
         but never un-drains (resume() does that)."""
+        self._require("drain", "drain and migration (drain())")
         now = time.monotonic()
         self._draining = True
         self._drained = False
@@ -2642,6 +2681,7 @@ class ContinuousDecoder:
         """The storage layout as wire-safe string fields — what a
         cacheless paged decoder matches a KV transfer's declared donor
         layout against (PrefixKVCache.wire_layout's twin)."""
+        self._require("kv_wire", "the disaggregated KV wire layout")
         return tuple(str(f) for f in self._kv_layout)
 
     def install_shipped_blocks(self, tokens, start_block: int,
@@ -2661,6 +2701,7 @@ class ContinuousDecoder:
         if not self.paged:
             raise ValueError(
                 "install_shipped_blocks needs a paged decoder")
+        self._require("kv_wire", "the install of shipped KV blocks")
         start = int(start_block)
         if start < 0:
             raise ValueError(f"negative start_block {start}")
@@ -3375,6 +3416,7 @@ class ContinuousDecoder:
         self._admit_waves = []
         scanned = False
         num_steps = scanned_slots = attend_width = attended = 0
+        model_counts = []     # what the model's step counted, if it counts
         if any_active:
             occupied = [s for s in range(self.max_slots) if active[s]]
             num_steps, required_t, budgets = self._round_plan(occupied)
@@ -3438,7 +3480,8 @@ class ContinuousDecoder:
                      self._context, k_pools, v_pools) = step(*args)
                 else:
                     (emitted, emitted_active, self._tokens,
-                     self._lengths, k_pools, v_pools) = step(*args)
+                     self._lengths, k_pools, v_pools,
+                     *model_counts) = step(*args)
                 self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
             elif self.speculate_k:
                 (emitted, emit_mask, self._tokens, self._lengths,
@@ -3477,8 +3520,12 @@ class ContinuousDecoder:
                 emitted, emit_mask, wave_firsts = jax.device_get(
                     (emitted, emit_mask, wave_firsts))
             else:
-                emitted, emitted_active, wave_firsts = jax.device_get(
-                    (emitted, emitted_active, wave_firsts))
+                emitted, emitted_active, wave_firsts, model_counts = \
+                    jax.device_get((emitted, emitted_active, wave_firsts,
+                                    model_counts))
+                for counted in model_counts:
+                    for name, value in zip(self._model.counters, counted):
+                        self.stats[name] += int(value)
             self.stats["decode_s"] += time.perf_counter() - decode_start
         elif wave_firsts:
             wave_firsts = jax.device_get(wave_firsts)

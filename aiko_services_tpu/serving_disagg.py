@@ -442,6 +442,8 @@ class PrefillClient:
                 "has to land somewhere: cache chain or direct "
                 "slot-table install)")
         self.runtime = runtime
+        decoder._require("kv_wire",
+                         "the disaggregated KV wire (serving_disagg)")
         self.decoder = decoder
         # cache may be None on a paged decoder (ISSUE 15 satellite):
         # shipped KV then lands via install_shipped_blocks — pool

@@ -1,0 +1,9 @@
+from benchmark.trace import regions
+
+
+def read(run):
+    """Device ms a decode step spends under `aiko.head`: the final norm, the output head and the argmax;
+    the own time of the device operations that carry the scope inside
+    `jit_step`, and of the scopeless ones they adopt (trace/regions.py),
+    over the steps run in the traced span."""
+    return regions.step_region_ms(run, "aiko.head")

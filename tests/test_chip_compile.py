@@ -291,6 +291,153 @@ def test_kernel_step_builds_no_views_and_copies_no_pool(chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+# -- the latent pool (ISSUE 31): ax-k1-ep16-d6 at the cell's geometry -------------
+
+def _latent_cell(chip):
+    """The configuration `doc_qa_open_loop` serves, as shapes on `chip`:
+    32 slots x 8,192 positions of one [blocks, 1, 32, 640] leaf a layer,
+    6 layers at the published widths, 12 of 192 experts held."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in (root, os.path.join(root, "benchmark", "drivers")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import latent_moe_decoder
+    from aiko_services_tpu.models.latent_moe import latent_moe_init
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ax-k1-ep16-d6.json")) as f:
+        sizes = json.load(f)
+    serve = sizes["serving"]
+    config = latent_moe_decoder.model_config(sizes, serve["max_seq"],
+                                             jnp.bfloat16)
+
+    def shaped(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    params = jax.tree.map(
+        lambda leaf: shaped(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: latent_moe_init(jax.random.PRNGKey(0),
+                                               config)))
+    slots, block = serve["max_slots"], serve["kv_block"]
+    leaf = (slots * serve["max_seq"] // block + 1, 1, block,
+            config.row_lanes)
+    pool = [shaped(leaf, jnp.bfloat16) for _ in range(config.num_layers)]
+    state = [shaped((slots,), jnp.int32), shaped((slots,), jnp.int32)]
+    return config, serve, params, pool, state, leaf, shaped
+
+
+def _no_pool_copy(compiled, leaf, temporaries):
+    text = compiled.as_text()
+    result = re.escape("[" + ",".join(map(str, leaf)) + "]")
+    assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+    return text
+
+
+def test_latent_walk_compiles_at_a_row_of_640_lanes(chip):
+    """The layout Mosaic takes: ONE leaf whose row is [c_kv 512 | k_rope
+    64 | 64 zero lanes], K the whole row and V its leading 512 lanes, one
+    shared "KV head" and 64 query rows a slot."""
+    from aiko_services_tpu.ops.paged_attention import paged_decode_attention
+    slots, blocks, lanes = 32, 257, 640
+
+    def walk(q, pool, tables, side, valid, lengths):
+        return paged_decode_attention(
+            q, pool, None, tables, side, side[..., :512], valid, lengths,
+            groups=64, scale=0.13, interpret=False)
+
+    compiled = jax.jit(walk).lower(*(
+        jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+        for shape, kind in [
+            ((slots, 1, 64, lanes), jnp.bfloat16),
+            ((slots * 256 + 1, 1, BLOCK, lanes), jnp.bfloat16),
+            ((slots, blocks), jnp.int32),
+            ((slots, 1, 4, lanes), jnp.bfloat16),
+            ((slots, 1, 4), jnp.bool_), ((slots,), jnp.int32)])).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes", [576, 64])
+def test_latent_rows_that_are_not_whole_lanes_are_refused(chip, lanes):
+    """Why the row is padded: a 576-lane row, or a 64-lane leaf of its
+    own for k_rope, cannot be sliced out of HBM block by block."""
+    from aiko_services_tpu.ops import paged_attention as pa
+    assert not pa.walks_live_blocks(lanes, False)
+    assert pa.walks_live_blocks(640, False)
+
+    def walk(q, pool, tables, side, valid, lengths):
+        return pa.paged_decode_attention(
+            q, pool, None, tables, side, side[..., :lanes // 2], valid,
+            lengths, groups=64, scale=0.13, interpret=False)
+
+    with pytest.raises(ValueError, match="latent pool"):
+        jax.jit(walk).lower(*(
+            jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+            for shape, kind in [
+                ((2, 1, 64, lanes), jnp.bfloat16),
+                ((65, 1, BLOCK, lanes), jnp.bfloat16), ((2, 32), jnp.int32),
+                ((2, 1, 4, lanes), jnp.bfloat16), ((2, 1, 4), jnp.bool_),
+                ((2,), jnp.int32)]))
+
+
+def test_latent_step_walks_the_pool_and_copies_none_of_it(chip, monkeypatch):
+    """The whole 6-layer `jit_step` x 4 of the cell: six walks, one a
+    layer, no pool-shaped copy, temporaries under 0.3 GB (the experts
+    that a token reached run inside conditionals; nothing is expanded)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from aiko_services_tpu import serving_paged
+    config, serve, params, pool, state, leaf, shaped = _latent_cell(chip)
+    slots = serve["max_slots"]
+    table = -(-(serve["max_seq"] + serve["steps_per_sync"])
+              // serve["kv_block"])
+    compiled = serving_paged._paged_step_for(config, True).lower(
+        params, *state, shaped((slots,), bool), shaped((slots,), jnp.int32),
+        pool, [], shaped((slots, table), jnp.int32),
+        num_steps=serve["steps_per_sync"], eos=-1,
+        t_cap=serve["max_seq"]).compile()
+    text = _no_pool_copy(compiled, leaf, 0.3e9)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == config.num_layers
+    memory = compiled.memory_analysis()
+    # 8.33 GB of weights + 2.01 GB of pool, the pool aliased in and out
+    assert 10.2e9 < memory.argument_size_in_bytes < 10.5e9
+    assert memory.alias_size_in_bytes > 2.0e9
+
+
+@pytest.mark.parametrize("program", ["admit-512x1", "admit-256x2",
+                                     "extend-512x1"])
+def test_latent_prefill_programs_compile_and_copy_no_pool(chip, program):
+    """Admit and extend go the EXPANDED way: no kernel, the prefix read
+    piece by piece through the table, the chunk's rows scattered in
+    place; the temporaries (a piece's per-head keys and values, the
+    expert tiles) stay under 0.3 GB."""
+    from aiko_services_tpu import serving_paged
+    config, serve, params, pool, state, leaf, shaped = _latent_cell(chip)
+    kind, _, size = program.partition("-")
+    tokens, width = (int(n) for n in size.split("x"))
+    block = serve["kv_block"]
+    context = shaped((1, 1), jnp.int32)
+    vector = shaped((width,), jnp.int32)
+    if kind == "admit":
+        lowered = serving_paged._paged_admit_fn_for(
+            config, tokens, width, False, False).lower(
+            params, pool, [], *state, context,
+            shaped((width, tokens), jnp.int32), vector, vector,
+            shaped((width,), bool),
+            shaped((width, -(-tokens // block)), jnp.int32))
+    else:
+        lowered = serving_paged._paged_extend_fn_for(
+            config, tokens, width, False, False, False).lower(
+            params, pool, [], *state, context,
+            shaped((width, tokens), jnp.int32), vector, vector,
+            shaped((width,), bool), shaped((width,), bool), vector,
+            shaped((width, serve["max_seq"] // block), jnp.int32),
+            t_cap=serve["max_seq"])
+    text = _no_pool_copy(lowered.compile(), leaf, 0.3e9)
+    assert "tpu_custom_call" not in text
+
+
 def test_paged_row_tile_stays_inside_vmem_budget():
     from aiko_services_tpu.ops import paged_attention as pa
     # a decode row fits whole; an extend's G*chunk rows are tiled
