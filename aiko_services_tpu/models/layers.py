@@ -31,6 +31,7 @@ __all__ = [
     "slice_kv_rows", "split_kv_blocks", "concat_kv_rows",
     "kv_rows_nbytes",
     "gather_paged_kv", "scatter_paged_rows", "write_paged_blocks",
+    "write_paged_runs", "writes_runs_by_blocks", "run_blocks",
     "slice_paged_block",
     "linear_logits",
     "sinusoid_position_encoding", "gelu", "rope_frequencies", "apply_rope",
@@ -486,6 +487,99 @@ def scatter_paged_rows(pool, dest_blocks, offsets, rows):
     heads = jnp.arange(pool.shape[1])
     return pool.at[dest_blocks[:, :, None], heads,
                    offsets[:, :, None]].set(vals, mode="drop")
+
+
+def run_blocks(width: int, block_tokens: int) -> int:
+    """How many blocks a run of `width` consecutive rows can fall in,
+    wherever it starts: the images write_paged_runs reads and writes
+    back for a slot."""
+    return (width - 1) // block_tokens + 2
+
+
+def writes_runs_by_blocks(heads: int, width: int,
+                          block_tokens: int) -> bool:
+    """Whether a run of `width` rows a slot goes to the pool by whole
+    blocks (write_paged_runs) or row by row (scatter_paged_rows), from
+    the static shapes alone.  The TPU walks a gather or a scatter
+    window by window, some 80 ns each whatever the window holds, so
+    the form with fewer windows a slot wins; the block form's count
+    twice, read and written back.  On the chip (PERF.md, PR 32; us a
+    leaf): 8 heads x 4 rows of 24 slots 62 by rows and 18 by blocks,
+    a chunk of 512 rows 281 and 34; ONE head x 4 rows of 32 slots (a
+    latent leaf: 4 windows either way, and the blocks move 160 KiB
+    for the rows' 5) 13 and 19, so a tie keeps the rows."""
+    return 2 * run_blocks(width, block_tokens) < width * heads
+
+
+# A run this short is laid into its images by one select a row, all
+# fused into ONE pass over the images (24 slots: 18 us a leaf at 4
+# rows, 22 at 8); a longer one, a chunk, by a slice update a slot on
+# the images laid out position-major, which for 4 and 8 rows of 24
+# slots took 87 us (PERF.md, PR 32: a scatter of 24 strided windows
+# and two transposes of the images).
+_SELECT_ROWS = 32
+
+
+def write_paged_runs(pool, tables, starts, rows, live):
+    """Write each slot's run of consecutive rows into the pool by
+    WHOLE blocks: rows is [S, H, W, D] (or the scale form [S, H, W]),
+    row (s, w) lands at position starts[s] + w of slot s, whose blocks
+    tables[s] ([S, nb]) names; slots where `live` ([S]) is False, and
+    positions past the table, drop.  The same write as
+    scatter_paged_rows over destinations formed from the table, in
+    (W - 1) // B + 2 scatter windows a slot where that has W x H:
+
+    1. the ids of the blocks the run touches, out of range (they drop)
+       for a slot that is not live, past the table, and past the run's
+       last row — a short run inside one block sends ONE image of it;
+    2. those blocks read from the pool, [S, nblk, H, B, D];
+    3. the rows laid into the images at starts % B; every other cell
+       of an image keeps the bits it was read with (int8 values and
+       scales are quantized once, by the caller, and never again);
+    4. the images written back by one whole-block scatter.
+
+    The rows go into the small IMAGE and never into the pool: a loop
+    of slice updates on the pool itself keeps two copies of it and is
+    8-20 times slower than the row scatter (PERF.md, PR 25).  No two
+    live slots may name one block in a run (the pool's rule for any
+    write): each would write the block back without the other's rows."""
+    if isinstance(pool, dict):
+        return {"q": write_paged_runs(pool["q"], tables, starts,
+                                      rows["q"], live),
+                "s": write_paged_runs(pool["s"], tables, starts,
+                                      rows["s"], live)}
+    num_total, heads, block = pool.shape[:3]
+    slots, width = rows.shape[0], rows.shape[2]
+    nb = tables.shape[1]
+    nblk = run_blocks(width, block)
+    first = starts // block
+    blocks = first[:, None] + jnp.arange(nblk)[None]           # [S, nblk]
+    last = (starts + width - 1) // block
+    ids = jnp.take_along_axis(tables, jnp.clip(blocks, 0, nb - 1), axis=1)
+    written = live[:, None] & (blocks < nb) & (blocks <= last[:, None])
+    images = jnp.take(pool, jnp.where(written, ids, 0), axis=0)
+    offsets = starts - first * block                           # [S]
+    rest = (1,) * (rows.ndim - 3)
+    if width <= _SELECT_ROWS:
+        # position of every image cell in its slot's run, or outside it
+        place = (jnp.arange(nblk)[:, None] * block +
+                 jnp.arange(block)[None])[None] - offsets[:, None, None]
+        place = place.reshape((slots, nblk, 1, block) + rest)
+        for w in range(width):
+            row = rows[:, None, :, w:w + 1]        # [S, 1, H, 1, ...]
+            images = jnp.where(place == w, row, images)
+    else:
+        flat = jnp.swapaxes(images, 1, 2).reshape(
+            (slots, heads, nblk * block) + images.shape[4:])
+        flat = jax.vmap(
+            lambda image, run, offset:
+            jax.lax.dynamic_update_slice_in_dim(image, run, offset,
+                                                axis=1))(
+            flat, rows.astype(pool.dtype), offsets)
+        images = jnp.swapaxes(flat.reshape(
+            (slots, heads, nblk, block) + images.shape[4:]), 1, 2)
+    return pool.at[jnp.where(written, ids, num_total)].set(
+        images.astype(pool.dtype), mode="drop")
 
 
 def write_paged_blocks(pool, block_ids, rows):

@@ -1754,6 +1754,12 @@ class ContinuousDecoder:
                     "the paged kernel's table body" if self.step_kernel
                     else "gathered views", self._attend_widths)
             self.logger.info("decode step attends through %s", how)
+            from .serving_paged import run_write_form
+            self.logger.info(
+                "decode step writes a round's rows to the pool as %s",
+                "rows at sparse positions" if self.speculate_k
+                else run_write_form(config, self.steps_per_sync,
+                                    self.kv_block))
         else:
             self._step = _spec_step_for(config, self.speculate_k,
                                         self.speculate_ngram) \
@@ -2342,7 +2348,12 @@ class ContinuousDecoder:
             # compile-cache boundary: builder runs once per (chunk,
             # width); allocs inside it are trace-time, not per-round
             if self.paged:
-                from .serving_paged import _paged_extend_fn_for
+                from .serving_paged import (_paged_extend_fn_for,
+                                            run_write_form)
+                self.logger.info(
+                    "extend %d x %d writes a chunk to the pool as %s",
+                    chunk, width,
+                    run_write_form(self.config, chunk, self.kv_block))
                 self._prefill_fns[key] = _paged_extend_fn_for(  # graft: disable=lint-hot-alloc
                     self.config, chunk, width, self.kv_int8,
                     bool(self.speculate_k), self.paged_kernel)

@@ -653,6 +653,42 @@ def _paged_scatter(pools, tables, positions, live, sides, kv_int8,
     return out
 
 
+def _paged_write_runs(pools, tables, starts, live, sides,
+                      block_tokens: int):
+    """Write each slot's RUN of side-buffer rows, positions [starts[s],
+    starts[s] + W) of its table row, into the pool leaves (`live` [S]:
+    slots that are not drop whole).  The step's merge and the extend's
+    chunk both write runs.  A leaf takes the whole-block form
+    (layers.write_paged_runs) wherever that has fewer scatter windows
+    than the row form, by its static shape alone, and the row form
+    (_paged_scatter, which also serves the speculative step's sparse
+    positions) where it has not.  int8 pools quantize the rows ONCE,
+    before either."""
+    out = []
+    for pool, side in zip(pools, sides):
+        heads, width = side.shape[1], side.shape[2]
+        kv_int8 = isinstance(pool, dict)
+        if L.writes_runs_by_blocks(heads, width, block_tokens):
+            rows = L.quantize_kv_cache(side) if kv_int8 else side
+            out.append(L.write_paged_runs(pool, tables, starts, rows,
+                                          live))
+        else:
+            positions = starts[:, None] + jnp.arange(width)[None]
+            out.extend(_paged_scatter([pool], tables, positions,
+                                      live[:, None], [side], kv_int8,
+                                      block_tokens))
+    return out
+
+
+def run_write_form(config, width: int, block_tokens: int) -> str:
+    """What a decoder logs of the choice above, for a run of `width`
+    rows a slot of this model's first pool side."""
+    heads = config.cache_leaves[0][0]
+    if L.writes_runs_by_blocks(heads, width, block_tokens):
+        return "%d whole blocks a slot" % L.run_blocks(width, block_tokens)
+    return "%d rows a slot" % (width * heads)
+
+
 def _build_paged_step(config, kernel: bool = False):
     """Paged sibling of serving._build_step's block-KV variant: gather
     the slot-major KV views from the pool (once — the main cache is
@@ -771,12 +807,8 @@ def _build_paged_step(config, kernel: bool = False):
         # entry drop entirely (their stale lengths point into prompt
         # regions their extends are writing).
         with jax.named_scope(SCOPE_KV_MERGE):
-            positions = entry_lengths[:, None] + \
-                jnp.arange(num_steps)[None]
-            live = entry_active[:, None]
-            merged = [_paged_scatter(side, tables, positions, live, rows,
-                                     isinstance(side[0], dict),
-                                     block_tokens)
+            merged = [_paged_write_runs(side, tables, entry_lengths,
+                                        entry_active, rows, block_tokens)
                       for side, rows in zip(pools, sides)]
         k_pools = merged[0]
         if len(merged) > 1:
@@ -1004,8 +1036,6 @@ def _paged_extend_fn_for(config, chunk_len: int,
                final_idx, tables_rows, t_cap):
         block_tokens = \
             jax.tree_util.tree_leaves(k_pools[0])[0].shape[2]
-        num_total = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[0]
         pools = _pool_sides(k_pools, v_pools)
         x = L.embedding(params["embed"],
                         chunk_tokens).astype(config.dtype)
@@ -1015,26 +1045,18 @@ def _paged_extend_fn_for(config, chunk_len: int,
                "tables_rows": tables_rows, "t_cap": t_cap,
                "block_tokens": block_tokens, "kv_int8": kv_int8}
         prepared = model.extend_prepare(config, chunk_len, kernel, ctx)
-        nbt = tables_rows.shape[1]
-        blocks = q_pos // block_tokens
-        block_offsets = q_pos % block_tokens
-        dest = jnp.take_along_axis(tables_rows,
-                                   jnp.clip(blocks, 0, nbt - 1),
-                                   axis=1)
-        dest = jnp.where(valid[:, None] & (blocks < nbt), dest,
-                         num_total)
-
         for i, layer in enumerate(params["layers"]):
             x, stores = extend_layer(
                 layer, config, x, cos, sin,
                 [side[i] for side in pools], ctx, prepared)
             with jax.named_scope(SCOPE_KV_MERGE):
-                if kv_int8:
-                    stores = [L.quantize_kv_cache(store)
-                              for store in stores]
-                for side, store in zip(pools, stores):
-                    side[i] = L.scatter_paged_rows(
-                        side[i], dest, block_offsets, store)
+                # the chunk is a run: positions [offset, offset + chunk)
+                # of each valid row, in blocks _copy_on_write made its own
+                written = _paged_write_runs(
+                    [side[i] for side in pools], tables_rows, offsets,
+                    valid, stores, block_tokens)
+                for side, leaf in zip(pools, written):
+                    side[i] = leaf
         with jax.named_scope(SCOPE_HEAD):
             x = L.rms_norm(params["ln_out"], x)
             last_hidden = jnp.take_along_axis(

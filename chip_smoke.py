@@ -202,6 +202,14 @@ def phase_kernels(shape: dict, seed: int, on_chip: bool) -> None:
                                  llama_config(shape, preset_heads=True))):
         check_paged_attention(config, shape, keys[3:7], on_chip)
 
+    # the pool's run writer against the row scatter (PR 32), at the
+    # llama phase's pool and at the latent phase's leaf
+    config = llama_config(shape)
+    check_run_writes("llama", (config.num_kv_heads, config.head_dim),
+                     shape, keys[7], on_chip)
+    check_run_writes("latent", shape["latent_config"].cache_leaves[0],
+                     shape, keys[7], on_chip)
+
 
 def check_paged_attention(config, shape: dict, keys, on_chip: bool) -> None:
     import jax
@@ -253,6 +261,64 @@ def check_paged_attention(config, shape: dict, keys, on_chip: bool) -> None:
             f"D{config.head_dim} B{block} nb{nb} P{side_len} {kv}",
             jax.jit(kernel_path), jax.jit(gather_path),
             [layer, x, *pools], on_chip)
+
+
+def check_run_writes(label: str, leaf: tuple, shape: dict, key,
+                     on_chip: bool) -> None:
+    """layers.write_paged_runs (a slot's run of new rows written by the
+    whole blocks it falls in) against scatter_paged_rows over the same
+    positions, for a round's merge and for a chunk, native and int8:
+    the two pools hold the same bits in every cell."""
+    import jax
+    import jax.numpy as jnp
+
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import layers as L
+
+    heads, lanes = leaf
+    slots, block = shape["slots"], 32
+    nb = -(-shape["max_seq"] // block)
+    keys = jax.random.split(key, 4)
+    native = jax.random.normal(
+        keys[0], (slots * nb + 1, heads, block, lanes), jnp.bfloat16)
+    tables = 1 + jax.random.permutation(
+        keys[1], slots * nb).astype(jnp.int32).reshape(slots, nb)
+
+    def by_rows(pool, tables, starts, side, live):
+        positions = starts[:, None] + jnp.arange(side.shape[2])[None]
+        return serving_paged._paged_scatter(
+            [pool], tables, positions, live[:, None], [side],
+            isinstance(pool, dict), block)[0]
+
+    def by_blocks(pool, tables, starts, side, live):
+        rows = L.quantize_kv_cache(side) if isinstance(pool, dict) else side
+        return L.write_paged_runs(pool, tables, starts, rows, live)
+
+    for run, (rows_n, width) in {
+            "merge": (slots, shape["steps_per_sync"]),
+            "chunk": (1, shape["prefill_chunk"])}.items():
+        # starts anywhere: inside a block, across an edge, the last past
+        # the table (its tail drops); one slot of a merge is not live
+        starts = jnp.linspace(block - 2, nb * block - width + 1,
+                              rows_n).astype(jnp.int32)
+        live = jnp.arange(rows_n) != 1
+        side = jax.random.normal(keys[2], (rows_n, heads, width, lanes),
+                                 jnp.bfloat16)
+        for kv, pool in (("bfloat16", native),
+                         ("int8", L.quantize_kv_cache(native))):
+            args = (pool, tables[:rows_n], starts, side, live)
+            got, want = jax.jit(by_blocks)(*args), jax.jit(by_rows)(*args)
+            same = all(bool(jnp.array_equal(a, b)) for a, b in zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(want)))
+            changed = any(bool(jnp.any(a != b)) for a, b in zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(pool)))
+            say(f"  write_paged_runs {label} H{heads} D{lanes} {run} "
+                f"{rows_n}x{width} {kv}: equal to the row scatter {same}")
+            require(same and changed,
+                    f"write_paged_runs {label} {run} {kv} differs from "
+                    f"the row scatter")
 
 
 # -- speech ------------------------------------------------------------------

@@ -217,12 +217,98 @@ def test_block_write_updates_the_donated_pool_in_place(chip):
     _assert_in_place(compiled, POOL, jnp.bfloat16)
 
 
-def _mistral_step(chip, kernel, width):
-    """`jit_step` x 4 of mistral-7b-v0.3-d16 as the benchmark's cells
-    run it, whole, compiled for `chip`: 24 slots, a full pool, the
-    table at its constant 65 blocks (64 and the merge's headroom)."""
+# a run's shape: (slots, rows a slot, table blocks a slot, what the leaf is)
+RUN_WRITES = {
+    "step-24x4-bf16": (24, 4, 65, "bf16"),
+    "step-24x4-s8": (24, 4, 65, "s8"),
+    "step-24x4-scale-f32": (24, 4, 65, "scale-f32"),
+    "extend-1x512-bf16": (1, 512, 64, "bf16"),
+    "extend-2x512-bf16": (2, 512, 64, "bf16"),
+    "extend-1x512-scale-f32": (1, 512, 64, "scale-f32"),
+}
+
+
+def _block_windows(text, leaf_shape, dtype_name):
+    """(how many, of what shape) the whole-block gather reads of the pool
+    leaf and the scatter writes back to it, from the optimized HLO."""
+    # (the compiler drops a unit axis: a latent leaf's one head)
+    leaf_shape = [n for n in leaf_shape if n != 1]
+    leaf = re.escape("[" + ",".join(map(str, leaf_shape)) + "]")
+    window = ",".join(map(str, leaf_shape[1:]))
+    reads = re.findall(
+        rf"= {dtype_name}\[([\d,]+),{window}\]\S* gather\(.*"
+        rf"slice_sizes=\{{1,{window}\}}", text)
+    writes = re.findall(
+        rf"= {dtype_name}{leaf}\S* scatter\(.*inserted_window_dims=\{{0\}}, "
+        rf"scatter_dims_to_operand_dims=\{{0\}}", text)
+    return reads, writes
+
+
+@pytest.mark.parametrize("case", sorted(RUN_WRITES))
+def test_run_write_is_whole_blocks_in_place(chip, case):
+    """layers.write_paged_runs (PR 32) at the step's merge and at a
+    chunk: the pool leaf is read by ONE gather whose window is a whole
+    block, (W - 1) // 32 + 2 of them a slot, and written by ONE scatter
+    of whole blocks into the donated leaf; no copy of a value leaf's
+    shape, temporaries under a leaf (the images)."""
+    from aiko_services_tpu.models import layers
+    slots, width, table, leaf = RUN_WRITES[case]
+    shape, dtype = POOL_LEAVES[leaf]
+    compiled = _compiled_donating(
+        layers.write_paged_runs, chip, (shape, dtype),
+        ((slots, table), jnp.int32), ((slots,), jnp.int32),
+        ((slots, shape[1], width) + shape[3:], dtype), ((slots,), bool))
+    text = compiled.as_text()
+    if leaf == "scale-f32":
+        # the device keeps a [N, H, 32] plane with N minor-most, where a
+        # block is no contiguous window: its 1.5 MB pass through fast
+        # memory in the other order and back (no int8 pool is in a cell)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e6
+    else:
+        _assert_in_place(compiled, shape, dtype)
+    reads, writes = _block_windows(text, shape, jnp.dtype(dtype).name
+                                   .replace("bfloat16", "bf16")
+                                   .replace("float32", "f32")
+                                   .replace("int8", "s8"))
+    nblk = layers.run_blocks(width, POOL[2])
+    assert len(reads) == 1 and len(writes) == 1, (reads, writes)
+    assert math.prod(int(n) for n in reads[0].split(",")) == slots * nblk
+
+
+def test_run_write_stays_on_its_shard_of_a_heads_sharded_pool(v5e):
+    """As the row scatter above: tensor parallel serving shards a leaf
+    over its heads, and the block form's images, a gather and a scatter
+    whose windows keep the heads axis whole, stay on each chip's two
+    heads: no collective, no copy of the shard."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from aiko_services_tpu.models import layers
+    mesh = Mesh(np.array(v5e.devices).reshape(4), ("model",))
+    heads = NamedSharding(mesh, PartitionSpec(None, "model"))
+    whole = NamedSharding(mesh, PartitionSpec())
+    compiled = jax.jit(layers.write_paged_runs, donate_argnums=(0,),
+                       out_shardings=heads).lower(
+        jax.ShapeDtypeStruct(POOL, jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct((24, 65), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((24,), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((24, POOL[1], 4, POOL[3]), jnp.bfloat16,
+                             sharding=heads),
+        jax.ShapeDtypeStruct((24,), bool, sharding=whole)).compile()
+    text = compiled.as_text()
+    shard = (POOL[0], POOL[1] // 4) + POOL[2:]
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text
+    _assert_in_place(compiled, shard, jnp.bfloat16)
+    reads, writes = _block_windows(text, shard, "bf16")
+    assert len(reads) == 1 and len(writes) == 1, (reads, writes)
+
+
+def _mistral_cell(chip):
+    """mistral-7b-v0.3-d16 as the benchmark's cells serve it, as shapes
+    on `chip`: the configuration, its serving block, the weights, a full
+    pool side (24 slots x 64 blocks and the null block) and `shaped`."""
     import json
-    from aiko_services_tpu import serving_paged
     from aiko_services_tpu.models.llama import LlamaConfig
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
@@ -237,7 +323,6 @@ def _mistral_step(chip, kernel, width):
         num_kv_heads=sizes["num_key_value_heads"],
         max_seq_len=serve["max_seq"], rope_theta=sizes["rope_theta"],
         dtype=jnp.bfloat16)
-    slots, table = serve["max_slots"], 65
 
     def shaped(shape, kind):
         return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
@@ -247,6 +332,16 @@ def _mistral_step(chip, kernel, width):
         lambda leaf: shaped(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: llama_init(jax.random.PRNGKey(0), config)))
     pool = [shaped(POOL, jnp.bfloat16) for _ in range(config.num_layers)]
+    return config, serve, params, pool, shaped
+
+
+def _mistral_step(chip, kernel, width):
+    """`jit_step` x 4 of mistral-7b-v0.3-d16 as the benchmark's cells
+    run it, whole, compiled for `chip`: 24 slots, a full pool, the
+    table at its constant 65 blocks (64 and the merge's headroom)."""
+    from aiko_services_tpu import serving_paged
+    config, serve, params, pool, shaped = _mistral_cell(chip)
+    slots, table = serve["max_slots"], 65
     return serving_paged._paged_step_for(config, kernel).lower(
         params, shaped((slots,), jnp.int32), shaped((slots,), jnp.int32),
         shaped((slots,), bool), shaped((slots,), jnp.int32), pool, pool,
@@ -255,6 +350,7 @@ def _mistral_step(chip, kernel, width):
 
 
 VIEW = r"bf16\[24,(\d+),8,32,128\]"       # a slot-major K or V view
+MERGE_IMAGES = {"2"}    # and the merge's: the two blocks four rows fall in
 
 
 @pytest.mark.parametrize("width, blocks, temporaries", [
@@ -267,7 +363,7 @@ def test_step_views_follow_the_attend_width(chip, width, blocks,
     no wider, whatever the table holds, and the temporaries shrink with
     them; the cap's program is not widened to the table's 65."""
     compiled = _mistral_step(chip, False, width)
-    views = set(re.findall(VIEW, compiled.as_text()))
+    views = set(re.findall(VIEW, compiled.as_text())) - MERGE_IMAGES
     assert views == {str(blocks)}, views
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
 
@@ -285,10 +381,39 @@ def test_kernel_step_builds_no_views_and_copies_no_pool(chip, monkeypatch):
     text = compiled.as_text()
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == 16
-    assert re.findall(VIEW, text) == []
+    assert set(re.findall(VIEW, text)) == MERGE_IMAGES
     result = re.escape("[" + ",".join(map(str, POOL)) + "]")
     assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    # the merge (PR 32): each of the 32 leaves is read by one gather of
+    # whole blocks, two a slot, and written by one scatter of them
+    reads, writes = _block_windows(text, POOL, "bf16")
+    assert reads == ["24,2"] * 32 and len(writes) == 32
+    assert len(re.findall(r" scatter\(", text)) == 32
+
+
+def test_mistral_extend_writes_its_chunk_by_whole_blocks(chip):
+    """`jit_extend` 512 x 1 of the cells (the gather path, as a cell
+    runs it): each leaf's chunk goes back as 17 whole blocks, in place;
+    the temporaries are the one slot's views at the cap and the chunk's
+    activations."""
+    from aiko_services_tpu import serving_paged
+    config, serve, params, pool, shaped = _mistral_cell(chip)
+    slots = serve["max_slots"]
+    vector = shaped((1,), jnp.int32)
+    compiled = serving_paged._paged_extend_fn_for(
+        config, 512, 1, False, False, False).lower(
+        params, pool, pool, shaped((slots,), jnp.int32),
+        shaped((slots,), jnp.int32), shaped((1, 1), jnp.int32),
+        shaped((1, 512), jnp.int32), vector, vector, shaped((1,), bool),
+        shaped((1,), bool), vector,
+        shaped((1, serve["max_seq"] // POOL[2]), jnp.int32),
+        t_cap=serve["max_seq"]).compile()
+    text = _no_pool_copy(compiled, POOL, 0.5e9)
+    reads, writes = _block_windows(text, POOL, "bf16")
+    # (the 64-block gathers are the prefix views of the one slot)
+    assert sorted(reads) == ["17"] * 32 + ["64"] * 32 and len(writes) == 32
+    assert len(re.findall(r" scatter\(", text)) == 32
 
 
 # -- the latent pool (ISSUE 31): ax-k1-ep16-d6 at the cell's geometry -------------
@@ -399,6 +524,10 @@ def test_latent_step_walks_the_pool_and_copies_none_of_it(chip, monkeypatch):
     text = _no_pool_copy(compiled, leaf, 0.3e9)
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == config.num_layers
+    # the merge keeps its ROWS here (PR 32): four windows of one head a
+    # slot against two blocks read and two written, 160 KiB for 5
+    assert _block_windows(text, leaf, "bf16") == ([], [])
+    assert len(re.findall(r" scatter\(", text)) == config.num_layers
     memory = compiled.memory_analysis()
     # 8.33 GB of weights + 2.01 GB of pool, the pool aliased in and out
     assert 10.2e9 < memory.argument_size_in_bytes < 10.5e9
@@ -436,6 +565,13 @@ def test_latent_prefill_programs_compile_and_copy_no_pool(chip, program):
             t_cap=serve["max_seq"])
     text = _no_pool_copy(lowered.compile(), leaf, 0.3e9)
     assert "tpu_custom_call" not in text
+    if kind == "extend":
+        # the chunk goes back as 17 whole blocks a leaf (PR 32); the
+        # 16-block gathers are the prefix, piece by piece
+        reads, writes = _block_windows(text, leaf, "bf16")
+        assert reads.count("17") == config.num_layers
+        assert len(writes) == config.num_layers
+        assert len(re.findall(r" scatter\(", text)) == config.num_layers
 
 
 def test_paged_row_tile_stays_inside_vmem_budget():

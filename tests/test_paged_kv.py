@@ -526,6 +526,27 @@ class TestCopyOnExtend:
         assert run(paged, {"w3": (long_prompt, 1)})["w3"] == cold
         assert paged.pool.used_blocks() == len(cache)
 
+    def test_slide_back_leaves_the_cached_blocks_bit_equal(self, params):
+        """The chunk goes to the pool by whole blocks (PR 32): the final
+        chunk of the 95-token prompt starts at 79, in the MIDDLE of a
+        block of 8 that the cached chain shares, and that block is read
+        whole and written back.  _copy_on_write makes every block of
+        [offset, offset + chunk) the slot's own first, the partial
+        first one too, so what the write-back touches is the copy: every
+        block the cache held before the hit holds the same bits after."""
+        long_prompt = [(i * 7) % 50 + 1 for i in range(95)]
+        _, paged, _, cache = pair(params, cache=True, prefill_chunk=16)
+        run(paged, {"w1": (long_prompt, 1)})
+        pool = paged.pool
+        held = np.flatnonzero(pool._refs > 0)
+        assert len(held) == len(cache) > 0
+        before = [np.asarray(leaf[held])
+                  for leaf in pool.k_pools + pool.v_pools]
+        run(paged, {"w2": (long_prompt, 1)})
+        assert pool.stats["cow_copies"] >= 1
+        for leaf, was in zip(pool.k_pools + pool.v_pools, before):
+            np.testing.assert_array_equal(np.asarray(leaf[held]), was)
+
     def test_no_copies_on_ordinary_hits(self, params):
         _, paged, *_ = pair(params, cache=True, prefill_chunk=16)
         run(paged, {"donor": (PROMPT, 10)})

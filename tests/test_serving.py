@@ -1030,3 +1030,56 @@ def test_deadline_admission_sheds_doomed_request(params):
     assert decoder.submit("open", [9], 4, called.append)
     assert len(decoder._pending) == 7
     assert called == []                        # refusals never call back
+
+
+@pytest.mark.parametrize("kwargs, step_says, extend_says", [
+    # 4 rows of tiny's 2 KV heads: 8 row windows against 2 blocks read
+    # and 2 written; a chunk of 16 in blocks of 8: 3 blocks against 32
+    ({}, "2 whole blocks a slot", "3 whole blocks a slot"),
+    ({"kv_cache_dtype": "int8"}, "2 whole blocks a slot",
+     "3 whole blocks a slot"),
+    # one step a round of 2 heads: 2 rows against 2 x 2 blocks
+    ({"steps_per_sync": 1}, "2 rows a slot", "3 whole blocks a slot"),
+    # the speculative step's positions are no run: rejected drafts drop
+    ({"speculate_k": 2}, "rows at sparse positions",
+     "3 whole blocks a slot"),
+])
+def test_decoder_says_how_its_rows_reach_the_pool(kwargs, step_says,
+                                                  extend_says):
+    """PR 32: a run of a slot's new rows goes to the pool by whole
+    blocks wherever that has fewer scatter windows than row by row, by
+    static shapes alone; the decoder says which on its logger, the step
+    at construction and an extend at its first build."""
+    import logging
+    params = llama_init(jax.random.PRNGKey(0), CONFIG)
+    heard = []
+
+    class Heard(logging.Handler):
+        def emit(self, record):
+            heard.append(record.getMessage())
+
+    # the logger does not propagate: listen on it, from before it speaks
+    name = "forms_%d" % abs(hash(tuple(sorted(kwargs))))
+    logger = logging.getLogger(f"serving.{name}")
+    handler = Heard(logging.INFO)
+    logger.addHandler(handler)
+    try:
+        options = dict(max_slots=4, prefill_buckets=(16,), steps_per_sync=4,
+                       paged_kv=True, kv_block=8, prefill_chunk=16,
+                       name=name)
+        options.update(kwargs)
+        decoder = ContinuousDecoder(params, CONFIG, **options)
+        done = {}
+        decoder.submit("long", [(i * 7) % 50 + 1 for i in range(40)], 3,
+                       lambda rid, tokens: done.update({rid: tokens}))
+        for _ in range(40):
+            decoder.pump()
+            if done:
+                break
+        assert done
+    finally:
+        logger.removeHandler(handler)
+    step = [m for m in heard if m.startswith("decode step writes")]
+    extend = [m for m in heard if m.startswith("extend 16 x 1 writes")]
+    assert len(step) == 1 and len(extend) == 1, heard
+    assert step[0].endswith(step_says) and extend[0].endswith(extend_says)
