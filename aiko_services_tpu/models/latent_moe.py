@@ -45,6 +45,7 @@ from .llama import (SCOPE_ATTN_CORE, SCOPE_ATTN_PROJ, SCOPE_HEAD,
 
 __all__ = ["LatentMoeConfig", "LATENT_MOE_PRESETS", "latent_moe_init",
            "latent_moe_forward", "yarn_rope_tables", "select_experts",
+           "swiglu",
            "SCOPE_MOE_ROUTE", "SCOPE_MOE_SHARED", "SCOPE_MOE_EXPERTS",
            "SCOPE_MLA_EXPAND", "MOE_COUNTERS"]
 
@@ -408,23 +409,49 @@ def expanded_attention(layer, config: LatentMoeConfig, x, cos, sin,
 
 # -- the expert layer --------------------------------------------------------------
 
-def select_experts(config: LatentMoeConfig, scores):
+def select_experts(config: LatentMoeConfig, scores, bias=None):
     """Which experts a token goes to, from its scores over ALL experts
     [N, num_experts] f32: (ids [N, top_k], weights [N, top_k] f32).
     `topk_method` "none", read as the plain rule: the top_k largest
-    scores, no correction bias, no group restriction; the weights are
-    the chosen scores over their sum, times routed_scale."""
-    chosen, ids = jax.lax.top_k(scores, config.top_k)
+    scores, no group restriction; the weights are the chosen scores
+    over their sum, times routed_scale.  With a correction `bias`
+    [num_experts] (`noaux_tc`) the choice is by score + bias and the
+    weights are still the scores'."""
+    if bias is None:
+        chosen, ids = jax.lax.top_k(scores, config.top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + bias, config.top_k)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
     weights = chosen / chosen.sum(axis=-1, keepdims=True) * \
         config.routed_scale
     return ids.astype(jnp.int32), weights
 
 
-def _expert_rows(experts, index: int, rows):
+def _gated(gate, up, limit=None):
+    """silu(gate) * up(); with a `limit` (`swiglu_limit`) the gate is cut
+    from above before its SiLU and the linear branch clipped to [-limit,
+    limit].  `up` is called after the gate's SiLU, as the layers always
+    ordered the two."""
+    if limit is None:
+        return jax.nn.silu(gate) * up()
+    return jax.nn.silu(jnp.minimum(gate, limit)) * \
+        jnp.clip(up(), -limit, limit)
+
+
+def swiglu(layer, x, limit=None):
+    """SwiGLU, clamped where the model states a `swiglu_limit`."""
+    if limit is None:
+        return _swiglu(layer, x)
+    return L.linear(layer["down"], _gated(
+        L.linear(layer["gate"], x), lambda: L.linear(layer["up"], x),
+        limit))
+
+
+def _expert_rows(experts, index: int, rows, limit=None):
     """Held expert `index` over rows [R, dim]: SwiGLU, f32 out."""
-    hidden = jax.nn.silu(L.linear({"w": experts["gate"]["w"][index]},
-                                  rows)) * \
-        L.linear({"w": experts["up"]["w"][index]}, rows)
+    hidden = _gated(
+        L.linear({"w": experts["gate"]["w"][index]}, rows),
+        lambda: L.linear({"w": experts["up"]["w"][index]}, rows), limit)
     return jnp.einsum("rf,fd->rd", hidden, experts["down"]["w"][index],
                       preferred_element_type=jnp.float32)
 
@@ -446,7 +473,8 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
             "nd,de->ne", tokens.astype(jnp.float32),
             layer["router"]["w"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
-        ids, weights = select_experts(config, jax.nn.sigmoid(logits))
+        ids, weights = select_experts(config, jax.nn.sigmoid(logits),
+                                      layer["router"].get("bias"))
         alive = jnp.ones((n,), bool) if live is None \
             else live.reshape(-1)
         local = ids - first
@@ -460,8 +488,9 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
             loads.sum(), alive.sum().astype(jnp.int32) * config.top_k])
         if n > _EXPERT_TILE:
             place = jnp.cumsum(routed, axis=0) - 1            # [N, E]
+    limit = getattr(config, "swiglu_limit", None)
     with jax.named_scope(SCOPE_MOE_SHARED):
-        y = _swiglu(layer["shared"], tokens)
+        y = swiglu(layer["shared"], tokens, limit)
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         experts = layer["experts"]
         out = jnp.zeros((n, shape[-1]), jnp.float32)
@@ -471,7 +500,7 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
                 # (zero where it was not routed), and nothing at all
                 # where no token came: its weights are not read
                 def run(out, e=e):
-                    return out + _expert_rows(experts, e, tokens) * \
+                    return out + _expert_rows(experts, e, tokens, limit) * \
                         weight_of[:, e, None]
 
                 out = jax.lax.cond(loads[e] > 0, run, lambda out: out, out)
@@ -489,7 +518,7 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
                     preferred_element_type=jnp.float32
                 ).astype(tokens.dtype)
                 gains = pick.astype(jnp.float32) @ weight_of[:, e]
-                given = (_expert_rows(experts, e, rows) *
+                given = (_expert_rows(experts, e, rows, limit) *
                          gains[:, None]).astype(tokens.dtype)
                 return out + jnp.einsum(
                     "rn,rd->nd", pick.astype(tokens.dtype), given,
@@ -669,9 +698,11 @@ def _extend_layer(kernel: bool):
     return extend_layer
 
 
-def _walks(config: LatentMoeConfig, kv_int8: bool, interpret: bool) -> bool:
+def _walks(config: LatentMoeConfig, kv_int8: bool,
+           interpret: bool) -> str | None:
     from ..ops.paged_attention import walks_live_blocks
-    return walks_live_blocks(config.row_lanes, kv_int8, interpret)
+    return "kernel" if walks_live_blocks(config.row_lanes, kv_int8,
+                                         interpret) else None
 
 
 @functools.cache

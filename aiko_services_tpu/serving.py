@@ -1625,7 +1625,8 @@ class ContinuousDecoder:
         # for the disaggregated wire — a cacheless paged decoder still
         # needs it for the direct slot-table install (ISSUE 15).  It
         # speaks of K and V leaves of one shape: the first leaf's
-        heads, lanes = config.cache_leaves[0]
+        from .serving_paged import first_leaf
+        heads, lanes = first_leaf(config)
         self._kv_layout = (config.num_layers, heads, lanes,
                            str(config.dtype), self.kv_int8,
                            self.kv_block, item)
@@ -1699,15 +1700,33 @@ class ContinuousDecoder:
             self.paged_kernel = ATTENTION_IMPL == "paged_kernel"
             self.step_kernel = self.paged_kernel or (
                 ATTENTION_IMPL is None and not self.speculate_k
-                and on_tpu and walks and self._weights_on_one_device())
+                and on_tpu and walks == "kernel"
+                and self._weights_on_one_device())
             # a step whose kernel walks each slot's own live blocks has
             # no width: the table goes in whole, ONE program a step
             # count.  The gather step builds its views at a width of
             # the ladder, and the kernel's table body (a head of 64, an
             # int8 pool) follows the table as far as it is cut
-            self._walks_live = self.step_kernel and walks
+            # (or the model reads the pool by hand, kernel or not)
+            self._walks_live = walks == "model" or (
+                self.step_kernel and walks == "kernel")
             self._attend_widths = (self.max_seq,) if self._walks_live \
                 else _attend_ladder(self.max_seq, block)
+            # what the model keeps of a slot and not of a token
+            # (ISSUE 33): device arrays beside the pool, which step,
+            # admit and extend take and hand back rewritten
+            self.slot_state = None
+            if getattr(config, "slot_state", ()):
+                from .serving_paged import SlotState
+                if not prefill_chunk or self.max_seq % prefill_chunk:
+                    # a chunk that slid back over positions already
+                    # prefilled would run them through the state twice
+                    raise ValueError(
+                        "a model with slot state prefills long prompts "
+                        "chunk after chunk from where the last one "
+                        "ended: prefill_chunk must be set and divide "
+                        f"max_seq, got {prefill_chunk} and {self.max_seq}")
+                self.slot_state = SlotState(config, max_slots)
             # (num_steps, width, state's placement) -> executable
             self._step_programs: dict = {}
             # per-slot owned/aliased pool block ids, in table order
@@ -1717,6 +1736,7 @@ class ContinuousDecoder:
             self._v = None
         else:
             self.pool = None
+            self.slot_state = None
             self.paged_kernel = self.step_kernel = False
             self._walks_live = False
             self._k = self._zero_caches()
@@ -1747,7 +1767,9 @@ class ContinuousDecoder:
                 self.step_kernel) \
                 if self.speculate_k \
                 else _paged_step_for(config, self.step_kernel)
-            if self._walks_live:
+            if walks == "model":
+                how = "the model's own reads of the pool"
+            elif self._walks_live:
                 how = "the paged kernel, each slot's live blocks"
             else:
                 how = "%s at widths %s" % (
@@ -1791,10 +1813,9 @@ class ContinuousDecoder:
         # charge a position).  int8 cache: D int8 values + one f32
         # scale per (slot, head, position) — ~(D+4)/(2D) of the bf16
         # bytes
-        self._kv_bytes_per_t = config.num_layers * max_slots * sum(
-            heads * ((lanes + 4) if self.kv_int8
-                     else lanes * jnp.dtype(config.dtype).itemsize)
-            for heads, lanes in config.cache_leaves)
+        from .serving_paged import token_nbytes
+        self._kv_bytes_per_t = int(max_slots *
+                                   token_nbytes(config, self.kv_int8))
         # cumulative decode-loop counters, mirrored onto the process
         # metrics registry (serving_decoder_total{kind=...}) so the
         # bench and the dashboard metrics pane read the SAME numbers
@@ -1849,7 +1870,10 @@ class ContinuousDecoder:
              # deadline checkpoints that harvested a live slot's
              # chain instead of letting it finish
              "drain_refused": 0, "drain_evacuated": 0,
-             "drain_checkpoints": 0}
+             "drain_checkpoints": 0,
+             # requests whose slot state (a recurrent layer's) was
+             # started from zeros (ISSUE 33)
+             "slot_states_zeroed": 0}
             # what the model's step counts of itself (an expert
             # layer's routing), brought back in the round's one fetch
             | {name: 0 for name in self._model.counters},
@@ -2175,11 +2199,13 @@ class ContinuousDecoder:
         """Refuse, by name, a serving path that the model's pool is
         not carried through (PagedModel.supports)."""
         if path not in self._model.supports:
+            from .serving_paged import layer_leaves
+            kinds = sorted(set(layer_leaves(self.config)))
             raise ValueError(
                 f"{type(self.config).__name__}: {what} is not carried "
-                f"for this model's cache (leaves "
-                f"{self.config.cache_leaves} a layer); its paged "
-                f"decoder serves native rows, unshared, on one device")
+                f"for this model's cache (a layer's leaves: {kinds}); "
+                f"its paged decoder serves native rows, unshared, on one "
+                f"device")
 
     def drain(self, deadline: float | None = None,
               on_evacuate=None, on_complete=None) -> list:
@@ -2408,7 +2434,12 @@ class ContinuousDecoder:
                 # garbage tail past the prompt is dead cells, same as
                 # the shorter-than-chunk admit)
                 offset = max(0, total - chunk)
-                if offset < request.prefix_hit:
+                if self.slot_state is not None:
+                    # slot state has run through everything before
+                    # prefill_pos already: the chunk starts there and
+                    # pads forward (max_seq is whole chunks)
+                    offset = request.prefill_pos
+                elif offset < request.prefix_hit:
                     # ...but never let the write extent leave the
                     # cache: near the seq cap the forward pad would
                     # exceed max_seq, where _fit_caches clamps and the
@@ -2490,15 +2521,16 @@ class ContinuousDecoder:
                 tables_rows[j] = self._tables_np[slot, :nbt]
             tables_rows[len(slots):] = 0  # pad rows must stay null
             (firsts, k_pools, v_pools, self._tokens, self._lengths,
-             self._context) = self._extend_fn(chunk, width)(
+             self._context, *state) = self._extend_fn(chunk, width)(
                 self.params, self.pool.k_pools, self.pool.v_pools,
                 self._tokens, self._lengths, self._context,
                 jnp.asarray(chunk_tokens), jnp.asarray(offsets),
                 jnp.asarray(slots + pad_slots, jnp.int32),
                 jnp.asarray(valid), jnp.asarray(finish_arr),
-                jnp.asarray(final_idx), jnp.array(tables_rows),
-                t_cap=self._cache_t)
+                jnp.asarray(final_idx), self._table_rows_device(tables_rows),
+                *self._state_args(), t_cap=self._cache_t)
             self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
+            self._state_back(state, int((offsets[:n] == 0).sum()))
         else:
             (firsts, self._k, self._v, self._tokens, self._lengths,
              self._context) = self._extend_fn(chunk, width)(
@@ -2669,6 +2701,31 @@ class ContinuousDecoder:
                                   eos=eos, t_cap=each)
             program = self._step_programs[key]
         return program
+
+    @staticmethod
+    def _table_rows_device(tables_rows):
+        """The scratch rows as a device array that OWNS its values.
+        `jnp.array` of a numpy array does not copy on the host, and one
+        row of the scratch is contiguous, so on the CPU the program's
+        argument aliased the scratch itself: the next dispatch rewrote
+        it under a prefill program that had not run yet (no round syncs
+        on an admit or an extend), which then read another request's
+        blocks.  Seen as a flaky token under load with programs as slow
+        as a recurrent model's on the CPU (ISSUE 33); a chip gets a
+        transfer either way."""
+        return jnp.asarray(tables_rows.copy())
+
+    def _state_args(self) -> tuple:
+        """The slot state as a program's argument: nothing where the
+        model keeps none."""
+        return () if self.slot_state is None else (self.slot_state.arrays,)
+
+    def _state_back(self, returned: list, started: int) -> None:
+        """Take the slot state a program handed back; `started` requests
+        began in it from zeros."""
+        if self.slot_state is not None:
+            (self.slot_state.arrays,) = returned
+            self.stats["slot_states_zeroed"] += started
 
     def _release_slot_blocks(self, slot: int,
                              tenant: str | None = None) -> None:
@@ -3245,13 +3302,15 @@ class ContinuousDecoder:
                 raise
             tables_rows[len(slots):] = 0  # pad rows must stay null
             (firsts, k_pools, v_pools, self._tokens, self._lengths,
-             self._context) = self._admit_fn(bucket, width)(
+             self._context, *state) = self._admit_fn(bucket, width)(
                 self.params, self.pool.k_pools, self.pool.v_pools,
                 self._tokens, self._lengths, self._context,
                 jnp.asarray(prompts), jnp.asarray(true_lens),
                 jnp.asarray(slots + pad_slots, jnp.int32),
-                jnp.asarray(valid), jnp.array(tables_rows))
+                jnp.asarray(valid), self._table_rows_device(tables_rows),
+                *self._state_args())
             self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
+            self._state_back(state, n)
         else:
             (firsts, self._k, self._v, self._tokens, self._lengths,
              self._context) = self._admit_fn(bucket, width)(
@@ -3483,7 +3542,8 @@ class ContinuousDecoder:
                 args = (self.params, self._tokens, self._lengths,
                         jnp.array(scan_active), jnp.array(budgets)) + \
                     ((self._context,) if self.speculate_k else ()) + \
-                    (self.pool.k_pools, self.pool.v_pools, tables)
+                    (self.pool.k_pools, self.pool.v_pools, tables) + \
+                    self._state_args()
                 step = self._step_program(program_steps, attend_width,
                                           eos, args)
                 if self.speculate_k:
@@ -3493,6 +3553,8 @@ class ContinuousDecoder:
                     (emitted, emitted_active, self._tokens,
                      self._lengths, k_pools, v_pools,
                      *model_counts) = step(*args)
+                    if self.slot_state is not None:
+                        self._state_back([model_counts.pop()], 0)
                 self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
             elif self.speculate_k:
                 (emitted, emit_mask, self._tokens, self._lengths,

@@ -62,7 +62,7 @@ from .models.llama import (SCOPE_ATTN_CORE, SCOPE_ATTN_PROJ, SCOPE_HEAD,
                            LlamaConfig, llama_ffn)
 from .utils import get_logger
 
-__all__ = ["BlockPool", "PagedModel"]
+__all__ = ["BlockPool", "PagedModel", "SlotState"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,11 +100,31 @@ class PagedModel:
     extend_layer(kernel) -> layer(layer, config, x, cos, sin, leaves,
         ctx, prepared) -> (x after the layer, the chunk's rows of each
         pool side)
-    walks(config, kv_int8, interpret) -> whether the pallas kernel walks
-        this model's pool by hand (ops.paged_attention)
+    walks(config, kv_int8, interpret) -> who reads a slot's live blocks
+        in the step: "kernel" where the pallas kernel walks this model's
+        pool by hand (ops.paged_attention), "model" where the model
+        reads the pool itself in every program, kernel or not (the step
+        then gathers no views and has one width), None where neither
+        does and the step attends gathered views
     counters: names of the step's counts, added to decoder.stats
     supports: the serving paths this model's pool is carried through;
-        the decoder refuses the others at construction"""
+        the decoder refuses the others at construction
+
+    A configuration may declare its leaves LAYER BY LAYER
+    (`config.layer_cache_leaves`: per layer a tuple of (heads, lanes,
+    tokens a row), possibly empty; see layer_leaves) and per-slot STATE
+    (`config.slot_state`: per layer a tuple of (shape, dtype); see
+    SlotState).  A model with slot state takes and returns it (ISSUE 33):
+        step_attention's attend(..., entry_active, state, active) ->
+            (output, sides, the layer's state after the token, counts
+            in `counters`' order or None); where `active` [S] is False
+            the slot decodes nothing and its state comes back unchanged
+        prefill -> (hidden, rows, per layer the state after each row's
+            true length)
+        extend_layer's layer(..., prepared, state) -> (x, rows, state)
+    residual_in(config, x), final_norm(params, config, x): what an
+        extend does to the embedding before the first layer and instead
+        of the last norm, where the residual is not one stream"""
     rope: object
     token_block_argmax: object
     step_attention: object
@@ -114,6 +134,80 @@ class PagedModel:
     walks: object
     counters: tuple = ()
     supports: frozenset = frozenset()
+    residual_in: object = None
+    final_norm: object = None
+
+
+def reads_own_pool(config) -> bool:
+    """Whether the model reads its pool itself in every program (its
+    `walks` says "model" whatever the pool's precision or backend)."""
+    return config.paged_model().walks(config, False, True) == "model"
+
+
+def layer_leaves(config) -> tuple:
+    """Layer by layer, the (heads, lanes, tokens a row) of each leaf a
+    layer keeps of a token: what the configuration declares
+    (`layer_cache_leaves`), or `cache_leaves` for every layer, a row a
+    token."""
+    declared = getattr(config, "layer_cache_leaves", None)
+    if declared is None:
+        declared = (config.cache_leaves,) * config.num_layers
+    return tuple(tuple((tuple(leaf) + (1,))[:3] for leaf in layer)
+                 for layer in declared)
+
+
+def first_leaf(config) -> tuple:
+    """(heads, lanes) of the first leaf of the first layer that keeps
+    any: what the decoder's layout tuple, its refusals and its log speak
+    of."""
+    return next(layer[0][:2] for layer in layer_leaves(config) if layer)
+
+
+def token_nbytes(config, kv_int8: bool = False) -> float:
+    """Bytes the pool keeps of ONE token over all layers and leaves."""
+    itemsize = jnp.dtype(config.dtype).itemsize
+    return sum(heads * ((lanes + 4) if kv_int8 else lanes * itemsize)
+               / every
+               for layer in layer_leaves(config)
+               for heads, lanes, every in layer)
+
+
+class SlotState:
+    """What a model keeps of a SLOT and not of a token (ISSUE 33): device
+    arrays [max_slots, ...], layer by layer as `config.slot_state`
+    declares them, beside the block pool.  Step, admit and extend take
+    `arrays` and hand them back rewritten; a request's first program (an
+    admit, or the chunk at offset 0) starts its slot from zeros whatever
+    the last request left there (the decoder counts those:
+    `stats["slot_states_zeroed"]`)."""
+
+    def __init__(self, config, max_slots: int):
+        self.arrays = [tuple(jnp.zeros((max_slots,) + tuple(shape), dtype)
+                             for shape, dtype in layer)
+                       for layer in config.slot_state]
+
+    def nbytes(self) -> int:
+        return int(sum(leaf.nbytes
+                       for leaf in jax.tree_util.tree_leaves(self.arrays)))
+
+
+def _state_rows(state, slots, fresh):
+    """The rows of `slots` of every state leaf; zeros where `fresh`."""
+    def rows(leaf):
+        taken = leaf[slots]
+        return jnp.where(fresh.reshape((-1,) + (1,) * (leaf.ndim - 1)),
+                         jnp.zeros_like(taken), taken)
+    return [tuple(rows(leaf) for leaf in layer) for layer in state]
+
+
+def _state_store(state, slots, valid, rows):
+    """`rows` written back to `slots` where `valid` (a row that is not
+    carries an out-of-range slot and drops)."""
+    def store(leaf, new):
+        return leaf.at[jnp.where(valid, slots, leaf.shape[0])].set(
+            new.astype(leaf.dtype), mode="drop")
+    return [tuple(store(leaf, new) for leaf, new in zip(layer, fresh))
+            for layer, fresh in zip(state, rows)]
 
 
 class BlockPool:
@@ -158,18 +252,22 @@ class BlockPool:
         # pool side.  A model with a single shared row (latent
         # attention) has no V side: v_pools is then the empty list,
         # which every program below takes and hands back as it is
-        leaves = config.cache_leaves
-        self.k_pools = self._zero_pools(n, leaves[0])
-        self.v_pools = self._zero_pools(n, leaves[1]) \
-            if len(leaves) > 1 else []
+        # A model may declare them layer by layer (layer_leaves): a
+        # layer that keeps nothing of a token has None in both lists,
+        # and a leaf may hold one row every few tokens.  The names are
+        # grouped-query attention's: k_pools is every layer's FIRST
+        # leaf and v_pools its SECOND, whatever they hold (a latent row
+        # a token and a pooled indexer key every four, ISSUE 33)
+        leaves = layer_leaves(config)
+        self.k_pools = self._zero_pools(n, leaves, 0)
+        self.v_pools = self._zero_pools(n, leaves, 1) \
+            if any(len(layer) > 1 for layer in leaves) else []
         self._refs = np.zeros((n,), np.int32)
         self._free = list(range(n - 1, 0, -1))       # 0 reserved
-        itemsize = jnp.dtype(config.dtype).itemsize
         # every leaf a layer keeps (K + V, or one latent row), all
         # layers, one block's tokens — the budget currency
-        self.block_nbytes = config.num_layers * self.block_tokens * sum(
-            heads * ((lanes + 4) if self.kv_int8 else lanes * itemsize)
-            for heads, lanes in config.cache_leaves)
+        self.block_nbytes = int(self.block_tokens *
+                                token_nbytes(config, self.kv_int8))
         from .observe.metrics import MirroredStats, default_registry
         self._registry = registry or default_registry()
         self.stats = MirroredStats(
@@ -211,16 +309,23 @@ class BlockPool:
             ledger.attach_pool(self)
 
     # -- device arrays -----------------------------------------------------
-    def _zero_pools(self, n: int, leaf: tuple) -> list:
-        config = self.config
-        heads, lanes = leaf
-        shape = (n, heads, self.block_tokens, lanes)
-        if self.kv_int8:
-            return [{"q": jnp.zeros(shape, jnp.int8),
-                     "s": jnp.zeros(shape[:3], jnp.float32)}
-                    for _ in range(config.num_layers)]
-        return [jnp.zeros(shape, config.dtype)
-                for _ in range(config.num_layers)]
+    def _zero_pools(self, n: int, leaves: tuple, side: int) -> list:
+        """Pool side `side` of every layer: None where the layer keeps
+        no such leaf."""
+        def zeros(layer):
+            if len(layer) <= side:
+                return None
+            heads, lanes, every = layer[side]
+            if self.block_tokens % every:
+                raise ValueError(
+                    f"a leaf of one row every {every} tokens needs blocks "
+                    f"of whole rows, got {self.block_tokens} tokens")
+            shape = (n, heads, self.block_tokens // every, lanes)
+            if self.kv_int8:
+                return {"q": jnp.zeros(shape, jnp.int8),
+                        "s": jnp.zeros(shape[:3], jnp.float32)}
+            return jnp.zeros(shape, self.config.dtype)
+        return [zeros(layer) for layer in leaves]
 
     def nbytes(self) -> int:
         """Bytes currently allocated to the pool device arrays — what
@@ -666,24 +771,32 @@ def _paged_write_runs(pools, tables, starts, live, sides,
     before either."""
     out = []
     for pool, side in zip(pools, sides):
+        if pool is None:               # a layer that keeps no such leaf
+            out.append(None)
+            continue
         heads, width = side.shape[1], side.shape[2]
         kv_int8 = isinstance(pool, dict)
-        if L.writes_runs_by_blocks(heads, width, block_tokens):
+        # a leaf of one row every few tokens: the run starts at the row
+        # that holds the first token, in blocks of fewer rows
+        block_rows = jax.tree_util.tree_leaves(pool)[0].shape[2]
+        first = starts if block_rows == block_tokens \
+            else starts // (block_tokens // block_rows)
+        if L.writes_runs_by_blocks(heads, width, block_rows):
             rows = L.quantize_kv_cache(side) if kv_int8 else side
-            out.append(L.write_paged_runs(pool, tables, starts, rows,
+            out.append(L.write_paged_runs(pool, tables, first, rows,
                                           live))
         else:
-            positions = starts[:, None] + jnp.arange(width)[None]
+            positions = first[:, None] + jnp.arange(width)[None]
             out.extend(_paged_scatter([pool], tables, positions,
                                       live[:, None], [side], kv_int8,
-                                      block_tokens))
+                                      block_rows))
     return out
 
 
 def run_write_form(config, width: int, block_tokens: int) -> str:
     """What a decoder logs of the choice above, for a run of `width`
     rows a slot of this model's first pool side."""
-    heads = config.cache_leaves[0][0]
+    heads = first_leaf(config)[0]
     if L.writes_runs_by_blocks(heads, width, block_tokens):
         return "%d whole blocks a slot" % L.run_blocks(width, block_tokens)
     return "%d rows a slot" % (width * heads)
@@ -721,14 +834,14 @@ def _build_paged_step(config, kernel: bool = False):
     model = config.paged_model()
     cos, sin = model.rope(config)
     attention = model.step_attention(kernel)
-    leaves = config.cache_leaves
+    leaves = layer_leaves(config)
+    stateful = bool(getattr(config, "slot_state", ()))
 
     def step(params, tokens, lengths, active, budgets, k_pools,
-             v_pools, tables, num_steps, eos, t_cap):
-        block_tokens = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[2]
+             v_pools, tables, state=(), *, num_steps, eos, t_cap):
+        block_tokens = jax.tree_util.tree_leaves(k_pools)[0].shape[2]
         pools = _pool_sides(k_pools, v_pools)
-        if kernel:
+        if kernel or reads_own_pool(config):
             views = None
             cap_tables = _table_cap(tables, block_tokens, t_cap)
         else:
@@ -740,38 +853,53 @@ def _build_paged_step(config, kernel: bool = False):
         entry_lengths = lengths
         entry_active = active
         slots_n = tokens.shape[0]
-        sides = [[jnp.zeros((slots_n, heads, num_steps, lanes),
-                            config.dtype) for _ in side]
-                 for side, (heads, lanes) in zip(pools, leaves)]
+        # one side buffer a leaf: the round's rows (a leaf of one row
+        # every few tokens closes at most that share of them)
+        sides = [[None if j >= len(layer) else jnp.zeros(
+            (slots_n, layer[j][0], -(-num_steps // layer[j][2]),
+             layer[j][1]), config.dtype) for layer in leaves]
+                 for j in range(len(pools))]
         counts = jnp.zeros((len(model.counters),), jnp.int32) \
             if model.counters else ()
 
         def body(carry, step_index):
-            tokens, lengths, active, budgets, sides, counts = carry
+            tokens, lengths, active, budgets, sides, counts, state = carry
             fresh = [[] for _ in sides]
+            after, tallies = [], []
 
             def attend(i, layer, normed):
-                attn_out, rewritten = attention(
+                held = (state[i], active) if stateful else ()
+                attn_out, rewritten, *more = attention(
                     cap_tables, layer, config, normed, cos, sin,
                     [side[i] for side in pools],
                     views and [view[i] for view in views],
                     [side[i] for side in sides], entry_lengths, lengths,
-                    step_index, entry_active)
+                    step_index, entry_active, *held)
                 for column, side in zip(fresh, rewritten):
                     column.append(side)
+                if stateful:
+                    # the model leaves the state of a slot that decodes
+                    # nothing (`active` False) as it was: the slot may be
+                    # in the middle of its prompt's chunks
+                    after.append(tuple(
+                        new.astype(old.dtype)
+                        for new, old in zip(more[0], state[i])))
+                    if more[1] is not None:
+                        tallies.append(more[1])
                 return attn_out
 
             next_tokens, counted = model.token_block_argmax(
                 params, config, tokens[:, None], attend, active)
             next_tokens = next_tokens[:, 0]
             if model.counters:
-                counts = counts + counted
+                counts = counts + sum(tallies, counted)
             next_tokens = jnp.where(active, next_tokens, tokens)
             lengths = jnp.where(active, lengths + 1, lengths)
             budgets = jnp.where(active, budgets - 1, budgets)
             still = active & (budgets > 0) & (next_tokens != eos)
             return ((next_tokens, lengths, still, budgets, fresh,
-                     counts), (next_tokens, active))
+                     counts, after if stateful else state),
+                    (next_tokens, active))
 
         # a loop that ends with the round and not a scan of num_steps:
         # the decoder runs every round through the ONE program of its
@@ -792,11 +920,12 @@ def _build_paged_step(config, kernel: bool = False):
                     (emitted.at[index].set(next_tokens),
                      emitted_active.at[index].set(was_active)))
 
-        _, (tokens, lengths, active, budgets, sides, counts), \
+        _, (tokens, lengths, active, budgets, sides, counts, state), \
             (emitted, emitted_active) = jax.lax.while_loop(
                 unfinished, iterate,
                 (jnp.int32(0),
-                 (tokens, lengths, active, budgets, sides, counts),
+                 (tokens, lengths, active, budgets, sides, counts,
+                  [tuple(layer) for layer in state] if stateful else ()),
                  (jnp.zeros((num_steps, slots_n), tokens.dtype),
                   jnp.zeros((num_steps, slots_n), bool))))
 
@@ -814,10 +943,11 @@ def _build_paged_step(config, kernel: bool = False):
         if len(merged) > 1:
             v_pools = merged[1]
         return (emitted, emitted_active, tokens, lengths,
-                k_pools, v_pools) + ((counts,) if model.counters else ())
+                k_pools, v_pools) + ((counts,) if model.counters else ()) \
+            + ((state,) if stateful else ())
 
     return jax.jit(step, static_argnames=("num_steps", "eos", "t_cap"),
-                   donate_argnames=("k_pools", "v_pools"))
+                   donate_argnames=("k_pools", "v_pools", "state"))
 
 
 @functools.lru_cache(maxsize=16)
@@ -849,8 +979,7 @@ def _build_paged_spec_step(config: LlamaConfig, k_spec: int,
 
     def spec_step(params, tokens, lengths, active, budgets, context,
                   k_pools, v_pools, tables, num_steps, eos, t_cap):
-        block_tokens = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[2]
+        block_tokens = jax.tree_util.tree_leaves(k_pools)[0].shape[2]
         if kernel:
             k_caches, v_caches = k_pools, v_pools
             attention = functools.partial(
@@ -948,16 +1077,16 @@ def _paged_admit_fn_for(config, bucket: int, width: int,
     to the block boundary as dead cells in blocks the slot owns;
     invalid (pad) rows carry out-of-range ids and drop."""
     model = config.paged_model()
+    leaves = layer_leaves(config)
+    stateful = bool(getattr(config, "slot_state", ()))
 
     def admit(params, k_pools, v_pools, tokens, lengths, context,
-              prompts, true_lens, slots, valid, tables_rows):
-        block_tokens = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[2]
-        num_total = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[0]
+              prompts, true_lens, slots, valid, tables_rows, state=()):
+        block_tokens = jax.tree_util.tree_leaves(k_pools)[0].shape[2]
+        num_total = jax.tree_util.tree_leaves(k_pools)[0].shape[0]
         pools = _pool_sides(k_pools, v_pools)
-        hidden, rows = model.prefill(params, config, prompts, valid,
-                                     true_lens)
+        hidden, rows, *after = model.prefill(params, config, prompts,
+                                             valid, true_lens)
         with jax.named_scope(SCOPE_HEAD):
             idx = jnp.maximum(true_lens - 1, 0)
             last_hidden = jnp.take_along_axis(
@@ -971,9 +1100,11 @@ def _paged_admit_fn_for(config, bucket: int, width: int,
         with jax.named_scope(SCOPE_KV_MERGE):
             for i, layer_rows in enumerate(rows):
                 if pad:
-                    spec = [(0, 0), (0, 0), (0, pad), (0, 0)]
-                    layer_rows = [jnp.pad(side_rows, spec)
-                                  for side_rows in layer_rows]
+                    layer_rows = [
+                        jnp.pad(side_rows, [(0, 0), (0, 0),
+                                            (0, pad // every), (0, 0)])
+                        for side_rows, (_, _, every)
+                        in zip(layer_rows, leaves[i])]
                 if kv_int8:
                     layer_rows = [L.quantize_kv_cache(side_rows)
                                   for side_rows in layer_rows]
@@ -988,11 +1119,15 @@ def _paged_admit_fn_for(config, bucket: int, width: int,
             context = context.at[slots, :bucket].set(
                 jnp.where(valid[:, None], prompts,
                           context[slots][:, :bucket]))
+        if stateful:
+            # an admit starts from zeros: what the slot held goes
+            return (firsts, k_pools, v_pools, tokens, lengths, context,
+                    _state_store(state, slots, valid, after[0]))
         return firsts, k_pools, v_pools, tokens, lengths, context
 
     return jax.jit(
         admit, donate_argnames=("k_pools", "v_pools", "tokens",
-                                "lengths", "context"))
+                                "lengths", "context", "state"))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1030,15 +1165,20 @@ def _paged_extend_fn_for(config, chunk_len: int,
     model = config.paged_model()
     cos, sin = model.rope(config)
     extend_layer = model.extend_layer(kernel)
+    stateful = bool(getattr(config, "slot_state", ()))
 
     def extend(params, k_pools, v_pools, tokens, lengths, context,
                chunk_tokens, offsets, slots, valid, finish,
-               final_idx, tables_rows, t_cap):
-        block_tokens = \
-            jax.tree_util.tree_leaves(k_pools[0])[0].shape[2]
+               final_idx, tables_rows, state=(), *, t_cap):
+        block_tokens = jax.tree_util.tree_leaves(k_pools)[0].shape[2]
         pools = _pool_sides(k_pools, v_pools)
         x = L.embedding(params["embed"],
                         chunk_tokens).astype(config.dtype)
+        if model.residual_in is not None:
+            x = model.residual_in(config, x)
+        if stateful:
+            # a prompt's first chunk starts its slot from zeros
+            held, after = _state_rows(state, slots, offsets == 0), []
         q_pos = offsets[:, None] + jnp.arange(chunk_len)[None, :]
         ctx = {"offsets": offsets, "q_pos": q_pos, "valid": valid,
                "finish": finish, "final_idx": final_idx,
@@ -1046,9 +1186,12 @@ def _paged_extend_fn_for(config, chunk_len: int,
                "block_tokens": block_tokens, "kv_int8": kv_int8}
         prepared = model.extend_prepare(config, chunk_len, kernel, ctx)
         for i, layer in enumerate(params["layers"]):
-            x, stores = extend_layer(
+            x, stores, *more = extend_layer(
                 layer, config, x, cos, sin,
-                [side[i] for side in pools], ctx, prepared)
+                [side[i] for side in pools], ctx, prepared,
+                *((held[i],) if stateful else ()))
+            if stateful:
+                after.append(more[0])
             with jax.named_scope(SCOPE_KV_MERGE):
                 # the chunk is a run: positions [offset, offset + chunk)
                 # of each valid row, in blocks _copy_on_write made its own
@@ -1058,7 +1201,9 @@ def _paged_extend_fn_for(config, chunk_len: int,
                 for side, leaf in zip(pools, written):
                     side[i] = leaf
         with jax.named_scope(SCOPE_HEAD):
-            x = L.rms_norm(params["ln_out"], x)
+            x = L.rms_norm(params["ln_out"], x) \
+                if model.final_norm is None \
+                else model.final_norm(params, config, x)
             last_hidden = jnp.take_along_axis(
                 x, final_idx[:, None, None], axis=1)[:, 0]
             last = L.linear_logits(params["lm_head"], last_hidden)
@@ -1077,12 +1222,15 @@ def _paged_extend_fn_for(config, chunk_len: int,
                                        offsets)
             context = context.at[slots].set(
                 jnp.where(valid[:, None], written, ctx_rows))
+        if stateful:
+            return (firsts, k_pools, v_pools, tokens, lengths, context,
+                    _state_store(state, slots, valid, after))
         return firsts, k_pools, v_pools, tokens, lengths, context
 
     return jax.jit(
         extend, static_argnames=("t_cap",),
         donate_argnames=("k_pools", "v_pools", "tokens", "lengths",
-                         "context"))
+                         "context", "state"))
 
 
 # -- grouped-query attention as a PagedModel ----------------------------------
@@ -1227,9 +1375,10 @@ def _gqa_extend_layer(kernel: bool):
     return extend_layer
 
 
-def _gqa_walks(config, kv_int8: bool, interpret: bool) -> bool:
+def _gqa_walks(config, kv_int8: bool, interpret: bool) -> str | None:
     from .ops.paged_attention import walks_live_blocks
-    return walks_live_blocks(config.head_dim, kv_int8, interpret)
+    return "kernel" if walks_live_blocks(config.head_dim, kv_int8,
+                                         interpret) else None
 
 
 GQA_PAGED_MODEL = PagedModel(

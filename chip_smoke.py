@@ -34,6 +34,15 @@ Phases of the default run:
            requests: the gather path forced, and a decoder that was told
            nothing, which on the chip must walk its latent pool; every
            served token held to the teacher-forced expanded forward
+  hybrid   ContinuousDecoder on models/hybrid_sparse.py (KDA slot state
+           beside a sparse-selected latent pool, hyper-connected streams)
+           at the published widths and a depth of two (KDA + dense MLP,
+           sparse attention + 12 of 288 experts): prompts that go in by an
+           admit and by chains of chunked extends, one of them past
+           index_topk positions, then decode; every served token held to
+           the benchmark's plain reference
+           (benchmark/reference/hybrid_sparse_lm.py, float32, its own
+           weights from the seed)
 """
 
 from __future__ import annotations
@@ -806,6 +815,93 @@ def phase_latent(shape: dict, seed: int, on_chip: bool,
     compare("unset vs gather", requests, chosen, cold)
 
 
+# -- recurrent state beside a sparse-selected latent pool ------------------------
+
+def phase_hybrid(shape: dict, seed: int, on_chip: bool,
+                 clock: CompileClock) -> None:
+    """models/hybrid_sparse.py through the same decoder: the slot state
+    zeroed at admit and carried from chunk to chunk, the KDA recurrence
+    and the gather of the chosen groups in the step, the chunked scan
+    and the masked absorbed attention in admit and extend."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu import serving
+    from benchmark import run as bench
+    from benchmark import weights_hybrid_sparse as W
+    from benchmark.reference import hybrid_sparse_lm
+
+    own = shape["hybrid"]
+    # the cell's configuration file under its published keys, cut to this
+    # phase's depth: the program's weights and the reference's are two
+    # readings of one seed (benchmark/weights_hybrid_sparse.py)
+    sizes = own["sizes"]
+    dtype = jnp.dtype(shape["llama_dtype"])
+    config = bench.load_module("drivers", sizes["driver"]).model_config(
+        sizes, own["max_seq"], dtype)
+    params = W.decoder_weights(W.key_for(seed), sizes, dtype)
+    rng = np.random.default_rng(seed)
+    requests = {
+        f"r{i}": (rng.integers(1, config.vocab, size=length).tolist(),
+                  shape["new_tokens"])
+        for i, length in enumerate(own["prompt_lengths"])}
+    require(max(own["prompt_lengths"]) > config.index_topk,
+            "no prompt reaches past index_topk positions")
+    decoder = serving.ContinuousDecoder(
+        params, config, paged_kv=True, max_slots=own["slots"],
+        max_seq=own["max_seq"], t_block=own["max_seq"],
+        prefill_buckets=own["prefill_buckets"],
+        prefill_chunk=own["prefill_chunk"],
+        prefill_budget=own["prefill_chunk"],
+        steps_per_sync=shape["steps_per_sync"], name="hybrid")
+    cold = timed_serve("first pass", decoder, requests, clock)
+    warm = timed_serve("second pass (every slot reused)", decoder,
+                       requests, clock)
+    require(cold == warm, "the same requests served twice differ: a "
+            "slot's state outlived its request")
+    stats = decoder.stats
+    require(stats["prefill_chunks"] > 0 and stats["prefills"] > 0,
+            "the prompts did not take both the admit and the extend")
+    require(stats["slot_states_zeroed"] == 2 * len(requests),
+            "a request did not start from zeroed state")
+    require(0 < stats["dsa_positions_attended"] <
+            stats["dsa_positions_live"],
+            "the sparse layer attended everything, or nothing")
+    require(decoder.pool.k_pools[0] is None and
+            decoder.pool.block_nbytes == decoder.kv_block * int(
+                (config.kv_rank + config.index_dim / config.index_pool)
+                * jnp.dtype(config.dtype).itemsize),
+            "a KDA layer holds pool blocks")
+    say(f"  prefill_chunks={stats['prefill_chunks']} rounds="
+        f"{stats['rounds']} leaves {decoder.pool.k_pools[-1].shape} "
+        f"{decoder.pool.v_pools[-1].shape} state "
+        f"{decoder.slot_state.nbytes() / 1e6:.1f} MB; attended "
+        f"{stats['dsa_positions_attended']} of "
+        f"{stats['dsa_positions_live']} live positions; pairs here "
+        f"{stats['moe_pairs_here']} of {stats['moe_pairs_routed']}")
+    # experts are chosen, and groups: as in phase_latent a token passes
+    # within 2 deviations and the MEAN is held to the cell's own limit in
+    # bfloat16; float32 against float32 leaves near-ties alone
+    routed = dtype == jnp.bfloat16
+    tolerance = 2.0 if routed else 1e-3
+    limits = sizes["correctness"]["limits"]
+    numbers = hybrid_sparse_lm.check(
+        [{"prompt": prompt, "served": cold[request_id]}
+         for request_id, (prompt, _) in requests.items()],
+        sizes, seed, str(dtype), say=lambda line: say("  " + line))["numbers"]
+    worst = max(numbers["served_token_gap_std"])
+    require(np.isfinite(worst) and worst <= tolerance,
+            f"hybrid: a served token is {worst:.3f} logit-std below the "
+            f"reference's best (tolerance {tolerance})")
+    for name, limit in limits.items():
+        require(not routed or max(numbers[name]) <= limit,
+                f"hybrid: {name} {max(numbers[name]):.4f} over the cell's "
+                f"limit {limit}")
+    say(f"  every token within {tolerance} logit-std of the plain "
+        f"reference's best (worst {worst:.4f}, mean "
+        f"{numbers['served_token_gap_mean_std'][0]:.4f})")
+
+
 # -- four chips --------------------------------------------------------------
 
 def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
@@ -877,12 +973,33 @@ def shapes(rehearse: bool) -> dict:
 
     from aiko_services_tpu.models.latent_moe import (LATENT_MOE_PRESETS,
                                                      LatentMoeConfig)
+    from benchmark import run as bench
+
+    def hybrid_sizes(max_seq: int, held: int | None) -> dict:
+        """The cell's configuration file (its `rehearse` sizes laid over
+        it for a rehearsal) cut to a KDA layer with the dense MLP and a
+        sparse-attention layer with `held` of the experts."""
+        sizes = bench.load_json("benchmark", "configs",
+                                "glm-5.3-flash-ep8-d5.json")
+        if rehearse:
+            sizes = bench.merged(sizes, sizes["rehearse"])
+        return bench.merged(sizes, {
+            "num_hidden_layers": 2,
+            "layer_types": ["linear_attention", "deepseek_sparse_attention"],
+            "mlp_layer_types": ["dense", "sparse"],
+            "n_routed_experts": held or sizes["n_routed_experts"],
+            "serving": {"max_seq": max_seq}})
     if rehearse:
         # the CPU rehearsal: same code paths, toy widths
         return {"whisper_preset": "test", "llama_preset": "tiny",
                 "latent_config": dataclasses.replace(
                     LATENT_MOE_PRESETS["tiny"], experts_first=2,
                     experts_held=4),
+                "hybrid": {
+                    "sizes": hybrid_sizes(128, None),
+                    "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
+                    "prefill_chunk": 32,
+                    "prompt_lengths": (8, 20, 44, 100)},
                 "llama_heads": 4,
                 "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
                 "prefill_buckets": (8, 32), "prefill_chunk": 32,
@@ -895,6 +1012,15 @@ def shapes(rehearse: bool) -> dict:
             # the vocabulary): 2.9 GB in bfloat16
             "latent_config": LatentMoeConfig(
                 vocab=20480, num_layers=2, experts_held=12),
+            # the published widths, a KDA layer with the dense MLP and a
+            # sparse-attention layer with 12 of the 288 experts, an
+            # eighth of the vocabulary: 1.8 GB in bfloat16; a prompt of
+            # 2,600 positions reaches past the 2,048 attended at most
+            "hybrid": {
+                "sizes": hybrid_sizes(3072, 12),
+                "max_seq": 3072, "slots": 4, "prefill_buckets": (64, 256),
+                "prefill_chunk": 256,
+                "prompt_lengths": (64, 200, 1024, 2600)},
             "llama_heads": 16,
             "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,
             "prefill_buckets": (64, 256), "prefill_chunk": 256,
@@ -946,7 +1072,8 @@ def main(argv=None) -> int:
     else:
         phases = {"kernels": phase_kernels, "speech": phase_speech,
                   "llama": functools.partial(phase_llama, clock=clock),
-                  "latent": functools.partial(phase_latent, clock=clock)}
+                  "latent": functools.partial(phase_latent, clock=clock),
+                  "hybrid": functools.partial(phase_hybrid, clock=clock)}
     for name, phase in phases.items():
         with Phase(name, clock):
             phase(shape, args.seed, on_chip)

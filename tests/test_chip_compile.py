@@ -608,3 +608,66 @@ class TestCompileCachePlacement:
         finally:
             # the suite itself runs cache-free
             jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_hybrid_step_copies_the_latent_leaf_under_a_scope_of_its_own(
+        chip, monkeypatch):
+    """The whole 5-layer `jit_step` x 4 of `long_doc_open_loop`: the gather
+    of the chosen groups wants a group's four rows together, the leaf keeps
+    rows in tiles of eight, so the compiler lays the WHOLE 1.07 GB leaf out
+    anew (once a round on the chip: PERF.md).  That operation carries
+    `aiko.dsa_relayout`, which `dsa_step_relayout_ms` reads; the PR that
+    takes the copy away turns this test round."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in (root, os.path.join(root, "benchmark", "drivers")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import hybrid_sparse_decoder
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import hybrid_sparse as M
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.3-flash-ep8-d5.json")) as f:
+        sizes = json.load(f)
+    serve = sizes["serving"]
+    config = hybrid_sparse_decoder.model_config(sizes, serve["max_seq"],
+                                                jnp.bfloat16)
+
+    def shaped(shape, kind):
+        return jax.ShapeDtypeStruct(tuple(shape), kind, sharding=chip)
+
+    params = jax.tree.map(
+        lambda leaf: shaped(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: M.hybrid_sparse_init(jax.random.PRNGKey(0),
+                                                    config)))
+    slots, block = serve["max_slots"], serve["kv_block"]
+    blocks = slots * serve["max_seq"] // block + 1
+    leaves = serving_paged.layer_leaves(config)
+    k_pools, v_pools = (
+        [shaped((blocks, layer[side][0], block // layer[side][2],
+                 layer[side][1]), jnp.bfloat16) if layer else None
+         for layer in leaves] for side in (0, 1))
+    state = [tuple(shaped((slots,) + tuple(shape), kind)
+                   for shape, kind in layer) for layer in config.slot_state]
+    table = -(-(serve["max_seq"] + serve["steps_per_sync"]) // block)
+    vector = shaped((slots,), jnp.int32)
+    compiled = serving_paged._paged_step_for(config, False).lower(
+        params, vector, vector, shaped((slots,), bool), vector, k_pools,
+        v_pools, shaped((slots, table), jnp.int32), state,
+        num_steps=serve["steps_per_sync"], eos=-1,
+        t_cap=serve["max_seq"]).compile()
+    leaf = blocks * block * config.kv_rank
+    copies = [line for line in compiled.as_text().splitlines()
+              if M.SCOPE_DSA_RELAYOUT in line and
+              re.search(r"= bf16\[(\d+),(\d+),(\d+)\]\S* (reshape|copy)\(",
+                        line)]
+    assert len(copies) == 1, copies
+    shape = re.search(r"= bf16\[(\d+),(\d+),(\d+)\]", copies[0]).groups()
+    assert math.prod(int(n) for n in shape) == leaf
+    memory = compiled.memory_analysis()
+    # 9.44 GB of weights, 1.14 GB of pool, 0.56 GB of slot state; the
+    # temporaries hold the leaf's copy (1.07 GB) beside the step's own
+    assert 11.0e9 < memory.argument_size_in_bytes < 11.3e9
+    assert 1.07e9 < memory.temp_size_in_bytes < 1.5e9
