@@ -47,6 +47,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..ops.kda_step import kda_live_step, moves_live_states
 from . import layers as L
 from .latent_moe import (MOE_COUNTERS, absorb_output, absorb_queries,
                          moe_ffn, swiglu)
@@ -59,7 +60,8 @@ __all__ = ["HybridSparseConfig", "HYBRID_SPARSE_PRESETS",
            "SCOPE_KDA_CORE", "SCOPE_DSA_INDEX", "SCOPE_DSA_RELAYOUT",
            "SCOPE_MHC"]
 
-SCOPE_KDA_CORE = "aiko.kda_core"     # conv, gates, scan or recurrence
+SCOPE_KDA_CORE = "aiko.kda_core"     # conv, gates, scan or recurrence (the
+                                     # step's: ops/kda_step.py on the chip)
 SCOPE_DSA_INDEX = "aiko.dsa_index"   # indexer projections, scores, top-k
 SCOPE_MHC = "aiko.mhc"               # mappings, Sinkhorn, mixing
 # inside aiko.attn_core in the step: the latent leaf laid out anew so that
@@ -69,9 +71,14 @@ SCOPE_DSA_RELAYOUT = "aiko.dsa_relayout"
 
 # what a decode step counts: the expert layers' four, then over the
 # sparse-attention layers and the slots that decoded the positions that
-# were live and those that were attended
+# were live and those that were attended, then over the KDA layers the
+# slot states S the token changed (the slots that decoded: what the
+# kernel moves, once in and once out) and those the layer holds (every
+# slot: what XLA's form of the recurrence passes over)
 HYBRID_COUNTERS = MOE_COUNTERS + ("dsa_positions_live",
-                                  "dsa_positions_attended")
+                                  "dsa_positions_attended",
+                                  "kda_states_moved", "kda_states_held")
+_OWN_COUNTERS = len(HYBRID_COUNTERS) - len(MOE_COUNTERS)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _KDA_CHUNK = 64        # tokens a WY block
@@ -398,11 +405,16 @@ def _kda_output(kda, config: HybridSparseConfig, out, gate, dtype):
 
 def kda_recurrent(q, k, v, g, beta, state):
     """ONE token of the gated delta rule: q, k, v, g [A, H, D], beta
-    [A, H], state [A, H, D, D] f32 -> (o [A, H, D], the new state).  As
-    written S is read once for both products and written once; the
-    program XLA makes of it for the chip moves EVERY row's state, live
-    slot or not, some three times (two fused reads and a write a layer:
-    PERF.md, where the time goes)."""
+    [A, H], state [A, H, D, D] f32 -> (o [A, H, D], the new state).  A
+    row with g = 0 and beta = 0 keeps its state.  As written S is read
+    once for both products and written once; the program XLA makes of
+    it for the chip passes over EVERY row's state, live or not, some
+    three times (two fused reads and a write).  So the decode step on a
+    TPU takes ops.kda_step.kda_live_step where the head is whole lanes
+    (`_step_attention`), which moves the live slots' state once in and
+    once out; this stays the form of every other backend and width (the
+    CPU's tests, the `tiny` preset's head of 16), an admit's or a
+    chunk's lone token, and the kernel's oracle."""
     decayed = state * jnp.exp(g)[..., None]
     seen = jnp.einsum("ahd,ahdv->ahv", k, decayed, precision=_HIGHEST)
     asked = jnp.einsum("ahd,ahdv->ahv", q, decayed, precision=_HIGHEST)
@@ -495,18 +507,21 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = _KDA_CHUNK,
     return out[:, :t], state
 
 
-def _kda_block(layer, config: HybridSparseConfig, x, state, live):
+def _kda_block(layer, config: HybridSparseConfig, x, state, live,
+               live_only: bool = False):
     """A KDA layer's token mixing over a block x [A, T, dim] (normed)
     from the slot state (S, tail): -> (out [A, T, dim], the state after
-    the block's live positions)."""
+    the block's live positions).  `live_only` (a block of one token):
+    the kernel that moves the state of the live rows and no other."""
     kda = layer["kda"]
     memory, tail = state
     with jax.named_scope(SCOPE_KDA_CORE):
         q, k, v, g, beta, gate, tail = _kda_inputs(kda, config, x, tail,
                                                    live)
         if x.shape[1] == 1:
-            out, memory = kda_recurrent(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                        beta[:, 0], memory)
+            one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], memory)
+            out, memory = kda_live_step(*one, live[:, 0]) if live_only \
+                else kda_recurrent(*one)
             out = out[:, None]
         else:
             out, memory = kda_chunked(q, k, v, g, beta, memory)
@@ -912,29 +927,41 @@ def _step_argmax(params, config: HybridSparseConfig, token_block, attend,
                                  _head_hidden(params, config, streams))
         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     moe = sum(counted, jnp.zeros((len(MOE_COUNTERS),), jnp.int32))
-    return tokens, jnp.concatenate([moe, jnp.zeros((2,), jnp.int32)])
+    return tokens, jnp.concatenate(
+        [moe, jnp.zeros((_OWN_COUNTERS,), jnp.int32)])
+
+
+def _state_kernel(config: HybridSparseConfig, interpret: bool) -> bool:
+    return moves_live_states(config.kda_heads, config.kda_head_dim,
+                             interpret)
 
 
 def _step_attention(kernel: bool):
     """A layer's token mixing in the decode step: the KDA recurrence
     over the slot's state, or the sparse layer over the pool (neither
-    builds a view; `kernel` changes nothing here)."""
+    builds a view).  `kernel` (the decoder's `step_kernel`: on a TPU,
+    the state on one device, nothing else asked for) lets a KDA layer
+    whose head is whole lanes take ops.kda_step's kernel over the slots
+    that decode; the sparse layer reads its pool the same either way."""
 
     def attend(tables, layer, config, x, cos, sin, leaves, views, sides,
                entry_lengths, lengths, step_index, entry_active, state,
                active):
+        counts = jnp.zeros((len(HYBRID_COUNTERS),), jnp.int32)
         if "kda" in layer:
             # a slot that is not live neither decays nor writes, and its
             # convolution tail stays: no pass of its own over the state
-            out, state = _kda_block(layer, config, x, state,
-                                    active[:, None])
-            return out, sides, state, None
+            out, state = _kda_block(
+                layer, config, x, state, active[:, None],
+                live_only=kernel and _state_kernel(
+                    config, jax.default_backend() != "tpu"))
+            return out, sides, state, counts.at[-2:].set(
+                jnp.stack([active.sum(), active.size]).astype(jnp.int32))
         out, sides, left, counted = _dsa_step(
             layer, config, x, cos, sin, tables, leaves, sides, state[0],
             entry_lengths, lengths, step_index, active)
-        counts = jnp.concatenate(
-            [jnp.zeros((len(MOE_COUNTERS),), jnp.int32), counted])
-        return out, sides, (left,), counts
+        first = len(MOE_COUNTERS)
+        return out, sides, (left,), counts.at[first:first + 2].set(counted)
 
     return attend
 
@@ -982,5 +1009,6 @@ def _paged_model():
         rope=rope_tables, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, counters=HYBRID_COUNTERS, supports=frozenset(),
+        walks=_walks, state_kernel=_state_kernel,
+        counters=HYBRID_COUNTERS, supports=frozenset(),
         residual_in=_streams_in, final_norm=_head_hidden)

@@ -1694,13 +1694,17 @@ class ContinuousDecoder:
             # (elsewhere it would run in the interpreter), at a pool
             # whose live blocks it walks by hand (its table body reads
             # every entry of every slot: no gain over views), with
-            # weights that sit on one device
+            # weights that sit on one device.  A model whose step runs a
+            # recurrence over slot state may have a kernel for THAT
+            # (`state_kernel`, ISSUE 34): the same rule, the same flag
             on_tpu = jax.default_backend() == "tpu"
             walks = self._model.walks(config, self.kv_int8, not on_tpu)
+            self._state_kernel = self._model.state_kernel is not None \
+                and self._model.state_kernel(config, not on_tpu)
             self.paged_kernel = ATTENTION_IMPL == "paged_kernel"
             self.step_kernel = self.paged_kernel or (
                 ATTENTION_IMPL is None and not self.speculate_k
-                and on_tpu and walks == "kernel"
+                and on_tpu and (walks == "kernel" or self._state_kernel)
                 and self._weights_on_one_device())
             # a step whose kernel walks each slot's own live blocks has
             # no width: the table goes in whole, ONE program a step
@@ -1776,6 +1780,13 @@ class ContinuousDecoder:
                     "the paged kernel's table body" if self.step_kernel
                     else "gathered views", self._attend_widths)
             self.logger.info("decode step attends through %s", how)
+            if self.slot_state is not None:
+                self.logger.info(
+                    "decode step's recurrence over slot state runs as %s",
+                    "the pallas kernel, the state of the slots that "
+                    "decode once in and once out"
+                    if self.step_kernel and self._state_kernel
+                    else "XLA's program over every slot's state")
             from .serving_paged import run_write_form
             self.logger.info(
                 "decode step writes a round's rows to the pool as %s",

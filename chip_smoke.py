@@ -219,6 +219,10 @@ def phase_kernels(shape: dict, seed: int, on_chip: bool) -> None:
     check_run_writes("latent", shape["latent_config"].cache_leaves[0],
                      shape, keys[7], on_chip)
 
+    # the KDA step's kernel over the live slots' state (PR 34) against
+    # the plain recurrence, at the hybrid phase's heads
+    check_kda_live_step(shape, keys[7], on_chip)
+
 
 def check_paged_attention(config, shape: dict, keys, on_chip: bool) -> None:
     import jax
@@ -328,6 +332,63 @@ def check_run_writes(label: str, leaf: tuple, shape: dict, key,
             require(same and changed,
                     f"write_paged_runs {label} {run} {kv} differs from "
                     f"the row scatter")
+
+
+def check_kda_live_step(shape: dict, key, on_chip: bool) -> None:
+    """ops.kda_step.kda_live_step against hybrid_sparse.kda_recurrent at
+    the hybrid phase's heads, some slots live, none, all: the live
+    slots' output and state to float32 rounding (both forms are float32
+    throughout), every other slot's state bit for bit, its output
+    zeros."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu.models import hybrid_sparse
+    from aiko_services_tpu.ops import kda_step
+
+    config = hybrid_config(shape)
+    heads, d, slots = config.kda_heads, config.kda_head_dim, shape["slots"]
+    takes = kda_step.moves_live_states(heads, d, not on_chip)
+    say(f"  kda_live_step H{heads} D{d}: a decode step takes it {takes}")
+    if not takes:                       # the rehearsal's head of 16
+        return
+    keys = jax.random.split(key, 6)
+
+    def unit(z):
+        return z / jnp.linalg.norm(z, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(keys[0], (slots, heads, d))) * d ** -0.5
+    k = unit(jax.random.normal(keys[1], (slots, heads, d)))
+    v = jax.random.normal(keys[2], (slots, heads, d))
+    g = config.gate_lower_bound * jax.nn.sigmoid(
+        jax.random.normal(keys[3], (slots, heads, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (slots, heads)))
+    state = jax.random.normal(keys[5], (slots, heads, d, d))
+    kernel = jax.jit(functools.partial(kda_step.kda_live_step,
+                                       interpret=not on_chip))
+    if on_chip:
+        require(lowered_has_kernel(kernel, q, k, v, g, beta, state,
+                                   jnp.ones((slots,), bool)),
+                "kda_live_step lowered without a tpu_custom_call")
+    for label, live in (("some", np.arange(slots) % 3 == 1),
+                        ("none", np.zeros(slots, bool)),
+                        ("all", np.ones(slots, bool))):
+        active = jnp.asarray(live)
+        out, new = kernel(q, k, v, g, beta, state, active)
+        want_out, want = jax.jit(hybrid_sparse.kda_recurrent)(
+            q, k, v, g * active[:, None, None], beta * active[:, None],
+            state)
+        worst = max(float(jnp.abs(out - want_out)[live].max(initial=0.0)),
+                    float(jnp.abs(new - want)[live].max(initial=0.0)))
+        kept = bool(np.array_equal(np.asarray(new)[~live],
+                                   np.asarray(state)[~live])) and \
+            not np.asarray(out)[~live].any()
+        say(f"  kda_live_step {label} of {slots} slots live: "
+            f"max|kernel-oracle|={worst:.2e}, the others untouched {kept}")
+        # float32 sums of 128 terms in another order, values of order one
+        require(worst <= 1e-5 and kept,
+                f"kda_live_step {label} live off by {worst}")
 
 
 # -- speech ------------------------------------------------------------------
@@ -817,6 +878,17 @@ def phase_latent(shape: dict, seed: int, on_chip: bool,
 
 # -- recurrent state beside a sparse-selected latent pool ------------------------
 
+def hybrid_config(shape: dict):
+    """The hybrid phase's model: the cell's configuration file through
+    its own driver."""
+    import jax.numpy as jnp
+
+    from benchmark import run as bench
+    own = shape["hybrid"]
+    return bench.load_module("drivers", own["sizes"]["driver"]).model_config(
+        own["sizes"], own["max_seq"], jnp.dtype(shape["llama_dtype"]))
+
+
 def phase_hybrid(shape: dict, seed: int, on_chip: bool,
                  clock: CompileClock) -> None:
     """models/hybrid_sparse.py through the same decoder: the slot state
@@ -827,7 +899,6 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
     import numpy as np
 
     from aiko_services_tpu import serving
-    from benchmark import run as bench
     from benchmark import weights_hybrid_sparse as W
     from benchmark.reference import hybrid_sparse_lm
 
@@ -837,8 +908,7 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
     # readings of one seed (benchmark/weights_hybrid_sparse.py)
     sizes = own["sizes"]
     dtype = jnp.dtype(shape["llama_dtype"])
-    config = bench.load_module("drivers", sizes["driver"]).model_config(
-        sizes, own["max_seq"], dtype)
+    config = hybrid_config(shape)
     params = W.decoder_weights(W.key_for(seed), sizes, dtype)
     rng = np.random.default_rng(seed)
     requests = {
@@ -854,6 +924,12 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
         prefill_chunk=own["prefill_chunk"],
         prefill_budget=own["prefill_chunk"],
         steps_per_sync=shape["steps_per_sync"], name="hybrid")
+    # told nothing, the step's KDA recurrence is the kernel on the chip
+    # at the published head of 128 and `kda_recurrent` in the rehearsal
+    require(decoder._walks_live and decoder.step_kernel == (
+        on_chip and config.kda_head_dim % 128 == 0),
+        f"hybrid: step_kernel {decoder.step_kernel} at a head of "
+        f"{config.kda_head_dim}, on the chip {on_chip}")
     cold = timed_serve("first pass", decoder, requests, clock)
     warm = timed_serve("second pass (every slot reused)", decoder,
                        requests, clock)
@@ -878,7 +954,9 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
         f"{decoder.slot_state.nbytes() / 1e6:.1f} MB; attended "
         f"{stats['dsa_positions_attended']} of "
         f"{stats['dsa_positions_live']} live positions; pairs here "
-        f"{stats['moe_pairs_here']} of {stats['moe_pairs_routed']}")
+        f"{stats['moe_pairs_here']} of {stats['moe_pairs_routed']}; KDA "
+        f"states moved {stats['kda_states_moved']} of "
+        f"{stats['kda_states_held']} held")
     # experts are chosen, and groups: as in phase_latent a token passes
     # within 2 deviations and the MEAN is held to the cell's own limit in
     # bfloat16; float32 against float32 leaves near-ties alone

@@ -38,6 +38,28 @@ def _lock_order_gate():
         + "\n".join(str(v) for v in violations))
 
 
+@pytest.fixture(autouse=True)
+def _no_metrics_snapshot_retained_from_another_test():
+    """A runtime built without a transport of its own speaks through the
+    process-wide default broker, and a MetricsPublisher there RETAINS a
+    snapshot of the process-wide registry: a gauge that one test left
+    high (a decoder with queued requests: serving_active_slots), a hop
+    histogram.  An Autoscaler that a later test builds on that broker
+    is handed the snapshot on subscribing and counts it as a process of
+    its fleet, so which tests shared a worker (xdist's loadfile moves
+    with every new test file) decided whether test_autoscaler and
+    test_drain_migrate's shrink tests passed.  Forget such snapshots
+    before every test."""
+    from aiko_services_tpu.observe.export import METRICS_TOPIC_SUFFIX
+    from aiko_services_tpu.transport.memory import default_broker
+    broker = default_broker()
+    with broker._lock:
+        for topic in [topic for topic in broker._retained
+                      if topic.endswith("/" + METRICS_TOPIC_SUFFIX)]:
+            del broker._retained[topic]
+    yield
+
+
 @pytest.fixture
 def engine():
     """A shared deterministic event engine (virtual clock)."""

@@ -610,15 +610,12 @@ class TestCompileCachePlacement:
             jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_hybrid_step_copies_the_latent_leaf_under_a_scope_of_its_own(
-        chip, monkeypatch):
-    """The whole 5-layer `jit_step` x 4 of `long_doc_open_loop`: the gather
-    of the chosen groups wants a group's four rows together, the leaf keeps
-    rows in tiles of eight, so the compiler lays the WHOLE 1.07 GB leaf out
-    anew (once a round on the chip: PERF.md).  That operation carries
-    `aiko.dsa_relayout`, which `dsa_step_relayout_ms` reads; the PR that
-    takes the copy away turns this test round."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+@pytest.fixture(scope="module")
+def hybrid_step(chip):
+    """The whole 5-layer `jit_step` x 4 of `long_doc_open_loop` as the
+    cell's decoder builds it on the chip (`step_kernel`: the KDA layers'
+    recurrence through ops/kda_step.py), compiled once for the tests
+    below: -> (compiled, config, the sizes' `serving`, pool blocks)."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -653,12 +650,26 @@ def test_hybrid_step_copies_the_latent_leaf_under_a_scope_of_its_own(
                    for shape, kind in layer) for layer in config.slot_state]
     table = -(-(serve["max_seq"] + serve["steps_per_sync"]) // block)
     vector = shaped((slots,), jnp.int32)
-    compiled = serving_paged._paged_step_for(config, False).lower(
-        params, vector, vector, shaped((slots,), bool), vector, k_pools,
-        v_pools, shaped((slots, table), jnp.int32), state,
-        num_steps=serve["steps_per_sync"], eos=-1,
-        t_cap=serve["max_seq"]).compile()
-    leaf = blocks * block * config.kv_rank
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = serving_paged._paged_step_for(config, True).lower(
+            params, vector, vector, shaped((slots,), bool), vector, k_pools,
+            v_pools, shaped((slots, table), jnp.int32), state,
+            num_steps=serve["steps_per_sync"], eos=-1,
+            t_cap=serve["max_seq"]).compile()
+    return compiled, config, serve, blocks
+
+
+def test_hybrid_step_copies_the_latent_leaf_under_a_scope_of_its_own(
+        hybrid_step):
+    """The gather of the chosen groups wants a group's four rows together,
+    the leaf keeps rows in tiles of eight, so the compiler lays the WHOLE
+    1.07 GB leaf out anew (once a round on the chip: PERF.md).  That
+    operation carries `aiko.dsa_relayout`, which `dsa_step_relayout_ms`
+    reads; the PR that takes the copy away turns this test round."""
+    from aiko_services_tpu.models import hybrid_sparse as M
+    compiled, config, serve, blocks = hybrid_step
+    leaf = blocks * serve["kv_block"] * config.kv_rank
     copies = [line for line in compiled.as_text().splitlines()
               if M.SCOPE_DSA_RELAYOUT in line and
               re.search(r"= bf16\[(\d+),(\d+),(\d+)\]\S* (reshape|copy)\(",
@@ -671,3 +682,32 @@ def test_hybrid_step_copies_the_latent_leaf_under_a_scope_of_its_own(
     # temporaries hold the leaf's copy (1.07 GB) beside the step's own
     assert 11.0e9 < memory.argument_size_in_bytes < 11.3e9
     assert 1.07e9 < memory.temp_size_in_bytes < 1.5e9
+
+
+def test_hybrid_step_moves_slot_state_through_the_kernel_alone(hybrid_step):
+    """The same program (PR 34): the four KDA layers' recurrence is four
+    custom calls under `aiko.kda_core`, their state argument aliased to
+    their result, and NO other computing operation makes a whole state
+    leaf `f32[32,64,128,128]`: no fusion over every slot's state, no copy
+    that a failed aliasing would put before the kernel (it would also
+    show as 134 MB a layer of temporaries: the bounds above)."""
+    from aiko_services_tpu.models import hybrid_sparse as M
+    compiled, config, serve, _ = hybrid_step
+    leaf = "f32[%d,%d,%d,%d]" % (
+        serve["max_slots"], config.kda_heads, config.kda_head_dim,
+        config.kda_head_dim)
+    made = [line.strip() for line in compiled.as_text().splitlines()
+            if re.search(r"= \(?[^=]*%s\S* (\S+)\(" % re.escape(leaf), line)]
+    kinds = [re.search(r"\S* ([a-z\-]+)\(", line.split(" = ", 1)[1]).group(1)
+             for line in made]
+    carried = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+               "custom-call", "call", "conditional"}
+    assert set(kinds) <= carried, [
+        line[:200] for line, kind in zip(made, kinds) if kind not in carried]
+    kernels = [line for line, kind in zip(made, kinds)
+               if kind == "custom-call"]
+    kda_layers = sum(kind == "kda" for kind in config.layer_types)
+    assert len(kernels) == kda_layers == 4
+    assert all(M.SCOPE_KDA_CORE in line and "tpu_custom_call" in line and
+               "output_to_operand_aliasing" in line for line in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

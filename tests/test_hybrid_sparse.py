@@ -226,9 +226,9 @@ def decoder_for(params, name, buckets=(8, 32), chunk=32, slots=4, **kwargs):
         prefill_budget=chunk, steps_per_sync=4, name=name, **kwargs)
 
 
-def serve(params, requests, name="hybrid", **kwargs):
+def serve(params, requests, name="hybrid", kernel=False, **kwargs):
     decoder = decoder_for(params, name, **kwargs)
-    assert decoder._walks_live and not decoder.step_kernel
+    assert decoder._walks_live and decoder.step_kernel is kernel
     served = {}
     for rid, (prompt, new) in requests.items():
         assert decoder.submit(rid, prompt, new, lambda rid, tokens:
@@ -307,6 +307,129 @@ def test_the_step_attends_the_chosen_groups_and_the_open_one(params):
         sum(p + 1 for p in positions)
     assert decoder.stats["dsa_positions_attended"] == \
         sum(3 * 4 + p % 4 + 1 for p in positions)
+
+
+# -- the step's recurrence: which form, and what it counts (ISSUE 34) ------------
+
+KDA_LAYERS = 3
+# a head of whole lanes, a tile of whole sublanes (ops/kda_step.py)
+WIDE = SIZES | {"linear_attn_config": SIZES["linear_attn_config"] |
+                {"head_dim": 128, "num_heads": 8}}
+
+
+def _kda_attend(kernel, sizes, backend, monkeypatch):
+    """The jaxpr of one KDA layer's token mixing in the decode step, as
+    `_step_attention(kernel)` traces it on `backend`."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    config = model_config(sizes)
+    layer = W.decoder_layer(W.key_for(SEED), 1, sizes, jnp.float32,
+                            ("kda", "sparse"))
+    state = tuple(jnp.zeros((3,) + shape, dtype)
+                  for shape, dtype in config.slot_state[1])
+    attend = M._step_attention(kernel)
+    lengths = jnp.zeros((3,), jnp.int32)
+    return jax.make_jaxpr(lambda x, state, active: attend(
+        None, layer, config, x, None, None, [], None, [], lengths, lengths,
+        0, active, state, active))(
+            jnp.ones((3, 1, 64)), state, jnp.asarray([True, False, True]))
+
+
+@pytest.mark.parametrize("kernel, sizes, backend, takes", [
+    (True, WIDE, "tpu", True),        # the cell's case, at a head of 128
+    (False, WIDE, "tpu", False),      # the decoder said no: sharded, asked
+    (True, SIZES, "tpu", False),      # the `tiny` head of 16 on a chip
+    (False, SIZES, "cpu", False),     # every CPU test
+    (True, SIZES, "cpu", True),       # asked for off the chip: interpreter
+], ids=["lanes-on-tpu", "not-chosen", "head-16-on-tpu", "cpu", "interpreter"])
+def test_the_step_takes_the_kernel_where_the_head_is_whole_lanes(
+        monkeypatch, kernel, sizes, backend, takes):
+    text = str(_kda_attend(kernel, sizes, backend, monkeypatch))
+    assert ("pallas_call" in text) is takes
+    # the plain recurrence's two products over the state, or neither
+    assert (text.count("dot_general") >= 2) or takes
+
+
+@pytest.mark.parametrize("impl, sizes, backend, kernel", [
+    (None, SIZES, "cpu", False), (None, SIZES, "tpu", False),
+    (None, WIDE, "cpu", False), (None, WIDE, "tpu", True),
+    ("two_pass", WIDE, "tpu", False), ("paged_kernel", SIZES, "cpu", True),
+], ids=["tiny-cpu", "tiny-tpu", "lanes-cpu", "lanes-tpu", "lanes-tpu-gather",
+        "tiny-cpu-asked"])
+def test_the_decoder_chooses_and_says_which_form_of_the_recurrence(
+        monkeypatch, impl, sizes, backend, kernel):
+    """Told nothing, a hybrid decoder takes the kernel on a TPU at a head
+    of whole lanes (its weights and state on one device) and
+    `kda_recurrent` everywhere else; it says which on its logger."""
+    import logging
+    monkeypatch.setattr(serving, "ATTENTION_IMPL", impl)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    heard = []
+
+    class Heard(logging.Handler):
+        def emit(self, record):
+            heard.append(record.getMessage())
+
+    name = "form-%s-%d-%s" % (impl, len(str(sizes)), backend)
+    logger = logging.getLogger(f"serving.{name}")
+    handler = Heard(logging.INFO)
+    logger.addHandler(handler)
+    try:
+        decoder = ContinuousDecoder(
+            W.decoder_weights(W.key_for(SEED), sizes, jnp.float32),
+            model_config(sizes), paged_kv=True, kv_block=8, max_slots=2,
+            max_seq=128, prefill_buckets=(8,), prefill_chunk=32, name=name)
+    finally:
+        logger.removeHandler(handler)
+    assert decoder._walks_live and decoder.step_kernel is kernel
+    said = [m for m in heard if "recurrence over slot state" in m]
+    assert len(said) == 1, heard
+    assert ("pallas kernel" in said[0]) is kernel
+    assert ("every slot" in said[0]) is not kernel
+
+
+def test_the_kernel_asked_for_serves_what_the_plain_recurrence_serves(
+        params, monkeypatch):
+    """The `tiny` decoder as every test builds it (`kda_recurrent`), and
+    with the kernel asked for (the interpreter, a head of 16): requests
+    that decode side by side and leave at different steps, a chunked
+    prompt among them.  Both within the tolerance of the reference, the
+    same counts."""
+    rng = np.random.default_rng(21)
+    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), new)
+                for n, new in ((10, 11), (45, 6), (5, 9))}
+    plain, one = serve(params, requests, name="plain-form")
+    monkeypatch.setattr(serving, "ATTENTION_IMPL", "paged_kernel")
+    asked, other = serve(params, requests, name="kernel-form", kernel=True)
+    for served in (plain, asked):
+        for rid, gap in served_gaps(requests, served).items():
+            assert gap < LOGIT_TOLERANCE, (rid, gap)
+    assert plain == asked
+    for name in M.HYBRID_COUNTERS:
+        assert one.stats[name] == other.stats[name], name
+
+
+def test_the_step_counts_the_states_it_must_move_and_those_it_holds(params):
+    """Three slots, two requests that decode 9 and 4 tokens, a step a
+    round: a KDA layer moves the state of the slots that decode in a
+    step, and holds every slot's."""
+    rng = np.random.default_rng(22)
+    decoder = ContinuousDecoder(
+        params, model_config(), paged_kv=True, kv_block=8, max_slots=3,
+        max_seq=128, prefill_buckets=(8, 32), prefill_chunk=32,
+        prefill_budget=32, steps_per_sync=1, name="states-counted")
+    done = {}
+    for rid, (n, new) in {"a": (12, 10), "b": (20, 5)}.items():
+        decoder.submit(rid, rng.integers(1, 256, size=n).tolist(), new,
+                       lambda rid, tokens: done.__setitem__(rid, tokens))
+    while len(done) < 2:
+        decoder.pump()
+    stats = decoder.stats
+    assert {"kda_states_moved", "kda_states_held"} <= set(stats)
+    # the first token of each comes from its admit: 9 + 4 slot-steps
+    assert stats["useful_steps"] == 13
+    assert stats["kda_states_moved"] == KDA_LAYERS * 13
+    assert stats["kda_states_held"] == KDA_LAYERS * 3 * stats["steps"]
+    assert 9 <= stats["steps"] <= 13
 
 
 def _state_after_prefill(params, prompt, name, **kwargs):
