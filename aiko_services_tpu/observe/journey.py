@@ -35,9 +35,20 @@
 # as separate fields, never subtracted across domains; span ordering
 # in a merged flight dump is by trace id, not by cross-domain
 # timestamp (observe/flight.py module doc).
+#
+# A journey knows the rounds that served it (ISSUE 36): beside the
+# stamps of its first and its last token it carries the `seq` of the
+# decoder's round record (observe/profiler.py, ROUND_RECORD) that
+# handed each over, so a reader joins a request to the ring by that one
+# counter and never by subtracting clocks.  Every finished journey
+# leaves those two, its id and its token count as one plain tuple
+# (JOURNEY_RECORD) in a ring of its log, which journey_log(name) hands
+# to a reader that holds no reference to the decoder, as
+# round_log(name) hands out the rounds.
 
 from __future__ import annotations
 
+import weakref
 from collections import OrderedDict, deque
 
 from .metrics import MetricsRegistry, default_registry
@@ -46,10 +57,32 @@ from .tracing import TraceContext, new_span_id, \
 
 __all__ = ["RequestJourney", "JourneyLog", "note_admission",
            "take_admission_note", "pending_admission_notes",
-           "tenant_slo_rows", "DEFAULT_TOKEN_RING"]
+           "tenant_slo_rows", "journey_log", "JOURNEY_RECORD",
+           "DEFAULT_TOKEN_RING"]
 
 DEFAULT_TOKEN_RING = 64       # per-request token timestamps retained
 _NOTE_CAP = 512               # pending admission notes (bounded)
+
+# what a finished journey leaves in its log's ring, a plain tuple in
+# this order: `first_round` and `last_round` on the profiler's `seq`
+# (-1 where the request never got there).  A field is here because a
+# reader uses it (benchmark/program_journeys.py splits the request's
+# gap between tokens over the rounds in between); the whole journey is
+# in `completed` while it is among the newest
+JOURNEY_RECORD = ("request_id", "tokens_total", "first_round", "last_round")
+RING_JOURNEYS = 8192          # a 40 s window finishes a few hundred
+
+_logs: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
+
+
+def journey_log(name: str) -> list:
+    """The finished journeys of the JourneyLog registered under `name`
+    (a decoder's name), one JOURNEY_RECORD tuple each, oldest first."""
+    log = _logs.get(name)
+    if log is None:
+        raise LookupError(f"no journey log named {name!r}; "
+                          f"there are {sorted(_logs)}")
+    return list(log.finished)
 
 # trace_id -> {"verdict", "queue_wait_s", "tenant", "tier"}; insertion
 # ordered so the bound sheds OLDEST — a note whose request died before
@@ -97,7 +130,7 @@ class RequestJourney:
                  "admission_wait_s", "slot", "waves", "token_ticks",
                  "tokens_total", "deadline", "deadline_margin_s",
                  "outcome", "prompt_tokens", "prefix_hit_tokens",
-                 "prefill_label")
+                 "prefill_label", "first_round", "last_round")
 
     def __init__(self, request_id: str, submit_t: float,
                  trace_id: str = "", parent_span_id: str = "",
@@ -137,6 +170,11 @@ class RequestJourney:
         # serving client stamps "remote" so journeys whose prompt KV
         # was computed by a prefill runtime form their own population
         self.prefill_label = ""
+        # the decoder's rounds (PhaseProfiler.seq) that handed over the
+        # first token and the last (ISSUE 36); the decoder stores each
+        # beside the stamp of the same moment, -1 until then
+        self.first_round = -1
+        self.last_round = -1
 
     def prefill(self) -> str:
         """The journey's prefill population: the explicit label when
@@ -268,7 +306,10 @@ class JourneyLog:
     process): finish() completes the journey, emits its spans, and
     mirrors the outcome into `journey_requests_total{tenant, outcome}`
     — the counter family the per-tenant SLO report reads deadline
-    attainment from."""
+    attainment from.  `completed` keeps the newest journeys whole (the
+    flight recorder's); `finished` keeps the JOURNEY_RECORD tuple of
+    each of the newest RING_JOURNEYS, which journey_log(name) hands
+    out."""
 
     def __init__(self, name: str = "journeys", maxlen: int = 256,
                  proc: str = "",
@@ -276,6 +317,8 @@ class JourneyLog:
         self.name = name
         self.proc = proc or name
         self.completed: deque = deque(maxlen=int(maxlen))
+        self.finished: deque = deque(maxlen=RING_JOURNEYS)
+        _logs[name] = self
         self._registry = registry or default_registry()
         self._counters: dict = {}
 
@@ -299,6 +342,8 @@ class JourneyLog:
                outcome: str = "") -> None:
         journey.finish(t, outcome)
         self.completed.append(journey)
+        self.finished.append((journey.request_id, journey.tokens_total,
+                              journey.first_round, journey.last_round))
         self._count(journey.tenant, journey.outcome, journey.prefill())
         journey.emit_spans(proc=self.proc)
 

@@ -86,8 +86,18 @@ ROUND_FIELDS = ("seq", "rounds", "t0", "gap_s", "idle_before", "wall_s") \
 # to edit outside a benchmark PR), so ROUND_FIELDS stays what PR 24
 # made it and a new field goes behind it: `attend_width`, the
 # positions the round's step built its views and attended at (the
-# dense cache's time extent; 0 for a round that ran no step)
-ROUND_RECORD = ROUND_FIELDS + ("attend_width",)
+# dense cache's time extent; 0 for a round that ran no step); then what
+# stood ahead of the round's step on the device and how deep its own
+# pieces read (ISSUE 36), each counted where the program is dispatched:
+# `prefill_ahead`, the prompt tokens of the admit and extend programs
+# dispatched since the step before this one was (0 for a round that ran
+# no step: the tokens wait for the next that does), `prefill_pieces`, the
+# admit and extend programs this round dispatched (a program, not a
+# row), and `prefill_prefix_tokens`, over the rows of those programs the
+# positions already in the cache that the row's attention reads (an
+# extend row's offset, 0 for a cold admit)
+ROUND_RECORD = ROUND_FIELDS + ("attend_width", "prefill_ahead",
+                               "prefill_pieces", "prefill_prefix_tokens")
 RING_ROUNDS = 8192              # over ten minutes of 100 ms rounds
 
 # a round is slow when it and the gap before it took longer than both
@@ -240,6 +250,15 @@ class PhaseProfiler:
             for phase in PHASES}
         _profilers[name] = self
 
+    @property
+    def seq(self) -> int:
+        """The `seq` that the open round's commit_round will write: the
+        one counter a request's journey is joined to the ring by.  Read
+        inside a round (the decoder does, where it hands over a token:
+        a round that hands one over is committed); between rounds it
+        is the number the next one will take."""
+        return self._seq + 1
+
     # -- the hot-path API (one perf_counter read each) ---------------------
     def begin_round(self) -> None:
         """Open the round; it begins in "plan"."""
@@ -287,7 +306,9 @@ class PhaseProfiler:
 
     def commit_round(self, rounds: int = 0, num_steps: int = 0,
                      slots: int = 0, prefill_tokens: int = 0,
-                     pending: int = 0, attend_width: int = 0) -> tuple:
+                     pending: int = 0, attend_width: int = 0,
+                     prefill_ahead: int = 0, prefill_pieces: int = 0,
+                     prefill_prefix_tokens: int = 0) -> tuple:
         """Fold the round into the sums and the ring; returns its
         record (ROUND_RECORD)."""
         now = time.perf_counter()
@@ -306,7 +327,8 @@ class PhaseProfiler:
                   0.0 if self._end is None else self._t0 - self._end,
                   self.idle, total, *staged.values(),
                   num_steps, slots, prefill_tokens, pending,
-                  attend_width)
+                  attend_width, prefill_ahead, prefill_pieces,
+                  prefill_prefix_tokens)
         self.ring.append(record)
         self._end, self.idle = now, False
         _trace_tick()

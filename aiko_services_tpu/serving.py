@@ -1905,6 +1905,14 @@ class ContinuousDecoder:
         self.itl_samples: deque = deque(maxlen=8192)
         self.gap_samples: deque = deque(maxlen=8192)
         self._round_prefill_tokens = 0
+        # beside it for the round's record (ISSUE 36): the admit and
+        # extend programs the round dispatched, the positions already
+        # in the cache that their rows' attention reads, and the prompt
+        # tokens dispatched since the last step was: they stand on the
+        # device ahead of the next one
+        self._round_prefill_pieces = 0
+        self._round_prefix_tokens = 0
+        self._prefill_ahead = 0
         # EWMA of recent working-round wall time (alpha 0.3), fed by
         # pump(): the deadline-aware admission estimate's time base
         self._round_ewma: float | None = None
@@ -2576,6 +2584,8 @@ class ContinuousDecoder:
                 wave.append((j, request))
             self.stats["prefill_chunks"] += 1
             self._round_prefill_tokens += chunk
+            self._round_prefix_tokens += offset
+        self._round_prefill_pieces += 1
         if wave:
             # the finish rows' first tokens resolve at the NEXT round's
             # sync — the extend program runs behind the decode scan
@@ -3347,6 +3357,7 @@ class ContinuousDecoder:
             if request.journey is not None:
                 request.journey.admitted(admit_t, slots[j], "admit")
             wave.append((j, request))
+        self._round_prefill_pieces += 1
         self._admit_waves.append((firsts, wave))
 
     def _finished(self, request: DecodeRequest, token: int) -> bool:
@@ -3398,7 +3409,9 @@ class ContinuousDecoder:
         if journey is not None:
             # completion closes the journey: deadline margin computed,
             # outcome counted per tenant, spans emitted under the
-            # frame's trace id (flight-dumpable)
+            # frame's trace id (flight-dumpable); the round that handed
+            # over its last token is the one that is open
+            journey.last_round = self.profiler.seq
             self.journeys.finish(journey, request.last_time
                                  or time.monotonic())
         generated = request.generated
@@ -3480,6 +3493,7 @@ class ContinuousDecoder:
             if self.idle:
                 self._drain_finish()
         self._round_prefill_tokens = 0
+        self._round_prefill_pieces = self._round_prefix_tokens = 0
         # ONE set of phase boundaries: each profiler.enter() below is a
         # sum, a field of the round's record and (while a profiler
         # session runs) a span on the device trace's clock
@@ -3496,7 +3510,7 @@ class ContinuousDecoder:
         waves_due = self._admit_waves
         self._admit_waves = []
         scanned = False
-        num_steps = scanned_slots = attend_width = attended = 0
+        num_steps = scanned_slots = attend_width = attended = ahead = 0
         model_counts = []     # what the model's step counted, if it counts
         if any_active:
             occupied = [s for s in range(self.max_slots) if active[s]]
@@ -3533,6 +3547,9 @@ class ContinuousDecoder:
                            else "scan_dispatch")
             self.stats["rounds"] += 1
             self.stats["occupancy_sum"] += float(active.mean())
+            # what was dispatched since the last step stands ahead of
+            # this one on the device's in-order stream
+            ahead, self._prefill_ahead = self._prefill_ahead, 0
             decode_start = time.perf_counter()
             eos = -1 if self.eos_token is None else int(self.eos_token)
             if self.paged:
@@ -3590,6 +3607,7 @@ class ContinuousDecoder:
         profiler.enter("extend_dispatch")
         self._advance_prefills()
         profiler.enter("host_sync")
+        self._prefill_ahead += self._round_prefill_tokens
         if self._round_prefill_tokens > \
                 self.stats["round_prefill_tokens_max"]:
             self.stats["round_prefill_tokens_max"] = \
@@ -3655,7 +3673,8 @@ class ContinuousDecoder:
             record = profiler.commit_round(
                 self.stats["rounds"], num_steps if scanned else 0,
                 scanned_slots, self._round_prefill_tokens,
-                len(self._pending), attended if scanned else 0)
+                len(self._pending), attended if scanned else 0, ahead,
+                self._round_prefill_pieces, self._round_prefix_tokens)
             # what remains of a stall in a run nobody traced
             slow = slow_round(record, self._round_ewma)
             if slow is not None:
@@ -3734,6 +3753,8 @@ class ContinuousDecoder:
             request.first_time = now
             ttft = now - request.submit_time
             self.ttft_samples.append(ttft)
+            if journey is not None:
+                journey.first_round = self.profiler.seq
             # mergeable SLO surface (ISSUE 12): the same number the
             # deque keeps, but fleet-mergeable and carrying the worst
             # requests' trace ids as exemplars.  Split the population

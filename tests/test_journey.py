@@ -463,6 +463,136 @@ class TestRequestJourney:
         assert shed and shed[0]["value"] == 1
 
 
+class TestJourneyRounds:
+    """ISSUE 36: a journey carries the `seq` of the decoder's round
+    records that served it, and every finished journey leaves a plain
+    tuple that `journey_log(name)` hands to a reader without the
+    decoder."""
+
+    PROMPT = [(i * 13) % 50 + 1 for i in range(40)]
+
+    def served(self, tiny_llama, name):
+        """A paged decoder serves three requests of different lengths,
+        the second in chunks, each submitted some rounds after the one
+        before: (decoder, {request: {seq: tokens handed over in it}},
+        {request: the last committed seq when it was submitted})."""
+        from aiko_services_tpu.observe import profiler as P
+        decoder = make_decoder(
+            tiny_llama, name, MetricsRegistry(), paged_kv=True, kv_block=8,
+            max_slots=4, prefill_buckets=(16,), prefill_chunk=16)
+        handed, submitted_after, done = {}, {}, []
+
+        def on_token(request_id, slot, token, now):
+            rounds = handed.setdefault(request_id, {})
+            seq = decoder.profiler.seq
+            rounds[seq] = rounds.get(seq, 0) + 1
+
+        decoder.on_token = on_token
+        for rid, prompt, new, pumps in (("a", self.PROMPT[:12], 11, 3),
+                                        ("b", self.PROMPT[:40], 7, 2),
+                                        ("c", self.PROMPT[:7], 4, 80)):
+            log = P.round_log(name)
+            submitted_after[rid] = log[-1][0] if log else 0
+            assert decoder.submit(rid, prompt, new,
+                                  lambda rid, toks: done.append(rid))
+            for _ in range(pumps):
+                decoder.pump()
+        assert sorted(done) == ["a", "b", "c"]
+        return decoder, handed, submitted_after
+
+    def test_round_numbers_are_in_order_and_in_the_ring(self, tiny_llama):
+        from aiko_services_tpu.observe import profiler as P
+        decoder, _, submitted_after = self.served(tiny_llama, "jrounds_a")
+        seqs = {r[0] for r in P.round_log("jrounds_a")}
+        journeys = {j.request_id: j for j in decoder.journeys.journeys()}
+        assert set(journeys) == {"a", "b", "c"}
+        for rid, j in journeys.items():
+            assert {j.first_round, j.last_round} <= seqs
+            # a round that began after the request was submitted handed
+            # over its first token, a later one its last
+            assert submitted_after[rid] < j.first_round < j.last_round
+        assert submitted_after["a"] == 0 < submitted_after["b"]
+        # the chunked prompt rode three extends before its first token
+        assert journeys["b"].waves == {"chunk-admit": 1, "extend": 3}
+        assert journeys["b"].first_round >= submitted_after["b"] + 4
+        assert journeys["a"].first_round <= submitted_after["a"] + 2
+
+    def test_the_rounds_between_first_and_last_hand_over_the_rest(
+            self, tiny_llama):
+        decoder, handed, _ = self.served(tiny_llama, "jrounds_b")
+        for j in decoder.journeys.journeys():
+            rounds = handed[j.request_id]
+            assert min(rounds) == j.first_round
+            assert max(rounds) == j.last_round > j.first_round
+            assert sum(rounds.values()) == j.tokens_total
+            # the first round hands over the owed first token and what its
+            # own step emitted; every later token falls in (first, last]
+            later = sum(count for seq, count in rounds.items()
+                        if j.first_round < seq <= j.last_round)
+            assert later == j.tokens_total - rounds[j.first_round]
+            assert 1 <= rounds[j.first_round] <= 1 + decoder.steps_per_sync
+            assert later >= 1
+
+    def test_the_tuple_carries_what_a_reader_uses(self, tiny_llama):
+        decoder, _, _ = self.served(tiny_llama, "jrounds_c")
+        records = journey.journey_log("jrounds_c")
+        assert journey.JOURNEY_RECORD == (
+            "request_id", "tokens_total", "first_round", "last_round")
+        finished = decoder.journeys.journeys()
+        assert len(records) == len(finished) == 3
+        for record, j in zip(records, finished):
+            assert type(record) is tuple
+            assert record == (j.request_id, j.tokens_total, j.first_round,
+                              j.last_round)
+            assert j.done_t == j.token_ticks[-1]          # the last token's
+
+    def test_a_request_that_never_got_there_keeps_minus_one(
+            self, tiny_llama):
+        decoder = make_decoder(tiny_llama, "jrounds_d", MetricsRegistry())
+        decoder._round_ewma = 10.0      # huge estimated wait: shed
+        assert not decoder.submit("doomed", [1], 4, lambda *_: None,
+                                  deadline=time.monotonic() + 0.001)
+        decoder._round_ewma = None
+        for index in range(3):          # two slots: the third one queues
+            assert decoder.submit(f"q{index}", [1, 2, 3], 6, lambda *_: None)
+        decoder.pump()
+        decoder.pump()
+        (evacuated,) = decoder.drain()
+        assert evacuated["request_id"] == "q2"
+        by_id = {r[0]: dict(zip(journey.JOURNEY_RECORD, r))
+                 for r in journey.journey_log("jrounds_d")}
+        outcomes = {j.request_id: j.outcome
+                    for j in decoder.journeys.journeys()}
+        assert outcomes == {"doomed": "shed", "q2": "evacuated"}
+        for rid in ("doomed", "q2"):
+            assert by_id[rid]["first_round"] == by_id[rid]["last_round"] \
+                == -1
+            assert by_id[rid]["tokens_total"] == 0
+
+    def test_journey_log_outlives_completed_and_goes_with_its_decoder(
+            self, tiny_llama):
+        import gc
+        log = journey.JourneyLog(name="jrounds_e",
+                                 registry=MetricsRegistry())
+        for index in range(300):
+            log.finish(journey.RequestJourney(f"r{index}", float(index)),
+                       float(index) + 1.0)
+        assert len(log.completed) == 256
+        records = journey.journey_log("jrounds_e")
+        assert [r[0] for r in records] == [f"r{i}" for i in range(300)]
+        assert log.finished.maxlen == journey.RING_JOURNEYS == 8192
+        del log
+        gc.collect()
+        with pytest.raises(LookupError):
+            journey.journey_log("jrounds_e")
+        decoder = make_decoder(tiny_llama, "jrounds_f", MetricsRegistry())
+        assert journey.journey_log("jrounds_f") == []
+        del decoder
+        gc.collect()
+        with pytest.raises(LookupError):
+            journey.journey_log("jrounds_f")
+
+
 # ---------------------------------------------------------------------------
 # per-tenant SLO rows: dashboard pane + slo_report script
 # ---------------------------------------------------------------------------

@@ -174,7 +174,132 @@ class TestRing:
         profiler.begin_round()
         profiler.commit_round(7, 4, 3, 64, 2, 1024)
         (record,) = P.round_log()
-        assert record[F["rounds"]] == 7 and record[-5:] == (4, 3, 64, 2, 1024)
+        # the fields behind `pending` default to 0 past the ones given
+        assert record[F["rounds"]] == 7 and \
+            record[F["num_steps"]:] == (4, 3, 64, 2, 1024, 0, 0, 0)
+
+
+class TestWhatStoodAhead:
+    """ISSUE 36: behind `attend_width` a round's record says what prefill
+    stood ahead of its step and how deep its own pieces read, and the
+    profiler hands out the number of the round that is open."""
+
+    def served(self, params, name):
+        """Three requests of different lengths, the first in chunks, each
+        submitted a few rounds after the one before: (decoder, the offsets
+        of every extend row, the programs `pump` dispatched)."""
+        decoder = paged(params, name, prefill_buckets=(16,),
+                        prefill_chunk=16)
+        offsets, programs = [], []
+        extend, admit = decoder._extend_group, decoder._admit_group
+
+        def extend_spy(chunk, width, batch):
+            offsets.extend(offset for _, _, offset, _ in batch)
+            programs.append("extend")
+            return extend(chunk, width, batch)
+
+        def admit_spy(*args):
+            programs.append("admit")
+            return admit(*args)
+
+        decoder._extend_group, decoder._admit_group = extend_spy, admit_spy
+        done = {}
+        for rid, prompt, new, pumps in (("b", PROMPT[:40], 5, 2),
+                                        ("a", PROMPT[:12], 9, 1),
+                                        ("c", PROMPT[:7], 3, 60)):
+            assert decoder.submit(rid, prompt, new,
+                                  lambda rid, t: done.update({rid: t}))
+            for _ in range(pumps):
+                decoder.pump()
+        assert set(done) == {"a", "b", "c"}
+        return decoder, offsets, programs
+
+    def test_the_record_is_pr_24s_fields_then_four(self, params):
+        assert P.ROUND_RECORD == P.ROUND_FIELDS + (
+            "attend_width", "prefill_ahead", "prefill_pieces",
+            "prefill_prefix_tokens")
+        assert len(P.ROUND_FIELDS) == 19
+        self.served(params, "ahead_a")
+        log = P.round_log("ahead_a")
+        assert log and all(len(r) == len(P.ROUND_RECORD) for r in log)
+
+    def test_prefill_ahead_is_what_was_dispatched_since_the_step_before(
+            self, params):
+        self.served(params, "ahead_b")
+        log = P.round_log("ahead_b")
+        ahead_at = P.ROUND_RECORD.index("prefill_ahead")
+        # the chunked prompt opens the ring with rounds that only prefill
+        assert log[0][F["num_steps"]] == 0 == log[1][F["num_steps"]]
+        assert log[0][F["prefill_tokens"]] == log[1][F["prefill_tokens"]] == 16
+        waiting, checked = 0, 0
+        for r in log:
+            if r[F["num_steps"]]:
+                assert r[ahead_at] == waiting
+                checked += r[ahead_at] > 0
+                waiting = 0
+            else:
+                assert r[ahead_at] == 0
+            waiting += r[F["prefill_tokens"]]
+        assert checked >= 2
+        first_step = next(r for r in log if r[F["num_steps"]])
+        assert first_step[ahead_at] >= 32       # across the rounds before it
+        assert sum(r[ahead_at] for r in log) + waiting == \
+            sum(r[F["prefill_tokens"]] for r in log) == 16 * 3 + 16 + 16
+
+    def test_pieces_are_programs_and_depth_is_the_extend_rows_offsets(
+            self, params):
+        _, offsets, programs = self.served(params, "ahead_c")
+        log = P.round_log("ahead_c")
+        pieces_at = P.ROUND_RECORD.index("prefill_pieces")
+        depth_at = P.ROUND_RECORD.index("prefill_prefix_tokens")
+        assert sorted(offsets) == [0, 16, 24]     # the last chunk slid back
+        assert sum(r[depth_at] for r in log) == sum(offsets) == 40
+        assert sum(r[pieces_at] for r in log) == len(programs) == 5
+        assert programs.count("extend") == 3
+        for r in log:
+            assert (r[pieces_at] > 0) == (r[F["prefill_tokens"]] > 0)
+            assert r[depth_at] == 0 or r[pieces_at] > 0
+
+    def test_one_admit_of_several_rows_is_one_piece(self, params):
+        decoder = paged(params, "ahead_d")
+        serve(decoder, {f"r{i}": (PROMPT[:10], 3) for i in range(4)})
+        first = P.round_log("ahead_d")[0]
+        assert first[F["prefill_tokens"]] == 4 * 16
+        assert first[P.ROUND_RECORD.index("prefill_pieces")] == 1
+
+    def test_seq_is_what_the_open_round_will_commit(self, params):
+        profiler = P.PhaseProfiler("ahead_e")
+        profiler.begin_round()
+        assert profiler.seq == 1
+        assert profiler.commit_round()[F["seq"]] == 1
+        profiler.begin_round()
+        assert profiler.seq == 2
+        profiler.abandon_round()                 # an idle tick took no number
+        profiler.begin_round()
+        assert profiler.seq == 2 == profiler.commit_round()[F["seq"]]
+        profiler.begin_round()                   # a round that raised half way
+        profiler.begin_round()                   # and the next one
+        assert profiler.seq == 3 == profiler.commit_round()[F["seq"]]
+
+    def test_the_rule_of_the_round_after_and_the_field_agree(self, params):
+        """`benchmark/program_rounds.prefill_classes` takes the round AFTER
+        a round with `prefill_tokens` over 0 as the one that pays; the
+        program now says it.  The rule implies the field; the field sees
+        more only where a round that ran no step stands between."""
+        self.served(params, "ahead_f")
+        log = P.round_log("ahead_f")
+        ahead_at = P.ROUND_RECORD.index("prefill_ahead")
+        both = differ = 0
+        for before, this in zip(log, log[1:]):
+            if not this[F["num_steps"]]:
+                continue
+            rule, field = before[F["prefill_tokens"]] > 0, this[ahead_at] > 0
+            assert field or not rule
+            both += rule and field
+            if field and not rule:
+                differ += 1
+                assert before[F["num_steps"]] == 0
+        assert both >= 2
 
 
 class TestSlowRound:
