@@ -64,26 +64,30 @@ SCOPE_KDA_CORE = "aiko.kda_core"     # conv, gates, scan or recurrence (the
                                      # step's: ops/kda_step.py on the chip)
 SCOPE_DSA_INDEX = "aiko.dsa_index"   # indexer projections, scores, top-k
 SCOPE_MHC = "aiko.mhc"               # mappings, Sinkhorn, mixing
-# inside aiko.attn_core in the step: the latent leaf laid out anew so that
-# a group's rows lie together (XLA copies the WHOLE leaf for it, once a
-# round: what a leaf laid out by groups, or a kernel, would take away)
+# inside aiko.attn_core in the step: the latent leaf seen by the whole
+# tiles its rows lie in, a bitcast and no operation.  The scope stays so
+# that what reads it (`dsa_step_relayout_ms`) reads 0.0, and a view for
+# which XLA copies the leaf (by groups it did, once a round) shows there
 SCOPE_DSA_RELAYOUT = "aiko.dsa_relayout"
 
 # what a decode step counts: the expert layers' four, then over the
 # sparse-attention layers and the slots that decoded the positions that
-# were live and those that were attended, then over the KDA layers the
-# slot states S the token changed (the slots that decoded: what the
-# kernel moves, once in and once out) and those the layer holds (every
-# slot: what XLA's form of the recurrence passes over)
-HYBRID_COUNTERS = MOE_COUNTERS + ("dsa_positions_live",
-                                  "dsa_positions_attended",
-                                  "kda_states_moved", "kda_states_held")
+# were live, those that were attended and the latent rows that the gather
+# of the chosen groups fetched for them (a whole tile a group), then over
+# the KDA layers the slot states S the token changed (the slots that
+# decoded: what the kernel moves, once in and once out) and those the
+# layer holds (every slot: what XLA's form of the recurrence passes over)
+_DSA_COUNTERS = ("dsa_positions_live", "dsa_positions_attended",
+                 "dsa_rows_fetched")
+HYBRID_COUNTERS = MOE_COUNTERS + _DSA_COUNTERS + ("kda_states_moved",
+                                                  "kda_states_held")
 _OWN_COUNTERS = len(HYBRID_COUNTERS) - len(MOE_COUNTERS)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _KDA_CHUNK = 64        # tokens a WY block
 _KDA_SUB = 16          # tokens whose decays are taken pair by pair
 _PREFIX_PIECE = 512    # positions of the prefix an extend attends at once
+_TILE_ROWS = 8         # rows of a leaf that lie together in the chip's memory
 
 
 @dataclass(frozen=True)
@@ -125,6 +129,13 @@ class HybridSparseConfig:
     dtype: object = jnp.float32
 
     rope_dim = 0                     # what latent_moe's absorption reads
+
+    def __post_init__(self):
+        if _TILE_ROWS % self.index_pool:
+            # the step fetches a chosen group by the tile that holds it
+            raise ValueError(
+                f"index_pool must divide a tile of {_TILE_ROWS} rows, got "
+                f"{self.index_pool}")
 
     @property
     def num_layers(self) -> int:
@@ -722,13 +733,14 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
     lengths[s]: index scores over the pooled keys of the slot's whole
     length, the best groups' latent rows GATHERED from the pool, one
     softmax over them, the open group's rows and this round's.  The
-    gather ATTENDS no other row, but it does not yet READ no other: it
-    wants a group's four rows together, the leaf keeps rows in tiles of
-    eight, and XLA copies the whole leaf into the other tiling once a
-    round, live rows or not (SCOPE_DSA_RELAYOUT; PERF.md, open
-    questions).  `left` [S, 128] is the key sum of the slot's open
-    group.  Returns (out, the sides rewritten, the new sum, [positions
-    live, positions attended] over the slots that decode)."""
+    leaf keeps its rows in tiles of `_TILE_ROWS`, so the gather takes
+    the whole tile that holds a chosen group from the leaf as it lies
+    (seen by tiles the leaf is the same bytes: SCOPE_DSA_RELAYOUT holds
+    no operation) and the tile's other groups are masked: it ATTENDS the
+    chosen rows alone and READS `_TILE_ROWS / index_pool` times as many.
+    `left` [S, 128] is the key sum of the slot's open group.  Returns
+    (out, the sides rewritten, the new sum, [positions live, positions
+    attended, rows fetched] over the slots that decode)."""
     pool_n, rank = config.index_pool, config.kv_rank
     latent, keys = leaves
     side_rows, side_keys = sides
@@ -777,14 +789,19 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
                         jnp.arange(side_keys.shape[2])[None, None])).any(1)
     with jax.named_scope(SCOPE_ATTN_CORE):
         per_block = block // pool_n
+        per_tile = _TILE_ROWS // pool_n
         where = jnp.take_along_axis(
             tables, jnp.clip(picked // per_block, 0, tables.shape[1] - 1),
             axis=1) * per_block + picked % per_block           # [S, K]
+        where = jnp.where(from_pool, where, 0)
         with jax.named_scope(SCOPE_DSA_RELAYOUT):
-            grouped = latent.reshape(-1, pool_n, rank)
-        chosen = jnp.take(grouped, jnp.where(from_pool, where, 0), axis=0)
-        chosen = chosen.reshape(slots_n, limit * pool_n, rank)
-        chosen_ok = jnp.repeat(from_pool, pool_n, axis=1)
+            tiles = latent.reshape(-1, _TILE_ROWS, rank)
+        # every id is a tile of the pool: no fill for one that is not
+        chosen = jnp.take(tiles, where // per_tile, axis=0, mode="clip")
+        chosen = chosen.reshape(slots_n, limit * _TILE_ROWS, rank)
+        chosen_ok = (from_pool[:, :, None] & (
+            jnp.arange(_TILE_ROWS)[None, None] // pool_n ==
+            (where % per_tile)[:, :, None])).reshape(slots_n, -1)
         # the rows of the round's first group that the pool holds
         recent_at = open_group[:, None] * pool_n + \
             jnp.arange(pool_n - 1)[None]                       # [S, p-1]
@@ -805,19 +822,32 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
             ((near_group < round_group.shape[1]) & jnp.take_along_axis(
                 round_group, jnp.clip(near_group, 0,
                                       round_group.shape[1] - 1), axis=1)))
-        rows_all = jnp.concatenate([chosen, near], axis=1)
-        ok = jnp.concatenate([chosen_ok, near_ok], axis=1)
-        s = jnp.einsum("srd,spd->srp", q_full[:, 0], rows_all,
-                       preferred_element_type=jnp.float32) * \
-            config.softmax_scale
-        s = jnp.where(ok[:, None], s, -1e30)
-        w = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("srp,spd->srd", w.astype(rows_all.dtype),
-                           rows_all, preferred_element_type=jnp.float32
-                           ).astype(x.dtype)[:, None]
+
+        # ONE softmax over the chosen rows and the near ones, scored
+        # apart (a maximum and a sum shared): joined into one array the
+        # fetched tiles would be copied once more
+        def scores(rows, ok):
+            s = jnp.einsum("srd,spd->srp", q_full[:, 0], rows,
+                           preferred_element_type=jnp.float32) * \
+                config.softmax_scale
+            return jnp.where(ok[:, None], s, -1e30)
+
+        def weighed(e, rows):
+            return jnp.einsum("srp,spd->srd", e.astype(rows.dtype), rows,
+                              preferred_element_type=jnp.float32)
+
+        s_far, s_near = scores(chosen, chosen_ok), scores(near, near_ok)
+        top = jnp.maximum(s_far.max(axis=-1), s_near.max(axis=-1))[..., None]
+        e_far, e_near = jnp.exp(s_far - top), jnp.exp(s_near - top)
+        total = e_far.sum(axis=-1) + e_near.sum(axis=-1)
+        o_lat = ((weighed(e_far, chosen) + weighed(e_near, near)) /
+                 total[..., None]).astype(x.dtype)[:, None]
         counted = jnp.stack([
             jnp.where(active, lengths + 1, 0).sum(),
-            jnp.where(active, ok.sum(axis=1), 0).sum()]).astype(jnp.int32)
+            jnp.where(active, chosen_ok.sum(axis=1) + near_ok.sum(axis=1),
+                      0).sum(),
+            jnp.where(active, from_pool.sum(axis=1) * _TILE_ROWS, 0).sum()
+        ]).astype(jnp.int32)
     with jax.named_scope(SCOPE_ATTN_PROJ):
         out = absorb_output(layer["attn"], config, o_lat, 1)
     return out, (side_rows, side_keys), left, counted
@@ -961,7 +991,8 @@ def _step_attention(kernel: bool):
             layer, config, x, cos, sin, tables, leaves, sides, state[0],
             entry_lengths, lengths, step_index, active)
         first = len(MOE_COUNTERS)
-        return out, sides, (left,), counts.at[first:first + 2].set(counted)
+        return out, sides, (left,), counts.at[
+            first:first + len(_DSA_COUNTERS)].set(counted)
 
     return attend
 
@@ -1011,4 +1042,5 @@ def _paged_model():
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
         walks=_walks, state_kernel=_state_kernel,
         counters=HYBRID_COUNTERS, supports=frozenset(),
+        block_multiple=_TILE_ROWS,
         residual_in=_streams_in, final_norm=_head_hidden)

@@ -1597,6 +1597,11 @@ class ContinuousDecoder:
         if self.paged and self.kv_block < 1:
             raise ValueError(
                 f"kv_block must be >= 1, got {kv_block}")
+        if self.paged and self.kv_block % self._model.block_multiple:
+            raise ValueError(
+                f"{type(config).__name__}: kv_block must be a multiple of "
+                f"{self._model.block_multiple} (the model reads a leaf by "
+                f"whole tiles of rows), got {self.kv_block}")
 
         # the dense cache's TIME axis is allocated at the workload, not
         # at max_seq: it grows/shrinks in t_block steps to cover the

@@ -114,6 +114,9 @@ class PagedModel:
     counters: names of the step's counts, added to decoder.stats
     supports: the serving paths this model's pool is carried through;
         the decoder refuses the others at construction
+    block_multiple: the tokens that a pool block holds a whole multiple
+        of (a model that reads a leaf by whole tiles of rows); the
+        decoder refuses another `kv_block` at construction
 
     A configuration may declare its leaves LAYER BY LAYER
     (`config.layer_cache_leaves`: per layer a tuple of (heads, lanes,
@@ -140,6 +143,7 @@ class PagedModel:
     state_kernel: object = None
     counters: tuple = ()
     supports: frozenset = frozenset()
+    block_multiple: int = 1
     residual_in: object = None
     final_norm: object = None
 

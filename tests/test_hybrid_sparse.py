@@ -289,24 +289,140 @@ def test_a_served_token_altered_is_seen(params):
     assert served_gaps(requests, served)["a"] > 100 * LOGIT_TOLERANCE
 
 
-def test_the_step_attends_the_chosen_groups_and_the_open_one(params):
-    """One request of 40 positions decoding 8 tokens, a step a round: at
-    position p the sparse layer attends 3 chosen groups and its open
-    group's p % 4 + 1 positions, of p + 1 live."""
-    prompt = np.random.default_rng(9).integers(1, 256, size=40).tolist()
+@pytest.mark.parametrize("length, new", [(40, 9), (6, 7)],
+                         ids=["three-groups-a-step", "one-then-two-groups"])
+def test_the_step_attends_the_chosen_groups_and_the_open_one(params, length,
+                                                             new):
+    """One request of two slots decoding a step a round: at position p the
+    sparse layer takes min(3, p // 4) groups from the pool, attends their
+    4 rows each and its open group's p % 4 + 1 positions, of p + 1 live,
+    and fetches a whole tile of 8 rows a group taken (ISSUE 37)."""
+    prompt = np.random.default_rng(9).integers(1, 256, size=length).tolist()
     decoder = ContinuousDecoder(
         params, model_config(), paged_kv=True, kv_block=8, max_slots=2,
         max_seq=128, prefill_buckets=(8, 32), prefill_chunk=32,
-        prefill_budget=32, steps_per_sync=1, name="counted")
+        prefill_budget=32, steps_per_sync=1, name=f"counted-{length}")
     done = []
-    decoder.submit("a", prompt, 9, lambda rid, tokens: done.append(tokens))
+    decoder.submit("a", prompt, new, lambda rid, tokens: done.append(tokens))
     while not done:
         decoder.pump()
-    positions = range(40, 48)                     # 8 steps feed 8 tokens
-    assert decoder.stats["dsa_positions_live"] == \
-        sum(p + 1 for p in positions)
-    assert decoder.stats["dsa_positions_attended"] == \
-        sum(3 * 4 + p % 4 + 1 for p in positions)
+    positions = range(length, length + new - 1)   # the admit gives the first
+    taken = [min(3, p // 4) for p in positions]
+    stats = decoder.stats
+    assert stats["dsa_positions_live"] == sum(p + 1 for p in positions)
+    assert stats["dsa_positions_attended"] == sum(
+        4 * groups + p % 4 + 1 for groups, p in zip(taken, positions))
+    assert stats["dsa_rows_fetched"] == 8 * sum(taken)
+
+
+# -- the step fetches the tile that holds a chosen group (ISSUE 37) --------------
+
+def _reference_choice(layer, h, p):
+    """The groups that the REFERENCE's rule chooses for the query at
+    position p of one sequence h [T, dim], by its own projections."""
+    _, _, q_i, k_i, weights = R.sparse_project(layer, h, jnp.int32(0),
+                                               sizes=SIZES)
+    whole = p // 4
+    pooled = k_i[:4 * whole].reshape(whole, 4, -1).mean(axis=1)
+    dots = jnp.einsum("qjd,gd->qjg", q_i[p:p + 1], pooled)
+    scores = jnp.einsum("qj,qjg->qg", weights[p:p + 1], jax.nn.relu(dots))
+    return np.asarray(R.chosen_groups(scores, p, SIZES))[0].nonzero()[0]
+
+
+def _hidden_whose_choice(layer, p, wanted):
+    """The first seeded input (whole groups, position p in the last) for
+    which the reference's choice at position p is one the case asks for."""
+    for seed in range(400):
+        h = jax.random.normal(jax.random.PRNGKey(1000 + seed),
+                              (p // 4 * 4 + 4, 64))
+        chosen = _reference_choice(layer, h, p).tolist()
+        if wanted(chosen):
+            return h, chosen
+    raise AssertionError("no input in 400 gives the case's choice")
+
+
+def _step_over_a_pool(layer, h, p, block, table):
+    """`_dsa_step` for the token at position p (the round's first step) of
+    slot 0 of two; the pool holds the rows and pooled keys of h[:p] through
+    `table`, and RANDOM rows and keys everywhere else: what a longer
+    request left in a reused block, in the other half of a tile, in the
+    rows past the slot's length.  -> (out [dim], the three counts)."""
+    config = model_config()
+    cos, sin = M.rope_tables(config)
+    _, rows, _, k_i, _ = M._dsa_project(layer, config, h[None, :p], cos, sin,
+                                        jnp.zeros((1,), jnp.int32))
+    whole, blocks = p // 4, max(table) + 2
+    keys = jax.random.split(jax.random.PRNGKey(5), 2)
+    latent = np.array(3.0 * jax.random.normal(
+        keys[0], (blocks, 1, block, config.kv_rank)))
+    pooled = np.array(3.0 * jax.random.normal(
+        keys[1], (blocks, 1, block // 4, config.index_dim)))
+    for t in range(p):
+        latent[table[t // block], 0, t % block] = rows[0, 0, t]
+    for g in range(whole):
+        pooled[table[g * 4 // block], 0, g % (block // 4)] = \
+            k_i[0, 4 * g:4 * g + 4].mean(axis=0)
+    tables = jnp.asarray([list(table) + [0] * (16 - len(table)), [0] * 16],
+                         jnp.int32)
+    lengths = jnp.asarray([p, 0], jnp.int32)
+    left = jnp.stack([k_i[0, 4 * whole:p].sum(axis=0),
+                      jnp.zeros((config.index_dim,))])
+    x = jnp.stack([h[p:p + 1], jnp.zeros((1, 64))])
+    out, _, _, counted = jax.jit(
+        lambda *args: M._dsa_step(layer, config, *args, 0,
+                                  jnp.asarray([True, False])))(
+        x, cos, sin, tables, (jnp.asarray(latent), jnp.asarray(pooled)),
+        (jnp.zeros((2, 1, 1, config.kv_rank)),
+         jnp.zeros((2, 1, 1, config.index_dim))), left, lengths, lengths)
+    return np.asarray(out[0, 0]), np.asarray(counted).tolist()
+
+
+# a tile of 8 rows holds two groups of 4: group g is the lower half of its
+# tile where g is even.  41 positions before the query: 10 complete groups
+@pytest.mark.parametrize("p, block, wanted", [
+    (41, 8, lambda c: len(c) == 3 and all(g % 2 == 0 for g in c)),
+    (41, 8, lambda c: len(c) == 3 and all(g % 2 == 1 for g in c)),
+    (41, 8, lambda c: any(g % 2 == 0 and g + 1 in c for g in c)),
+    (10, 8, lambda c: c == [0, 1]),
+    (49, 16, lambda c: 11 in c and all(g % 4 >= 2 for g in c)),
+], ids=["lower-halves", "upper-halves", "both-halves-of-a-tile",
+        "fewer-groups-than-the-limit", "a-blocks-last-tile"])
+def test_the_step_fetches_tiles_and_attends_the_chosen_halves(
+        params, p, block, wanted):
+    """The sparse layer's step against the reference's layer over the
+    same sequence, where the chosen groups lie as the case says in their
+    tiles (a block of 16 holds two tiles, groups 2, 3 of its 4 the last;
+    group 11 ends the third block of a slot of 49 positions).  What the
+    other half of a fetched tile holds moves nothing, and the counts say
+    what was fetched: a whole tile a group taken."""
+    layer = params["layers"][3]
+    h, chosen = _hidden_whose_choice(layer, p, wanted)
+    table = [5, 2, 7, 1, 4, 6][:-(-(p + 1) // block)]
+    ours, counted = _step_over_a_pool(layer, h, p, block, table)
+    with jax.default_matmul_precision("highest"):
+        theirs = np.asarray(R.sparse_attention(layer, h, SIZES)[0][p])
+    assert float(np.abs(theirs).max()) > 0.05
+    assert np.abs(ours - theirs).max() < LOGIT_TOLERANCE
+    assert counted == [p + 1, 4 * len(chosen) + p % 4 + 1, 8 * len(chosen)]
+
+
+def test_a_reused_slots_step_reads_nothing_the_longer_request_left(params):
+    """One slot: a request of 115 + 6 positions, which fills every block
+    of the pool, then one of 21 + 11 in blocks the first gave back.  The
+    tiles the second fetches hold the first's rows in their other halves
+    and past its length; its tokens are the reference's to within the
+    tolerance."""
+    rng = np.random.default_rng(31)
+    first = (rng.integers(1, 256, size=115).tolist(), 6)
+    second = (rng.integers(1, 256, size=21).tolist(), 11)
+    both, decoder = serve(params, {"a": first, "b": second},
+                          name="reused-tiles", slots=1)
+    assert decoder.stats["slot_states_zeroed"] == 2
+    leaf = np.asarray(decoder.pool.k_pools[3])
+    # whichever blocks the second took, the first had written them
+    assert (np.abs(leaf[1:]).max(axis=(1, 2, 3)) > 0.05).all()
+    for rid, gap in served_gaps({"a": first, "b": second}, both).items():
+        assert gap < LOGIT_TOLERANCE, (rid, gap)
 
 
 # -- the step's recurrence: which form, and what it counts (ISSUE 34) ------------
@@ -631,8 +747,9 @@ def test_the_other_models_declare_the_same_leaves_for_every_layer():
     (dict(weight_quant=True), "weight-only int8"),
     (dict(prefill_chunk=None), "prefill_chunk must be set"),
     (dict(prefill_chunk=24), "divide max_seq"),
+    (dict(kv_block=4), "kv_block must be a multiple of 8"),
 ], ids=["dense", "int8-kv", "speculation", "prefix-cache", "weight-quant",
-        "no-chunk", "chunk-not-dividing"])
+        "no-chunk", "chunk-not-dividing", "block-of-half-a-tile"])
 def test_paths_not_carried_refuse_at_construction(params, kwargs, named):
     kwargs = dict(paged_kv=True, kv_block=8, max_slots=2, max_seq=64,
                   prefill_chunk=32) | kwargs
@@ -640,6 +757,11 @@ def test_paths_not_carried_refuse_at_construction(params, kwargs, named):
         kwargs["prefix_cache"] = serving.PrefixKVCache(block_tokens=8)
     with pytest.raises(ValueError, match=named):
         ContinuousDecoder(params, model_config(max_seq=64), **kwargs)
+
+
+def test_groups_that_do_not_fill_a_tile_refuse_at_construction():
+    with pytest.raises(ValueError, match="index_pool must divide a tile"):
+        dataclasses.replace(model_config(), index_pool=3)
 
 
 def test_tensor_parallel_weights_refuse_at_construction(params):
