@@ -409,14 +409,27 @@ def expanded_attention(layer, config: LatentMoeConfig, x, cos, sin,
 
 # -- the expert layer --------------------------------------------------------------
 
+def router_scores(config, logits):
+    """A token's scores over ALL experts from the router's logits [N,
+    num_experts] f32: each logit's sigmoid (`scoring_func` "sigmoid":
+    DeepSeek-V3's, the default), or the softmax over all the experts
+    where the configuration says `router_scores = "softmax"` (Qwen3-MoE's
+    rule, ISSUE 38)."""
+    if getattr(config, "router_scores", "sigmoid") == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    return jax.nn.sigmoid(logits)
+
+
 def select_experts(config: LatentMoeConfig, scores, bias=None):
     """Which experts a token goes to, from its scores over ALL experts
-    [N, num_experts] f32: (ids [N, top_k], weights [N, top_k] f32).
+    [N, num_experts] f32, sigmoids or a softmax (router_scores): (ids
+    [N, top_k], weights [N, top_k] f32).
     `topk_method` "none", read as the plain rule: the top_k largest
     scores, no group restriction; the weights are the chosen scores
-    over their sum, times routed_scale.  With a correction `bias`
-    [num_experts] (`noaux_tc`) the choice is by score + bias and the
-    weights are still the scores'."""
+    over their sum, times routed_scale (a softmax's chosen
+    probabilities renormalised, `norm_topk_prob`, at scale 1).  With a
+    correction `bias` [num_experts] (`noaux_tc`) the choice is by
+    score + bias and the weights are still the scores'."""
     if bias is None:
         chosen, ids = jax.lax.top_k(scores, config.top_k)
     else:
@@ -458,10 +471,13 @@ def _expert_rows(experts, index: int, rows, limit=None):
 
 def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
     """The sparse layer's feed-forward over x [..., dim]: the shared
-    expert plus what the experts HELD HERE give the tokens routed to
-    them.  `live` [...] bool leaves tokens out of the routing (a slot
-    that decodes nothing, a prompt's padding): they cost no expert its
-    weights.  Returns (y, counts int32 [4] in MOE_COUNTERS' order)."""
+    expert, where the layer has one (`layer["shared"]`; a model of
+    routed experts alone has none, ISSUE 38), plus what the experts HELD
+    HERE give the tokens routed to them by sigmoid or softmax scores
+    (router_scores).  `live` [...] bool leaves tokens out of the routing
+    (a slot that decodes nothing, a prompt's padding): they cost no
+    expert its weights.  Returns (y, counts int32 [4] in MOE_COUNTERS'
+    order)."""
     shape = x.shape
     tokens = x.reshape(-1, shape[-1])
     n = tokens.shape[0]
@@ -473,7 +489,7 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
             "nd,de->ne", tokens.astype(jnp.float32),
             layer["router"]["w"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
-        ids, weights = select_experts(config, jax.nn.sigmoid(logits),
+        ids, weights = select_experts(config, router_scores(config, logits),
                                       layer["router"].get("bias"))
         alive = jnp.ones((n,), bool) if live is None \
             else live.reshape(-1)
@@ -489,8 +505,10 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
         if n > _EXPERT_TILE:
             place = jnp.cumsum(routed, axis=0) - 1            # [N, E]
     limit = getattr(config, "swiglu_limit", None)
-    with jax.named_scope(SCOPE_MOE_SHARED):
-        y = swiglu(layer["shared"], tokens, limit)
+    y = None
+    if "shared" in layer:
+        with jax.named_scope(SCOPE_MOE_SHARED):
+            y = swiglu(layer["shared"], tokens, limit)
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         experts = layer["experts"]
         out = jnp.zeros((n, shape[-1]), jnp.float32)
@@ -526,7 +544,8 @@ def moe_ffn(layer, config: LatentMoeConfig, x, live=None):
 
             out = jax.lax.fori_loop(
                 0, -(-loads[e] // _EXPERT_TILE), tile, out)
-        y = y + out.astype(y.dtype)
+        y = out.astype(tokens.dtype) if y is None \
+            else y + out.astype(y.dtype)
     return y.reshape(shape), counts
 
 
@@ -598,7 +617,8 @@ def _step_attention(kernel: bool):
     over gathered views (the CPU's path and the oracle)."""
 
     def attend(tables, layer, config, x, cos, sin, leaves, views, sides,
-               entry_lengths, lengths, step_index, entry_active):
+               entry_lengths, lengths, step_index, entry_active, state,
+               active):
         (side,) = sides
         attn = layer["attn"]
         with jax.named_scope(SCOPE_ATTN_PROJ):
@@ -631,7 +651,8 @@ def _step_attention(kernel: bool):
                     config, q_full, view, side, main_valid,
                     side_ok[:, None, None])
         with jax.named_scope(SCOPE_ATTN_PROJ):
-            return absorb_output(attn, config, o_lat, x.shape[1]), (side,)
+            return absorb_output(attn, config, o_lat, x.shape[1]), \
+                (side,), (), None
 
     return attend
 

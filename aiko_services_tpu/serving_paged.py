@@ -85,10 +85,14 @@ class PagedModel:
         in `counters`' order, or () where the model counts nothing
     step_attention(kernel) -> attend(tables, layer, config, x, cos, sin,
         leaves, views, sides, entry_lengths, lengths, step_index,
-        entry_active) -> (attention output, the sides rewritten):
-        `leaves` are the layer's pool leaves, `views` their gathered
-        slot-major views (None for the kernel), `sides` this round's
-        side buffers, one a pool side
+        entry_active, state, active) -> (attention output, the sides
+        rewritten, the layer's slot state after the token, counts in
+        `counters`' order or None): `leaves` are the layer's pool
+        leaves, `views` their gathered slot-major views (None for the
+        kernel), `sides` this round's side buffers, one a pool side,
+        `state` the layer's slot state (() where the model keeps none,
+        and () comes back), `active` [S] the slots that decode this
+        token
     prefill(params, config, prompts, valid, true_lens) -> (hidden after
         the last norm [A, T, dim], per layer the rows [A, heads, T,
         lanes] of each pool side): an admit's compute over prompts
@@ -123,16 +127,19 @@ class PagedModel:
     tokens a row), possibly empty; see layer_leaves) and per-slot STATE
     (`config.slot_state`: per layer a tuple of (shape, dtype); see
     SlotState).  A model with slot state takes and returns it (ISSUE 33):
-        step_attention's attend(..., entry_active, state, active) ->
-            (output, sides, the layer's state after the token, counts
-            in `counters`' order or None); where `active` [S] is False
-            the slot decodes nothing and its state comes back unchanged
+        step_attention's attend: where `active` [S] is False the slot
+            decodes nothing and its state comes back unchanged
         prefill -> (hidden, rows, per layer the state after each row's
             true length)
         extend_layer's layer(..., prepared, state) -> (x, rows, state)
     residual_in(config, x), final_norm(params, config, x): what an
         extend does to the embedding before the first layer and instead
-        of the last norm, where the residual is not one stream"""
+        of the last norm, where the residual is not one stream
+
+    A layer may keep MORE than two leaves of a token (ISSUE 38: K, V and
+    an indexer key): `sides` and `leaves` then have one entry a leaf, and
+    the pool's `v_pools` holds every leaf after the first, leaf after
+    leaf (see BlockPool, _pool_sides)."""
     rope: object
     token_block_argmax: object
     step_attention: object
@@ -267,11 +274,15 @@ class BlockPool:
         # and a leaf may hold one row every few tokens.  The names are
         # grouped-query attention's: k_pools is every layer's FIRST
         # leaf and v_pools its SECOND, whatever they hold (a latent row
-        # a token and a pooled indexer key every four, ISSUE 33)
+        # a token and a pooled indexer key every four, ISSUE 33), and
+        # after every layer's second every layer's THIRD (an indexer
+        # key beside K and V, ISSUE 38): num_layers entries a leaf,
+        # which the programs split again (_pool_sides)
         leaves = layer_leaves(config)
         self.k_pools = self._zero_pools(n, leaves, 0)
-        self.v_pools = self._zero_pools(n, leaves, 1) \
-            if any(len(layer) > 1 for layer in leaves) else []
+        self.v_pools = [pool for side in range(
+            1, max(len(layer) for layer in leaves))
+            for pool in self._zero_pools(n, leaves, side)]
         self._refs = np.zeros((n,), np.int32)
         self._free = list(range(n - 1, 0, -1))       # 0 reserved
         # every leaf a layer keeps (K + V, or one latent row), all
@@ -742,10 +753,17 @@ def _kernel_attention_spec(tables, layer, config: LlamaConfig, x, cos,
 
 
 def _pool_sides(k_pools, v_pools) -> list:
-    """The pool sides a model keeps, as the programs below walk them: K
-    and V, or K alone where a layer keeps one latent row (v_pools is
-    then the empty list, handed through as it is)."""
-    return [side for side in (k_pools, v_pools) if side]
+    """The pool sides a model keeps, as the programs below walk them,
+    each a list by layer: K and V, or K alone where a layer keeps one
+    latent row (v_pools is then the empty list), or K, V and a third
+    where v_pools holds two leaves a layer, one leaf after the other."""
+    n = len(k_pools)
+    return [k_pools] + [v_pools[i:i + n] for i in range(0, len(v_pools), n)]
+
+
+def _join_sides(pools: list) -> tuple:
+    """(k_pools, v_pools) of the sides again, as the pool holds them."""
+    return pools[0], [leaf for side in pools[1:] for leaf in side]
 
 
 def _paged_scatter(pools, tables, positions, live, sides, kv_int8,
@@ -878,13 +896,13 @@ def _build_paged_step(config, kernel: bool = False):
             after, tallies = [], []
 
             def attend(i, layer, normed):
-                held = (state[i], active) if stateful else ()
-                attn_out, rewritten, *more = attention(
+                attn_out, rewritten, left, tally = attention(
                     cap_tables, layer, config, normed, cos, sin,
                     [side[i] for side in pools],
                     views and [view[i] for view in views],
                     [side[i] for side in sides], entry_lengths, lengths,
-                    step_index, entry_active, *held)
+                    step_index, entry_active,
+                    state[i] if stateful else (), active)
                 for column, side in zip(fresh, rewritten):
                     column.append(side)
                 if stateful:
@@ -893,9 +911,9 @@ def _build_paged_step(config, kernel: bool = False):
                     # in the middle of its prompt's chunks
                     after.append(tuple(
                         new.astype(old.dtype)
-                        for new, old in zip(more[0], state[i])))
-                    if more[1] is not None:
-                        tallies.append(more[1])
+                        for new, old in zip(left, state[i])))
+                if tally is not None:
+                    tallies.append(tally)
                 return attn_out
 
             next_tokens, counted = model.token_block_argmax(
@@ -949,9 +967,7 @@ def _build_paged_step(config, kernel: bool = False):
             merged = [_paged_write_runs(side, tables, entry_lengths,
                                         entry_active, rows, block_tokens)
                       for side, rows in zip(pools, sides)]
-        k_pools = merged[0]
-        if len(merged) > 1:
-            v_pools = merged[1]
+        k_pools, v_pools = _join_sides(merged)
         return (emitted, emitted_active, tokens, lengths,
                 k_pools, v_pools) + ((counts,) if model.counters else ()) \
             + ((state,) if stateful else ())
@@ -1121,6 +1137,7 @@ def _paged_admit_fn_for(config, bucket: int, width: int,
                 for side, side_rows in zip(pools, layer_rows):
                     side[i] = L.write_paged_blocks(side[i], dest,
                                                    side_rows)
+        k_pools, v_pools = _join_sides(pools)
         tokens = tokens.at[slots].set(
             jnp.where(valid, firsts, tokens[slots]))
         lengths = lengths.at[slots].set(
@@ -1210,6 +1227,7 @@ def _paged_extend_fn_for(config, chunk_len: int,
                     valid, stores, block_tokens)
                 for side, leaf in zip(pools, written):
                     side[i] = leaf
+        k_pools, v_pools = _join_sides(pools)
         with jax.named_scope(SCOPE_HEAD):
             x = L.rms_norm(params["ln_out"], x) \
                 if model.final_norm is None \
@@ -1263,7 +1281,8 @@ def _gqa_step_attention(kernel: bool):
     from .serving import _slot_attention_block
 
     def attend(tables, layer, config, x, cos, sin, leaves, views, sides,
-               entry_lengths, lengths, step_index, entry_active):
+               entry_lengths, lengths, step_index, entry_active, state,
+               active):
         if kernel:
             attn_out, k_side, v_side = _kernel_attention_block(
                 tables, layer, config, x, cos, sin, leaves[0], leaves[1],
@@ -1273,7 +1292,7 @@ def _gqa_step_attention(kernel: bool):
             attn_out, k_side, v_side = _slot_attention_block(
                 layer, config, x, cos, sin, views[0], views[1],
                 sides[0], sides[1], entry_lengths, lengths, step_index)
-        return attn_out, (k_side, v_side)
+        return attn_out, (k_side, v_side), (), None
 
     return attend
 

@@ -43,6 +43,14 @@ Phases of the default run:
            the benchmark's plain reference
            (benchmark/reference/hybrid_sparse_lm.py, float32, its own
            weights from the seed)
+  sparse_gqa  ContinuousDecoder on models/sparse_gqa.py (K, V and an
+           indexer key a token, the exact top 2,048 positions chosen a
+           query, softmax-routed experts and no shared one) at the
+           published widths and a depth of two, 16 of 128 experts held:
+           prompts on both sides of 2,048 positions, admit, chunked
+           extends and decode, every slot served twice; prints the
+           selection's and the experts' counters; every served token held
+           to benchmark/reference/sparse_gqa_lm.py
 """
 
 from __future__ import annotations
@@ -980,6 +988,99 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
         f"{numbers['served_token_gap_mean_std'][0]:.4f})")
 
 
+# -- a third leaf beside K and V, keys chosen token by token --------------------
+
+def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
+                     clock: CompileClock) -> None:
+    """models/sparse_gqa.py through the same decoder: K, V and an indexer
+    key a token in three pool leaves, every live position scored, the
+    exact top of them chosen and those single rows gathered in the step;
+    a chunk's queries each choosing their own positions of the prefix."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu import serving
+    from benchmark import run as bench
+    from benchmark import weights_sparse_gqa as W
+    from benchmark.reference import sparse_gqa_lm
+
+    own = shape["sparse_gqa"]
+    sizes = own["sizes"]
+    dtype = jnp.dtype(shape["llama_dtype"])
+    config = bench.load_module("drivers", sizes["driver"]).model_config(
+        sizes, own["max_seq"], dtype)
+    params = W.decoder_weights(W.key_for(seed), sizes, dtype)
+    rng = np.random.default_rng(seed)
+    requests = {
+        f"r{i}": (rng.integers(1, config.vocab, size=length).tolist(),
+                  shape["new_tokens"])
+        for i, length in enumerate(own["prompt_lengths"])}
+    require(min(own["prompt_lengths"]) + shape["new_tokens"] <
+            config.index_topk < max(own["prompt_lengths"]),
+            "the prompts do not lie on both sides of topk")
+    decoder = serving.ContinuousDecoder(
+        params, config, paged_kv=True, max_slots=own["slots"],
+        max_seq=own["max_seq"], t_block=own["max_seq"],
+        prefill_buckets=own["prefill_buckets"],
+        prefill_chunk=own["prefill_chunk"],
+        prefill_budget=own["prefill_chunk"],
+        steps_per_sync=shape["steps_per_sync"], name="sparse_gqa")
+    require(decoder._walks_live and not decoder.step_kernel,
+            "sparse_gqa: the model reads its pool itself, kernel or not")
+    cold = timed_serve("first pass", decoder, requests, clock)
+    warm = timed_serve("second pass (every slot reused)", decoder,
+                       requests, clock)
+    require(cold == warm, "the same requests served twice differ")
+    stats, pool = decoder.stats, decoder.pool
+    require(stats["prefill_chunks"] > 0 and stats["prefills"] > 0,
+            "the prompts did not take both the admit and the extend")
+    require(0 < stats["dsa_positions_attended"] <
+            stats["dsa_positions_live"],
+            "the selection attended everything, or nothing")
+    require(0 < stats["dsa_slot_steps_dense"] and
+            stats["dsa_rows_fetched"] <= stats["dsa_positions_attended"],
+            "no slot-step attended all it held, or rows were fetched that "
+            "nobody attended")
+    layers = config.num_layers
+    require(len(pool.k_pools) == layers and len(pool.v_pools) == 2 * layers
+            and pool.block_nbytes == decoder.kv_block * layers * int(
+                (2 * config.num_kv_heads * config.head_dim +
+                 config.index_row_lanes) * jnp.dtype(config.dtype).itemsize),
+            "the pool does not hold K, V and an indexer key a layer")
+    say(f"  prefill_chunks={stats['prefill_chunks']} rounds="
+        f"{stats['rounds']} leaves {pool.k_pools[-1].shape} "
+        f"{pool.v_pools[layers - 1].shape} {pool.v_pools[-1].shape}; "
+        f"attended {stats['dsa_positions_attended']} of "
+        f"{stats['dsa_positions_live']} live positions, "
+        f"{stats['dsa_rows_fetched']} fetched from the pool, "
+        f"{stats['dsa_slot_steps_dense']} slot-steps attended all; pairs "
+        f"here {stats['moe_pairs_here']} of {stats['moe_pairs_routed']}, "
+        f"experts hit {stats['moe_experts_hit']} over "
+        f"{stats['moe_layer_steps']} layer-steps")
+    # as in phase_hybrid: a token passes within 2 deviations and the MEAN
+    # is held in bfloat16, here to TEN times the cell's own limit: the
+    # limit is for a run's several hundred served tokens, and of this
+    # phase's hundred one token a near-tie apart moves the mean by a
+    # hundredth of its gap
+    routed = dtype == jnp.bfloat16
+    tolerance = 2.0 if routed else 1e-3
+    numbers = sparse_gqa_lm.check(
+        [{"prompt": prompt, "served": cold[request_id]}
+         for request_id, (prompt, _) in requests.items()],
+        sizes, seed, str(dtype), say=lambda line: say("  " + line))["numbers"]
+    worst = max(numbers["served_token_gap_std"])
+    require(np.isfinite(worst) and worst <= tolerance,
+            f"sparse_gqa: a served token is {worst:.3f} logit-std below "
+            f"the reference's best (tolerance {tolerance})")
+    for name, limit in sizes["correctness"]["limits"].items():
+        require(not routed or max(numbers[name]) <= 10 * limit,
+                f"sparse_gqa: {name} {max(numbers[name]):.5f} over ten "
+                f"times the cell's limit {limit}")
+    say(f"  every token within {tolerance} logit-std of the plain "
+        f"reference's best (worst {worst:.4f}, mean "
+        f"{numbers['served_token_gap_mean_std'][0]:.5f})")
+
+
 # -- four chips --------------------------------------------------------------
 
 def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
@@ -1067,6 +1168,16 @@ def shapes(rehearse: bool) -> dict:
             "mlp_layer_types": ["dense", "sparse"],
             "n_routed_experts": held or sizes["n_routed_experts"],
             "serving": {"max_seq": max_seq}})
+
+    def sparse_gqa_sizes(max_seq: int) -> dict:
+        """The newest cell's configuration file (its `rehearse` sizes
+        laid over it for a rehearsal) cut to two layers."""
+        sizes = bench.load_json("benchmark", "configs",
+                                "keye-vl-2.0-30b-a3b-ep8-d12.json")
+        if rehearse:
+            sizes = bench.merged(sizes, sizes["rehearse"])
+        return bench.merged(sizes, {"num_hidden_layers": 2,
+                                    "serving": {"max_seq": max_seq}})
     if rehearse:
         # the CPU rehearsal: same code paths, toy widths
         return {"whisper_preset": "test", "llama_preset": "tiny",
@@ -1078,6 +1189,11 @@ def shapes(rehearse: bool) -> dict:
                     "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
                     "prefill_chunk": 32,
                     "prompt_lengths": (8, 20, 44, 100)},
+                "sparse_gqa": {
+                    "sizes": sparse_gqa_sizes(128),
+                    "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
+                    "prefill_chunk": 32,
+                    "prompt_lengths": (5, 20, 44, 100)},
                 "llama_heads": 4,
                 "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
                 "prefill_buckets": (8, 32), "prefill_chunk": 32,
@@ -1096,6 +1212,14 @@ def shapes(rehearse: bool) -> dict:
             # 2,600 positions reaches past the 2,048 attended at most
             "hybrid": {
                 "sizes": hybrid_sizes(3072, 12),
+                "max_seq": 3072, "slots": 4, "prefill_buckets": (64, 256),
+                "prefill_chunk": 256,
+                "prompt_lengths": (64, 200, 1024, 2600)},
+            # the published widths, two layers with 16 of the 128 experts,
+            # an eighth of the vocabulary: 0.5 GB in bfloat16; prompts on
+            # both sides of the 2,048 positions attended at most
+            "sparse_gqa": {
+                "sizes": sparse_gqa_sizes(3072),
                 "max_seq": 3072, "slots": 4, "prefill_buckets": (64, 256),
                 "prefill_chunk": 256,
                 "prompt_lengths": (64, 200, 1024, 2600)},
@@ -1151,7 +1275,9 @@ def main(argv=None) -> int:
         phases = {"kernels": phase_kernels, "speech": phase_speech,
                   "llama": functools.partial(phase_llama, clock=clock),
                   "latent": functools.partial(phase_latent, clock=clock),
-                  "hybrid": functools.partial(phase_hybrid, clock=clock)}
+                  "hybrid": functools.partial(phase_hybrid, clock=clock),
+                  "sparse_gqa": functools.partial(phase_sparse_gqa,
+                                                  clock=clock)}
     for name, phase in phases.items():
         with Phase(name, clock):
             phase(shape, args.seed, on_chip)

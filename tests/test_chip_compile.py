@@ -736,3 +736,114 @@ def test_hybrid_step_moves_slot_state_through_the_kernel_alone(hybrid_step):
     assert all(M.SCOPE_KDA_CORE in line and "tpu_custom_call" in line and
                "output_to_operand_aliasing" in line for line in kernels)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# -- three leaves a layer, keys chosen token by token (ISSUE 38) -----------------
+
+def _sparse_gqa_cell(chip):
+    """keye-vl-2.0-30b-a3b-ep8-d12 at the cell's sizes, as shapes on the
+    described chip: -> (config, serving, params, k_pools, v_pools, shaped)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in (root, os.path.join(root, "benchmark", "drivers")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import sparse_gqa_decoder
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import sparse_gqa as M
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye-vl-2.0-30b-a3b-ep8-d12.json")) as f:
+        sizes = json.load(f)
+    serve = sizes["serving"]
+    config = sparse_gqa_decoder.model_config(sizes, serve["max_seq"],
+                                             jnp.bfloat16)
+
+    def shaped(shape, kind):
+        return jax.ShapeDtypeStruct(tuple(shape), kind, sharding=chip)
+
+    params = jax.tree.map(
+        lambda leaf: shaped(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: M.sparse_gqa_init(jax.random.PRNGKey(0),
+                                                 config)))
+    block = serve["kv_block"]
+    blocks = serve["max_slots"] * serve["max_seq"] // block + 1
+    sides = [[shaped((blocks, heads, block, lanes), jnp.bfloat16)
+              for heads, lanes, _ in (layer[side] for layer in
+                                      serving_paged.layer_leaves(config))]
+             for side in range(3)]
+    return config, serve, params, sides[0], sides[1] + sides[2], shaped
+
+
+def _leaf_shapes(config, serve):
+    blocks = serve["max_slots"] * serve["max_seq"] // serve["kv_block"] + 1
+    return [(blocks, heads, serve["kv_block"], lanes)
+            for heads, lanes in config.cache_leaves]
+
+
+def test_sparse_gqa_step_selects_exactly_and_copies_no_leaf(chip,
+                                                            monkeypatch):
+    """The whole 12-layer `jit_step` x 4 of `long_ctx_open_loop`: it fits
+    the chip beside its pool; the top 2,048 are a SORT a layer (exact:
+    no approximate top-k), apart from the scores; the chosen rows are
+    gathered from the K and V leaves seen as rows, no leaf is copied, and
+    the three leaves of every layer are merged in place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from aiko_services_tpu import serving_paged
+    config, serve, params, k_pools, v_pools, shaped = _sparse_gqa_cell(chip)
+    slots = serve["max_slots"]
+    table = -(-(serve["max_seq"] + serve["steps_per_sync"])
+              // serve["kv_block"])
+    vector = shaped((slots,), jnp.int32)
+    compiled = serving_paged._paged_step_for(config, True).lower(
+        params, vector, vector, shaped((slots,), bool), vector, k_pools,
+        v_pools, shaped((slots, table), jnp.int32),
+        num_steps=serve["steps_per_sync"], eos=-1,
+        t_cap=serve["max_seq"]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "ApproxTopK" not in text
+    for leaf in _leaf_shapes(config, serve):
+        result = re.escape("[" + ",".join(map(str, leaf)) + "]")
+        assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
+    # a sort a layer for the positions, one for the router's eight, and
+    # ONE for the order in which the slots that decode are taken (every
+    # layer's is the same: the compiler keeps one)
+    assert len(re.findall(r" sort\(", text)) == 2 * config.num_layers + 1
+    assert len(re.findall(r"aiko\.dsa_select/[\w()]*top_k", text)) > 0
+    merges = re.findall(r"fusion\([^\n]*aiko\.kv_merge/scatter", text)
+    assert len(merges) == 3 * config.num_layers
+    memory = compiled.memory_analysis()
+    # 2.48 GB of weights + the pool, the pool aliased in and out; the
+    # indexer keys of every layer gathered once a round are temporaries
+    pool = sum(math.prod(leaf) * 2 for leaf in _leaf_shapes(config, serve)) \
+        * config.num_layers
+    assert memory.alias_size_in_bytes >= pool
+    assert 2.4e9 < memory.argument_size_in_bytes - pool < 2.6e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16.4e9
+
+
+def test_sparse_gqa_extend_chooses_without_a_sort_and_copies_no_leaf(chip):
+    """A 512-token chunk against a prefix of up to 32k: every query's
+    2,048 positions come from a threshold found bit by bit (the only sort
+    is the router's), the prefix is read piece by piece, the chunk's rows
+    of the three leaves are scattered in place."""
+    from aiko_services_tpu import serving_paged
+    config, serve, params, k_pools, v_pools, shaped = _sparse_gqa_cell(chip)
+    slots, chunk = serve["max_slots"], serve["prefill_chunk"]
+    table = -(-(serve["max_seq"] + serve["steps_per_sync"])
+              // serve["kv_block"])
+    vector, one = shaped((slots,), jnp.int32), shaped((1,), jnp.int32)
+    compiled = serving_paged._paged_extend_fn_for(
+        config, chunk, 1, False, False, False).lower(
+        params, k_pools, v_pools, vector, vector, shaped((slots, 1),
+                                                         jnp.int32),
+        shaped((1, chunk), jnp.int32), one, one, shaped((1,), bool),
+        shaped((1,), bool), one, shaped((1, table), jnp.int32),
+        t_cap=serve["max_seq"]).compile()
+    text = compiled.as_text()
+    for leaf in _leaf_shapes(config, serve):
+        result = re.escape("[" + ",".join(map(str, leaf)) + "]")
+        assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
+    assert len(re.findall(r" sort\(", text)) == config.num_layers
+    assert "aiko.dsa_select" in text and "aiko.dsa_index" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9

@@ -1,0 +1,554 @@
+# The sparse grouped-query decoder (ISSUE 38: K, V and an indexer key a token
+# in three pool leaves, the exact top `topk` positions chosen a query by a
+# lightning indexer, softmax-routed held experts and no shared expert) at a
+# small size on the CPU in float32: the model against the benchmark's plain
+# reference (benchmark/reference/sparse_gqa_lm.py: sectioned rotary, its own
+# sort-based top-k, one masked softmax, experts as a loop, precision
+# "highest"), prefill through admit and chunked extend then decode through
+# the pool on both sides of `topk` and across it, the selection's ties, the
+# rotary's two forms, the eight-way share, the router's plain columns,
+# the parameter count, the pool's geometry, and the serving paths that
+# refuse.
+#
+# Comparisons are of LOGITS or masks, never of sampled tokens.  Each
+# tolerance states its reason.
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for path in (ROOT, os.path.join(ROOT, "benchmark", "drivers")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+import aiko_services_tpu.serving as serving  # noqa: E402
+from aiko_services_tpu import serving_paged  # noqa: E402
+from aiko_services_tpu.models import latent_moe  # noqa: E402
+from aiko_services_tpu.models import sparse_gqa as M  # noqa: E402
+from aiko_services_tpu.serving import ContinuousDecoder  # noqa: E402
+from aiko_services_tpu.serving_paged import BlockPool  # noqa: E402
+from benchmark import ops_bytes_sparse_gqa as ops  # noqa: E402
+from benchmark import weights_sparse_gqa as W  # noqa: E402
+from benchmark.reference import sparse_gqa_lm as R  # noqa: E402
+
+SEED = 2**31 + 29
+# every mechanism of the published file at a size a test holds: two layers,
+# 8 query heads over 2 K/V heads of 16, 4 indexer heads of 8 (rotary on 4
+# lanes), 16 positions attended at most, 8 experts top 2 (all held)
+SIZES = dict(
+    hidden_size=64, vocab_size=256, num_hidden_layers=2, head_dim=16,
+    num_attention_heads=8, num_key_value_heads=2, rope_theta=10000000,
+    rope_scaling=dict(mrope_section=[2, 3, 3], rope_type="default",
+                      type="default"),
+    sa_config=dict(indexer_head_dim=8, indexer_num_heads=4,
+                   indexer_num_kv_heads=1, kv_chunk_size=512,
+                   q_chunk_size=512, topk=16),
+    assumed_sizes=dict(index_rope_head_dim=4, index_rope_theta=10000000),
+    moe_intermediate_size=32, num_experts=8, num_local_experts=8,
+    num_experts_per_tok=2, norm_topk_prob=True, mlp_only_layers=[],
+    decoder_sparse_step=1, attention_bias=False, hidden_act="silu",
+    use_sliding_window=False, tie_word_embeddings=False, rms_norm_eps=1e-6)
+# float32 against float32 at "highest": what is left is the order of the
+# sums (online against one softmax, grouped against per-head einsums, tiles
+# against a loop over experts), a few float32 ulps of logits whose spread
+# is 1: measured 1e-5 at most.  bfloat16 anywhere reads 1e-2 and more.
+LOGIT_TOLERANCE = 2e-4
+
+
+def model_config(sizes=SIZES, dtype=jnp.float32, max_seq=128):
+    import sparse_gqa_decoder
+    return sparse_gqa_decoder.model_config(sizes, max_seq, dtype)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return W.decoder_weights(W.key_for(SEED), SIZES, jnp.float32)
+
+
+def reference_logits(tokens, sizes=SIZES, seed=SEED, streams=None):
+    return np.asarray(R.forward_logits(tokens, sizes, seed, jnp.float32,
+                                       streams))
+
+
+def test_seeded_weights_have_the_programs_layout(params):
+    assert model_config() == M.SPARSE_GQA_PRESETS["tiny"]
+    ours = jax.eval_shape(
+        lambda: M.sparse_gqa_init(jax.random.PRNGKey(0), model_config()))
+    assert jax.tree.structure(ours) == jax.tree.structure(params)
+    for (path, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(ours),
+                                 jax.tree_util.tree_leaves_with_path(params)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), \
+            jax.tree_util.keystr(path)
+    assert "shared" not in params["layers"][0]
+
+
+def test_full_forward_agrees_with_the_reference(params):
+    """90 tokens where a query attends 16: the choice of positions is in
+    every later logit, and the reference found it by its own sort."""
+    tokens = np.random.default_rng(0).integers(1, 256, size=90)
+    ours = M.sparse_gqa_forward(params, model_config(),
+                                jnp.asarray(tokens)[None])[0]
+    theirs = reference_logits(tokens)
+    assert float(theirs.std()) > 0.5            # logits of spread ~1
+    assert np.abs(np.asarray(ours) - theirs).max() < LOGIT_TOLERANCE
+
+
+def test_the_selection_is_in_the_numbers(params):
+    """Attending everything (topk past the sequence) is another model."""
+    tokens = np.random.default_rng(0).integers(1, 256, size=90)
+    dense = SIZES | {"sa_config": SIZES["sa_config"] | {"topk": 128}}
+    assert np.abs(reference_logits(tokens, dense) -
+                  reference_logits(tokens)).max() > 100 * LOGIT_TOLERANCE
+    # and up to topk positions it is the same model: nothing is left out
+    assert np.abs(reference_logits(tokens, dense)[:16] -
+                  reference_logits(tokens)[:16]).max() < LOGIT_TOLERANCE
+
+
+def test_bfloat16_would_fail(params):
+    tokens = np.random.default_rng(0).integers(1, 256, size=90)
+    low = jax.tree.map(lambda leaf: leaf.astype(jnp.bfloat16)
+                       if leaf.ndim > 1 else leaf, params)
+    ours = M.sparse_gqa_forward(low, model_config(dtype=jnp.bfloat16),
+                                jnp.asarray(tokens)[None])[0]
+    assert np.abs(np.asarray(ours) - reference_logits(tokens)).max() > \
+        10 * LOGIT_TOLERANCE
+
+
+# -- rotary ----------------------------------------------------------------------
+
+def test_plain_rotary_is_the_sectioned_one_on_equal_streams():
+    """The program's rotary (half-split pairs, one position a token)
+    against the reference's sectioned form fed the token's index in all
+    three streams, at the published head and sections."""
+    config = M.SparseGqaConfig(max_seq_len=64)
+    cos, sin = M.rope_tables(config)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 3, 40, 128))
+    positions = jnp.arange(7, 47)[None]
+    ours = M._rotate(x, cos[0], sin[0], positions)[0]        # [H, T, D]
+    theirs = R.sectioned_rotary(
+        jnp.swapaxes(x[0], 0, 1), jnp.broadcast_to(positions, (3, 40)),
+        (16, 24, 24), 1e7)
+    assert np.abs(np.asarray(jnp.swapaxes(ours, 0, 1)) -
+                  np.asarray(theirs)).max() < 1e-5
+
+
+def test_the_reference_keeps_to_its_definition_on_unequal_streams():
+    """A token at (t, h, w) = (5, 2, 9): pair i < 16 turns by 5 x its
+    frequency, 16 <= i < 40 by 2 x, 40 <= i < 64 by 9 x, lanes (i, i +
+    64)."""
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(2), (1, 1, 128)))
+    streams = jnp.asarray([[5], [2], [9]])
+    out = np.asarray(R.sectioned_rotary(jnp.asarray(x), streams,
+                                        (16, 24, 24), 1e7))[0, 0]
+    for i in (0, 15, 16, 39, 40, 63):
+        at = 5 if i < 16 else 2 if i < 40 else 9
+        angle = at * 1e7 ** (-i / 64)
+        low, high = x[0, 0, i], x[0, 0, i + 64]
+        assert out[i] == pytest.approx(
+            low * np.cos(angle) - high * np.sin(angle), abs=1e-5)
+        assert out[i + 64] == pytest.approx(
+            high * np.cos(angle) + low * np.sin(angle), abs=1e-5)
+    # and an image-like sequence is another result than the text's
+    tokens = np.random.default_rng(1).integers(1, 256, size=24)
+    grid = np.stack([np.arange(24), np.arange(24) // 6, np.arange(24) % 6])
+    assert np.abs(reference_logits(tokens, streams=grid) -
+                  reference_logits(tokens)).max() > 100 * LOGIT_TOLERANCE
+
+
+# -- the selection ---------------------------------------------------------------
+
+def _stable_top(scores, limit):
+    """Rows' `limit` largest by a stable sort: ties to the lower index."""
+    order = np.argsort(-scores, axis=-1, kind="stable")[..., :limit]
+    mask = np.zeros(scores.shape, bool)
+    np.put_along_axis(mask, order, True, axis=-1)
+    return mask
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "zeros-of-both-signs",
+                                  "fewer-than-the-limit", "with-minus-inf"])
+def test_top_positions_is_exact_with_ties_to_the_lower_index(case):
+    rng = np.random.default_rng(4)
+    scores = rng.standard_normal((3, 5, 40)).astype(np.float32)
+    limit = 16
+    if case == "ties":
+        scores = np.round(scores * 2) / 2          # many equal values
+    elif case == "zeros-of-both-signs":
+        scores = np.where(scores < 0.3, 0.0, scores).astype(np.float32)
+    elif case == "fewer-than-the-limit":
+        limit = 64
+    elif case == "with-minus-inf":
+        scores[..., 10:] = -np.inf                 # ten candidates a row
+    ours = np.asarray(M.top_positions(jnp.asarray(scores), limit))
+    assert (ours == _stable_top(scores, limit)).all()
+    assert (ours.sum(-1) == min(limit, 40)).all()
+
+
+def test_program_and_reference_choose_the_same_positions(params):
+    """The block's choice (a bit-by-bit threshold) and the reference's (a
+    sort's) over the same scores, ties made on purpose, and the step's
+    stable top-k: one set."""
+    rng = np.random.default_rng(5)
+    scores = (np.round(rng.standard_normal((40, 40)) * 3) / 3 + 0.0).astype(
+        np.float32)                     # + 0.0: one zero, as `_scores` makes
+    theirs = np.asarray(R.chosen_positions(jnp.asarray(scores), 0, 16))
+    causal = np.tril(np.ones((40, 40), bool))
+    masked = np.where(causal, scores, -np.inf).astype(np.float32)
+    ours = np.asarray(M.top_positions(jnp.asarray(masked), 16)) & causal
+    assert (ours == theirs).all()
+    assert (theirs.sum(-1) == np.minimum(np.arange(40) + 1, 16)).all()
+    _, picked = jax.lax.top_k(jnp.asarray(masked), 16)
+    step = np.zeros((40, 40), bool)
+    np.put_along_axis(step, np.asarray(picked), True, axis=-1)
+    assert ((step & causal) == theirs).all()
+
+
+# -- through the decoder: admit, chunked extend, decode through the pool ---------
+
+def decoder_for(params, name, buckets=(8, 32), chunk=32, slots=4, **kwargs):
+    return ContinuousDecoder(
+        params, model_config(), paged_kv=True, kv_block=8, max_slots=slots,
+        max_seq=128, prefill_buckets=buckets, prefill_chunk=chunk,
+        prefill_budget=chunk, steps_per_sync=4, name=name, **kwargs)
+
+
+def serve(params, requests, name="sparse-gqa", **kwargs):
+    decoder = decoder_for(params, name, **kwargs)
+    assert decoder._walks_live and not decoder.step_kernel
+    served = {}
+    for rid, (prompt, new) in requests.items():
+        assert decoder.submit(rid, prompt, new, lambda rid, tokens:
+                              served.__setitem__(rid, list(tokens)))
+    for _ in range(400):
+        if len(served) == len(requests):
+            break
+        decoder.pump()
+    assert len(served) == len(requests)
+    return served, decoder
+
+
+def served_gaps(requests, served):
+    """Per request, how far each served token's logit lies below the
+    reference's best at its position (one full teacher-forced forward),
+    in standard deviations of that position's logits."""
+    out = {}
+    for rid, (prompt, _) in requests.items():
+        tokens = served[rid]
+        logits = reference_logits(np.asarray(prompt + tokens[:-1]))
+        at = logits[len(prompt) - 1:]
+        out[rid] = float(((at.max(-1) - at[np.arange(len(tokens)), tokens])
+                          / at.std(-1)).max())
+    return out
+
+
+def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(params):
+    """Seven requests over four slots: prompts of 10 and 30 go in by one
+    padded admit, 5 and 3 by a narrow one, 45 and 77 by chains of 32-token
+    extends whose last chunk is padded, 64 by two whole chunks; three wait
+    for a slot that another request leaves.  All decode 11 tokens: 3 stays
+    under topk 16 (every step attends everything), 5 ends AT it, 10
+    crosses it while decoding, the others are past it from the start;
+    each served token is the reference's best at its position to within
+    the tolerance."""
+    rng = np.random.default_rng(7)
+    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 11)
+                for n in (10, 45, 77, 5, 30, 64, 3)}
+    served, decoder = serve(params, requests)
+    stats = decoder.stats
+    assert stats["prefill_chunks"] == 7 and stats["prefills"] == 4
+    for rid, gap in served_gaps(requests, served).items():
+        assert gap < LOGIT_TOLERANCE, (rid, gap)
+    # every pair of the whole model lands on a held expert
+    assert stats["moe_pairs_here"] == stats["moe_pairs_routed"] > 0
+    assert 0 < stats["moe_layer_steps"] <= 2 * stats["steps"]
+    # what was attended: everything up to 16 positions, 16 past them
+    assert 0 < stats["dsa_positions_attended"] < \
+        0.6 * stats["dsa_positions_live"]
+    # the round's own rows are attended and fetched from nowhere
+    assert 0 < stats["dsa_rows_fetched"] < stats["dsa_positions_attended"]
+    # r3's ten steps, r5's ten and r10's six (positions 10 to 15), in
+    # each of two layers (a prompt's first token comes from its prefill)
+    assert stats["dsa_slot_steps_dense"] == 2 * (10 + 10 + 6)
+
+
+def test_slots_beyond_one_group_are_served_group_by_group(params):
+    """Six slots are two groups of `_SLOT_GROUP` (the second padded with
+    rows that drop): the slots that decode are taken first, so a round
+    with five live computes both groups, one with two live the first
+    alone; every token is the reference's best either way."""
+    assert M._SLOT_GROUP == 4
+    rng = np.random.default_rng(12)
+    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 4 + n % 5)
+                for n in (9, 21, 33, 50, 62, 18, 40)}
+    served, decoder = serve(params, requests, name="two-groups", slots=6)
+    for rid, gap in served_gaps(requests, served).items():
+        assert gap < LOGIT_TOLERANCE, (rid, gap)
+    stats = decoder.stats
+    # a step a generated token after the first, in each of two layers
+    assert stats["dsa_slot_steps_dense"] == 2 * 7        # r9: positions 9-15
+    assert stats["dsa_positions_live"] == 2 * sum(
+        n + j for n in (9, 21, 33, 50, 62, 18, 40)
+        for j in range(1, 4 + n % 5))
+
+
+def test_the_counters_of_long_contexts_alone_say_nothing_was_dense(params):
+    rng = np.random.default_rng(9)
+    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 6)
+                for n in (40, 70)}
+    _, decoder = serve(params, requests, name="long-only")
+    stats = decoder.stats
+    assert stats["dsa_slot_steps_dense"] == 0
+    # five steps a request in two layers, 16 positions each
+    assert stats["dsa_positions_attended"] == 2 * 2 * 5 * 16
+
+
+def test_a_served_token_altered_is_seen(params):
+    rng = np.random.default_rng(8)
+    requests = {"a": (rng.integers(1, 256, size=12).tolist(), 6)}
+    served, _ = serve(params, requests, name="altered")
+    served["a"][2] = (served["a"][2] + 1) % 256
+    assert served_gaps(requests, served)["a"] > 100 * LOGIT_TOLERANCE
+
+
+def test_chunked_extend_equals_one_shot(params):
+    """77 tokens through chunks of 32 and of 16: the same logits' choice,
+    whatever the pieces the prefix was read in."""
+    rng = np.random.default_rng(10)
+    requests = {"c": (rng.integers(1, 256, size=77).tolist(), 5)}
+    wide, _ = serve(params, requests, name="chunks-32")
+    narrow, _ = serve(params, requests, name="chunks-16", chunk=16,
+                      buckets=(8, 16))
+    assert wide == narrow
+
+
+def test_a_slot_reused_reads_nothing_the_longer_request_left(params):
+    """One slot: a request of 90 positions, then one of 20 in the same
+    blocks; the second's indexer sees stale keys past its length and
+    must choose none of them."""
+    rng = np.random.default_rng(11)
+    requests = {"long": (rng.integers(1, 256, size=90).tolist(), 4),
+                "short": (rng.integers(1, 256, size=20).tolist(), 8)}
+    served, _ = serve(params, requests, name="reused", slots=1)
+    for rid, gap in served_gaps(requests, served).items():
+        assert gap < LOGIT_TOLERANCE, (rid, gap)
+
+
+# -- the expert layer: softmax scores, no shared expert, the chip's share --------
+
+def share_layer(layer, first, held):
+    return layer | {"experts": jax.tree.map(
+        lambda w: w[first:first + held], layer["experts"])}
+
+
+@pytest.mark.parametrize("tokens", [24, 200], ids=["decode-block", "tiles"])
+@pytest.mark.parametrize("chips", [8, 2], ids=["eight-chips", "two-chips"])
+def test_the_shares_add_up_to_the_uncut_layer(tokens, chips):
+    """`chips` chips hold 8 / chips experts each of a layer of eight (top
+    2 of a softmax over all eight, renormalised): what each gives, added
+    up, is the reference's whole layer; there is no shared expert to count
+    once."""
+    held = 8 // chips
+    whole = SIZES | {"num_experts": 8}
+    layer = W.decoder_layer(W.key_for(SEED), 1, whole, jnp.float32)
+    y = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, 64)) * 4
+    with jax.default_matmul_precision("highest"):
+        theirs = np.asarray(R.feed_forward(layer, y, sizes=whole))
+    total, pairs = np.zeros_like(theirs), 0
+    normed = latent_moe.L.rms_norm(layer["ln_mlp"], y)
+    for chip in range(chips):
+        config = dataclasses.replace(model_config(), experts_first=chip * held,
+                                     experts_held=held)
+        out, counts = latent_moe.moe_ffn(
+            share_layer(layer, chip * held, held), config, normed)
+        total += np.asarray(out)
+        pairs += int(counts[2])
+        assert int(counts[3]) == tokens * 2
+    assert pairs == tokens * 2              # every pair landed on one share
+    assert np.abs(total - theirs).max() < 5e-5
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 5, 2**32 + 11])
+def test_the_router_is_plain_and_the_held_share_an_eighth_on_average(seed):
+    """16 of 128 experts held, top 8: the router's columns are one draw an
+    expert (no two alike, so a token's eight gates differ and a sigmoid in
+    the softmax's place changes the layer), the held experts get an eighth
+    of the pairs over many tokens, and a token may hit none of them or
+    several."""
+    sizes = SIZES | {"num_experts": 16, "num_experts_per_tok": 8,
+                     "published": {"num_experts": 128}}
+    layer = W.decoder_layer(W.key_for(seed), 0, sizes, jnp.float32)
+    weights = np.asarray(layer["router"]["w"])
+    assert weights.shape == (64, 128)
+    assert len({column.tobytes() for column in weights.T}) == 128
+    x = jax.random.normal(jax.random.PRNGKey(seed % 1000), (4000, 64))
+    ours = dataclasses.replace(model_config(), num_experts=128, top_k=8,
+                               experts_held=16)
+    ids, gains = latent_moe.select_experts(
+        ours, latent_moe.router_scores(ours, x @ weights))
+    here = (np.asarray(ids) < 16).sum(axis=-1)
+    assert abs(here.mean() / 8 - 1 / 8) < 0.01
+    assert (here == 0).any() and (here >= 2).any()
+    gains = np.asarray(gains)
+    assert np.allclose(gains.sum(axis=-1), 1.0, atol=1e-5)
+    assert np.median(gains.max(axis=-1) / gains.min(axis=-1)) > 2
+    theirs = dataclasses.replace(ours, router_scores="sigmoid")
+    softmax, _ = latent_moe.moe_ffn(layer, ours, x[:64])
+    sigmoid, _ = latent_moe.moe_ffn(layer, theirs, x[:64])
+    assert np.abs(np.asarray(softmax) - np.asarray(sigmoid)).max() > \
+        0.05 * np.abs(np.asarray(softmax)).max()
+
+
+def test_softmax_scores_and_sigmoid_scores_are_told_apart():
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    ours = model_config()
+    assert np.allclose(latent_moe.router_scores(ours, logits),
+                       jax.nn.softmax(logits))
+    theirs = latent_moe.LATENT_MOE_PRESETS["tiny"]
+    assert np.allclose(latent_moe.router_scores(theirs, logits),
+                       jax.nn.sigmoid(logits))
+
+
+# -- the count from the published keys -------------------------------------------
+
+def test_the_parameter_count_reproduces_the_published_size():
+    """30.6 B, 3.5 B of them active a token, from the file's keys."""
+    import json
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "keye-vl-2.0-30b-a3b-ep8-d12.json")) as f:
+        sizes = json.load(f)
+    assert ops.attention_params(sizes) == 18_874_624
+    assert ops.indexer_params(sizes) == 2_261_120
+    assert ops.expert_params(sizes) == 4_718_592
+    assert round(ops.layer_params(sizes, 128) / 1e6, 1) == 625.4
+    whole = ops.published_parameters(sizes)
+    assert round(whole["total"] / 1e9, 1) == 30.6
+    assert round(whole["active"] / 1e9, 1) == 3.5
+    held = ops.params(sizes)
+    assert round(held["total"] * 2 / 1e9, 2) == 2.48       # GB here
+    config = model_config(sizes, jnp.bfloat16, 32768)
+    made = jax.eval_shape(
+        lambda: M.sparse_gqa_init(jax.random.PRNGKey(0), config))
+    assert sum(leaf.size for leaf in jax.tree.leaves(made)) == held["total"]
+
+
+# -- the pool: three leaves a layer ----------------------------------------------
+
+def test_the_pool_takes_three_leaves_from_the_model():
+    """K and V as a dense grouped-query model's, the indexer key padded
+    from 64 lanes to a lane tile: 2,304 B a token and layer at the
+    published widths (2,176 unpadded)."""
+    published = dataclasses.replace(
+        M.SparseGqaConfig(dtype=jnp.bfloat16), num_layers=3, vocab=256,
+        experts_held=1)
+    assert published.cache_leaves == ((4, 128), (4, 128), (1, 128))
+    assert serving_paged.first_leaf(published) == (4, 128)
+    assert serving_paged.reads_own_pool(published)
+    pool = BlockPool(published, 32, False, initial_blocks=2, name="geo-pub")
+    assert pool.block_nbytes == 32 * 3 * 2304
+    assert len(pool.k_pools) == 3 and len(pool.v_pools) == 6
+    assert [leaf.shape for leaf in pool.k_pools] == [(3, 4, 32, 128)] * 3
+    assert [leaf.shape for leaf in pool.v_pools] == \
+        [(3, 4, 32, 128)] * 3 + [(3, 1, 32, 128)] * 3
+    assert pool.nbytes() == 3 * pool.block_nbytes
+    sizes = {"num_key_value_heads": 4, "head_dim": 128,
+             "sa_config": {"indexer_head_dim": 64}}
+    assert ops.token_row_bytes(sizes, 2) == 2176
+    # growth and copy walk every leaf
+    tiny = BlockPool(model_config(), 8, False, initial_blocks=4, name="geo")
+    assert tiny.block_nbytes == 8 * 2 * (2 * 2 * 16 + 128) * 4
+    ids = tiny.alloc_blocks(2)
+    assert tiny.copy_blocks(ids[:1], ids[1:]) == tiny.block_nbytes
+    tiny.reserve(12)
+    assert all(leaf.shape[0] >= 13 for leaf in tiny.k_pools + tiny.v_pools)
+
+
+@pytest.mark.parametrize("leaves", [1, 2, 3])
+def test_the_programs_split_and_join_the_pools_sides(leaves):
+    layers = 4
+    k_pools = [f"a{i}" for i in range(layers)]
+    v_pools = [f"{'bc'[side]}{i}" for side in range(leaves - 1)
+               for i in range(layers)]
+    sides = serving_paged._pool_sides(k_pools, v_pools)
+    assert len(sides) == leaves and all(len(s) == layers for s in sides)
+    assert [side[2] for side in sides] == ["a2", "b2", "c2"][:leaves]
+    assert serving_paged._join_sides(sides) == (k_pools, v_pools)
+
+
+def test_a_norm_of_another_epsilon_is_refused():
+    with pytest.raises(ValueError, match="1e-6"):
+        dataclasses.replace(model_config(), norm_eps=1e-5)
+
+
+def test_the_driver_refuses_keys_it_does_not_compute():
+    import sparse_gqa_decoder
+    for key, value in (("attention_bias", True), ("mlp_only_layers", [0]),
+                       ("decoder_sparse_step", 2), ("norm_topk_prob", False),
+                       ("use_sliding_window", True)):
+        with pytest.raises(ValueError, match="the program computes"):
+            sparse_gqa_decoder.model_config(SIZES | {key: value}, 64,
+                                            jnp.float32)
+    with pytest.raises(ValueError, match="ONE key head"):
+        W.indexer_sizes(SIZES | {"sa_config": SIZES["sa_config"] |
+                                 {"indexer_num_kv_heads": 2}})
+
+
+# -- the paths three leaves are not carried through refuse, by name --------------
+
+@pytest.mark.parametrize("kwargs, named", [
+    (dict(paged_kv=False), "dense slot cache"),
+    (dict(kv_cache_dtype="int8"), "int8 KV cache"),
+    (dict(speculate_k=2), "speculative decoding"),
+    (dict(prefix_cache=True), "prefix cache"),
+    (dict(weight_quant=True), "weight-only int8"),
+], ids=["dense", "int8-kv", "speculation", "prefix-cache", "weight-quant"])
+def test_paths_not_carried_refuse_at_construction(params, kwargs, named):
+    kwargs = dict(paged_kv=True, kv_block=8, max_slots=2, max_seq=64,
+                  prefill_chunk=32) | kwargs
+    if kwargs.get("prefix_cache"):
+        kwargs["prefix_cache"] = serving.PrefixKVCache(block_tokens=8)
+    with pytest.raises(ValueError, match=named):
+        ContinuousDecoder(params, model_config(max_seq=64), **kwargs)
+
+
+def test_tensor_parallel_weights_refuse_at_construction(params):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
+    sharded = dict(params)
+    sharded["lm_head"] = {"w": jax.device_put(
+        params["lm_head"]["w"], NamedSharding(mesh, P(None, "model")))}
+    with pytest.raises(ValueError, match="tensor-parallel"):
+        ContinuousDecoder(sharded, model_config(max_seq=64), paged_kv=True,
+                          kv_block=8, max_slots=2, max_seq=64,
+                          prefill_chunk=32)
+
+
+@pytest.mark.parametrize("path", ["drain", "wire-layout", "install",
+                                  "disagg-client"])
+def test_drain_and_the_kv_wire_refuse_by_name(params, path):
+    decoder = ContinuousDecoder(params, model_config(max_seq=64),
+                                paged_kv=True, kv_block=8, max_slots=2,
+                                max_seq=64, prefill_chunk=32,
+                                name=f"refuse-sparse-gqa-{path}")
+    with pytest.raises(ValueError, match="not carried"):
+        if path == "drain":
+            decoder.drain()
+        elif path == "wire-layout":
+            decoder.kv_wire_layout()
+        elif path == "install":
+            decoder.install_shipped_blocks([1] * 16, 0, [{}])
+        else:
+            from aiko_services_tpu.serving_disagg import PrefillClient
+            PrefillClient(None, decoder)
+
+
+def test_serving_tests_no_models_name():
+    for module in ("serving.py", "serving_paged.py"):
+        with open(os.path.join(ROOT, "aiko_services_tpu", module)) as f:
+            text = f.read()
+        assert "sparse_gqa" not in text and "SparseGqa" not in text
