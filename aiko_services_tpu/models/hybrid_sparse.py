@@ -1040,7 +1040,7 @@ def _paged_model():
         rope=rope_tables, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, state_kernel=_state_kernel,
+        walks=_walks, step_kernel=_state_kernel,
         counters=HYBRID_COUNTERS, supports=frozenset(),
         block_multiple=_TILE_ROWS,
         residual_in=_streams_in, final_norm=_head_hidden)

@@ -32,13 +32,21 @@
 # row would halve the leaf and its read, at the price of a merge that
 # writes half rows: left to a later change (ROADMAP).
 #
-# The model reads its pool by hand in every program (`walks` "model"): the
-# decode step scores EVERY live position of a slot against the cached
-# keys, takes the exact top `index_topk` (a sort), and fetches those single
-# rows from the K and the V leaf, the slots that decode a group at a time
-# and the others not at all; a chunk's queries each choose their own
-# positions of the prefix (a threshold found bit by bit, over as much of
-# the window as a prefix reaches) and attend it masked, piece by piece.
+# The model reads its pool by hand in every program (`walks` "model"), and
+# the choice of positions is a MASK everywhere (a threshold found bit by
+# bit, `top_positions`: no sort, no list of positions).  The decode step
+# scores EVERY live position of a slot against the cached keys, marks the
+# exact top `index_topk`, and attends the slot's live K and V blocks once
+# with what was not chosen masked (ISSUE 39): on the chip through the walk
+# of ops/paged_attention (`step_kernel`: a position is 1 KB a leaf and the
+# walk streams at the memory's speed, where fetching the chosen twelfth
+# row by row paid a row's latency 16,384 times a slot and layer; past some
+# 50k live positions a slot at four decoding slots the rows would win
+# again, ROADMAP K1), elsewhere through the attention an extend uses; the
+# slots that decode a group at a time and the others not at all.  A
+# chunk's queries each choose their own positions of the prefix (over as
+# much of the window as a prefix reaches) and attend it masked, piece by
+# piece.
 
 from __future__ import annotations
 
@@ -48,6 +56,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..ops.paged_attention import (paged_decode_attention, walk_positions,
+                                   walks_live_blocks)
 from . import layers as L
 from .hybrid_sparse import SCOPE_DSA_INDEX, _index_scores
 from .latent_moe import MOE_COUNTERS, layer_ffn
@@ -61,9 +71,10 @@ SCOPE_DSA_SELECT = "aiko.dsa_select"
 
 # what a decode step counts: the expert layers' four, then over the layers
 # and the slots that decoded the positions that were live, those that were
-# attended, the K/V positions that the gather fetched from the pool (single
-# rows: no tile is fetched whole for one of its rows), and the slot-steps
-# that had `index_topk` positions or fewer and attended them all
+# attended, the K/V positions that the step READ of the pool to attend them
+# (the walk: a slot's live blocks, whole; the plain form: the pieces it
+# gathers), and the slot-steps that had `index_topk` positions or fewer
+# and attended them all
 _DSA_COUNTERS = ("dsa_positions_live", "dsa_positions_attended",
                  "dsa_rows_fetched", "dsa_slot_steps_dense")
 SPARSE_GQA_COUNTERS = MOE_COUNTERS + _DSA_COUNTERS
@@ -364,6 +375,10 @@ class _PoolPrefix:
                          offsets[:, None, None], out, -jnp.inf)
 
     def attend(self, carry, attend, chosen):
+        if chosen.shape[-1] < self.positions:   # a table cut mid-piece
+            chosen = jnp.pad(chosen, [(0, 0)] * (chosen.ndim - 1) + [
+                (0, self.positions - chosen.shape[-1])])
+
         def piece(j, carry):
             mask = jax.lax.dynamic_slice_in_dim(chosen, j * self.span,
                                                 self.span, axis=2)
@@ -371,6 +386,45 @@ class _PoolPrefix:
                           self._piece(self.v, j), mask)
 
         return jax.lax.fori_loop(0, self.pieces, piece, carry)
+
+
+def _masked_attention(config: SparseGqaConfig, q, k, v, chosen, prefix=None):
+    """One softmax of the queries q [A, H, C, D] over the rows k, v [A, KV,
+    P, D] that came with them and, where `prefix` is given, over the
+    pool's positions before those, read piece by piece: a query attends
+    what `chosen` [A, C, the pool's positions ++ P] names, the same for
+    every head.  -> [A, H, C, D] float32."""
+    a, _, c, d = q.shape
+    kv, group = config.num_kv_heads, config.num_heads // config.num_kv_heads
+    own = k.shape[2]
+    grouped = q.reshape(a, kv, group, c, d)
+
+    def attend(carry, keys, values, mask):
+        """Online softmax over one more piece of positions: keys, values
+        [A, KV, P, D]; mask [A, C, P]."""
+        row_max, row_sum, acc = carry
+        s = jnp.einsum("akgcd,akpd->akgcp", grouped, keys,
+                       preferred_element_type=jnp.float32) * \
+            config.softmax_scale
+        mask = mask[:, None, None]
+        s = jnp.where(mask, s, -1e30)
+        new_max = jnp.maximum(row_max, s.max(axis=-1, keepdims=True))
+        w = jnp.where(mask, jnp.exp(s - new_max), 0.0)
+        fade = jnp.exp(row_max - new_max)
+        return (new_max, row_sum * fade + w.sum(-1, keepdims=True),
+                acc * fade + jnp.einsum(
+                    "akgcp,akpd->akgcd", w.astype(values.dtype), values,
+                    preferred_element_type=jnp.float32))
+
+    carry = (jnp.full((a, kv, group, c, 1), -1e30, jnp.float32),
+             jnp.zeros((a, kv, group, c, 1), jnp.float32),
+             jnp.zeros((a, kv, group, c, d), jnp.float32))
+    carry = attend(carry, k, v, chosen[..., chosen.shape[-1] - own:])
+    if prefix is not None:
+        carry = prefix.attend(carry, attend,
+                              chosen[..., :chosen.shape[-1] - own])
+    _, row_sum, acc = carry
+    return (acc / row_sum).reshape(a, config.num_heads, c, d)
 
 
 def _attention_block(layer, config: SparseGqaConfig, x, cos, sin, offsets,
@@ -400,38 +454,9 @@ def _attention_block(layer, config: SparseGqaConfig, x, cos, sin, offsets,
             chosen = visible & _choose(scores, limit, c, offsets)
         else:
             chosen = visible
-    kv, group = config.num_kv_heads, config.num_heads // config.num_kv_heads
-    d = config.head_dim
     with jax.named_scope(SCOPE_ATTN_CORE):
-        grouped = q.reshape(a, kv, group, c, d)
-
-        def attend(carry, keys, values, mask):
-            """Online softmax over one more piece of positions: keys,
-            values [A, KV, P, D]; mask [A, C, P], the same for every
-            head."""
-            row_max, row_sum, acc = carry
-            s = jnp.einsum("akgcd,akpd->akgcp", grouped, keys,
-                           preferred_element_type=jnp.float32) * \
-                config.softmax_scale
-            mask = mask[:, None, None]
-            s = jnp.where(mask, s, -1e30)
-            new_max = jnp.maximum(row_max, s.max(axis=-1, keepdims=True))
-            w = jnp.where(mask, jnp.exp(s - new_max), 0.0)
-            fade = jnp.exp(row_max - new_max)
-            return (new_max, row_sum * fade + w.sum(-1, keepdims=True),
-                    acc * fade + jnp.einsum(
-                        "akgcp,akpd->akgcd", w.astype(values.dtype), values,
-                        preferred_element_type=jnp.float32))
-
-        carry = (jnp.full((a, kv, group, c, 1), -1e30, jnp.float32),
-                 jnp.zeros((a, kv, group, c, 1), jnp.float32),
-                 jnp.zeros((a, kv, group, c, d), jnp.float32))
-        carry = attend(carry, k, v, chosen[..., -c:])
-        if prefix is not None:
-            carry = prefix.attend(carry, attend, chosen[..., :-c])
-        _, row_sum, acc = carry
-        out = (acc / row_sum).astype(x.dtype).reshape(
-            a, config.num_heads, c, d)
+        out = _masked_attention(config, q, k, v, chosen,
+                                prefix).astype(x.dtype)
     with jax.named_scope(SCOPE_ATTN_PROJ):
         out = L.linear(layer["attn"]["o"], L._merge_heads(out))
     return out, (k, v, k_i.astype(x.dtype))
@@ -470,11 +495,12 @@ def sparse_gqa_forward(params, config: SparseGqaConfig, tokens):
 
 # -- the decode step -------------------------------------------------------------
 
-def _attend_slots(config: SparseGqaConfig, leaves, tables, sides, q, q_i,
-                  weights, entry_lengths, lengths, step_index, active):
-    """Scores, choice, fetch and softmax for the slots given (a group of
-    them: every argument's leading axis): -> (out [W, H, D], the counts
-    of `_attention_step` over those of them that decode)."""
+def _attend_slots(config: SparseGqaConfig, kernel: bool, leaves, tables,
+                  sides, q, q_i, weights, entry_lengths, lengths, step_index,
+                  active):
+    """Scores, choice and softmax for the slots given (a group of them:
+    every argument's leading axis): -> (out [W, H, D], the counts of
+    `_attention_step` over those of them that decode)."""
     k_pool, v_pool, key_pool = leaves
     k_side, v_side, key_side = sides
     slots_n, steps = q.shape[0], k_side.shape[2]
@@ -498,69 +524,54 @@ def _attend_slots(config: SparseGqaConfig, leaves, tables, sides, q, q_i,
              lengths[:, None]))
         scores = jnp.where(visible, scores, -jnp.inf)
     with jax.named_scope(SCOPE_DSA_SELECT):
-        limit = min(config.index_topk, held + steps)
-        best, picked = jax.lax.top_k(scores, limit)            # [W, K]
-        taken = best > -jnp.inf
-        from_pool = taken & (picked < held)
-        near_ok = (taken[:, :, None] & (
-            picked[:, :, None] - held ==
-            jnp.arange(steps)[None, None])).any(axis=1)        # [W, steps]
-    kv, group = config.num_kv_heads, config.num_heads // config.num_kv_heads
-    d = config.head_dim
+        # a mask and no list: nothing below wants a position's number
+        chosen = visible
+        if held + steps > config.index_topk:
+            chosen = visible & top_positions(scores, config.index_topk)
+        attended = chosen.sum(axis=1)
+    walked = jnp.where(active, entry_lengths, 0)
     with jax.named_scope(SCOPE_ATTN_CORE):
-        at = jnp.where(from_pool, picked, 0)
-        ids = jnp.take_along_axis(tables, at // block, axis=1)  # [W, K]
-        # row (id, head, at % block) of a leaf seen as rows alone
-        row = (ids[:, :, None] * kv + jnp.arange(kv)[None, None]) * block \
-            + (at % block)[:, :, None]                         # [W, K, KV]
-        far_k = jnp.take(k_pool.reshape(-1, d), row, axis=0, mode="clip")
-        far_v = jnp.take(v_pool.reshape(-1, d), row, axis=0, mode="clip")
-        grouped = q[:, :, 0].reshape(slots_n, kv, group, d)
-
-        def scored(s, ok):
-            return jnp.where(ok, s * config.softmax_scale, -1e30)
-
-        # ONE softmax over the fetched rows and the round's, scored apart
-        # (a maximum and a sum shared)
-        s_far = scored(jnp.einsum("skgd,spkd->skgp", grouped, far_k,
-                                  preferred_element_type=jnp.float32),
-                       from_pool[:, None, None])
-        s_near = scored(jnp.einsum("skgd,skpd->skgp", grouped, k_side,
-                                   preferred_element_type=jnp.float32),
-                        near_ok[:, None, None])
-        top = jnp.maximum(s_far.max(axis=-1), s_near.max(axis=-1))[..., None]
-        e_far, e_near = jnp.exp(s_far - top), jnp.exp(s_near - top)
-        total = e_far.sum(axis=-1) + e_near.sum(axis=-1)
-        out = (jnp.einsum("skgp,spkd->skgd", e_far.astype(far_v.dtype),
-                          far_v, preferred_element_type=jnp.float32) +
-               jnp.einsum("skgp,skpd->skgd", e_near.astype(v_side.dtype),
-                          v_side, preferred_element_type=jnp.float32)) / \
-            total[..., None]
-        fetched = from_pool.sum(axis=1)
+        if kernel:
+            # every live block of a slot that decodes once through VMEM,
+            # what was not chosen masked: ops/paged_attention's walk
+            kv = config.num_kv_heads
+            group = config.num_heads // kv
+            out = paged_decode_attention(
+                q[:, :, 0].reshape(slots_n, kv, group, config.head_dim),
+                k_pool, v_pool, tables, k_side, v_side,
+                chosen[:, None, held:], walked, groups=group,
+                scale=config.softmax_scale, chosen=chosen[:, :held])
+            read = walk_positions(walked, block)
+        else:
+            # the same mask through the extend's attention, the pool read
+            # piece by piece as far as the longest of these slots reaches
+            prefix = _PoolPrefix(config, leaves, tables, block, walked.max())
+            out = _masked_attention(config, q, k_side, v_side,
+                                    chosen[:, None], prefix)
+            read = jnp.where(active, prefix.pieces * prefix.span, 0)
         counted = jnp.stack([
             jnp.where(active, lengths + 1, 0).sum(),
-            jnp.where(active, fetched + near_ok.sum(axis=1), 0).sum(),
-            jnp.where(active, fetched, 0).sum(),
+            jnp.where(active, attended, 0).sum(), read.sum(),
             (active & (lengths + 1 <= config.index_topk)).sum()
         ]).astype(jnp.int32)
-    return out.reshape(slots_n, config.num_heads, d), counted
+    return out.reshape(slots_n, config.num_heads, config.head_dim), counted
 
 
-def _attention_step(layer, config: SparseGqaConfig, x, cos, sin, tables,
-                    leaves, sides, entry_lengths, lengths, step_index,
-                    active):
+def _attention_step(layer, config: SparseGqaConfig, kernel: bool, x, cos,
+                    sin, tables, leaves, sides, entry_lengths, lengths,
+                    step_index, active):
     """The attention in a decode step, x [S, 1, dim] at position
     lengths[s]: index scores over the cached keys of the slot's whole
-    length and this round's, the exact top `index_topk` of them, those
-    positions' K and V rows GATHERED from the pool row by row, one softmax
-    over them and the round's own rows that were chosen.  All of that
-    costs the same for a slot that decodes nothing (2,048 rows a leaf
-    fetched for it, a sort of its scores), so the slots that decode are
-    taken first, `_SLOT_GROUP` at a time, and a group with none of them
-    is left out: the step's time follows what is live.  Returns (out, the
-    sides rewritten, [positions live, positions attended, positions
-    fetched from the pool, slot-steps that attended everything] over the
-    slots that decode)."""
+    length and this round's, the exact top `index_topk` of them as a MASK,
+    and one softmax over the slot's live K and V rows and the round's own
+    with what was not chosen masked: `kernel` walks the slot's live blocks
+    through ops/paged_attention, else the pool is read as an extend reads
+    it.  The scores and the choice cost the same for a slot that decodes
+    nothing, so the slots that decode are taken first, `_SLOT_GROUP` at a
+    time, and a group with none of them is left out: the step's time
+    follows what is live.  Returns (out, the sides rewritten, [positions
+    live, positions attended, positions read of the pool, slot-steps that
+    attended everything] over the slots that decode)."""
     k_side, v_side, key_side = sides
     slots_n = x.shape[0]
     q, k, v, q_i, k_i, weights = _project(layer, config, x, cos, sin,
@@ -590,7 +601,8 @@ def _attention_step(layer, config: SparseGqaConfig, x, cos, sin, tables,
         def run(carry):
             out, counted = carry
             part, counts = _attend_slots(
-                config, leaves, of(tables), [of(side) for side in sides],
+                config, kernel, leaves, of(tables),
+                [of(side) for side in sides],
                 of(q), of(q_i), of(weights), of(entry_lengths), of(lengths),
                 step_index, of(active) & (rows < slots_n))
             return (out.at[rows].set(part.astype(out.dtype), mode="drop"),
@@ -629,16 +641,25 @@ def _step_argmax(params, config: SparseGqaConfig, token_block, attend, live):
         [moe, jnp.zeros((len(_DSA_COUNTERS),), jnp.int32)])
 
 
+def _step_kernel(config: SparseGqaConfig, interpret: bool) -> bool:
+    return walks_live_blocks(config.head_dim, False, interpret)
+
+
 def _step_attention(kernel: bool):
     """A layer's attention in the decode step: the model reads its pool
-    itself, kernel or not, and builds no view."""
+    itself, kernel or not, and builds no view.  `kernel` (the decoder's
+    `step_kernel`: on a TPU, the weights on one device, nothing else asked
+    for) lets a head of whole lanes take the walk of ops/paged_attention
+    over the slots that decode, the chosen positions its mask."""
 
     def attend(tables, layer, config, x, cos, sin, leaves, views, sides,
                entry_lengths, lengths, step_index, entry_active, state,
                active):
         out, sides, counted = _attention_step(
-            layer, config, x, cos, sin, tables, leaves, sides,
-            entry_lengths, lengths, step_index, active)
+            layer, config,
+            kernel and _step_kernel(config, jax.default_backend() != "tpu"),
+            x, cos, sin, tables, leaves, sides, entry_lengths, lengths,
+            step_index, active)
         return out, sides, (), jnp.concatenate(
             [jnp.zeros((len(MOE_COUNTERS),), jnp.int32), counted])
 
@@ -685,4 +706,5 @@ def _paged_model():
         rope=rope_tables, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, counters=SPARSE_GQA_COUNTERS, supports=frozenset())
+        walks=_walks, step_kernel=_step_kernel,
+        counters=SPARSE_GQA_COUNTERS, supports=frozenset())

@@ -1,7 +1,7 @@
 # Paged decode attention: a pallas TPU kernel that reads K/V straight
 # out of the serving block pool through per-slot block tables — vLLM
 # PagedAttention's indirection (Kwon et al., SOSP 2023), TPU-flavored
-# (ISSUE 16, ROADMAP item 2; the walk of ISSUE 30).
+# (ISSUE 16, ROADMAP item 2; the walk of ISSUE 30; its mask of ISSUE 39).
 #
 # The XLA paged path (serving_paged._gather_views) must materialize a
 # slot-major [S, H, T, D] copy of every slot's blocks once per round
@@ -40,6 +40,21 @@
 # past it are zeroed in the buffer (the rest of a last live block, and
 # what an earlier chunk left behind it): no dead cell reaches a result,
 # whatever it holds.
+#
+# A SPARSE SELECTION (ISSUE 39: a model whose indexer chooses, token by
+# token, which positions a query attends) rides the walk as a MASK:
+# `chosen` [S, positions], the same for every head and query row of a
+# slot, an int32 VMEM operand [S, units, unit] of which the K item of
+# chunk j reads row j and masks `pos < length` AND chosen to -1e30.  The
+# slot still walks ALL its live blocks, and the V pass needs nothing (a
+# masked score's weight is an exact zero and its V row is a live, finite
+# one).  That reads twelve times what a 2,048-of-24k selection attends
+# and is still the faster form up to some 50k live positions a slot:
+# a position is 1 KB a leaf at 4 heads of 128 and streams at the
+# memory's speed, where a gather of the chosen single rows pays a row's
+# latency each (PERF.md §6, PR 39).  Without `chosen` the operand does
+# not exist and the module is the one it was.  The walk only: the table
+# body refuses a mask by name.
 #
 # A LATENT pool (ISSUE 31: multi-head latent attention, absorbed) has
 # ONE leaf, [N, 1, B, lanes]: one shared row a token, and V is the
@@ -103,8 +118,11 @@
 # heads, kv_block 32, bf16 and int8, fold on and off, decode /
 # speculative / extend widths) and the whole 7B step around the walk;
 # chip_smoke.py runs both on a v5e chip against the gather path,
-# standalone and inside ContinuousDecoder; the benchmark's two cells
-# run the walk in every round (PERF.md §5 has its times).  In bf16 on
+# standalone and inside ContinuousDecoder; four of the benchmark's cells
+# run the walk in every round (PERF.md §5 has its times), one of them
+# with a mask (models/sparse_gqa.py: tests/test_sparse_gqa.py holds it
+# to a plain softmax over the chosen rows, tests/test_paged_kv.py the
+# mask alone).  In bf16 on
 # the chip kernel and gather path differ by rounding, so greedy tokens
 # flip at near-ties there (CHANGES.md, PR 21).
 
@@ -150,9 +168,11 @@ def walk_positions(lengths, block_tokens: int):
 
 
 def _unit_scores(q, k, k_scale, first, length, *, fold: bool,
-                 scale: float):
+                 scale: float, chosen=None):
     """Masked f32 scores [Hkv, R, U] of the queries against one unit
-    of K rows [Hkv, U, D] that starts at absolute position `first`."""
+    of K rows [Hkv, U, D] that starts at absolute position `first`;
+    `chosen` [1, U] int32, where given, is which of the unit's
+    positions the slot attends at all."""
     import jax
     import jax.numpy as jnp
     if k_scale is not None and not fold:
@@ -170,7 +190,10 @@ def _unit_scores(q, k, k_scale, first, length, *, fold: bool,
     # extent (entry_lengths) are dead cells / null-block zeros / rows
     # of a buffer that no copy wrote
     pos = first + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 2)
-    return jnp.where(pos < length, sc, -1e30)
+    live = pos < length
+    if chosen is not None:
+        live = live & (chosen[None] != 0)
+    return jnp.where(live, sc, -1e30)
 
 
 def _side_softmax(q, k_side, v_side, valid, row_max, scores, count, *,
@@ -233,14 +256,17 @@ def _leading_lanes(rows, lanes: int):
 
 
 def _walk_kernel(tables_ref, entry_ref, q_ref, k_hbm, v_hbm, k_side_ref,
-                 v_side_ref, valid_ref, o_ref, ring, sems, scores, acc,
-                 chained, *, scale: float, chunk_blocks: int):
-    """The body that walks a slot's live blocks by hand (header)."""
+                 v_side_ref, valid_ref, *refs, scale: float,
+                 chunk_blocks: int):
+    """The body that walks a slot's live blocks by hand (header).  A
+    call with a `chosen` mask has one operand more, before the result."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    *masks, o_ref, ring, sems, scores, acc, chained = refs
+    chosen_ref = masks[0] if masks else None
     s, r = pl.program_id(0), pl.program_id(1)
     slots_n, row_tiles = pl.num_programs(0), pl.num_programs(1)
     block_tokens = k_hbm.shape[2]
@@ -299,8 +325,9 @@ def _walk_kernel(tables_ref, entry_ref, q_ref, k_hbm, v_hbm, k_side_ref,
             start(v_hbm, s, 0, 1 - at)
 
         wait(k_hbm, j, at)
-        sc = _unit_scores(q, ring[at], None, j * chunk, length,
-                          fold=True, scale=scale)
+        sc = _unit_scores(
+            q, ring[at], None, j * chunk, length, fold=True, scale=scale,
+            chosen=None if chosen_ref is None else chosen_ref[0, pl.ds(j, 1)])
         scores[j] = sc               # leading-axis index: see the header
         return jnp.maximum(row_max, jnp.max(sc, axis=-1, keepdims=True))
 
@@ -441,7 +468,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, k_side, v_side,
                            side_valid, entry_lengths, *, groups: int,
                            scale: float | None = None,
                            fold_scales: bool = True,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None, chosen=None):
     """Block-table-native decode attention over a paged KV pool.
 
     q:            [S, Hkv, G*W, D] grouped queries (G-major: the
@@ -464,6 +491,12 @@ def paged_decode_attention(q, k_pool, v_pool, tables, k_side, v_side,
                   pos_side <= q_pos mask arrive here unchanged)
     entry_lengths: [S] int32 read-only main extent per slot; 0 reads
                   nothing of the pool into the result
+    chosen:       [S, positions] bool or int32, or None: which of the
+                  pool's positions a slot attends, the same for every
+                  head and query row of it (a sparse selection).  The
+                  slot still walks every live block; a position that
+                  was not chosen is masked as one past the length is.
+                  The walk only: the table body refuses it
 
     Returns [S, Hkv, G*W, D] f32.  interpret=None auto-selects:
     compiled pallas on TPU, interpreter mode elsewhere (CPU tests run
@@ -490,7 +523,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, k_side, v_side,
     # chunk patched by a test is another entry of jit's cache
     return _attend_jit()(
         q, k_pool, v_pool, tables, k_side, v_side, side_valid,
-        entry_lengths, groups=groups, scale=scale,
+        entry_lengths, chosen, groups=groups, scale=scale,
         fold_scales=fold_scales, interpret=interpret,
         chunk_blocks=_chunk_blocks(tables.shape[1], block_tokens))
 
@@ -503,7 +536,7 @@ def _attend_jit():
 
 
 def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
-            entry_lengths, *, groups: int, scale: float,
+            entry_lengths, chosen, *, groups: int, scale: float,
             fold_scales: bool, interpret: bool, chunk_blocks: int):
     """paged_decode_attention with every default resolved."""
     import jax
@@ -527,6 +560,11 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
             "paged_decode_attention: values narrower than keys are a "
             "latent pool's (v_pool=None), which only the walk reads: a "
             "native leaf whose rows are whole lanes")
+    if chosen is not None and not walk:
+        raise ValueError(
+            "paged_decode_attention: `chosen` is a mask over the blocks "
+            "that the walk copies itself; the table body (a head of 64, "
+            "an int8 pool) takes none")
     c = chunk_blocks if walk else 1
     unit = c * block_tokens
     units = -(-nb // c)
@@ -557,14 +595,31 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
         def valid_map(s, r, tables, entries):
             return (s, r, 0)
 
+        def mask_map(s, r, tables, entries):
+            return (s, 0, 0)
+
         in_pool = pl.BlockSpec(memory_space=pl.ANY)
+        masks, mask_specs = [], []
+        if chosen is not None:
+            if chosen.shape[1] > units * unit:
+                raise ValueError(
+                    f"paged_decode_attention: `chosen` names "
+                    f"{chosen.shape[1]} positions a slot, the table "
+                    f"reaches {nb * block_tokens}")
+            # a unit's mask is a row of the operand: int32 as the side
+            # mask is, indexed by the unit as the scores scratch is
+            masks = [jnp.pad(chosen.astype(jnp.int32), (
+                (0, 0), (0, units * unit - chosen.shape[1]))).reshape(
+                    slots_n, units, unit)]
+            mask_specs = [pl.BlockSpec((1, units, unit), mask_map)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots_n, gw // rows),
             in_specs=[pl.BlockSpec(row_block, q_map), in_pool, in_pool,
                       pl.BlockSpec(side_block, side_map),
                       pl.BlockSpec(v_side_block, side_map),
-                      pl.BlockSpec((1, rows, side_len), valid_map)],
+                      pl.BlockSpec((1, rows, side_len), valid_map),
+                      *mask_specs],
             out_specs=pl.BlockSpec(out_block, q_map),
             scratch_shapes=[
                 # the two-deep ring that K and V chunks pass through
@@ -583,7 +638,7 @@ def _attend(q, k_pool, v_pool, tables, k_side, v_side, side_valid,
                 vmem_limit_bytes=_WALK_VMEM_LIMIT),
             interpret=interpret,
         )(tables.astype(jnp.int32), entries, q, kq, vq, k_side, v_side,
-          valid_rows)
+          valid_rows, *masks)
 
     def q_map(s, r, p, j, tables, entries):
         return (s, 0, r, 0)

@@ -1699,17 +1699,18 @@ class ContinuousDecoder:
             # (elsewhere it would run in the interpreter), at a pool
             # whose live blocks it walks by hand (its table body reads
             # every entry of every slot: no gain over views), with
-            # weights that sit on one device.  A model whose step runs a
-            # recurrence over slot state may have a kernel for THAT
-            # (`state_kernel`, ISSUE 34): the same rule, the same flag
+            # weights that sit on one device.  A model may have a kernel
+            # for its OWN step (`PagedModel.step_kernel`: a recurrence
+            # over slot state, ISSUE 34; its own walk of the pool,
+            # ISSUE 39): the same rule, the same flag
             on_tpu = jax.default_backend() == "tpu"
             walks = self._model.walks(config, self.kv_int8, not on_tpu)
-            self._state_kernel = self._model.state_kernel is not None \
-                and self._model.state_kernel(config, not on_tpu)
+            self._model_kernel = self._model.step_kernel is not None \
+                and self._model.step_kernel(config, not on_tpu)
             self.paged_kernel = ATTENTION_IMPL == "paged_kernel"
             self.step_kernel = self.paged_kernel or (
                 ATTENTION_IMPL is None and not self.speculate_k
-                and on_tpu and (walks == "kernel" or self._state_kernel)
+                and on_tpu and (walks == "kernel" or self._model_kernel)
                 and self._weights_on_one_device())
             # a step whose kernel walks each slot's own live blocks has
             # no width: the table goes in whole, ONE program a step
@@ -1777,7 +1778,9 @@ class ContinuousDecoder:
                 if self.speculate_k \
                 else _paged_step_for(config, self.step_kernel)
             if walks == "model":
-                how = "the model's own reads of the pool"
+                how = "the model's own reads of the pool" + (
+                    " (its step's pallas kernels)"
+                    if self.step_kernel and self._model_kernel else "")
             elif self._walks_live:
                 how = "the paged kernel, each slot's live blocks"
             else:
@@ -1790,7 +1793,7 @@ class ContinuousDecoder:
                     "decode step's recurrence over slot state runs as %s",
                     "the pallas kernel, the state of the slots that "
                     "decode once in and once out"
-                    if self.step_kernel and self._state_kernel
+                    if self.step_kernel and self._model_kernel
                     else "XLA's program over every slot's state")
             from .serving_paged import run_write_form
             self.logger.info(

@@ -110,11 +110,11 @@ class PagedModel:
         reads the pool itself in every program, kernel or not (the step
         then gathers no views and has one width), None where neither
         does and the step attends gathered views
-    state_kernel(config, interpret) -> whether the step's recurrence
-        over the model's slot state has a pallas form at this geometry
-        (ISSUE 34); None where the model has none.  Like "kernel" above
-        it is the decoder that decides, and step_attention's `kernel`
-        that says so
+    step_kernel(config, interpret) -> whether the model's OWN step has
+        a pallas form at this geometry: a recurrence over its slot state
+        (ISSUE 34), its own walk of the pool (ISSUE 39); None where the
+        model has none.  Like "kernel" above it is the decoder that
+        decides, and step_attention's `kernel` that says so
     counters: names of the step's counts, added to decoder.stats
     supports: the serving paths this model's pool is carried through;
         the decoder refuses the others at construction
@@ -147,7 +147,7 @@ class PagedModel:
     extend_prepare: object
     extend_layer: object
     walks: object
-    state_kernel: object = None
+    step_kernel: object = None
     counters: tuple = ()
     supports: frozenset = frozenset()
     block_multiple: int = 1

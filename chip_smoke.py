@@ -994,7 +994,8 @@ def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
                      clock: CompileClock) -> None:
     """models/sparse_gqa.py through the same decoder: K, V and an indexer
     key a token in three pool leaves, every live position scored, the
-    exact top of them chosen and those single rows gathered in the step;
+    exact top of them chosen as a mask and the slot's live blocks attended
+    under it in the step (on the chip: the walk of ops/paged_attention);
     a chunk's queries each choosing their own positions of the prefix."""
     import jax.numpy as jnp
     import numpy as np
@@ -1025,8 +1026,12 @@ def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
         prefill_chunk=own["prefill_chunk"],
         prefill_budget=own["prefill_chunk"],
         steps_per_sync=shape["steps_per_sync"], name="sparse_gqa")
-    require(decoder._walks_live and not decoder.step_kernel,
-            "sparse_gqa: the model reads its pool itself, kernel or not")
+    # told nothing, the step attends through the masked walk on the chip
+    # at the published head of 128, and the plain form in the rehearsal
+    require(decoder._walks_live and decoder.step_kernel == (
+        on_chip and config.head_dim % 128 == 0),
+        f"sparse_gqa: step_kernel {decoder.step_kernel} at a head of "
+        f"{config.head_dim}, on the chip {on_chip}")
     cold = timed_serve("first pass", decoder, requests, clock)
     warm = timed_serve("second pass (every slot reused)", decoder,
                        requests, clock)
@@ -1037,10 +1042,14 @@ def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
     require(0 < stats["dsa_positions_attended"] <
             stats["dsa_positions_live"],
             "the selection attended everything, or nothing")
+    # a slot-step attends at most the round's own rows beside what it
+    # read of the pool, chosen or not
     require(0 < stats["dsa_slot_steps_dense"] and
-            stats["dsa_rows_fetched"] <= stats["dsa_positions_attended"],
-            "no slot-step attended all it held, or rows were fetched that "
-            "nobody attended")
+            stats["dsa_rows_fetched"] >= stats["dsa_positions_attended"] -
+            shape["steps_per_sync"] * config.num_layers *
+            stats["tokens_decode"],
+            "no slot-step attended all it held, or positions were attended "
+            "that the step did not read")
     layers = config.num_layers
     require(len(pool.k_pools) == layers and len(pool.v_pools) == 2 * layers
             and pool.block_nbytes == decoder.kv_block * layers * int(
@@ -1052,7 +1061,7 @@ def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
         f"{pool.v_pools[layers - 1].shape} {pool.v_pools[-1].shape}; "
         f"attended {stats['dsa_positions_attended']} of "
         f"{stats['dsa_positions_live']} live positions, "
-        f"{stats['dsa_rows_fetched']} fetched from the pool, "
+        f"{stats['dsa_rows_fetched']} read of the pool, "
         f"{stats['dsa_slot_steps_dense']} slot-steps attended all; pairs "
         f"here {stats['moe_pairs_here']} of {stats['moe_pairs_routed']}, "
         f"experts hit {stats['moe_experts_hit']} over "

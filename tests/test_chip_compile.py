@@ -783,14 +783,30 @@ def _leaf_shapes(config, serve):
 
 def test_sparse_gqa_step_selects_exactly_and_copies_no_leaf(chip,
                                                             monkeypatch):
-    """The whole 12-layer `jit_step` x 4 of `long_ctx_open_loop`: it fits
-    the chip beside its pool; the top 2,048 are a SORT a layer (exact:
-    no approximate top-k), apart from the scores; the chosen rows are
-    gathered from the K and V leaves seen as rows, no leaf is copied, and
-    the three leaves of every layer are merged in place."""
+    """The whole 12-layer `jit_step` x 4 of `long_ctx_open_loop` as the
+    cell's decoder builds it on the chip (`step_kernel`, ISSUE 39): it fits
+    the chip beside its pool; a layer's attention is ONE Pallas call under
+    `aiko.attn_core` (the walk, the chosen positions its mask) and no K or
+    V leaf is gathered, as rows or otherwise; the choice is exact and has
+    no sort (the sorts left are the router's and the one for the order in
+    which the slots that decode are taken); no leaf is copied, and the
+    three leaves of every layer are merged in place."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
     from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import sparse_gqa as M
+    from aiko_services_tpu.serving import ContinuousDecoder
     config, serve, params, k_pools, v_pools, shaped = _sparse_gqa_cell(chip)
+    # who decides: a decoder told nothing, at this model's head of 128
+    small = dataclasses.replace(
+        M.SPARSE_GQA_PRESETS["tiny"], head_dim=config.head_dim,
+        mrope_section=config.mrope_section)
+    decoder = ContinuousDecoder(
+        M.sparse_gqa_init(jax.random.PRNGKey(0), small), small,
+        paged_kv=True, kv_block=8, max_slots=2, max_seq=64, prefill_chunk=32,
+        name="sparse-gqa-described")
+    assert decoder.step_kernel and decoder._walks_live
+    assert decoder._attend_widths == (64,)
     slots = serve["max_slots"]
     table = -(-(serve["max_seq"] + serve["steps_per_sync"])
               // serve["kv_block"])
@@ -801,15 +817,23 @@ def test_sparse_gqa_step_selects_exactly_and_copies_no_leaf(chip,
         num_steps=serve["steps_per_sync"], eos=-1,
         t_cap=serve["max_seq"]).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and "ApproxTopK" not in text
+    walks = re.findall(r"custom-call\([^\n]*tpu_custom_call[^\n]*", text)
+    assert len(walks) == config.num_layers
+    assert all("aiko.attn_core" in walk for walk in walks)
+    assert "ApproxTopK" not in text
+    # what is gathered of a K or V leaf is the merge's whole blocks; a
+    # leaf seen as rows gave single rows of a head's lanes
+    row = "slice_sizes={1,%d}" % config.head_dim
+    assert row not in text and " gather(" in text
     for leaf in _leaf_shapes(config, serve):
         result = re.escape("[" + ",".join(map(str, leaf)) + "]")
         assert re.findall(rf"= \w+{result}\S* copy\(.*", text) == []
-    # a sort a layer for the positions, one for the router's eight, and
-    # ONE for the order in which the slots that decode are taken (every
-    # layer's is the same: the compiler keeps one)
-    assert len(re.findall(r" sort\(", text)) == 2 * config.num_layers + 1
-    assert len(re.findall(r"aiko\.dsa_select/[\w()]*top_k", text)) > 0
+    # a sort a layer for the router's eight, and ONE for the order in
+    # which the slots that decode are taken (every layer's is the same:
+    # the compiler keeps one); the positions are chosen without one
+    assert len(re.findall(r" sort\(", text)) == config.num_layers + 1
+    assert not re.findall(r"aiko\.dsa_select/[^\n\"]*(top_k|sort)", text)
+    assert "aiko.dsa_select" in text
     merges = re.findall(r"fusion\([^\n]*aiko\.kv_merge/scatter", text)
     assert len(merges) == 3 * config.num_layers
     memory = compiled.memory_analysis()

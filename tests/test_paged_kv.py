@@ -396,6 +396,101 @@ def test_walk_positions_are_whole_live_blocks():
     assert not walks_live_blocks(16, True, interpret=True)
 
 
+# The walk under a sparse selection (ISSUE 39): `chosen` masks positions of
+# the blocks a slot walks anyway.  Same sizes as above; a slot a case.
+
+MASKED_CASES = {            # slot -> (entry length, which positions chosen)
+    "empty": (0, "some"), "a-block": (8, "some"), "mid-chunk": (45, "some"),
+    "cap": (96, "some"), "everything": (45, "all"),
+    "nothing-of-the-pool": (45, "none"),
+    "without-its-last-chunk": (70, "not-from-64-on"),
+    "chosen-past-the-length": (45, "all-the-table")}
+
+
+@pytest.fixture(scope="module")
+def masked_walk():
+    """-> (the walk with `chosen`, the walk without, a float64 softmax over
+    the chosen live positions and the valid side rows), a slot a case of
+    MASKED_CASES, the side rows partly valid (another part a slot).  Every
+    dead cell of K and V, chosen or not, holds NaN."""
+    from aiko_services_tpu.ops import paged_attention
+    slots, block, nb = len(MASKED_CASES), WALK_BLOCK, WALK_TABLE
+    heads, groups, dim, side = 2, 2, 16, 4
+    keys = jax.random.split(jax.random.PRNGKey(39), 6)
+    rng = np.random.default_rng(39)
+    entry = np.asarray([case[0] for case in MASKED_CASES.values()], np.int32)
+    at = np.arange(nb * block)[None]
+    chosen = np.stack([
+        {"some": rng.random(nb * block) < 0.3, "all": at[0] < length,
+         "none": at[0] < 0, "not-from-64-on": at[0] < 64,
+         "all-the-table": at[0] >= 0}[kind]
+        for length, kind in MASKED_CASES.values()])
+    tables = 1 + rng.permutation(slots * nb).astype(np.int32).reshape(
+        slots, nb)
+    live = np.zeros((slots * nb + 1, block), bool)
+    live[tables] = (at < entry[:, None]).reshape(slots, nb, block)
+    pools = [np.where(live[:, None, :, None], np.asarray(jax.random.normal(
+        key, (slots * nb + 1, heads, block, dim))), np.nan)
+        for key in keys[:2]]
+    q = np.asarray(jax.random.normal(keys[2], (slots, heads, groups, dim)))
+    sides = [np.asarray(jax.random.normal(key, (slots, heads, side, dim)))
+             for key in keys[3:5]]
+    valid = rng.random((slots, 1, side)) < 0.5
+    valid[:, 0, 0] = True               # a token always attends itself
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(paged_attention, "_CHUNK", WALK_CHUNK)
+        masked, plain = (np.asarray(paged_attention.paged_decode_attention(
+            *map(jnp.asarray, (q, *pools, tables, *sides, valid, entry)),
+            groups=groups, interpret=True, chosen=mask))
+            for mask in (jnp.asarray(chosen), None))
+    theirs = np.zeros(q.shape)
+    for s in range(slots):
+        keep = np.concatenate([chosen[s] & (at[0] < entry[s]), valid[s, 0]])
+        rows = [np.concatenate([
+            pool[tables[s]].transpose(1, 0, 2, 3).reshape(heads, -1, dim),
+            side_rows[s]], axis=1)[:, keep].astype(np.float64)
+            for pool, side_rows in zip(pools, sides)]
+        scores = np.einsum("hgd,hpd->hgp", q[s], rows[0]) / np.sqrt(dim)
+        weights = np.exp(scores - scores.max(-1, keepdims=True))
+        theirs[s] = np.einsum("hgp,hpd->hgd",
+                              weights / weights.sum(-1, keepdims=True),
+                              rows[1])
+    return masked, plain, theirs
+
+
+@pytest.mark.parametrize("case", list(MASKED_CASES))
+def test_masked_walk_attends_the_chosen_live_positions_alone(masked_walk,
+                                                             case):
+    masked, plain, theirs = masked_walk
+    slot = list(MASKED_CASES).index(case)
+    assert np.isfinite(masked[slot]).all()
+    np.testing.assert_allclose(masked[slot], theirs[slot], rtol=2e-5,
+                               atol=2e-6)
+    # a mask of everything live, or of the whole table, is no mask: the
+    # same bits as the call that has none; any other is another result
+    same = (masked[slot] == plain[slot]).all()
+    assert same == (MASKED_CASES[case][1] in ("all", "all-the-table")
+                    or MASKED_CASES[case][0] == 0)
+
+
+def test_the_table_body_refuses_a_mask_by_name():
+    """A head of 64 compiled for a chip, or an int8 pool anywhere, goes
+    through the body that follows the table: it takes no `chosen`."""
+    from aiko_services_tpu.ops.paged_attention import paged_decode_attention
+    pool = jnp.zeros((5, 2, WALK_BLOCK, 64))
+    side = jnp.zeros((1, 2, 4, 64))
+    with pytest.raises(ValueError, match="`chosen`.*the table body"):
+        paged_decode_attention(
+            jnp.zeros((1, 2, 2, 64)), pool, pool, jnp.ones((1, 4), jnp.int32),
+            side, side, jnp.ones((1, 1, 4), bool), jnp.asarray([9]),
+            groups=2, interpret=False, chosen=jnp.ones((1, 32), bool))
+    with pytest.raises(ValueError, match="names 40 positions"):
+        paged_decode_attention(
+            jnp.zeros((1, 2, 2, 64)), pool, pool, jnp.ones((1, 4), jnp.int32),
+            side, side, jnp.ones((1, 1, 4), bool), jnp.asarray([9]),
+            groups=2, interpret=True, chosen=jnp.ones((1, 40), bool))
+
+
 @pytest.mark.parametrize("kwargs", [{}, {"prefill_chunk": 16}],
                          ids=["bucketed", "chunked"])
 def test_ragged_lengths_in_one_round_keep_token_identity(

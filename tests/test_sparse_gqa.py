@@ -14,6 +14,7 @@
 # tolerance states its reason.
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -31,6 +32,7 @@ import aiko_services_tpu.serving as serving  # noqa: E402
 from aiko_services_tpu import serving_paged  # noqa: E402
 from aiko_services_tpu.models import latent_moe  # noqa: E402
 from aiko_services_tpu.models import sparse_gqa as M  # noqa: E402
+from aiko_services_tpu.ops.paged_attention import walk_positions  # noqa: E402
 from aiko_services_tpu.serving import ContinuousDecoder  # noqa: E402
 from aiko_services_tpu.serving_paged import BlockPool  # noqa: E402
 from benchmark import ops_bytes_sparse_gqa as ops  # noqa: E402
@@ -191,9 +193,9 @@ def test_top_positions_is_exact_with_ties_to_the_lower_index(case):
 
 
 def test_program_and_reference_choose_the_same_positions(params):
-    """The block's choice (a bit-by-bit threshold) and the reference's (a
-    sort's) over the same scores, ties made on purpose, and the step's
-    stable top-k: one set."""
+    """The program's choice (a bit-by-bit threshold, in a block and in the
+    step alike) and the reference's (a sort's) over the same scores, ties
+    made on purpose: one set, and a stable sort's."""
     rng = np.random.default_rng(5)
     scores = (np.round(rng.standard_normal((40, 40)) * 3) / 3 + 0.0).astype(
         np.float32)                     # + 0.0: one zero, as `_scores` makes
@@ -203,10 +205,121 @@ def test_program_and_reference_choose_the_same_positions(params):
     ours = np.asarray(M.top_positions(jnp.asarray(masked), 16)) & causal
     assert (ours == theirs).all()
     assert (theirs.sum(-1) == np.minimum(np.arange(40) + 1, 16)).all()
-    _, picked = jax.lax.top_k(jnp.asarray(masked), 16)
-    step = np.zeros((40, 40), bool)
-    np.put_along_axis(step, np.asarray(picked), True, axis=-1)
-    assert ((step & causal) == theirs).all()
+    assert ((_stable_top(masked, 16) & causal) == theirs).all()
+
+
+# -- the step's attention alone: a mask, then the walk or the plain form ----------
+
+STEP_SLOTS = {      # slot -> (entry length, the round's tokens so far, decodes)
+    "idle": (31, 0, False), "fresh": (0, 2, True), "under": (9, 1, True),
+    "at-the-limit": (14, 1, True), "one-over": (14, 2, True),
+    "long": (45, 2, True)}
+
+
+@pytest.fixture(scope="module")
+def step_inputs():
+    """One layer's leaves, tables, sides and queries for STEP_SLOTS, at
+    the tiny preset: blocks of 8, a table of 6, a round of 4 steps, the
+    step at its third token (step_index 2); `live` says which cells of the
+    pool hold a position of a slot that decodes."""
+    config = model_config()
+    slots, block, nb, steps = len(STEP_SLOTS), 8, 6, 4
+    keys = jax.random.split(jax.random.PRNGKey(39), 8)
+    entry = jnp.asarray([case[0] for case in STEP_SLOTS.values()], jnp.int32)
+    taken = jnp.asarray([case[1] for case in STEP_SLOTS.values()], jnp.int32)
+    active = jnp.asarray([case[2] for case in STEP_SLOTS.values()])
+    tables = 1 + jax.random.permutation(
+        keys[0], slots * nb).astype(jnp.int32).reshape(slots, nb)
+    live = jnp.zeros((slots * nb + 1, block), bool).at[tables].set(
+        (jnp.arange(nb * block)[None] <
+         jnp.where(active, entry, 0)[:, None]).reshape(slots, nb, block))
+    leaves = [jax.random.normal(key, (slots * nb + 1, heads, block, lanes))
+              for key, (heads, lanes) in zip(keys[1:4], config.cache_leaves)]
+    sides = [jax.random.normal(key, (slots, heads, steps, lanes))
+             for key, (heads, lanes) in zip(keys[4:7], config.cache_leaves)]
+    q, q_i = (jax.random.normal(key, (slots, heads, 1, lanes))
+              for key, heads, lanes in (
+                  (keys[7], config.num_heads, config.head_dim),
+                  (keys[0], config.index_heads, config.index_dim)))
+    return (config, leaves, live[:, None, :, None], tables, sides, q, q_i,
+            entry, entry + taken, active)
+
+
+def _plain_softmax(config, leaves, tables, sides, q, chosen):
+    """float64 softmax of every slot's heads over the positions `chosen`
+    [S, the table's ++ the round's] names, each read where it lies."""
+    out = np.zeros(q.shape[:2] + q.shape[3:])
+    group = config.num_heads // config.num_kv_heads
+    for s in range(q.shape[0]):
+        rows = [np.concatenate([
+            np.asarray(leaf)[np.asarray(tables[s])].transpose(
+                1, 0, 2, 3).reshape(leaf.shape[1], -1, leaf.shape[3]),
+            np.asarray(side[s])], axis=1)[:, chosen[s]].astype(np.float64)
+            for leaf, side in zip(leaves[:2], sides[:2])]
+        for h in range(config.num_heads):
+            k, v = rows[0][h // group], rows[1][h // group]
+            scores = k @ np.asarray(q[s, h, 0], np.float64) * \
+                config.softmax_scale
+            w = np.exp(scores - scores.max())
+            out[s, h] = (w / w.sum()) @ v
+    return out
+
+
+@pytest.mark.parametrize("scores", ["random", "all-tied"])
+@pytest.mark.parametrize("form", ["plain", "plain-in-pieces", "kernel"])
+def test_the_step_attends_the_chosen_positions_as_a_mask(
+        step_inputs, form, scores, monkeypatch):
+    """Both forms of the step against a plain softmax over the positions a
+    stable sort of the same index scores takes: slots under `topk` 16, at
+    it (14 in the pool, the round's one before and the token's own), one
+    over, far over, one with nothing in the pool yet and one that decodes
+    nothing.  With every
+    indexer weight zero ALL scores tie at the limit's edge, and the 16
+    lowest positions are the set.  Every dead cell of K and V (the rest
+    of a last live block, the blocks past it, all of a slot that decodes
+    nothing) holds NaN for the kernel, which zeroes what it did not walk,
+    and a large finite value for the plain form, whose weights there are
+    exact zeros as an extend's are; read in pieces of 32 positions the
+    table of 48 ends mid-piece.  The counts are the same either way but
+    for what was read of the pool."""
+    kernel, span = form == "kernel", 32 if form == "plain-in-pieces" else 48
+    monkeypatch.setattr(M, "_PREFIX_PIECE", span)
+    config, leaves, held_live, tables, sides, q, q_i, entry, lengths, \
+        active = step_inputs
+    dead = jnp.nan if kernel else 1e4
+    leaves = [jnp.where(held_live, leaf, dead) for leaf in leaves[:2]] + \
+        [jnp.where(held_live, leaves[2], 0.0)]
+    weights = jax.random.normal(jax.random.PRNGKey(5), (len(STEP_SLOTS), 1,
+                                                        config.index_heads))
+    if scores == "all-tied":
+        weights = jnp.zeros_like(weights)
+    held, steps = tables.shape[1] * 8, 4
+    # a function of its own a case: the piece is read as it is traced
+    out, counted = jax.jit(functools.partial(M._attend_slots, config, kernel))(
+        leaves, tables, sides, q, q_i, weights, entry, lengths, 2, active)
+    keys = np.concatenate([
+        np.asarray(leaves[2])[np.asarray(tables)][:, :, 0].reshape(
+            len(STEP_SLOTS), held, -1), np.asarray(sides[2][:, 0])], axis=1)
+    index = np.asarray(M._scores(config, q_i, weights, jnp.asarray(keys)))[:, 0]
+    at = np.arange(held + steps)[None]
+    visible = np.where(at < held, at < np.asarray(entry)[:, None],
+                       (at - held <= 2) & (np.asarray(entry)[:, None] + at -
+                                           held <= np.asarray(lengths)[:, None]))
+    chosen = _stable_top(np.where(visible, index, -np.inf), 16) & visible
+    live = np.asarray(active)
+    assert chosen[live].sum(1).tolist() == [3, 11, 16, 16, 16]
+    if scores == "all-tied":            # the lowest positions, not the round's
+        assert chosen[-1, :16].all()
+    theirs = _plain_softmax(config, leaves, tables, sides, q, chosen)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out)[live], theirs[live],
+                               rtol=2e-5, atol=2e-6)
+    walked = np.where(live, np.asarray(entry), 0)
+    read = walk_positions(walked, 8).sum() if kernel else \
+        live.sum() * -(-walked.max() // span) * span
+    assert np.asarray(counted).tolist() == [
+        (np.asarray(lengths) + 1)[live].sum(), chosen[live].sum(), read,
+        (live & (np.asarray(lengths) + 1 <= 16)).sum()]
 
 
 # -- through the decoder: admit, chunked extend, decode through the pool ---------
@@ -218,9 +331,15 @@ def decoder_for(params, name, buckets=(8, 32), chunk=32, slots=4, **kwargs):
         prefill_budget=chunk, steps_per_sync=4, name=name, **kwargs)
 
 
-def serve(params, requests, name="sparse-gqa", **kwargs):
-    decoder = decoder_for(params, name, **kwargs)
-    assert decoder._walks_live and not decoder.step_kernel
+def serve(params, requests, name="sparse-gqa", kernel=False, **kwargs):
+    """`kernel`: the step as a chip's decoder builds it (the walk with the
+    chosen positions as its mask, here in the interpreter), asked for by
+    name as off a chip it must be; else the plain form."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(serving, "ATTENTION_IMPL",
+                      "paged_kernel" if kernel else None)
+        decoder = decoder_for(params, name, **kwargs)
+    assert decoder._walks_live and decoder.step_kernel is kernel
     served = {}
     for rid, (prompt, new) in requests.items():
         assert decoder.submit(rid, prompt, new, lambda rid, tokens:
@@ -247,7 +366,21 @@ def served_gaps(requests, served):
     return out
 
 
-def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(params):
+def _pool_reads(prompt: int, new: int, kernel: bool) -> int:
+    """What one layer's steps read of the pool for a request of `prompt`
+    tokens that decodes `new` - 1 times in rounds of four steps: a round's
+    steps walk the blocks of 8 that were live as the round began (the
+    kernel), or gather the one piece that a table of 128 positions is."""
+    steps = new - 1
+    return sum(
+        min(4, steps - first) *
+        (int(walk_positions(np.int32(prompt + first), 8)) if kernel else 128)
+        for first in range(0, steps, 4))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(
+        params, kernel):
     """Seven requests over four slots: prompts of 10 and 30 go in by one
     padded admit, 5 and 3 by a narrow one, 45 and 77 by chains of 32-token
     extends whose last chunk is padded, 64 by two whole chunks; three wait
@@ -259,7 +392,8 @@ def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(params):
     rng = np.random.default_rng(7)
     requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 11)
                 for n in (10, 45, 77, 5, 30, 64, 3)}
-    served, decoder = serve(params, requests)
+    served, decoder = serve(params, requests, name=f"sparse-gqa-{kernel}",
+                            kernel=kernel)
     stats = decoder.stats
     assert stats["prefill_chunks"] == 7 and stats["prefills"] == 4
     for rid, gap in served_gaps(requests, served).items():
@@ -270,8 +404,13 @@ def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(params):
     # what was attended: everything up to 16 positions, 16 past them
     assert 0 < stats["dsa_positions_attended"] < \
         0.6 * stats["dsa_positions_live"]
-    # the round's own rows are attended and fetched from nowhere
-    assert 0 < stats["dsa_rows_fetched"] < stats["dsa_positions_attended"]
+    # what the steps READ of the pool to attend that: every live block of
+    # a slot that decodes (the kernel), the table's one piece (plain);
+    # the round's own rows come from nowhere
+    assert stats["dsa_rows_fetched"] == 2 * sum(
+        _pool_reads(len(prompt), new, kernel)
+        for prompt, new in requests.values())
+    assert stats["dsa_rows_fetched"] > stats["dsa_positions_attended"]
     # r3's ten steps, r5's ten and r10's six (positions 10 to 15), in
     # each of two layers (a prompt's first token comes from its prefill)
     assert stats["dsa_slot_steps_dense"] == 2 * (10 + 10 + 6)
