@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.kda_step import kda_live_step, moves_live_states
+from . import delta_rule
 from . import layers as L
 from .latent_moe import (MOE_COUNTERS, absorb_output, absorb_queries,
                          moe_ffn, swiglu)
@@ -84,8 +85,6 @@ HYBRID_COUNTERS = MOE_COUNTERS + _DSA_COUNTERS + ("kda_states_moved",
 _OWN_COUNTERS = len(HYBRID_COUNTERS) - len(MOE_COUNTERS)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-_KDA_CHUNK = 64        # tokens a WY block
-_KDA_SUB = 16          # tokens whose decays are taken pair by pair
 _PREFIX_PIECE = 512    # positions of the prefix an extend attends at once
 _TILE_ROWS = 8         # rows of a leaf that lie together in the chip's memory
 
@@ -414,108 +413,11 @@ def _kda_output(kda, config: HybridSparseConfig, out, gate, dtype):
                     (normed.reshape(a, t, -1) * gate).astype(dtype))
 
 
-def kda_recurrent(q, k, v, g, beta, state):
-    """ONE token of the gated delta rule: q, k, v, g [A, H, D], beta
-    [A, H], state [A, H, D, D] f32 -> (o [A, H, D], the new state).  A
-    row with g = 0 and beta = 0 keeps its state.  As written S is read
-    once for both products and written once; the program XLA makes of
-    it for the chip passes over EVERY row's state, live or not, some
-    three times (two fused reads and a write).  So the decode step on a
-    TPU takes ops.kda_step.kda_live_step where the head is whole lanes
-    (`_step_attention`), which moves the live slots' state once in and
-    once out; this stays the form of every other backend and width (the
-    CPU's tests, the `tiny` preset's head of 16), an admit's or a
-    chunk's lone token, and the kernel's oracle."""
-    decayed = state * jnp.exp(g)[..., None]
-    seen = jnp.einsum("ahd,ahdv->ahv", k, decayed, precision=_HIGHEST)
-    asked = jnp.einsum("ahd,ahdv->ahv", q, decayed, precision=_HIGHEST)
-    write = beta[..., None] * (v - seen)
-    out = asked + (q * k).sum(axis=-1, keepdims=True) * write
-    return out, decayed + k[..., None] * write[..., None, :]
-
-
-# the largest exponent a key's scaling may take: a sub-block's own
-# cumulative decay, _KDA_SUB tokens at the lower bound of -5 a token
-_KDA_EXP_CAP = 80.0
-
-
-def _pair_products(x, k, cumulative, sub: int, strict: bool):
-    """P[t, s] = sum_c x_t[c] k_s[c] exp(G_t[c] - G_s[c]) for s <= t (s < t
-    where `strict`), 0 elsewhere, over chunks [..., C, D], as ONE product
-    a sub-block of `sub` queries: both sides are scaled to the cumulative
-    decay B just before the queries' sub-block, the queries by exp(G_t -
-    B) <= 1 and a key by exp(B - G_s), which is <= 1 for every earlier
-    sub-block and at most exp(5 x sub) inside the queries' own (float32
-    holds e^80; later keys are capped and masked)."""
-    c, d = x.shape[-2:]
-    blocks = c // sub
-    lead = x.shape[:-2]
-    xs = x.reshape(lead + (blocks, sub, d))
-    gs = cumulative.reshape(lead + (blocks, sub, d))
-    before = jnp.concatenate(
-        [jnp.zeros(lead + (1, d), cumulative.dtype),
-         gs[..., :-1, -1, :]], axis=-2)                      # [.., blocks, D]
-    queries = xs * jnp.exp(gs - before[..., None, :])
-    keys = k[..., None, :, :] * jnp.exp(jnp.minimum(
-        before[..., :, None, :] - cumulative[..., None, :, :],
-        _KDA_EXP_CAP))                                       # [.., blocks, C, D]
-    pairs = jnp.einsum("...imd,...isd->...ims", queries, keys,
-                       precision=_HIGHEST).reshape(lead + (c, c))
-    order = jnp.arange(c)
-    keep = order[:, None] > order[None, :] if strict \
-        else order[:, None] >= order[None, :]
-    return jnp.where(keep, pairs, 0.0)
-
-
-def kda_chunked(q, k, v, g, beta, state, chunk: int = _KDA_CHUNK,
-                sub: int = _KDA_SUB):
-    """The gated delta rule over T tokens in its chunked (WY) form: q, k,
-    v, g [A, T, H, D] f32, beta [A, T, H], state [A, H, D, D] f32 ->
-    (o [A, T, H, D], the state after the last token).  Equals T calls
-    of kda_recurrent.  A position with beta = 0 and g = 0 changes
-    nothing.  T is padded to whole chunks with such positions."""
-    a, t, heads, d = k.shape
-    c = min(chunk, -(-t // sub) * sub) if t > sub else t
-    m = min(sub, c)
-    pad = -t % c
-    if pad:
-        q, k, v, g = (jnp.pad(z, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                      for z in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    n = (t + pad) // c
-
-    def split(z):                       # [A, T, H, *] -> [N, A, H, C, *]
-        return z.reshape(a, n, c, heads, -1).transpose(1, 0, 3, 2, 4)
-
-    q, k, v, g = split(q), split(k), split(v), split(g)
-    beta = split(beta[..., None])                            # [N, A, H, C, 1]
-    total = jnp.cumsum(g, axis=-2)                           # G_t, inclusive
-    kk = _pair_products(k, k, total, m, strict=True)
-    qk = _pair_products(q, k, total, m, strict=False)
-    system = jnp.eye(c, dtype=jnp.float32) + beta * kk
-    solved = jax.scipy.linalg.solve_triangular(
-        system, beta * jnp.concatenate([v, k * jnp.exp(total)], axis=-1),
-        lower=True, unit_diagonal=True)
-    writes, reads = solved[..., :d], solved[..., d:]
-    asks = q * jnp.exp(total)
-    last = total[..., -1:, :]                                # G_C
-    keeps = k * jnp.exp(last - total)
-
-    def one(state, xs):
-        writes, reads, asks, qk, keeps, last = xs
-        u = writes - jnp.einsum("ahcd,ahdv->ahcv", reads, state,
-                                precision=_HIGHEST)
-        out = jnp.einsum("ahcd,ahdv->ahcv", asks, state,
-                         precision=_HIGHEST) + \
-            jnp.einsum("ahcs,ahsv->ahcv", qk, u, precision=_HIGHEST)
-        state = state * jnp.exp(last)[..., 0, :, None] + \
-            jnp.einsum("ahcd,ahcv->ahdv", keeps, u, precision=_HIGHEST)
-        return state, out
-
-    state, out = jax.lax.scan(one, state,
-                              (writes, reads, asks, qk, keeps, last))
-    out = out.transpose(1, 0, 3, 2, 4).reshape(a, n * c, heads, d)
-    return out[:, :t], state
+# the gated delta rule itself, token by token and chunked, is
+# models/delta_rule.py's (a second model shares it, ISSUE 40): here a gate
+# a CHANNEL, square heads
+kda_recurrent = delta_rule.recurrent
+kda_chunked = delta_rule.chunked
 
 
 def _kda_block(layer, config: HybridSparseConfig, x, state, live,

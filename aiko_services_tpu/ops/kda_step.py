@@ -1,5 +1,7 @@
-# One token of the gated delta rule (Kimi Delta Attention, arXiv:2510.26692)
-# over the state of the slots that DECODE, as a pallas TPU kernel (ISSUE 34).
+# One token of the gated delta rule (Kimi Delta Attention, arXiv:2510.26692;
+# Gated DeltaNet, arXiv:2412.06464) over the state of the slots that DECODE,
+# as a pallas TPU kernel (ISSUE 34; a head of unequal sides and one gate a
+# head, ISSUE 40: further down).
 #
 # A KDA layer keeps, for every slot of the decoder, a state S [H, D, D] in
 # float32 (4 MB a slot at 64 heads of 128; 134 MB a layer at 32 slots).  A
@@ -8,8 +10,8 @@
 #     S' = diag(exp g) S;   write = beta (v - k^T S');   S <- S' + k write^T
 #     o  = q^T S' + (q . k) write                                  (= q^T S)
 #
-# models/hybrid_sparse.kda_recurrent says this in four lines and stays the
-# oracle.  The program XLA makes of it for the chip passes over EVERY
+# models/delta_rule.recurrent (hybrid_sparse.kda_recurrent) says this in
+# four lines and stays the oracle.  The program XLA makes of it for the chip passes over EVERY
 # slot's state three times (two fused reads and a write, a multiply by
 # exp(0) and a zero write for a slot that decodes nothing): 2.5 ms of a
 # 9.6 ms step where a quarter of the slots decode (PERF.md §6, PR 34).
@@ -51,12 +53,36 @@
 # over the key axis; no matrix unit, so no bfloat16 pass): what differs
 # from the oracle is the order of the 128-term sums.
 #
+# A head need not be square: S is [Dk (key), Dv (value)], the vectors g, k
+# and q have Dk lanes, v and o have Dv (ISSUE 40).  On the chip both sides
+# are whole lanes and a tile's heads whole sublanes (`moves_live_states`).
+#
+# ONE GATE A HEAD (Gated DeltaNet, arXiv:2412.06464; ISSUE 40: 30 heads of
+# [96, 192]) takes a SIBLING body, `_head_kernel`, and not the one above,
+# for two reasons that are the geometry's.  A value side of 192 is no whole
+# number of lanes, so a head [96, 192] cannot be a tile of its own: the
+# state lies as [S, Dk, H x Dv], the heads SIDE BY SIDE on the lanes (30 x
+# 192 = 5,760 = 45 x 128: nothing padded, where [H, 96, 256] would pad a
+# third of every byte the step moves), and a head's boundary falls inside a
+# vector of 128 lanes.  And the decay is one number a head: there are no
+# [H, Dk] rows of g to lay beside k and q, exp(g), beta and q . k are
+# scalars in SMEM, spread over their head's lanes by a select.  What is
+# shared is everything around the arithmetic: the compaction of the live
+# slots' ids, the three-deep ring, the order of the copies, the aliasing.
+# A slot's whole state is ONE item (2.2 MB at the published widths:
+# `_SLOT_BYTES` bounds it), passed a GROUP of heads at a time, the fewest
+# whose lanes are whole vectors (two heads of 192: 384 lanes); k and q
+# arrive as COLUMNS [Dk, 128] a slot (k at lanes [0, 64), q at [64, 128): a
+# transpose XLA makes of [S, H, Dk], 49 KB a slot beside 2.2 MB), a head's
+# column read at a static lane and spread over the head's lanes.
+#
 # Validated where: tests/test_kda_step.py (interpreter, CPU: mixed, none
 # and all slots live, bits of the untouched states, four donated steps in
-# a while_loop); tests/test_chip_compile.py (the cell's whole step compiled
-# for a described v5e: four custom calls, no other operation on a state
-# leaf); chip_smoke.py's hybrid phase and the cell long_doc_open_loop on
-# the chip.
+# a while_loop, both bodies, unequal head sides); tests/test_chip_compile.py
+# (each cell's whole step compiled for a described v5e: one custom call a
+# recurrent layer, no other operation on a state leaf); chip_smoke.py's
+# hybrid and gated_delta phases and the cells long_doc_open_loop and
+# gdn_decode_saturated on the chip.
 
 from __future__ import annotations
 
@@ -77,22 +103,54 @@ _TILE_BYTES = 2 << 20
 _GROUP = 8
 
 
-def moves_live_states(heads: int, head_dim: int,
-                      interpret: bool = False) -> bool:
-    """Whether a state of `heads` heads [head_dim, head_dim] can take the
-    kernel: mosaic slices a tile out of an HBM operand only where its
-    minor axis is whole lanes, and lays a tile's rows into VMEM at whole
-    sublanes.  The interpreter has neither."""
-    return interpret or (head_dim % _LANES == 0 and
-                         _head_tile(heads, head_dim) % 8 == 0)
+# one gate a head: the state of a whole slot is one item of the ring, and
+# the ring, v and o of every slot (whole in VMEM: 1.5 MB each at 64 slots
+# of 5,760 lanes) have to fit what the call asks of VMEM (128 MiB a core)
+_SLOT_BYTES = 4 << 20
+_HEAD_VMEM_LIMIT = 48 << 20
 
 
-def _head_tile(heads: int, head_dim: int) -> int:
-    """Heads a tile: as many as divide `heads`, lay the tile's rows of
-    g, k and q one under the other in a block of 128 rows, and keep the
-    tile's state at _TILE_BYTES."""
-    most = max(1, min(_LANES // 3, _TILE_BYTES // (4 * head_dim * head_dim)))
+def moves_live_states(heads: int, head_dim: int, interpret: bool = False,
+                      value_dim: int | None = None,
+                      by_head: bool = False) -> bool:
+    """Whether a state of `heads` heads [head_dim (key), value_dim (value;
+    head_dim where None)] can take the kernel.  A gate a CHANNEL: mosaic
+    slices a tile out of an HBM operand only where its minor axis is whole
+    lanes (both sides of a head), and lays a tile's rows into VMEM at whole
+    sublanes (a tile of a multiple of 8 heads).  A gate a HEAD (`by_head`):
+    the heads lie side by side on the lanes, so a slot's `heads x
+    value_dim` lanes are whole vectors and so are a group's
+    (`_head_group`), the key side whole sublanes, k and q of every head
+    fit a vector's halves, and a slot's state is one item of the ring.
+    The interpreter has no tiles."""
+    value_dim = value_dim or head_dim
+    if interpret:
+        return True
+    if by_head:
+        return (heads * value_dim) % _LANES == 0 and head_dim % 8 == 0 \
+            and heads <= _LANES // 2 \
+            and heads % _head_group(heads, value_dim) == 0 \
+            and 4 * heads * head_dim * value_dim <= _SLOT_BYTES
+    return head_dim % _LANES == 0 and value_dim % _LANES == 0 and \
+        _head_tile(heads, head_dim, value_dim) % 8 == 0
+
+
+def _head_tile(heads: int, head_dim: int, value_dim: int | None = None) -> int:
+    """Heads a tile (a gate a channel): as many as divide `heads`, lay the
+    tile's rows of g, k and q one under the other in a block of 128 rows,
+    and keep the tile's state at _TILE_BYTES."""
+    most = max(1, min(_LANES // 3, _TILE_BYTES // (
+        4 * head_dim * (value_dim or head_dim))))
     return max(t for t in range(1, min(heads, most) + 1) if heads % t == 0)
+
+
+def _head_group(heads: int, value_dim: int) -> int:
+    """Heads a group (a gate a head): the fewest whose value lanes, side by
+    side, are whole vectors (two heads of 192: 384 lanes), or every head
+    where no such few divide `heads` (the interpreter's sizes)."""
+    import math
+    few = math.lcm(value_dim, _LANES) // value_dim
+    return few if heads % few == 0 else heads
 
 
 def _kernel(count_ref, ids_ref, beta_ref, qk_ref, g_hbm, k_hbm, q_hbm, v_hbm,
@@ -134,26 +192,7 @@ def _kernel(count_ref, ids_ref, beta_ref, qk_ref, g_hbm, k_hbm, q_hbm, v_hbm,
     # a slot that decodes nothing reads zeros, whatever VMEM held
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    @pl.when(items > 0)
-    def _():
-        for copy in arriving(0, 0):
-            copy.start()
-
-    def one(item, _):
-        at = jax.lax.rem(item, _RING)
-        ahead = jax.lax.rem(item + 1, _RING)
-
-        @pl.when(item + 1 < items)
-        def _():
-            @pl.when(item + 1 >= _RING)
-            def _():
-                leaving(item + 1 - _RING, ahead).wait()
-
-            for copy in arriving(item + 1, ahead):
-                copy.start()
-
-        for copy in arriving(item, at):
-            copy.wait()
+    def update(item, at):
         slot, first = where(item)
 
         def group(n, _):
@@ -183,6 +222,42 @@ def _kernel(count_ref, ids_ref, beta_ref, qk_ref, g_hbm, k_hbm, q_hbm, v_hbm,
             return 0
 
         jax.lax.fori_loop(0, ht // hg, group, 0)
+
+    _through_the_ring(items, arriving, leaving, update)
+
+
+def _through_the_ring(items, arriving, leaving, update) -> None:
+    """`items` items through the three-deep ring, one after the other:
+    start in(i + 1) (which first awaits out(i - 2), the ring slot it
+    fills), wait in(i), `update(i, ring slot)` in place, start out(i);
+    then the last outs are awaited.  `arriving(item, at)` are the copies
+    that bring item `item` into ring slot `at`, `leaving(item, at)` the one
+    that takes it back."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    @pl.when(items > 0)
+    def _():
+        for copy in arriving(0, 0):
+            copy.start()
+
+    def one(item, _):
+        at = jax.lax.rem(item, _RING)
+        ahead = jax.lax.rem(item + 1, _RING)
+
+        @pl.when(item + 1 < items)
+        def _():
+            @pl.when(item + 1 >= _RING)
+            def _():
+                leaving(item + 1 - _RING, ahead).wait()
+
+            for copy in arriving(item + 1, ahead):
+                copy.start()
+
+        for copy in arriving(item, at):
+            copy.wait()
+        update(item, at)
         leaving(item, at).start()
         return 0
 
@@ -198,17 +273,24 @@ def _kernel(count_ref, ids_ref, beta_ref, qk_ref, g_hbm, k_hbm, q_hbm, v_hbm,
 def kda_live_step(q, k, v, g, beta, state, active, *,
                   interpret: bool | None = None):
     """One token of the gated delta rule for the slots where `active`:
-    q, k, v, g [S, H, D] float32, beta [S, H], state [S, H, D, D] float32,
-    active [S] bool -> (o [S, H, D], the new state).  Where `active` is
+    q, k [S, H, Dk], v [S, H, Dv] float32, beta [S, H], active [S] bool,
+    and by the gate's grain
+        g [S, H, Dk] (a channel), state [S, H, Dk, Dv] float32, or
+        g [S, H] (a head), state [S, Dk, H x Dv] float32: the heads side
+        by side on the lanes (`heads_side_by_side` lays it so)
+    -> (o [S, H, Dv], the new state, laid as it came).  Where `active` is
     False the state comes back bit for bit (it is never touched: the
     result IS the argument's buffer, `input_output_aliases`) and o is
     zeros; g and beta of such a slot are not read.  Equals
-    hybrid_sparse.kda_recurrent on the live slots up to the order of
+    models/delta_rule.recurrent on the live slots up to the order of
     float32 sums.  interpret=None: compiled on a TPU, the interpreter
     elsewhere."""
     import jax
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if g.ndim == 2:
+        return _head_step_jit()(q, k, v, g, beta, state, active,
+                                interpret=interpret)
     # ONE jitted function for every KDA layer of a program: traced and
     # lowered to its mosaic module once (ops.paged_attention._attend_jit).
     # The tile is a static argument (a test that patches _TILE_BYTES gets
@@ -216,7 +298,8 @@ def kda_live_step(q, k, v, g, beta, state, active, *,
     # body is traced, so a sweep over them needs a new function each time
     return _step_jit()(q, k, v, g, beta, state, active,
                        interpret=interpret,
-                       head_tile=_head_tile(q.shape[1], q.shape[2]))
+                       head_tile=_head_tile(q.shape[1], q.shape[2],
+                                            v.shape[2]))
 
 
 @functools.cache
@@ -225,39 +308,45 @@ def _step_jit():
     return jax.jit(_step, static_argnames=("interpret", "head_tile"))
 
 
+def _live_ids(active):
+    """(the live slots' ids, ascending, then zeros; their count [1]): a
+    handful of compares (no sort, no scatter)."""
+    import jax.numpy as jnp
+    live = active.astype(jnp.int32)
+    order = jnp.arange(active.shape[0], dtype=jnp.int32)
+    rank = jnp.cumsum(live) - 1
+    ids = jnp.sum(jnp.where(
+        (rank[None, :] == order[:, None]) & active[None, :],
+        order[None, :], 0), axis=1)
+    return ids, live.sum()[None]
+
+
 def _step(q, k, v, g, beta, state, active, *, interpret: bool,
           head_tile: int):
-    """kda_live_step with every default resolved."""
+    """kda_live_step with every default resolved, a gate a channel."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     slots, heads, d = q.shape
+    dv = v.shape[2]
     ht = head_tile
-    # the live slots' ids, ascending, then zeros; a handful of compares
-    # (no sort, no scatter)
-    live = active.astype(jnp.int32)
-    order = jnp.arange(slots, dtype=jnp.int32)
-    rank = jnp.cumsum(live) - 1
-    ids = jnp.sum(jnp.where(
-        (rank[None, :] == order[:, None]) & active[None, :],
-        order[None, :], 0), axis=1)
-    count = live.sum()[None]
+    ids, count = _live_ids(active)
 
     scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out, state = pl.pallas_call(
         functools.partial(_kernel, head_tile=ht),
-        out_shape=(jax.ShapeDtypeStruct((slots, heads, d), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((slots, heads, dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
         in_specs=[scalars] * 4 + [in_hbm] * 5,
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM), in_hbm),
-        scratch_shapes=[pltpu.VMEM((_RING, ht, d, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_RING, ht, d, dv), jnp.float32),
                         pltpu.VMEM((_RING, _LANES, d), jnp.float32),
                         pltpu.VMEM((_LANES, d), jnp.float32),
                         pltpu.VMEM((d, _LANES), jnp.float32),
-                        pltpu.VMEM((_RING, ht, d), jnp.float32),
+                        pltpu.VMEM((_RING, ht, dv), jnp.float32),
                         pltpu.SemaphoreType.DMA((_RING,)),
                         pltpu.SemaphoreType.DMA((_RING,))],
         input_output_aliases={8: 1},
@@ -266,3 +355,130 @@ def _step(q, k, v, g, beta, state, active, *, interpret: bool,
     )(count, ids, beta.reshape(-1), (q * k).sum(axis=-1).reshape(-1),
       g, k, q, v, state)
     return out, state
+
+
+# -- one gate a head: the heads side by side on the lanes ---------------------
+
+def heads_side_by_side(state):
+    """[A, H, Dk, Dv] -> [A, Dk, H x Dv]: a state as the kernel of a gate a
+    head holds it (`heads_apart` is the way back)."""
+    a, heads, dk, dv = state.shape
+    return state.transpose(0, 2, 1, 3).reshape(a, dk, heads * dv)
+
+
+def heads_apart(state, heads: int):
+    """[A, Dk, H x Dv] -> [A, H, Dk, Dv]."""
+    a, dk, lanes = state.shape
+    return state.reshape(a, dk, heads, lanes // heads).transpose(0, 2, 1, 3)
+
+
+def _head_kernel(count_ref, ids_ref, decay_ref, beta_ref, qk_ref, cols_hbm,
+                 v_ref, state_hbm, o_ref, state_out, ring, cols, arrived,
+                 left, *, heads: int, group: int):
+    """The body of a gate a head (header).  `state_out` is `state_hbm`'s
+    own buffer; an item is a slot's whole state."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dk, lanes = state_hbm.shape[1:]
+    dv = lanes // heads
+    wide = group * dv
+    items = count_ref[0]
+
+    def arriving(item, at):
+        slot = ids_ref[item]
+        return [pltpu.make_async_copy(state_hbm.at[slot], ring.at[at],
+                                      arrived.at[at]),
+                pltpu.make_async_copy(cols_hbm.at[slot], cols.at[at],
+                                      arrived.at[at])]
+
+    def leaving(item, at):
+        return pltpu.make_async_copy(ring.at[at], state_out.at[ids_ref[item]],
+                                     left.at[at])
+
+    # a slot that decodes nothing reads zeros, whatever VMEM held
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, wide), 1)
+
+    def update(item, at):
+        slot = ids_ref[item]
+
+        def spread(of):
+            """of(j) of the group's head j over that head's lanes."""
+            out = of(0)
+            for j in range(1, group):
+                out = jnp.where(lane >= j * dv, of(j), out)
+            return out
+
+        # every group written out: a head's column sits at a STATIC lane
+        for n in range(heads // group):
+            first = n * group
+            here = pl.ds(n * wide, wide)
+
+            def scalar(ref):
+                return spread(lambda j: jnp.full(
+                    (1, wide), ref[slot * heads + first + j], jnp.float32))
+
+            def column(base):
+                return spread(lambda j: jnp.broadcast_to(
+                    cols[at, :, base + first + j:base + first + j + 1],
+                    (dk, wide)))
+
+            k, q = column(0), column(_LANES // 2)
+            decayed = ring[at, :, here] * scalar(decay_ref)          # [Dk, w]
+            seen = jnp.sum(decayed * k, axis=0, keepdims=True)       # [1, w]
+            asked = jnp.sum(decayed * q, axis=0, keepdims=True)
+            write = scalar(beta_ref) * (v_ref[pl.ds(slot, 1), here] - seen)
+            ring[at, :, here] = decayed + k * write
+            o_ref[pl.ds(slot, 1), here] = asked + scalar(qk_ref) * write
+
+    _through_the_ring(items, arriving, leaving, update)
+
+
+@functools.cache
+def _head_step_jit():
+    import jax
+    return jax.jit(_head_step, static_argnames=("interpret",))
+
+
+def _head_step(q, k, v, g, beta, state, active, *, interpret: bool):
+    """kda_live_step with every default resolved, a gate a head."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, dk = q.shape
+    dv = v.shape[2]
+    half = _LANES // 2
+    ids, count = _live_ids(active)
+    # k and q as columns, a slot a [Dk, 128] block: k's heads at lanes
+    # [0, 64), q's at [64, 128)
+    columns = jnp.concatenate(
+        [jnp.pad(z.transpose(0, 2, 1), ((0, 0), (0, 0), (0, half - heads)))
+         for z in (k, q)], axis=-1)
+    scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out, state = pl.pallas_call(
+        functools.partial(_head_kernel, heads=heads,
+                          group=_head_group(heads, dv)),
+        out_shape=(jax.ShapeDtypeStruct((slots, heads * dv), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
+        in_specs=[scalars] * 5 + [in_hbm, in_vmem, in_hbm],
+        out_specs=(in_vmem, in_hbm),
+        scratch_shapes=[pltpu.VMEM((_RING, dk, heads * dv), jnp.float32),
+                        pltpu.VMEM((_RING, dk, _LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((_RING,)),
+                        pltpu.SemaphoreType.DMA((_RING,))],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_HEAD_VMEM_LIMIT),
+        name="gdn_live_step",
+        interpret=interpret,
+    )(count, ids, jnp.exp(g).reshape(-1), beta.reshape(-1),
+      (q * k).sum(axis=-1).reshape(-1), columns,
+      v.reshape(slots, heads * dv), state)
+    return out.reshape(slots, heads, dv), state

@@ -657,9 +657,10 @@ def _gather_views(pools, tables, t_cap: int) -> list:
     itself moves t_cap positions whatever the table's width (a step
     at half the cap builds half the views); the time slice after it
     only trims a t_cap that ends inside a block."""
-    block_tokens = jax.tree_util.tree_leaves(pools[0])[0].shape[2]
+    block_tokens = jax.tree_util.tree_leaves(pools)[0].shape[2]
     capped = _table_cap(tables, block_tokens, t_cap)
-    return [_slice_time(L.gather_paged_kv(pool, capped), t_cap)
+    return [None if pool is None      # a layer that keeps no such leaf
+            else _slice_time(L.gather_paged_kv(pool, capped), t_cap)
             for pool in pools]
 
 

@@ -51,6 +51,15 @@ Phases of the default run:
            extends and decode, every slot served twice; prints the
            selection's and the experts' counters; every served token held
            to benchmark/reference/sparse_gqa_lm.py
+  gated_delta  ops/kda_step.py's kernel with ONE gate a head against
+           models/delta_rule.recurrent at the published head sizes (30
+           heads of [96, 192]; some slots live, none, all), then
+           ContinuousDecoder on models/gated_delta.py (recurrent slot state
+           beside a K/V pool that the shared paged kernel walks) at the
+           published widths, one period of four layers and the whole
+           vocabulary: prompts admitted whole and prompts that go chunk by
+           chunk, decode, every slot served twice; every served token held
+           to benchmark/reference/gated_delta_lm.py
 """
 
 from __future__ import annotations
@@ -344,59 +353,78 @@ def check_run_writes(label: str, leaf: tuple, shape: dict, key,
 
 def check_kda_live_step(shape: dict, key, on_chip: bool) -> None:
     """ops.kda_step.kda_live_step against hybrid_sparse.kda_recurrent at
-    the hybrid phase's heads, some slots live, none, all: the live
-    slots' output and state to float32 rounding (both forms are float32
-    throughout), every other slot's state bit for bit, its output
-    zeros."""
+    the hybrid phase's heads (a gate a channel), some slots live, none,
+    all."""
+    config = hybrid_config(shape)
+    check_live_step("a gate a channel", config.kda_heads,
+                    config.kda_head_dim, config.kda_head_dim, False,
+                    shape["slots"], key, on_chip)
+
+
+def check_live_step(grain: str, heads: int, dk: int, dv: int, by_head: bool,
+                    slots: int, key, on_chip: bool) -> bool:
+    """ops.kda_step.kda_live_step against models/delta_rule.recurrent at
+    `heads` heads of [dk, dv], a gate a channel or (`by_head`) ONE a head
+    with beta up to 2 and the state laid with its heads side by side; some
+    slots live, none, all: the live slots' output and state to float32
+    rounding (both forms are float32 throughout), every other slot's state
+    bit for bit, its output zeros.  -> whether a decode step takes the
+    kernel at this geometry (nothing is compared where it does not)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from aiko_services_tpu.models import hybrid_sparse
+    from aiko_services_tpu.models import delta_rule
     from aiko_services_tpu.ops import kda_step
 
-    config = hybrid_config(shape)
-    heads, d, slots = config.kda_heads, config.kda_head_dim, shape["slots"]
-    takes = kda_step.moves_live_states(heads, d, not on_chip)
-    say(f"  kda_live_step H{heads} D{d}: a decode step takes it {takes}")
+    takes = kda_step.moves_live_states(heads, dk, not on_chip, value_dim=dv,
+                                       by_head=by_head)
+    say(f"  kda_live_step, {grain}, H{heads} [{dk}, {dv}]: a decode step "
+        f"takes it {takes}")
     if not takes:                       # the rehearsal's head of 16
-        return
+        return False
     keys = jax.random.split(key, 6)
 
     def unit(z):
         return z / jnp.linalg.norm(z, axis=-1, keepdims=True)
 
-    q = unit(jax.random.normal(keys[0], (slots, heads, d))) * d ** -0.5
-    k = unit(jax.random.normal(keys[1], (slots, heads, d)))
-    v = jax.random.normal(keys[2], (slots, heads, d))
-    g = config.gate_lower_bound * jax.nn.sigmoid(
-        jax.random.normal(keys[3], (slots, heads, d)))
-    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (slots, heads)))
-    state = jax.random.normal(keys[5], (slots, heads, d, d))
+    q = unit(jax.random.normal(keys[0], (slots, heads, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (slots, heads, dk)))
+    v = jax.random.normal(keys[2], (slots, heads, dv))
+    g = -5.0 * jax.nn.sigmoid(jax.random.normal(
+        keys[3], (slots, heads) if by_head else (slots, heads, dk)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (slots, heads))) * (
+        2.0 if by_head else 1.0)
+    state = jax.random.normal(keys[5], (slots, heads, dk, dv))
+    laid = kda_step.heads_side_by_side(state) if by_head else state
     kernel = jax.jit(functools.partial(kda_step.kda_live_step,
                                        interpret=not on_chip))
     if on_chip:
-        require(lowered_has_kernel(kernel, q, k, v, g, beta, state,
+        require(lowered_has_kernel(kernel, q, k, v, g, beta, laid,
                                    jnp.ones((slots,), bool)),
                 "kda_live_step lowered without a tpu_custom_call")
     for label, live in (("some", np.arange(slots) % 3 == 1),
                         ("none", np.zeros(slots, bool)),
                         ("all", np.ones(slots, bool))):
         active = jnp.asarray(live)
-        out, new = kernel(q, k, v, g, beta, state, active)
-        want_out, want = jax.jit(hybrid_sparse.kda_recurrent)(
-            q, k, v, g * active[:, None, None], beta * active[:, None],
-            state)
+        out, new = kernel(q, k, v, g, beta, laid, active)
+        want_out, want = jax.jit(delta_rule.recurrent)(
+            q, k, v, g * active.reshape((-1,) + (1,) * (g.ndim - 1)),
+            beta * active[:, None], state)
+        if by_head:
+            want = kda_step.heads_side_by_side(want)
         worst = max(float(jnp.abs(out - want_out)[live].max(initial=0.0)),
                     float(jnp.abs(new - want)[live].max(initial=0.0)))
         kept = bool(np.array_equal(np.asarray(new)[~live],
-                                   np.asarray(state)[~live])) and \
+                                   np.asarray(laid)[~live])) and \
             not np.asarray(out)[~live].any()
-        say(f"  kda_live_step {label} of {slots} slots live: "
+        say(f"  kda_live_step, {grain}, {label} of {slots} slots live: "
             f"max|kernel-oracle|={worst:.2e}, the others untouched {kept}")
-        # float32 sums of 128 terms in another order, values of order one
+        # float32 sums of a hundred terms in another order, values of
+        # order one
         require(worst <= 1e-5 and kept,
-                f"kda_live_step {label} live off by {worst}")
+                f"kda_live_step, {grain}, {label} live off by {worst}")
+    return True
 
 
 # -- speech ------------------------------------------------------------------
@@ -1090,6 +1118,97 @@ def phase_sparse_gqa(shape: dict, seed: int, on_chip: bool,
         f"{numbers['served_token_gap_mean_std'][0]:.5f})")
 
 
+# -- recurrent state beside a K/V pool the shared kernel walks -------------------
+
+def phase_gated_delta(shape: dict, seed: int, on_chip: bool,
+                      clock: CompileClock) -> None:
+    """models/gated_delta.py through the same decoder: recurrent layers
+    whose state is a slot's (zeroed at admit, carried from chunk to chunk,
+    moved by ops/kda_step's kernel in the step on the chip) beside full
+    layers whose K and V pool the shared paged kernel walks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu import serving
+    from benchmark import run as bench
+    from benchmark import weights_gated_delta as W
+    from benchmark.reference import gated_delta_lm
+
+    own = shape["gated_delta"]
+    sizes = own["sizes"]
+    dtype = jnp.dtype(shape["llama_dtype"])
+    config = bench.load_module("drivers", sizes["driver"]).model_config(
+        sizes, own["max_seq"], dtype)
+    require(check_live_step(
+        "a gate a head", config.gdn_heads, config.key_dim, config.value_dim,
+        True, 8 * own["slots"], jax.random.PRNGKey(seed + 40), on_chip),
+        "the phase's head sizes do not take the kernel")
+    params = W.decoder_weights(W.key_for(seed), sizes, dtype)
+    rng = np.random.default_rng(seed)
+    requests = {
+        f"r{i}": (rng.integers(1, config.vocab, size=length).tolist(),
+                  shape["new_tokens"])
+        for i, length in enumerate(own["prompt_lengths"])}
+    decoder = serving.ContinuousDecoder(
+        params, config, paged_kv=True, max_slots=own["slots"],
+        max_seq=own["max_seq"], t_block=own["max_seq"],
+        prefill_buckets=own["prefill_buckets"],
+        prefill_chunk=own["prefill_chunk"],
+        prefill_budget=own["prefill_chunk"],
+        steps_per_sync=shape["steps_per_sync"], name="gated_delta")
+    # told nothing, on the chip the step is the kernels' for BOTH reasons
+    # (the walk of the pool, the recurrence over the live slots' state);
+    # in the rehearsal gathered views and the recurrence over every slot
+    require(decoder.step_kernel == on_chip and
+            decoder._walks_live == on_chip,
+            f"gated_delta: step_kernel {decoder.step_kernel}, walks live "
+            f"{decoder._walks_live}, on the chip {on_chip}")
+    cold = timed_serve("first pass", decoder, requests, clock)
+    warm = timed_serve("second pass (every slot reused)", decoder,
+                       requests, clock)
+    require(cold == warm, "the same requests served twice differ: a "
+            "slot's state outlived its request")
+    stats, pool = decoder.stats, decoder.pool
+    require(stats["prefill_chunks"] > 0 and stats["prefills"] > 0,
+            "the prompts did not take both the admit and the extend")
+    require(stats["slot_states_zeroed"] == 2 * len(requests),
+            "a request did not start from zeroed state")
+    require(0 < stats["gdn_states_moved"] <= stats["gdn_states_held"],
+            "the recurrence moved no state, or more than the layers hold")
+    full = [i for i, kind in enumerate(config.layer_types) if kind == "full"]
+    require(all((pool.k_pools[i] is not None) == (i in full)
+                for i in range(config.num_layers)) and
+            pool.block_nbytes == decoder.kv_block * len(full) * 2 *
+            config.num_heads * config.head_dim *
+            jnp.dtype(config.dtype).itemsize,
+            "a recurrent layer holds pool blocks, or a full layer none")
+    say(f"  prefill_chunks={stats['prefill_chunks']} rounds="
+        f"{stats['rounds']} leaves {pool.k_pools[full[0]].shape} state "
+        f"{decoder.slot_state.nbytes() / 1e6:.1f} MB; states moved "
+        f"{stats['gdn_states_moved']} of {stats['gdn_states_held']} held")
+    # no expert and no group is chosen: a token passes within the dense
+    # cell's own gap in bfloat16, and float32 leaves near-ties alone
+    half = dtype == jnp.bfloat16
+    tolerance = 0.5 if half else 1e-3
+    numbers = gated_delta_lm.check(
+        [{"prompt": prompt, "served": cold[request_id]}
+         for request_id, (prompt, _) in requests.items()],
+        sizes, seed, str(dtype), say=lambda line: say("  " + line))["numbers"]
+    worst = max(numbers["served_token_gap_std"])
+    require(np.isfinite(worst) and worst <= tolerance,
+            f"gated_delta: a served token is {worst:.3f} logit-std below "
+            f"the reference's best (tolerance {tolerance})")
+    for name, limit in sizes["correctness"]["limits"].items():
+        require(not half or max(numbers[name]) <= limit,
+                f"gated_delta: {name} {max(numbers[name]):.4f} over the "
+                f"cell's limit {limit}")
+    say(f"  every token within {tolerance} logit-std of the plain "
+        f"reference's best (worst {worst:.4f}, mean "
+        f"{numbers['served_token_gap_mean_std'][0]:.5f})")
+
+
+
 # -- four chips --------------------------------------------------------------
 
 def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
@@ -1187,6 +1306,19 @@ def shapes(rehearse: bool) -> dict:
             sizes = bench.merged(sizes, sizes["rehearse"])
         return bench.merged(sizes, {"num_hidden_layers": 2,
                                     "serving": {"max_seq": max_seq}})
+
+    def gated_delta_sizes(max_seq: int) -> dict:
+        """The Gated-DeltaNet cell's configuration file (its `rehearse`
+        sizes laid over it for a rehearsal) cut to one period: three
+        recurrent layers and a full one."""
+        sizes = bench.load_json("benchmark", "configs",
+                                "olmo-hybrid-7b-d16.json")
+        if rehearse:
+            sizes = bench.merged(sizes, sizes["rehearse"])
+        return bench.merged(sizes, {
+            "num_hidden_layers": 4,
+            "layer_types": ["linear_attention"] * 3 + ["full_attention"],
+            "serving": {"max_seq": max_seq}})
     if rehearse:
         # the CPU rehearsal: same code paths, toy widths
         return {"whisper_preset": "test", "llama_preset": "tiny",
@@ -1200,6 +1332,11 @@ def shapes(rehearse: bool) -> dict:
                     "prompt_lengths": (8, 20, 44, 100)},
                 "sparse_gqa": {
                     "sizes": sparse_gqa_sizes(128),
+                    "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
+                    "prefill_chunk": 32,
+                    "prompt_lengths": (5, 20, 44, 100)},
+                "gated_delta": {
+                    "sizes": gated_delta_sizes(128),
                     "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
                     "prefill_chunk": 32,
                     "prompt_lengths": (5, 20, 44, 100)},
@@ -1232,6 +1369,15 @@ def shapes(rehearse: bool) -> dict:
                 "max_seq": 3072, "slots": 4, "prefill_buckets": (64, 256),
                 "prefill_chunk": 256,
                 "prompt_lengths": (64, 200, 1024, 2600)},
+            # the published widths, one period (three recurrent layers and
+            # a full one) and the whole vocabulary: 3.2 GB in bfloat16;
+            # prompts that are admitted whole and prompts that go chunk by
+            # chunk, each chunk from the state the last one left
+            "gated_delta": {
+                "sizes": gated_delta_sizes(1024),
+                "max_seq": 1024, "slots": 4, "prefill_buckets": (64, 256),
+                "prefill_chunk": 256,
+                "prompt_lengths": (64, 200, 600, 900)},
             "llama_heads": 16,
             "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,
             "prefill_buckets": (64, 256), "prefill_chunk": 256,
@@ -1286,7 +1432,9 @@ def main(argv=None) -> int:
                   "latent": functools.partial(phase_latent, clock=clock),
                   "hybrid": functools.partial(phase_hybrid, clock=clock),
                   "sparse_gqa": functools.partial(phase_sparse_gqa,
-                                                  clock=clock)}
+                                                  clock=clock),
+                  "gated_delta": functools.partial(phase_gated_delta,
+                                                   clock=clock)}
     for name, phase in phases.items():
         with Phase(name, clock):
             phase(shape, args.seed, on_chip)

@@ -871,3 +871,116 @@ def test_sparse_gqa_extend_chooses_without_a_sort_and_copies_no_leaf(chip):
     assert len(re.findall(r" sort\(", text)) == config.num_layers
     assert "aiko.dsa_select" in text and "aiko.dsa_index" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+# -- slot state beside a pool the shared kernel walks (ISSUE 40) -----------------
+
+@pytest.fixture(scope="module")
+def gated_delta_step(chip):
+    """The whole 16-layer `jit_step` x 4 of `gdn_decode_saturated` as the
+    cell's decoder builds it on the chip (`step_kernel` for both reasons:
+    the full layers' walk of the pool, the recurrent layers' state through
+    ops/kda_step.py), compiled once: -> (compiled, config, `serving`)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in (root, os.path.join(root, "benchmark", "drivers")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import gated_delta_decoder
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import gated_delta as M
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-d16.json")) as f:
+        sizes = json.load(f)
+    serve = sizes["serving"]
+    config = gated_delta_decoder.model_config(sizes, serve["max_seq"],
+                                              jnp.bfloat16)
+
+    def shaped(shape, kind):
+        return jax.ShapeDtypeStruct(tuple(shape), kind, sharding=chip)
+
+    params = jax.tree.map(
+        lambda leaf: shaped(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: M.gated_delta_init(jax.random.PRNGKey(0),
+                                                  config)))
+    slots, block = serve["max_slots"], serve["kv_block"]
+    blocks = slots * serve["max_seq"] // block + 1
+    leaves = serving_paged.layer_leaves(config)
+    k_pools, v_pools = (
+        [shaped((blocks, layer[side][0], block, layer[side][1]),
+                jnp.bfloat16) if layer else None for layer in leaves]
+        for side in (0, 1))
+    state = [tuple(shaped((slots,) + tuple(shape), kind)
+                   for shape, kind in layer) for layer in config.slot_state]
+    table = -(-(serve["max_seq"] + serve["steps_per_sync"]) // block)
+    vector = shaped((slots,), jnp.int32)
+    model = config.paged_model()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        # what a decoder that is told nothing finds on the chip
+        assert model.walks(config, False, False) == "kernel"
+        assert model.step_kernel(config, False) is True
+        compiled = serving_paged._paged_step_for(config, True).lower(
+            params, vector, vector, shaped((slots,), bool), vector, k_pools,
+            v_pools, shaped((slots, table), jnp.int32), state,
+            num_steps=serve["steps_per_sync"], eos=-1,
+            t_cap=serve["max_seq"]).compile()
+    return compiled, config, serve
+
+
+def test_gated_delta_step_moves_slot_state_through_the_kernel_alone(
+        gated_delta_step):
+    """The twelve recurrent layers' recurrence is twelve custom calls under
+    `aiko.gdn_state`, their state argument aliased to their result, and NO
+    other computing operation makes a whole state leaf `f32[64,96,5760]`:
+    no fusion over every slot's state, no copy that a failed aliasing would
+    put before the kernel (it would also show as 141 MB a layer of
+    temporaries: the bound below).  A live slot's state goes once in and
+    once out, a slot that does not decode is not addressed."""
+    from aiko_services_tpu.models import gated_delta as M
+    compiled, config, serve = gated_delta_step
+    leaf = "f32[%d,%d,%d]" % (serve["max_slots"], config.key_dim,
+                              config.gdn_heads * config.value_dim)
+    made = [line.strip() for line in compiled.as_text().splitlines()
+            if re.search(r"= \(?[^=]*%s\S* (\S+)\(" % re.escape(leaf), line)]
+    kinds = [re.search(r"\S* ([a-z\-]+)\(", line.split(" = ", 1)[1]).group(1)
+             for line in made]
+    carried = _HLO_CARRIES | {"custom-call"}
+    assert set(kinds) <= carried, [
+        line[:200] for line, kind in zip(made, kinds) if kind not in carried]
+    kernels = [line for line, kind in zip(made, kinds)
+               if kind == "custom-call"]
+    recurrent = sum(kind == "gdn" for kind in config.layer_types)
+    assert len(kernels) == recurrent == 12
+    assert all(M.SCOPE_GDN_STATE in line and "tpu_custom_call" in line and
+               "output_to_operand_aliasing" in line for line in kernels)
+    memory = compiled.memory_analysis()
+    # 8.20 GB of weights, 4.03 GB of pool, 1.75 GB of slot state
+    assert 13.9e9 < memory.argument_size_in_bytes < 14.1e9
+    assert memory.temp_size_in_bytes < 0.3e9
+
+
+def test_gated_delta_step_walks_the_full_layers_pool_and_copies_none_of_it(
+        gated_delta_step):
+    """The same program: the four full layers attend through four custom
+    calls under `aiko.attn_core` (the shared walk, a group of 1) and no
+    operation but the merge's in-place writes makes an array of a pool
+    leaf's size; the convolution's tails are rewritten by fusions, small
+    (64 x 3 x 11,520) as they are."""
+    from aiko_services_tpu.models.llama import (SCOPE_ATTN_CORE,
+                                                SCOPE_KV_MERGE)
+    compiled, config, serve = gated_delta_step
+    lines = [line.strip() for line in compiled.as_text().splitlines()]
+    walks = [line for line in lines if "tpu_custom_call" in line and
+             SCOPE_ATTN_CORE in line]
+    assert len(walks) == sum(kind == "full" for kind in config.layer_types) \
+        == 4
+    blocks = serve["max_slots"] * serve["max_seq"] // serve["kv_block"] + 1
+    leaf = "bf16[%d,%d,%d,%d]" % (blocks, config.num_heads, serve["kv_block"],
+                                  config.head_dim)
+    made = [line for line in lines for found in [re.search(
+        r"= %s\S* ([a-z\-]+)\(" % re.escape(leaf), line)]
+        if found and found.group(1) not in _HLO_CARRIES]
+    assert made and all(SCOPE_KV_MERGE in line for line in made), \
+        [line[:200] for line in made if SCOPE_KV_MERGE not in line]

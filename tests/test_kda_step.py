@@ -1,6 +1,8 @@
 # ops/kda_step.py (ISSUE 34): one token of the gated delta rule over the
 # state of the slots that decode, the pallas kernel in the interpreter on
-# the CPU at a head of 128, against models/hybrid_sparse.kda_recurrent.
+# the CPU at a head of 128, against models/hybrid_sparse.kda_recurrent
+# (models/delta_rule.recurrent); since ISSUE 40 at unequal head sides too,
+# and with ONE gate a head (the sibling body, the heads side by side).
 # What the interpreter cannot see (tiling, VMEM, the aliasing inside the
 # whole step) is tests/test_chip_compile.py's; times are the chip's.
 
@@ -23,46 +25,70 @@ ACTIVE = {"mixed": [True, False, True, True, False],
           "last": [False] * (SLOTS - 1) + [True]}
 
 
-def inputs(seed, slots=SLOTS, heads=HEADS):
+def inputs(seed, slots=SLOTS, heads=HEADS, dk=D, dv=D, by_head=False):
+    """q, k, v, g, beta and the state as the kernel takes it: a gate a
+    channel and S [slots, heads, dk, dv], or (`by_head`) a gate a head,
+    beta up to 2 and S [slots, dk, heads x dv]."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
 
     def unit(z):
         return z / jnp.linalg.norm(z, axis=-1, keepdims=True)
 
-    shape = (slots, heads, D)
-    return (unit(jax.random.normal(keys[0], shape)) * D ** -0.5,
+    shape = (slots, heads, dk)
+    state = jax.random.normal(keys[5], shape + (dv,))
+    return (unit(jax.random.normal(keys[0], shape)) * dk ** -0.5,
             unit(jax.random.normal(keys[1], shape)),
-            jax.random.normal(keys[2], shape),
-            -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], shape)),
-            jax.nn.sigmoid(jax.random.normal(keys[4], shape[:2])),
-            jax.random.normal(keys[5], shape + (D,)))
+            jax.random.normal(keys[2], (slots, heads, dv)),
+            -5.0 * jax.nn.sigmoid(jax.random.normal(
+                keys[3], shape[:2] if by_head else shape)),
+            jax.nn.sigmoid(jax.random.normal(keys[4], shape[:2])) * (
+                2.0 if by_head else 1.0),
+            kda_step.heads_side_by_side(state) if by_head else state)
 
 
 def oracle(q, k, v, g, beta, state, active):
     """kda_recurrent as the decode step calls it: g and beta zeroed
-    where the slot decodes nothing."""
-    return kda_recurrent(q, k, v, g * active[:, None, None],
-                         beta * active[:, None], state)
+    where the slot decodes nothing; a state that came with its heads side
+    by side goes back so."""
+    by_head = g.ndim == 2
+    out, new = kda_recurrent(
+        q, k, v, g * active.reshape((-1,) + (1,) * (g.ndim - 1)),
+        beta * active[:, None],
+        kda_step.heads_apart(state, q.shape[1]) if by_head else state)
+    return out, kda_step.heads_side_by_side(new) if by_head else new
 
 
-@pytest.fixture(params=["one tile", "two tiles"])
+# (heads, key side, value side, one gate a head): the published square
+# head with a gate a channel in one tile and in two, then ISSUE 40's: 6
+# heads (no multiple of 8) of [8, 16], both grains of gate
+SHAPES = {"a channel, 4 heads of 128, one tile": (HEADS, D, D, False),
+          "a channel, 4 heads of 128, two tiles": (HEADS, D, D, False),
+          "a channel, 6 heads of [8, 16]": (6, 8, 16, False),
+          "a head, 6 heads of [8, 16]": (6, 8, 16, True)}
+
+
+@pytest.fixture(params=sorted(SHAPES))
 def tiles(request, monkeypatch):
-    """Heads a tile: all four of a slot, or two (the ring then passes a
-    slot in two pieces, and twice as many items as its depth)."""
-    if request.param == "two tiles":
+    """The geometry of a case, as `inputs`' keywords.  Heads a tile (a
+    gate a channel): all of a slot, or two of four (the ring then passes
+    a slot in two pieces, and twice as many items as its depth); a gate a
+    head passes a slot's whole state as one item."""
+    heads, dk, dv, by_head = SHAPES[request.param]
+    if request.param.endswith("two tiles"):
         monkeypatch.setattr(kda_step, "_TILE_BYTES", 2 * 4 * D * D)
-    return HEADS // kda_step._head_tile(HEADS, D)
+        assert HEADS // kda_step._head_tile(HEADS, D) == 2
+    return {"heads": heads, "dk": dk, "dv": dv, "by_head": by_head}
 
 
 @pytest.mark.parametrize("case", sorted(ACTIVE))
 def test_live_slots_follow_the_recurrence_and_the_others_keep_their_bits(
         case, tiles):
-    q, k, v, g, beta, state = inputs(3)
+    q, k, v, g, beta, state = inputs(3, **tiles)
     active = jnp.asarray(ACTIVE[case])
     out, new = kda_step.kda_live_step(q, k, v, g, beta, state, active)
     want_out, want = oracle(q, k, v, g, beta, state, active)
     live = np.asarray(active)
-    assert out.shape == q.shape and new.shape == state.shape
+    assert out.shape == v.shape and new.shape == state.shape
     assert out.dtype == new.dtype == jnp.float32
     # (with no slot live there is nothing to compare, and nothing moved)
     if live.any():
@@ -80,7 +106,7 @@ def test_what_a_slot_that_decodes_nothing_holds_is_never_read(tiles):
     """NaN in every vector of the idle slots and in their state: the live
     slots' results are what they are without it, the idle state is still
     the same bits."""
-    q, k, v, g, beta, state = inputs(4)
+    q, k, v, g, beta, state = inputs(4, **tiles)
     active = jnp.asarray(ACTIVE["mixed"])
     idle = ~active
     clean = kda_step.kda_live_step(q, k, v, g, beta, state, active)
@@ -94,11 +120,16 @@ def test_what_a_slot_that_decodes_nothing_holds_is_never_read(tiles):
     assert not np.asarray(out)[~live].any()
 
 
-def test_four_steps_in_a_loop_with_the_state_donated_are_four_recurrences():
+@pytest.mark.parametrize("shape", [
+    name for name in sorted(SHAPES) if "two tiles" not in name])
+def test_four_steps_in_a_loop_with_the_state_donated_are_four_recurrences(
+        shape):
     """As the decode step runs it: inside a `lax.while_loop` under `jit`,
     the state carried and donated, the set of live slots changing from
     step to step (one slot stops, as a budget that runs out)."""
-    q, k, v, g, beta, state = inputs(5)
+    heads, dk, dv, by_head = SHAPES[shape]
+    q, k, v, g, beta, state = inputs(5, heads=heads, dk=dk, dv=dv,
+                                     by_head=by_head)
     steps = 4
     # slot 1 never decodes, slot 3 stops after two steps
     lives = jnp.asarray([[True, False, True, True, True]] * 2 +
@@ -117,7 +148,7 @@ def test_four_steps_in_a_loop_with_the_state_donated_are_four_recurrences():
 
         return jax.lax.while_loop(
             lambda loop: loop[0] < steps, body,
-            (jnp.int32(0), state, jnp.zeros((steps,) + q.shape)))[1:]
+            (jnp.int32(0), state, jnp.zeros((steps,) + v.shape)))[1:]
 
     want, want_outs = state, []
     for index in range(steps):
@@ -168,3 +199,34 @@ def test_heads_a_tile(heads, head_dim, tile):
 def test_the_kernel_wants_whole_lanes_and_whole_sublanes(
         heads, head_dim, interpret, takes):
     assert kda_step.moves_live_states(heads, head_dim, interpret) is takes
+
+
+@pytest.mark.parametrize("heads, key_dim, value_dim, interpret, takes", [
+    (30, 96, 192, False, True),     # the published Gated DeltaNet widths
+    (64, 128, 128, False, True),    # square heads, one gate a head
+    (30, 96, 200, False, False),    # 6,000 lanes a slot: no whole vectors
+    (30, 100, 192, False, False),   # a key side of no whole sublanes
+    (96, 96, 192, False, False),    # k and q of 96 heads pass a vector's halves
+    (64, 256, 256, False, False),   # 16 MB a slot: no item of the ring
+    (6, 8, 16, False, False),       # the `tiny` preset (the interpreter only)
+    (6, 8, 16, True, True)])
+def test_one_gate_a_head_wants_whole_vectors_a_slot_and_a_group(
+        heads, key_dim, value_dim, interpret, takes):
+    assert kda_step.moves_live_states(
+        heads, key_dim, interpret, value_dim=value_dim, by_head=True) is takes
+
+
+@pytest.mark.parametrize("heads, value_dim, group", [
+    (30, 192, 2),       # two heads of 192 are three vectors of 128
+    (64, 128, 1), (6, 16, 6), (8, 16, 8), (30, 96, 30)])
+def test_heads_a_group(heads, value_dim, group):
+    assert kda_step._head_group(heads, value_dim) == group
+
+
+@pytest.mark.parametrize("heads, key_dim, value_dim, takes", [
+    (64, 128, 256, True), (64, 256, 128, True), (64, 128, 192, False),
+    (64, 96, 128, False)])
+def test_a_gate_a_channel_takes_unequal_sides_of_whole_lanes(
+        heads, key_dim, value_dim, takes):
+    assert kda_step.moves_live_states(heads, key_dim, False,
+                                      value_dim=value_dim) is takes
