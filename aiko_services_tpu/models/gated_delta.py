@@ -27,7 +27,9 @@
 # [Dk, H x Dv] a slot, the heads side by side on the lanes (ops/kda_step.py
 # says why); the decode step's recurrence is that module's kernel on the
 # chip (`step_kernel`) and models/delta_rule.recurrent everywhere else, an
-# admit's and a chunk's the chunked form with one decay a head.
+# admit's and a chunk's the chunked form with one decay a head: on the chip
+# ops/delta_chunk's kernel, ONE call a layer over the state as the pool
+# keeps it (`_scan_kernel`, ISSUE 41), models/delta_rule.chunked elsewhere.
 
 from __future__ import annotations
 
@@ -37,6 +39,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..ops.delta_chunk import delta_chunk_scan, scans_chunks
 from ..ops.kda_step import (heads_apart, heads_side_by_side, kda_live_step,
                             moves_live_states)
 from ..ops.paged_attention import paged_decode_attention, walks_live_blocks
@@ -264,9 +267,12 @@ def _gdn_block(layer, config: GatedDeltaConfig, x, state, live,
         out = out[:, None]
     else:
         with jax.named_scope(SCOPE_GDN_SCAN):
-            out, memory = delta_rule.chunked(q, k, v, g, beta,
-                                             heads_apart(memory, heads))
-            memory = heads_side_by_side(memory)
+            if _scan_kernel(config, jax.default_backend() != "tpu"):
+                out, memory = delta_chunk_scan(q, k, v, g, beta, memory)
+            else:
+                out, memory = delta_rule.chunked(q, k, v, g, beta,
+                                                 heads_apart(memory, heads))
+                memory = heads_side_by_side(memory)
     return _gdn_output(gdn, config, out, gate, x.dtype), (memory, tail)
 
 
@@ -439,6 +445,14 @@ def _state_kernel(config: GatedDeltaConfig, interpret: bool) -> bool:
                              value_dim=config.value_dim, by_head=True)
 
 
+def _scan_kernel(config: GatedDeltaConfig, interpret: bool) -> bool:
+    """Whether a prompt's piece runs the chunked form as ops/delta_chunk's
+    kernel: on the chip, where the heads tile.  Read off the geometry at
+    trace time; the interpreter is never taken unasked."""
+    return not interpret and scans_chunks(
+        config.gdn_heads, config.key_dim, config.value_dim, by_head=True)
+
+
 def _walks(config: GatedDeltaConfig, kv_int8: bool,
            interpret: bool) -> str | None:
     return "kernel" if walks_live_blocks(config.head_dim, kv_int8,
@@ -521,5 +535,5 @@ def _paged_model():
         rope=_rope, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, step_kernel=_state_kernel,
+        walks=_walks, step_kernel=_state_kernel, scan_kernel=_scan_kernel,
         counters=GATED_DELTA_COUNTERS, supports=frozenset())

@@ -47,6 +47,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..ops.delta_chunk import delta_chunk_scan, scans_chunks
 from ..ops.kda_step import kda_live_step, moves_live_states
 from . import delta_rule
 from . import layers as L
@@ -436,6 +437,8 @@ def _kda_block(layer, config: HybridSparseConfig, x, state, live,
             out, memory = kda_live_step(*one, live[:, 0]) if live_only \
                 else kda_recurrent(*one)
             out = out[:, None]
+        elif _scan_kernel(config, jax.default_backend() != "tpu"):
+            out, memory = delta_chunk_scan(q, k, v, g, beta, memory)
         else:
             out, memory = kda_chunked(q, k, v, g, beta, memory)
     with jax.named_scope(SCOPE_ATTN_PROJ):
@@ -868,6 +871,15 @@ def _state_kernel(config: HybridSparseConfig, interpret: bool) -> bool:
                              interpret)
 
 
+def _scan_kernel(config: HybridSparseConfig, interpret: bool) -> bool:
+    """Whether a prompt's piece runs the chunked form as ops/delta_chunk's
+    kernel: on the chip, where the heads tile.  Read off the geometry at
+    trace time; the interpreter is never taken unasked."""
+    return not interpret and scans_chunks(
+        config.kda_heads, config.kda_head_dim, config.kda_head_dim,
+        by_head=False)
+
+
 def _step_attention(kernel: bool):
     """A layer's token mixing in the decode step: the KDA recurrence
     over the slot's state, or the sparse layer over the pool (neither
@@ -942,7 +954,7 @@ def _paged_model():
         rope=rope_tables, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, step_kernel=_state_kernel,
+        walks=_walks, step_kernel=_state_kernel, scan_kernel=_scan_kernel,
         counters=HYBRID_COUNTERS, supports=frozenset(),
         block_multiple=_TILE_ROWS,
         residual_in=_streams_in, final_norm=_head_hidden)

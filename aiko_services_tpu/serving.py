@@ -1795,6 +1795,13 @@ class ContinuousDecoder:
                     "decode once in and once out"
                     if self.step_kernel and self._model_kernel
                     else "XLA's program over every slot's state")
+                if self._model.scan_kernel is not None:
+                    self.logger.info(
+                        "a prompt's pieces (admit, extend) run the "
+                        "recurrence's chunked form as %s",
+                        "the pallas kernel, one call a layer"
+                        if self._model.scan_kernel(config, not on_tpu)
+                        else "XLA's program, models/delta_rule.chunked")
             from .serving_paged import run_write_form
             self.logger.info(
                 "decode step writes a round's rows to the pool as %s",

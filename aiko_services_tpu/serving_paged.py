@@ -115,6 +115,11 @@ class PagedModel:
         (ISSUE 34), its own walk of the pool (ISSUE 39); None where the
         model has none.  Like "kernel" above it is the decoder that
         decides, and step_attention's `kernel` that says so
+    scan_kernel(config, interpret) -> whether a prompt's piece (an admit,
+        an extend) runs the model's recurrence over slot state as a
+        pallas kernel (ISSUE 41: ops/delta_chunk); None where the model
+        has none.  The MODEL decides, at trace time, from what it can
+        observe; the decoder only logs the answer at set-up
     counters: names of the step's counts, added to decoder.stats
     supports: the serving paths this model's pool is carried through;
         the decoder refuses the others at construction
@@ -148,6 +153,7 @@ class PagedModel:
     extend_layer: object
     walks: object
     step_kernel: object = None
+    scan_kernel: object = None
     counters: tuple = ()
     supports: frozenset = frozenset()
     block_multiple: int = 1

@@ -427,6 +427,74 @@ def check_live_step(grain: str, heads: int, dk: int, dv: int, by_head: bool,
     return True
 
 
+def check_chunk_scan(grain: str, heads: int, dk: int, dv: int, by_head: bool,
+                     tokens: int, key, on_chip: bool) -> bool:
+    """ops.delta_chunk.delta_chunk_scan against models/delta_rule.chunked
+    (and `recurrent` token by token) over a prompt's piece of `tokens` at
+    `heads` heads of [dk, dv], a gate a channel (to -5 a token) or
+    (`by_head`) ONE a head, beta over (0, 2), from a state that is not zero
+    and a tail that is not live: output and state to 2e-4 (float32 at
+    HIGHEST on both sides, sums in another order).  -> whether a prompt's
+    piece takes the kernel at this geometry on the chip (in a rehearsal the
+    interpreter runs it whatever the geometry, and the model does not)."""
+    import jax
+    import jax.numpy as jnp
+
+    from aiko_services_tpu.models import delta_rule
+    from aiko_services_tpu.ops import delta_chunk, kda_step
+
+    takes = delta_chunk.scans_chunks(heads, dk, dv, by_head)
+    say(f"  delta_chunk_scan, {grain}, H{heads} [{dk}, {dv}]: a prompt's "
+        f"piece takes it on the chip {takes}")
+    if on_chip and not takes:
+        return False
+    keys = jax.random.split(key, 6)
+
+    def unit(z):
+        return z / jnp.linalg.norm(z, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(keys[0], (2, tokens, heads, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (2, tokens, heads, dk)))
+    v = jax.random.normal(keys[2], (2, tokens, heads, dv))
+    live = jnp.arange(tokens)[None] < jnp.array([[tokens],
+                                                 [tokens - tokens // 3]])
+    g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(
+        keys[3], (2, tokens, heads) + (() if by_head else (dk,))))
+    g = g * live.reshape(live.shape + (1,) * (g.ndim - 2))
+    beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(
+        keys[4], (2, tokens, heads))) * live[..., None]
+    state = jax.random.normal(keys[5], (2, heads, dk, dv))
+    laid = kda_step.heads_side_by_side(state) if by_head else state
+    kernel = jax.jit(functools.partial(delta_chunk.delta_chunk_scan,
+                                       interpret=not on_chip))
+    if on_chip:
+        require(lowered_has_kernel(kernel, q, k, v, g, beta, laid),
+                "delta_chunk_scan lowered without a tpu_custom_call")
+    out, new = kernel(q, k, v, g, beta, laid)
+    if by_head:
+        new = kda_step.heads_apart(new, heads)
+
+    @jax.jit
+    def token_by_token(q, k, v, g, beta, state):
+        def one(state, xs):
+            out, state = delta_rule.recurrent(*xs, state)
+            return state, out
+        state, out = jax.lax.scan(one, state, tuple(
+            jnp.moveaxis(z, 1, 0) for z in (q, k, v, g, beta)))
+        return jnp.moveaxis(out, 0, 1), state
+
+    for name, (want_out, want) in (
+            ("chunked", jax.jit(delta_rule.chunked)(q, k, v, g, beta, state)),
+            ("recurrent", token_by_token(q, k, v, g, beta, state))):
+        worst = max(float(jnp.abs(out - want_out).max()),
+                    float(jnp.abs(new - want).max()))
+        say(f"  delta_chunk_scan, {grain}, {tokens} tokens: "
+            f"max|kernel-{name}|={worst:.2e}")
+        require(worst <= 2e-4,
+                f"delta_chunk_scan, {grain}, off {name} by {worst}")
+    return takes
+
+
 # -- speech ------------------------------------------------------------------
 
 def seeded_microphone(seed: int):
@@ -750,6 +818,28 @@ def timed_serve(label: str, decoder, requests: dict,
     return served
 
 
+def prefill_programs_hold(decoder, bucket: int, chunk: int,
+                         kernel_name: str) -> tuple:
+    """Whether the decoder's admit (one prompt of `bucket`) and its extend
+    (one piece of `chunk`), lowered as it dispatches them, hold the pallas
+    call named `kernel_name`: (admit, extend)."""
+    import jax.numpy as jnp
+    one, flag = jnp.zeros((1,), jnp.int32), jnp.ones((1,), bool)
+    pools = (decoder.params, decoder.pool.k_pools, decoder.pool.v_pools,
+             decoder._tokens, decoder._lengths, decoder._context)
+    table = -(-decoder._cache_t // decoder.kv_block)
+    admit = decoder._admit_fn(bucket, 1).lower(
+        *pools, jnp.zeros((1, bucket), jnp.int32), one, one, flag,
+        jnp.zeros((1, -(-bucket // decoder.kv_block)), jnp.int32),
+        *decoder._state_args())
+    extend = decoder._extend_fn(chunk, 1).lower(
+        *pools, jnp.zeros((1, chunk), jnp.int32), one, one, flag, flag, one,
+        jnp.zeros((1, table), jnp.int32), *decoder._state_args(),
+        t_cap=decoder._cache_t)
+    return tuple(kernel_name in lowered.as_text()
+                 for lowered in (admit, extend))
+
+
 def decode_step_has_kernel(decoder) -> bool:
     import jax.numpy as jnp
     slots = decoder.max_slots
@@ -931,6 +1021,7 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
     zeroed at admit and carried from chunk to chunk, the KDA recurrence
     and the gather of the chosen groups in the step, the chunked scan
     and the masked absorbed attention in admit and extend."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -966,6 +1057,18 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
         on_chip and config.kda_head_dim % 128 == 0),
         f"hybrid: step_kernel {decoder.step_kernel} at a head of "
         f"{config.kda_head_dim}, on the chip {on_chip}")
+    # a prompt's pieces run KDA's chunked form as ONE kernel a layer on
+    # the chip (checked against delta_rule.chunked first), XLA's program
+    # in the rehearsal
+    scans = check_chunk_scan(
+        "a gate a channel", config.kda_heads, config.kda_head_dim,
+        config.kda_head_dim, False, own["prefill_chunk"],
+        jax.random.PRNGKey(seed + 33), on_chip)
+    held = prefill_programs_hold(decoder, own["prefill_buckets"][-1],
+                                 own["prefill_chunk"], "kda_chunk_scan")
+    require(held == (on_chip and scans,) * 2,
+            f"hybrid: admit and extend hold the chunk kernel {held}, on "
+            f"the chip {on_chip}")
     cold = timed_serve("first pass", decoder, requests, clock)
     warm = timed_serve("second pass (every slot reused)", decoder,
                        requests, clock)
@@ -1144,6 +1247,11 @@ def phase_gated_delta(shape: dict, seed: int, on_chip: bool,
         "a gate a head", config.gdn_heads, config.key_dim, config.value_dim,
         True, 8 * own["slots"], jax.random.PRNGKey(seed + 40), on_chip),
         "the phase's head sizes do not take the kernel")
+    scans = check_chunk_scan(
+        "a gate a head", config.gdn_heads, config.key_dim, config.value_dim,
+        True, own["prefill_chunk"], jax.random.PRNGKey(seed + 41), on_chip)
+    require(scans or not on_chip,
+            "the phase's head sizes do not take the chunk kernel")
     params = W.decoder_weights(W.key_for(seed), sizes, dtype)
     rng = np.random.default_rng(seed)
     requests = {
@@ -1164,6 +1272,13 @@ def phase_gated_delta(shape: dict, seed: int, on_chip: bool,
             decoder._walks_live == on_chip,
             f"gated_delta: step_kernel {decoder.step_kernel}, walks live "
             f"{decoder._walks_live}, on the chip {on_chip}")
+    # a prompt's pieces run the chunked form as ONE kernel a layer on the
+    # chip, XLA's program (delta_rule.chunked) in the rehearsal
+    held = prefill_programs_hold(decoder, own["prefill_buckets"][-1],
+                                 own["prefill_chunk"], "gdn_chunk_scan")
+    require(held == (on_chip, on_chip),
+            f"gated_delta: admit and extend hold the chunk kernel {held}, "
+            f"on the chip {on_chip}")
     cold = timed_serve("first pass", decoder, requests, clock)
     warm = timed_serve("second pass (every slot reused)", decoder,
                        requests, clock)

@@ -87,6 +87,19 @@ def _paged(int8, fold, width, side, slots=8, t_cap=1280,
              ((slots, width, side), jnp.bool_), ((slots,), jnp.int32)])
 
 
+def _delta_chunk(tokens, heads, dk, dv, by_head, rows=1):
+    """ops/delta_chunk.py over a prompt's piece: the state as the pool
+    keeps it, heads side by side where the gate is a head's."""
+    from aiko_services_tpu.ops.delta_chunk import delta_chunk_scan
+    f32 = jnp.float32
+    vector = lambda *tail: ((rows, tokens, heads) + tail, f32)  # noqa: E731
+    return (lambda *args: delta_chunk_scan(*args, interpret=False)[0],
+            [vector(dk), vector(dk), vector(dv),
+             vector() if by_head else vector(dk), vector(),
+             ((rows, dk, heads * dv) if by_head else (rows, heads, dk, dv),
+              f32)])
+
+
 MISTRAL = {"slots": 24, "t_cap": 2048, "head_dim": 128}
 
 CASES = {
@@ -124,6 +137,21 @@ CASES = {
     "paged-int8-d128-step-w1": lambda: _paged(True, True, 1, 4, **MISTRAL),
     "paged-int8-d128-extend-c512": lambda: _paged(
         True, False, 512, 512, **(MISTRAL | {"slots": 1})),
+    # the chunked delta rule as one kernel a layer (ISSUE 41), at the two
+    # cells' heads and pieces: olmo-hybrid's admit (a bucket of 512, 30
+    # heads of [96, 192], a gate a head: pairs of heads are 384 lanes of
+    # the state, a head's own 192 are cut out of them at no vector's edge)
+    # and a piece that is no whole chunk; glm's extend (512 tokens of 64
+    # heads of [128, 128], a gate a channel)
+    "delta-chunk-gdn-t512": lambda: _delta_chunk(512, 30, 96, 192, True),
+    "delta-chunk-gdn-t200-two-rows": lambda: _delta_chunk(
+        200, 30, 96, 192, True, rows=2),
+    "delta-chunk-kda-t512": lambda: _delta_chunk(512, 64, 128, 128, False),
+    # sides that are no whole lanes, a gate of each grain: what
+    # `scans_chunks` lets through off the published widths
+    "delta-chunk-gdn-small-heads": lambda: _delta_chunk(128, 8, 8, 16, True),
+    "delta-chunk-kda-half-lanes": lambda: _delta_chunk(128, 32, 64, 64,
+                                                       False),
 }
 
 
@@ -875,12 +903,10 @@ def test_sparse_gqa_extend_chooses_without_a_sort_and_copies_no_leaf(chip):
 
 # -- slot state beside a pool the shared kernel walks (ISSUE 40) -----------------
 
-@pytest.fixture(scope="module")
-def gated_delta_step(chip):
-    """The whole 16-layer `jit_step` x 4 of `gdn_decode_saturated` as the
-    cell's decoder builds it on the chip (`step_kernel` for both reasons:
-    the full layers' walk of the pool, the recurrent layers' state through
-    ops/kda_step.py), compiled once: -> (compiled, config, `serving`)."""
+def _gated_delta_cell(chip):
+    """`gdn_decode_saturated`'s model and the arguments its programs
+    share, as shapes on `chip`: (config, `serving`, params, k_pools,
+    v_pools, state, `shaped`)."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -913,6 +939,19 @@ def gated_delta_step(chip):
         for side in (0, 1))
     state = [tuple(shaped((slots,) + tuple(shape), kind)
                    for shape, kind in layer) for layer in config.slot_state]
+    return config, serve, params, k_pools, v_pools, state, shaped
+
+
+@pytest.fixture(scope="module")
+def gated_delta_step(chip):
+    """The whole 16-layer `jit_step` x 4 of `gdn_decode_saturated` as the
+    cell's decoder builds it on the chip (`step_kernel` for both reasons:
+    the full layers' walk of the pool, the recurrent layers' state through
+    ops/kda_step.py), compiled once: -> (compiled, config, `serving`)."""
+    from aiko_services_tpu import serving_paged
+    config, serve, params, k_pools, v_pools, state, shaped = \
+        _gated_delta_cell(chip)
+    slots, block = serve["max_slots"], serve["kv_block"]
     table = -(-(serve["max_seq"] + serve["steps_per_sync"]) // block)
     vector = shaped((slots,), jnp.int32)
     model = config.paged_model()
@@ -959,6 +998,46 @@ def test_gated_delta_step_moves_slot_state_through_the_kernel_alone(
     # 8.20 GB of weights, 4.03 GB of pool, 1.75 GB of slot state
     assert 13.9e9 < memory.argument_size_in_bytes < 14.1e9
     assert memory.temp_size_in_bytes < 0.3e9
+
+
+def test_gated_delta_admit_scans_a_prompt_in_one_kernel_a_layer(chip):
+    """The cell's `jit_admit` (one prompt padded to the bucket of 512) as a
+    decoder traces it on the chip: the twelve recurrent layers' chunked
+    delta rule is twelve `gdn_chunk_scan` custom calls under
+    `aiko.gdn_scan` (ISSUE 41), their state argument aliased to their
+    result, and NO loop is left under that scope: XLA's form of
+    models/delta_rule.chunked was a `while` of eight trips a layer."""
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import gated_delta as M
+    config, serve, params, k_pools, v_pools, state, shaped = \
+        _gated_delta_cell(chip)
+    slots, block = serve["max_slots"], serve["kv_block"]
+    bucket = serve["prefill_buckets"][-1]
+    vector = shaped((slots,), jnp.int32)
+    one, flag = shaped((1,), jnp.int32), shaped((1,), bool)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        assert config.paged_model().scan_kernel(config, False) is True
+        serving_paged._paged_admit_fn_for.cache_clear()
+        compiled = serving_paged._paged_admit_fn_for(
+            config, bucket, 1, False, False).lower(
+            params, k_pools, v_pools, vector, vector,
+            shaped((1, 1), jnp.int32), shaped((1, bucket), jnp.int32), one,
+            one, flag, shaped((1, -(-bucket // block)), jnp.int32),
+            state).compile()
+    serving_paged._paged_admit_fn_for.cache_clear()
+    lines = [line.strip() for line in compiled.as_text().splitlines()]
+    scans = [line for line in lines if "tpu_custom_call" in line and
+             M.SCOPE_GDN_SCAN in line]
+    recurrent = sum(kind == "gdn" for kind in config.layer_types)
+    assert len(scans) == recurrent == 12
+    assert all("gdn_chunk_scan" in line and
+               "output_to_operand_aliasing" in line for line in scans)
+    assert not [line[:160] for line in lines
+                if re.search(r" while\(", line) and M.SCOPE_GDN_SCAN in line]
+    # weights 8.20 GB, pool 4.03, slot state 1.75: the admit's own
+    # temporaries a quarter of a gigabyte
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
 def test_gated_delta_step_walks_the_full_layers_pool_and_copies_none_of_it(

@@ -11,6 +11,7 @@
 # Comparisons are of LOGITS or states, never of sampled tokens.  Each
 # tolerance states its reason.
 
+import contextlib
 import os
 import sys
 
@@ -140,12 +141,37 @@ def serve(params, requests, name="gated-delta", kernel=False, **kwargs):
     for rid, (prompt, new) in requests.items():
         assert decoder.submit(rid, prompt, new, lambda rid, tokens:
                               served.__setitem__(rid, list(tokens)))
-    for _ in range(400):
-        if len(served) == len(requests):
-            break
-        decoder.pump()
+    with scan_kernel_interpreted(kernel):
+        for _ in range(400):
+            if len(served) == len(requests):
+                break
+            decoder.pump()
     assert len(served) == len(requests)
     return served, decoder
+
+
+@contextlib.contextmanager
+def scan_kernel_interpreted(kernel: bool):
+    """`kernel`: a prompt's pieces take ops/delta_chunk's kernel too, in
+    the interpreter (the model takes it unasked on a chip alone: the
+    choice is made where the admit and the extend are TRACED, so the
+    builders' caches, which know nothing of it, are emptied around)."""
+    if not kernel:
+        yield
+        return
+    builders = (serving_paged._paged_admit_fn_for,
+                serving_paged._paged_extend_fn_for)
+    for builder in builders:
+        builder.cache_clear()
+    traced, scan = [], M.delta_chunk_scan
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(M, "_scan_kernel", lambda config, interpret: True)
+        patch.setattr(M, "delta_chunk_scan",
+                      lambda *args: traced.append(1) or scan(*args))
+        yield
+    for builder in builders:
+        builder.cache_clear()
+    assert traced, "no admit or extend was traced through the chunk kernel"
 
 
 def served_gaps(requests, served):

@@ -11,6 +11,7 @@
 # Comparisons are of LOGITS, states or attention outputs, never of sampled
 # tokens.  Each tolerance states its reason.
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -226,19 +227,46 @@ def decoder_for(params, name, buckets=(8, 32), chunk=32, slots=4, **kwargs):
         prefill_budget=chunk, steps_per_sync=4, name=name, **kwargs)
 
 
-def serve(params, requests, name="hybrid", kernel=False, **kwargs):
+def serve(params, requests, name="hybrid", kernel=False, scan=False,
+          **kwargs):
     decoder = decoder_for(params, name, **kwargs)
     assert decoder._walks_live and decoder.step_kernel is kernel
     served = {}
     for rid, (prompt, new) in requests.items():
         assert decoder.submit(rid, prompt, new, lambda rid, tokens:
                               served.__setitem__(rid, list(tokens)))
-    for _ in range(400):
-        if len(served) == len(requests):
-            break
-        decoder.pump()
+    with scan_kernel_interpreted(scan):
+        for _ in range(400):
+            if len(served) == len(requests):
+                break
+            decoder.pump()
     assert len(served) == len(requests)
     return served, decoder
+
+
+@contextlib.contextmanager
+def scan_kernel_interpreted(scan: bool):
+    """`scan`: a prompt's pieces take ops/delta_chunk's kernel, in the
+    interpreter (the model takes it unasked on a chip alone: the choice is
+    made where the admit and the extend are TRACED, so the builders'
+    caches, which know nothing of it, are emptied around)."""
+    if not scan:
+        yield
+        return
+    from aiko_services_tpu import serving_paged
+    builders = (serving_paged._paged_admit_fn_for,
+                serving_paged._paged_extend_fn_for)
+    for builder in builders:
+        builder.cache_clear()
+    traced, kernel = [], M.delta_chunk_scan
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(M, "_scan_kernel", lambda config, interpret: True)
+        patch.setattr(M, "delta_chunk_scan",
+                      lambda *args: traced.append(1) or kernel(*args))
+        yield
+    for builder in builders:
+        builder.cache_clear()
+    assert traced, "no admit or extend was traced through the chunk kernel"
 
 
 def served_gaps(requests, served):
@@ -255,18 +283,23 @@ def served_gaps(requests, served):
     return out
 
 
+@pytest.mark.parametrize("scan", [False, True],
+                         ids=["chunked-by-xla", "chunk-kernel-interpreted"])
 def test_prefill_then_decode_through_pool_and_state_agrees_with_one_forward(
-        params):
+        params, scan):
     """Six requests over four slots: prompts of 10 and 30 go in by one
     padded admit, 5 by a narrow one, 45 and 77 by chains of 32-token
     extends whose last chunk is padded, 64 by two whole chunks; two wait
     for a slot that another request leaves.  All decode 11 tokens, past
     16 positions, so every step chooses groups; each served token is the
-    reference's best at its position to within the tolerance."""
+    reference's best at its position to within the tolerance.  `scan`:
+    the admits' and the extends' KDA layers through ops/delta_chunk's
+    kernel (ISSUE 41), as a chip runs them."""
     rng = np.random.default_rng(7)
     requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 11)
                 for n in (10, 45, 77, 5, 30, 64)}
-    served, decoder = serve(params, requests)
+    served, decoder = serve(params, requests, name=f"agree-{scan}",
+                            scan=scan)
     stats = decoder.stats
     assert stats["prefill_chunks"] == 7 and stats["prefills"] == 3
     assert stats["slot_states_zeroed"] == 6
