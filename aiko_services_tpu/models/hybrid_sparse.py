@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.delta_chunk import delta_chunk_scan, scans_chunks
-from ..ops.kda_step import kda_live_step, moves_live_states
+from ..ops.kda_step import _live_ids, kda_live_step, moves_live_states
 from . import delta_rule
 from . import layers as L
 from .latent_moe import (MOE_COUNTERS, absorb_output, absorb_queries,
@@ -78,16 +78,25 @@ SCOPE_DSA_RELAYOUT = "aiko.dsa_relayout"
 # of the chosen groups fetched for them (a whole tile a group), then over
 # the KDA layers the slot states S the token changed (the slots that
 # decoded: what the kernel moves, once in and once out) and those the
-# layer holds (every slot: what XLA's form of the recurrence passes over)
+# layer holds (every slot: what XLA's form of the recurrence passes over),
+# then over the sparse-attention layers again the slots the step computed
+# for (whole windows of `_STEP_WINDOW`, of the slots live at the round's
+# entry) and the slots that decoded in it
 _DSA_COUNTERS = ("dsa_positions_live", "dsa_positions_attended",
                  "dsa_rows_fetched")
-HYBRID_COUNTERS = MOE_COUNTERS + _DSA_COUNTERS + ("kda_states_moved",
-                                                  "kda_states_held")
+_KDA_COUNTERS = ("kda_states_moved", "kda_states_held")
+_WINDOW_COUNTERS = ("dsa_slots_computed", "dsa_slots_decoding")
+HYBRID_COUNTERS = MOE_COUNTERS + _DSA_COUNTERS + _KDA_COUNTERS + \
+    _WINDOW_COUNTERS
 _OWN_COUNTERS = len(HYBRID_COUNTERS) - len(MOE_COUNTERS)
+# where a KDA layer's two and a sparse layer's five lie in the counts
+_KDA_AT = tuple(map(HYBRID_COUNTERS.index, _KDA_COUNTERS))
+_DSA_AT = tuple(map(HYBRID_COUNTERS.index, _DSA_COUNTERS + _WINDOW_COUNTERS))
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _PREFIX_PIECE = 512    # positions of the prefix an extend attends at once
 _TILE_ROWS = 8         # rows of a leaf that lie together in the chip's memory
+_STEP_WINDOW = 8       # slots the sparse layer's decode step takes at once
 
 
 @dataclass(frozen=True)
@@ -633,26 +642,83 @@ class _PoolPrefix:
 
 def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
               leaves, sides, left, entry_lengths, lengths, step_index,
-              active):
+              entry_active, active):
     """The sparse layer in a decode step, x [S, 1, dim] at position
-    lengths[s]: index scores over the pooled keys of the slot's whole
-    length, the best groups' latent rows GATHERED from the pool, one
-    softmax over them, the open group's rows and this round's.  The
-    leaf keeps its rows in tiles of `_TILE_ROWS`, so the gather takes
-    the whole tile that holds a chosen group from the leaf as it lies
-    (seen by tiles the leaf is the same bytes: SCOPE_DSA_RELAYOUT holds
-    no operation) and the tile's other groups are masked: it ATTENDS the
-    chosen rows alone and READS `_TILE_ROWS / index_pool` times as many.
-    `left` [S, 128] is the key sum of the slot's open group.  Returns
-    (out, the sides rewritten, the new sum, [positions live, positions
+    lengths[s].  The projections run once over every slot (their cost
+    is their weights', whatever decodes); what grows with the slots,
+    scores, choice, gather and softmax, runs for the slots that were
+    live at the round's entry alone: their ids compacted (`entry_active`
+    does not change inside a round, and a slot only LEAVES `active` in
+    it), `_STEP_WINDOW` of them at a time through `_dsa_window`, what it
+    made written back to their rows.  A slot that was not live at entry
+    costs no window and keeps its side rows, its side keys and its
+    `left` (it may stand between two pieces of its prompt) as they were;
+    its `out` is zeros.  Returns (out, the sides rewritten, the new sum,
+    `_dsa_window`'s three counts over the slots that decode and then the
+    slots computed for, whole windows)."""
+    slots_n = x.shape[0]
+    width = min(_STEP_WINDOW, slots_n)
+    ids, count = _live_ids(entry_active)
+    # past the count an id no slot has: clipped where a window reads,
+    # dropped where it writes (slot 0 is written by its own lane alone)
+    pad = -slots_n % width
+    ids = jnp.where(jnp.arange(slots_n + pad) < count,
+                    jnp.pad(ids, (0, pad)), slots_n)
+    windows = -(-count[0] // width)
+    projected = _dsa_project(layer, config, x, cos, sin, lengths)
+
+    def window(j, carried):
+        o_lat, side_rows, side_keys, left, counted = carried
+        at = jax.lax.dynamic_slice_in_dim(ids, j * width, width)
+
+        def held(whole):
+            return jnp.take(whole, at, axis=0, mode="clip")
+
+        made, rewritten, summed, tally = _dsa_window(
+            config, tuple(map(held, projected)), held(tables), leaves,
+            (held(side_rows), held(side_keys)), held(left),
+            held(entry_lengths), held(lengths), step_index,
+            held(active) & (at < slots_n))
+        return tuple(whole.at[at].set(part, mode="drop")
+                     for whole, part in zip(
+                         (o_lat, side_rows, side_keys, left),
+                         (made,) + rewritten + (summed,))) + \
+            (counted + tally,)
+
+    o_lat, side_rows, side_keys, left, counted = jax.lax.fori_loop(
+        0, windows, window,
+        (jnp.zeros((slots_n, 1, config.num_heads, config.kv_rank),
+                   x.dtype),) + tuple(sides) + (
+            left, jnp.zeros((len(_DSA_COUNTERS),), jnp.int32)))
+    with jax.named_scope(SCOPE_ATTN_PROJ):
+        out = absorb_output(layer["attn"], config, o_lat, 1)
+    return out, (side_rows, side_keys), left, jnp.concatenate(
+        [counted, jnp.stack([windows * width, active.sum()]).astype(
+            jnp.int32)])
+
+
+def _dsa_window(config: HybridSparseConfig, projected, tables, leaves,
+                sides, left, entry_lengths, lengths, step_index, active):
+    """One window of `_dsa_step`: `projected` is `_dsa_project`'s five
+    and every other argument a slot's, at the window's W slots (every
+    slot's arithmetic is its own): index scores over the pooled keys of
+    the slot's whole length, the best groups' latent rows GATHERED from
+    the pool, one softmax over them, the open group's rows and this
+    round's.  The leaf keeps its rows in tiles of `_TILE_ROWS`, so the
+    gather takes the whole tile that holds a chosen group from the leaf
+    as it lies (seen by tiles the leaf is the same bytes:
+    SCOPE_DSA_RELAYOUT holds no operation) and the tile's other groups
+    are masked: it ATTENDS the chosen rows alone and READS `_TILE_ROWS /
+    index_pool` times as many.  `left` [W, 128] is the key sum of the
+    slot's open group.  Returns (the attended latents [W, 1, H, rank],
+    the sides rewritten, the new sum, [positions live, positions
     attended, rows fetched] over the slots that decode)."""
     pool_n, rank = config.index_pool, config.kv_rank
     latent, keys = leaves
     side_rows, side_keys = sides
-    slots_n = x.shape[0]
+    q_full, rows, q_i, k_i, weights = projected
+    slots_n = q_full.shape[0]
     block = latent.shape[2]
-    q_full, rows, q_i, k_i, weights = _dsa_project(layer, config, x, cos,
-                                                   sin, lengths)
     steps = side_rows.shape[2]
     open_group = entry_lengths // pool_n                       # G0 [S]
     group = lengths // pool_n
@@ -674,7 +740,9 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
                          jnp.where(closes[:, None], 0.0, total), left)
         # scores over the pool's complete groups and the round's
         table_groups = tables.shape[1] * block // pool_n
-        pooled = jnp.take(keys, tables, axis=0)[:, :, 0]      # [S, nb, B/p, 128]
+        # every id is a block of the pool: no fill for one that is not
+        pooled = jnp.take(keys, tables, axis=0,
+                          mode="clip")[:, :, 0]               # [W, nb, B/p, 128]
         pooled = pooled.reshape(slots_n, table_groups, -1)
         everything = jnp.concatenate([pooled, side_keys[:, 0]], axis=1)
         scores = _index_scores(q_i, weights, everything)[:, 0]  # [S, G + P]
@@ -701,7 +769,6 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
         where = jnp.where(from_pool, where, 0)
         with jax.named_scope(SCOPE_DSA_RELAYOUT):
             tiles = latent.reshape(-1, _TILE_ROWS, rank)
-        # every id is a tile of the pool: no fill for one that is not
         chosen = jnp.take(tiles, where // per_tile, axis=0, mode="clip")
         chosen = chosen.reshape(slots_n, limit * _TILE_ROWS, rank)
         chosen_ok = (from_pool[:, :, None] & (
@@ -746,16 +813,14 @@ def _dsa_step(layer, config: HybridSparseConfig, x, cos, sin, tables,
         e_far, e_near = jnp.exp(s_far - top), jnp.exp(s_near - top)
         total = e_far.sum(axis=-1) + e_near.sum(axis=-1)
         o_lat = ((weighed(e_far, chosen) + weighed(e_near, near)) /
-                 total[..., None]).astype(x.dtype)[:, None]
+                 total[..., None]).astype(rows.dtype)[:, None]
         counted = jnp.stack([
             jnp.where(active, lengths + 1, 0).sum(),
             jnp.where(active, chosen_ok.sum(axis=1) + near_ok.sum(axis=1),
                       0).sum(),
             jnp.where(active, from_pool.sum(axis=1) * _TILE_ROWS, 0).sum()
         ]).astype(jnp.int32)
-    with jax.named_scope(SCOPE_ATTN_PROJ):
-        out = absorb_output(layer["attn"], config, o_lat, 1)
-    return out, (side_rows, side_keys), left, counted
+    return o_lat, (side_rows, side_keys), left, counted
 
 
 # -- feed-forward ----------------------------------------------------------------
@@ -899,14 +964,13 @@ def _step_attention(kernel: bool):
                 layer, config, x, state, active[:, None],
                 live_only=kernel and _state_kernel(
                     config, jax.default_backend() != "tpu"))
-            return out, sides, state, counts.at[-2:].set(
+            return out, sides, state, counts.at[jnp.asarray(_KDA_AT)].set(
                 jnp.stack([active.sum(), active.size]).astype(jnp.int32))
         out, sides, left, counted = _dsa_step(
             layer, config, x, cos, sin, tables, leaves, sides, state[0],
-            entry_lengths, lengths, step_index, active)
-        first = len(MOE_COUNTERS)
+            entry_lengths, lengths, step_index, entry_active, active)
         return out, sides, (left,), counts.at[
-            first:first + len(_DSA_COUNTERS)].set(counted)
+            jnp.asarray(_DSA_AT)].set(counted)
 
     return attend
 

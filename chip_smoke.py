@@ -1026,6 +1026,7 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
     import numpy as np
 
     from aiko_services_tpu import serving
+    from aiko_services_tpu.models import hybrid_sparse
     from benchmark import weights_hybrid_sparse as W
     from benchmark.reference import hybrid_sparse_lm
 
@@ -1095,7 +1096,20 @@ def phase_hybrid(shape: dict, seed: int, on_chip: bool,
         f"{stats['dsa_positions_live']} live positions; pairs here "
         f"{stats['moe_pairs_here']} of {stats['moe_pairs_routed']}; KDA "
         f"states moved {stats['kda_states_moved']} of "
-        f"{stats['kda_states_held']} held")
+        f"{stats['kda_states_held']} held; the sparse step computed for "
+        f"{stats['dsa_slots_computed']} slots where "
+        f"{stats['dsa_slots_decoding']} decoded")
+    # the sparse layer's step takes the slots live at a round's entry a
+    # window at a time: fewer decode here than a window leaves over, so
+    # it never computes for the house
+    sparse_layers = sum(kind == "dsa" for kind in config.layer_types)
+    require(stats["dsa_slots_decoding"] <= stats["dsa_slots_computed"] and (
+        len(requests) >= own["slots"] - hybrid_sparse._STEP_WINDOW or
+        stats["dsa_slots_computed"] <
+        own["slots"] * stats["steps"] * sparse_layers),
+        f"hybrid: the sparse step computed for "
+        f"{stats['dsa_slots_computed']} slots over {stats['steps']} steps "
+        f"of {own['slots']} slots, {stats['dsa_slots_decoding']} decoding")
     # experts are chosen, and groups: as in phase_latent a token passes
     # within 2 deviations and the MEAN is held to the cell's own limit in
     # bfloat16; float32 against float32 leaves near-ties alone
@@ -1442,7 +1456,7 @@ def shapes(rehearse: bool) -> dict:
                     experts_held=4),
                 "hybrid": {
                     "sizes": hybrid_sizes(128, None),
-                    "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
+                    "max_seq": 128, "slots": 16, "prefill_buckets": (8, 32),
                     "prefill_chunk": 32,
                     "prompt_lengths": (8, 20, 44, 100)},
                 "sparse_gqa": {
@@ -1470,10 +1484,12 @@ def shapes(rehearse: bool) -> dict:
             # the published widths, a KDA layer with the dense MLP and a
             # sparse-attention layer with 12 of the 288 experts, an
             # eighth of the vocabulary: 1.8 GB in bfloat16; a prompt of
-            # 2,600 positions reaches past the 2,048 attended at most
+            # 2,600 positions reaches past the 2,048 attended at most;
+            # sixteen slots for four requests, so that the sparse step's
+            # window of eight leaves slots it does not compute for
             "hybrid": {
                 "sizes": hybrid_sizes(3072, 12),
-                "max_seq": 3072, "slots": 4, "prefill_buckets": (64, 256),
+                "max_seq": 3072, "slots": 16, "prefill_buckets": (64, 256),
                 "prefill_chunk": 256,
                 "prompt_lengths": (64, 200, 1024, 2600)},
             # the published widths, two layers with 16 of the 128 experts,

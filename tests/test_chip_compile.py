@@ -719,9 +719,10 @@ def test_hybrid_step_fetches_whole_tiles_from_the_latent_leaf_as_it_lies(
     assert len([line for line in made if " fusion(" in line]) == 1
     assert [" bitcast(" in line for line in lines
             if M.SCOPE_DSA_RELAYOUT in line] == [True]
-    # [slots x top_groups, 8, rank] a step, out of the leaf seen by tiles
+    # [window x top_groups, 8, rank] a window of the slots that decode
+    # (ISSUE 42; every slot's until then), out of the leaf seen by tiles
     tiles = "bf16[%d,8,%d]" % (rows // 8, config.kv_rank)
-    fetched = "bf16[%d,8,%d]" % (serve["max_slots"] * config.top_groups,
+    fetched = "bf16[%d,8,%d]" % (M._STEP_WINDOW * config.top_groups,
                                  config.kv_rank)
     gathers = [line for line in lines
                if re.match(r"%\S+ = " + re.escape(fetched), line) and

@@ -401,13 +401,13 @@ def _step_over_a_pool(layer, h, p, block, table):
     left = jnp.stack([k_i[0, 4 * whole:p].sum(axis=0),
                       jnp.zeros((config.index_dim,))])
     x = jnp.stack([h[p:p + 1], jnp.zeros((1, 64))])
+    live = jnp.asarray([True, False])      # at the round's entry, and now
     out, _, _, counted = jax.jit(
-        lambda *args: M._dsa_step(layer, config, *args, 0,
-                                  jnp.asarray([True, False])))(
+        lambda *args: M._dsa_step(layer, config, *args, 0, live, live))(
         x, cos, sin, tables, (jnp.asarray(latent), jnp.asarray(pooled)),
         (jnp.zeros((2, 1, 1, config.kv_rank)),
          jnp.zeros((2, 1, 1, config.index_dim))), left, lengths, lengths)
-    return np.asarray(out[0, 0]), np.asarray(counted).tolist()
+    return np.asarray(out[0, 0]), np.asarray(counted)[:3].tolist()
 
 
 # a tile of 8 rows holds two groups of 4: group g is the lower half of its
@@ -456,6 +456,132 @@ def test_a_reused_slots_step_reads_nothing_the_longer_request_left(params):
     assert (np.abs(leaf[1:]).max(axis=(1, 2, 3)) > 0.05).all()
     for rid, gap in served_gaps({"a": first, "b": second}, both).items():
         assert gap < LOGIT_TOLERANCE, (rid, gap)
+
+
+# -- the step computes for the slots that decode (ISSUE 42) ----------------------
+
+WINDOW = M._STEP_WINDOW
+ROUND_SLOTS, ROUND_STEPS, ROUND_BLOCK = WINDOW + 4, 4, 8
+
+
+def _round_inputs(config):
+    """What `jit_step` takes for `ROUND_SLOTS` slots in the middle of
+    their answers, as numpy: RANDOM pools and slot state (both forms of
+    the layer read the same, whatever it is), lengths of 17 to 50 (every
+    step chooses groups), each slot's table its own blocks."""
+    rng = np.random.default_rng(42)
+    width = -(-(64 + ROUND_STEPS) // ROUND_BLOCK)
+    pool = BlockPool(config, ROUND_BLOCK, False,
+                     initial_blocks=ROUND_SLOTS * width, name="windows")
+
+    def drawn(leaf):
+        return None if leaf is None else rng.standard_normal(
+            leaf.shape).astype(np.float32)
+
+    tables = 1 + rng.permutation(ROUND_SLOTS * width).reshape(
+        ROUND_SLOTS, width).astype(np.int32)
+    state = [tuple(0.3 * drawn(leaf) for leaf in layer)
+             for layer in SlotState(config, ROUND_SLOTS).arrays]
+    return dict(
+        tokens=rng.integers(1, 256, ROUND_SLOTS).astype(np.int32),
+        lengths=rng.integers(17, 50, ROUND_SLOTS).astype(np.int32),
+        k_pools=[drawn(leaf) for leaf in pool.k_pools],
+        v_pools=[drawn(leaf) for leaf in pool.v_pools],
+        tables=tables, state=state)
+
+
+@pytest.fixture(scope="module")
+def round_programs(params):
+    """`jit_step` of the tiny model twice: as it is, and with the sparse
+    layer's body run ONCE over every slot (`_dsa_window` at the full
+    width between the projections, what `_dsa_step` was before it took
+    windows): -> (inputs,
+    run(program name, active, budgets) -> the program's results)."""
+    from aiko_services_tpu import serving_paged
+    config = model_config()
+    inputs = _round_inputs(config)
+
+    def every_slot(layer, config, x, cos, sin, tables, leaves, sides, left,
+                   entry_lengths, lengths, step_index, entry_active, active):
+        o_lat, sides, left, counted = M._dsa_window(
+            config, M._dsa_project(layer, config, x, cos, sin, lengths),
+            tables, leaves, sides, left, entry_lengths, lengths, step_index,
+            active)
+        return (M.absorb_output(layer["attn"], config, o_lat, 1), sides,
+                left, jnp.concatenate([counted, jnp.zeros((2,), jnp.int32)]))
+
+    def arguments(active, budgets):
+        return (params, inputs["tokens"], inputs["lengths"], active,
+                budgets, inputs["k_pools"], inputs["v_pools"],
+                inputs["tables"], inputs["state"])
+
+    programs = {}
+    first = arguments(np.ones(ROUND_SLOTS, bool),
+                      np.ones(ROUND_SLOTS, np.int32))
+    for name in ("windows", "every-slot"):
+        with pytest.MonkeyPatch.context() as patch:
+            if name == "every-slot":
+                patch.setattr(M, "_dsa_step", every_slot)
+            programs[name] = serving_paged._build_paged_step(
+                config, False).lower(
+                    *first, num_steps=ROUND_STEPS, eos=-1,
+                    t_cap=128).compile()
+
+    def run(name, active, budgets):
+        emitted, emitted_active, _, lengths, k_pools, v_pools, counts, \
+            state = programs[name](*jax.tree.map(
+                jnp.asarray, arguments(active, budgets)))
+        return dict(
+            emitted=np.asarray(emitted), active=np.asarray(emitted_active),
+            lengths=np.asarray(lengths), pools=jax.tree.map(
+                np.asarray, [k_pools, v_pools]),
+            counts=dict(zip(M.HYBRID_COUNTERS, np.asarray(counts).tolist())),
+            state=jax.tree.map(np.asarray, state))
+
+    return inputs, run
+
+
+@pytest.mark.parametrize("live, slot_zero", [
+    (0, False), (1, True), (1, False), (WINDOW - 1, False), (WINDOW, True),
+    (WINDOW + 1, True), (WINDOW + 1, False), (ROUND_SLOTS, True)],
+    ids=str)
+def test_the_sparse_step_computes_for_the_slots_live_at_entry(
+        round_programs, live, slot_zero):
+    """A round of four steps over 12 slots of which `live` decode, slot 0
+    among them or not, one of them out of budget after two steps: the
+    step that takes the live slots in windows of 8 serves what the body
+    over every slot serves (tokens exactly, the pools and the state of
+    the live slots to the tolerance), leaves what a slot that was not
+    live holds as it was to the last bit, counts the same positions and
+    rows, and computes for whole windows of the live slots alone."""
+    inputs, run = round_programs
+    rng = np.random.default_rng(100 * live + slot_zero)
+    others = 1 + rng.permutation(ROUND_SLOTS - 1)
+    chosen = ([0] if slot_zero else []) + others.tolist()
+    active = np.zeros(ROUND_SLOTS, bool)
+    active[chosen[:live]] = True
+    budgets = np.where(active, ROUND_STEPS, 0).astype(np.int32)
+    if live:
+        budgets[chosen[live - 1]] = 2
+    ours, theirs = run("windows", active, budgets), \
+        run("every-slot", active, budgets)
+    assert (ours["emitted"] == theirs["emitted"]).all()
+    assert (ours["active"] == theirs["active"]).all()
+    assert ours["active"].sum() == max(0, 4 * live - 2)
+    for one, other in zip(jax.tree.leaves(ours["pools"]),
+                          jax.tree.leaves(theirs["pools"])):
+        assert np.abs(one - other).max() < LOGIT_TOLERANCE
+    for one, other, before in zip(*map(jax.tree.leaves, (
+            ours["state"], theirs["state"], inputs["state"]))):
+        assert np.abs(one - other)[active].max(initial=0) < LOGIT_TOLERANCE
+        assert (one[~active] == before[~active]).all()
+    for name in M._DSA_COUNTERS + M._KDA_COUNTERS:
+        assert ours["counts"][name] == theirs["counts"][name], name
+    assert (ours["counts"]["dsa_positions_live"] > 0) == (live > 0)
+    steps = int(ours["active"].any(axis=1).sum())
+    assert ours["counts"]["dsa_slots_computed"] == \
+        -(-live // WINDOW) * WINDOW * steps
+    assert ours["counts"]["dsa_slots_decoding"] == ours["active"].sum()
 
 
 # -- the step's recurrence: which form, and what it counts (ISSUE 34) ------------
