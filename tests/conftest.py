@@ -2,7 +2,10 @@
 # jax import, so multi-chip sharding tests run without TPU hardware
 # (SURVEY.md §4: TPU-less CI via the jax CPU backend).
 
+import atexit
 import os
+import shutil
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 # Run the whole suite under the lock-order race detector (utils/lock.py):
@@ -10,10 +13,31 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # graph, so an ABBA inversion anywhere in the tests surfaces as a
 # potential-deadlock report instead of a once-a-month CI hang.
 os.environ.setdefault("AIKO_LOCK_CHECK", "1")
+# The CPU's code at LLVM's -O0 (ISSUE 43; CHANGES.md, PR 43, has what this
+# and the cache below were worth in a run of the driver's command): a model
+# case's time is compilation, and a tiny model's program runs no slower for
+# it; what the HLO passes make of a program, which is what the tests read,
+# is the same at every level, and so is a program for the described v5e.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = \
-        (_flags + " --xla_force_host_platform_device_count=8").strip()
+    _flags += " --xla_force_host_platform_device_count=8"
+if "xla_backend_optimization_level" not in _flags:
+    _flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = _flags.strip()
+# One persistent compilation cache for the run: much of what a case
+# compiles another case, file or worker compiles too.  The first process to
+# load this file (xdist's controller, or a plain run) makes a directory
+# under TMPDIR, names it in the environment, which the workers inherit, and
+# removes it as it exits; one that the environment already names is used and
+# left.  Every program is kept: the eager cases' are small and many.  (The
+# `v5e` fixture turns the cache off around compiles for the described chip.)
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="aiko-tier1-jax-cache-")
+    atexit.register(shutil.rmtree, os.environ["JAX_COMPILATION_CACHE_DIR"],
+                    ignore_errors=True)
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 import pytest  # noqa: E402
 
@@ -58,6 +82,36 @@ def _no_metrics_snapshot_retained_from_another_test():
                       if topic.endswith("/" + METRICS_TOPIC_SUFFIX)]:
             del broker._retained[topic]
     yield
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e 2x2 (the TPU compiler is installed here though no
+    chip is); the persistent compilation cache is off around the module
+    (an executable compiled for a described device cannot be read back
+    without one: a warm cache would only add warnings)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:           # no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e: {exc!r}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topology
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(v5e):
+    """SingleDeviceSharding on one chip of the described v5e."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.fixture

@@ -700,9 +700,14 @@ class TestPagedDisagg:
 
     def test_burst_coalesces_into_batch_envelopes(self, params):
         """Same-destination transfers inside the batch window ride ONE
-        kv_transfer_batch envelope (PR 14 residue b)."""
+        kv_transfer_batch envelope (PR 14 residue b).  The client
+        waits for a transfer as long as the test waits for the burst:
+        on a loaded machine the prefill side's first compile outlasts
+        the default 5 s twice, and a transfer that gives up is
+        prefilled locally and never installed."""
         harness = make_harness(params, disagg=True, max_slots=8,
                                prefill_slots=4, batch_window=0.05,
+                               transfer_timeout=300.0,
                                decoder_opts={"paged_kv": True})
         try:
             assert harness.wait_discovered(15.0)

@@ -1,14 +1,15 @@
 # The sparse grouped-query decoder (ISSUE 38: K, V and an indexer key a token
 # in three pool leaves, the exact top `topk` positions chosen a query by a
 # lightning indexer, softmax-routed held experts and no shared expert) at a
-# small size on the CPU in float32: the model against the benchmark's plain
-# reference (benchmark/reference/sparse_gqa_lm.py: sectioned rotary, its own
+# small size on the CPU in float32, the layer's own functions, no decoder:
+# the model against the benchmark's plain reference
+# (benchmark/reference/sparse_gqa_lm.py: sectioned rotary, its own
 # sort-based top-k, one masked softmax, experts as a loop, precision
-# "highest"), prefill through admit and chunked extend then decode through
-# the pool on both sides of `topk` and across it, the selection's ties, the
-# rotary's two forms, the eight-way share, the router's plain columns,
-# the parameter count, the pool's geometry, and the serving paths that
-# refuse.
+# "highest"), the selection's ties, the rotary's two forms, the step's
+# attention as a mask, the eight-way share, the router's plain columns, the
+# parameter count, the pool's geometry.  This file holds the suite's SIZES
+# and its `CASES` (tests/paged_model_cases.py); the cases that serve
+# through a decoder are in test_0_served_sparse_gqa.py.
 #
 # Comparisons are of LOGITS or masks, never of sampled tokens.  Each
 # tolerance states its reason.
@@ -16,28 +17,22 @@
 import dataclasses
 import functools
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (ROOT, os.path.join(ROOT, "benchmark", "drivers")):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+from paged_model_cases import ROOT, PagedModelCases, share_layer
 
-import aiko_services_tpu.serving as serving  # noqa: E402
-from aiko_services_tpu import serving_paged  # noqa: E402
-from aiko_services_tpu.models import latent_moe  # noqa: E402
-from aiko_services_tpu.models import sparse_gqa as M  # noqa: E402
-from aiko_services_tpu.ops.paged_attention import walk_positions  # noqa: E402
-from aiko_services_tpu.serving import ContinuousDecoder  # noqa: E402
-from aiko_services_tpu.serving_paged import BlockPool  # noqa: E402
-from benchmark import ops_bytes_sparse_gqa as ops  # noqa: E402
-from benchmark import weights_sparse_gqa as W  # noqa: E402
-from benchmark.reference import sparse_gqa_lm as R  # noqa: E402
+from aiko_services_tpu import serving_paged
+from aiko_services_tpu.models import latent_moe
+from aiko_services_tpu.models import sparse_gqa as M
+from aiko_services_tpu.ops.paged_attention import walk_positions
+from aiko_services_tpu.serving_paged import BlockPool
+from benchmark import ops_bytes_sparse_gqa as ops
+from benchmark import weights_sparse_gqa as W
+from benchmark.reference import sparse_gqa_lm as R
 
 SEED = 2**31 + 29
 # every mechanism of the published file at a size a test holds: two layers,
@@ -63,63 +58,47 @@ SIZES = dict(
 LOGIT_TOLERANCE = 2e-4
 
 
-def model_config(sizes=SIZES, dtype=jnp.float32, max_seq=128):
-    import sparse_gqa_decoder
-    return sparse_gqa_decoder.model_config(sizes, max_seq, dtype)
+CASES = PagedModelCases(
+    "sparse_gqa_decoder", W,
+    lambda tokens, sizes, seed, streams=None: R.forward_logits(
+        tokens, sizes, seed, jnp.float32, streams), SIZES, SEED)
+model_config = CASES.model_config
+TOKENS = np.random.default_rng(0).integers(1, 256, size=90)
 
 
-@pytest.fixture(scope="module")
-def params():
-    return W.decoder_weights(W.key_for(SEED), SIZES, jnp.float32)
+def reference_logits(tokens, sizes=SIZES, streams=None):
+    if streams is None:
+        return CASES.reference_logits(tokens, sizes)
+    return np.asarray(CASES.reference_forward(tokens, sizes, SEED, streams))
 
 
-def reference_logits(tokens, sizes=SIZES, seed=SEED, streams=None):
-    return np.asarray(R.forward_logits(tokens, sizes, seed, jnp.float32,
-                                       streams))
-
-
-def test_seeded_weights_have_the_programs_layout(params):
+def test_seeded_weights_have_the_programs_layout():
     assert model_config() == M.SPARSE_GQA_PRESETS["tiny"]
-    ours = jax.eval_shape(
-        lambda: M.sparse_gqa_init(jax.random.PRNGKey(0), model_config()))
-    assert jax.tree.structure(ours) == jax.tree.structure(params)
-    for (path, a), (_, b) in zip(jax.tree_util.tree_leaves_with_path(ours),
-                                 jax.tree_util.tree_leaves_with_path(params)):
-        assert (a.shape, a.dtype) == (b.shape, b.dtype), \
-            jax.tree_util.keystr(path)
-    assert "shared" not in params["layers"][0]
+    CASES.has_the_layout_of(M.sparse_gqa_init)
+    assert "shared" not in CASES.params["layers"][0]
 
 
-def test_full_forward_agrees_with_the_reference(params):
+def test_full_forward_agrees_with_the_reference():
     """90 tokens where a query attends 16: the choice of positions is in
     every later logit, and the reference found it by its own sort."""
-    tokens = np.random.default_rng(0).integers(1, 256, size=90)
-    ours = M.sparse_gqa_forward(params, model_config(),
-                                jnp.asarray(tokens)[None])[0]
-    theirs = reference_logits(tokens)
-    assert float(theirs.std()) > 0.5            # logits of spread ~1
-    assert np.abs(np.asarray(ours) - theirs).max() < LOGIT_TOLERANCE
+    gap, spread = CASES.forward_gap(M.sparse_gqa_forward, TOKENS)
+    assert spread > 0.5                         # logits of spread ~1
+    assert gap < LOGIT_TOLERANCE
 
 
-def test_the_selection_is_in_the_numbers(params):
+def test_the_selection_is_in_the_numbers():
     """Attending everything (topk past the sequence) is another model."""
-    tokens = np.random.default_rng(0).integers(1, 256, size=90)
-    dense = SIZES | {"sa_config": SIZES["sa_config"] | {"topk": 128}}
-    assert np.abs(reference_logits(tokens, dense) -
-                  reference_logits(tokens)).max() > 100 * LOGIT_TOLERANCE
+    dense = reference_logits(
+        TOKENS, SIZES | {"sa_config": SIZES["sa_config"] | {"topk": 128}})
+    sparse = reference_logits(TOKENS)
+    assert np.abs(dense - sparse).max() > 100 * LOGIT_TOLERANCE
     # and up to topk positions it is the same model: nothing is left out
-    assert np.abs(reference_logits(tokens, dense)[:16] -
-                  reference_logits(tokens)[:16]).max() < LOGIT_TOLERANCE
+    assert np.abs(dense[:16] - sparse[:16]).max() < LOGIT_TOLERANCE
 
 
-def test_bfloat16_would_fail(params):
-    tokens = np.random.default_rng(0).integers(1, 256, size=90)
-    low = jax.tree.map(lambda leaf: leaf.astype(jnp.bfloat16)
-                       if leaf.ndim > 1 else leaf, params)
-    ours = M.sparse_gqa_forward(low, model_config(dtype=jnp.bfloat16),
-                                jnp.asarray(tokens)[None])[0]
-    assert np.abs(np.asarray(ours) - reference_logits(tokens)).max() > \
-        10 * LOGIT_TOLERANCE
+def test_bfloat16_would_fail():
+    gap, _ = CASES.forward_gap(M.sparse_gqa_forward, TOKENS, jnp.bfloat16)
+    assert gap > 10 * LOGIT_TOLERANCE
 
 
 # -- rotary ----------------------------------------------------------------------
@@ -192,7 +171,7 @@ def test_top_positions_is_exact_with_ties_to_the_lower_index(case):
     assert (ours.sum(-1) == min(limit, 40)).all()
 
 
-def test_program_and_reference_choose_the_same_positions(params):
+def test_program_and_reference_choose_the_same_positions():
     """The program's choice (a bit-by-bit threshold, in a block and in the
     step alike) and the reference's (a sort's) over the same scores, ties
     made on purpose: one set, and a stable sort's."""
@@ -322,168 +301,7 @@ def test_the_step_attends_the_chosen_positions_as_a_mask(
         (live & (np.asarray(lengths) + 1 <= 16)).sum()]
 
 
-# -- through the decoder: admit, chunked extend, decode through the pool ---------
-
-def decoder_for(params, name, buckets=(8, 32), chunk=32, slots=4, **kwargs):
-    return ContinuousDecoder(
-        params, model_config(), paged_kv=True, kv_block=8, max_slots=slots,
-        max_seq=128, prefill_buckets=buckets, prefill_chunk=chunk,
-        prefill_budget=chunk, steps_per_sync=4, name=name, **kwargs)
-
-
-def serve(params, requests, name="sparse-gqa", kernel=False, **kwargs):
-    """`kernel`: the step as a chip's decoder builds it (the walk with the
-    chosen positions as its mask, here in the interpreter), asked for by
-    name as off a chip it must be; else the plain form."""
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(serving, "ATTENTION_IMPL",
-                      "paged_kernel" if kernel else None)
-        decoder = decoder_for(params, name, **kwargs)
-    assert decoder._walks_live and decoder.step_kernel is kernel
-    served = {}
-    for rid, (prompt, new) in requests.items():
-        assert decoder.submit(rid, prompt, new, lambda rid, tokens:
-                              served.__setitem__(rid, list(tokens)))
-    for _ in range(400):
-        if len(served) == len(requests):
-            break
-        decoder.pump()
-    assert len(served) == len(requests)
-    return served, decoder
-
-
-def served_gaps(requests, served):
-    """Per request, how far each served token's logit lies below the
-    reference's best at its position (one full teacher-forced forward),
-    in standard deviations of that position's logits."""
-    out = {}
-    for rid, (prompt, _) in requests.items():
-        tokens = served[rid]
-        logits = reference_logits(np.asarray(prompt + tokens[:-1]))
-        at = logits[len(prompt) - 1:]
-        out[rid] = float(((at.max(-1) - at[np.arange(len(tokens)), tokens])
-                          / at.std(-1)).max())
-    return out
-
-
-def _pool_reads(prompt: int, new: int, kernel: bool) -> int:
-    """What one layer's steps read of the pool for a request of `prompt`
-    tokens that decodes `new` - 1 times in rounds of four steps: a round's
-    steps walk the blocks of 8 that were live as the round began (the
-    kernel), or gather the one piece that a table of 128 positions is."""
-    steps = new - 1
-    return sum(
-        min(4, steps - first) *
-        (int(walk_positions(np.int32(prompt + first), 8)) if kernel else 128)
-        for first in range(0, steps, 4))
-
-
-@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
-def test_prefill_then_decode_through_the_pool_agrees_with_one_forward(
-        params, kernel):
-    """Seven requests over four slots: prompts of 10 and 30 go in by one
-    padded admit, 5 and 3 by a narrow one, 45 and 77 by chains of 32-token
-    extends whose last chunk is padded, 64 by two whole chunks; three wait
-    for a slot that another request leaves.  All decode 11 tokens: 3 stays
-    under topk 16 (every step attends everything), 5 ends AT it, 10
-    crosses it while decoding, the others are past it from the start;
-    each served token is the reference's best at its position to within
-    the tolerance."""
-    rng = np.random.default_rng(7)
-    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 11)
-                for n in (10, 45, 77, 5, 30, 64, 3)}
-    served, decoder = serve(params, requests, name=f"sparse-gqa-{kernel}",
-                            kernel=kernel)
-    stats = decoder.stats
-    assert stats["prefill_chunks"] == 7 and stats["prefills"] == 4
-    for rid, gap in served_gaps(requests, served).items():
-        assert gap < LOGIT_TOLERANCE, (rid, gap)
-    # every pair of the whole model lands on a held expert
-    assert stats["moe_pairs_here"] == stats["moe_pairs_routed"] > 0
-    assert 0 < stats["moe_layer_steps"] <= 2 * stats["steps"]
-    # what was attended: everything up to 16 positions, 16 past them
-    assert 0 < stats["dsa_positions_attended"] < \
-        0.6 * stats["dsa_positions_live"]
-    # what the steps READ of the pool to attend that: every live block of
-    # a slot that decodes (the kernel), the table's one piece (plain);
-    # the round's own rows come from nowhere
-    assert stats["dsa_rows_fetched"] == 2 * sum(
-        _pool_reads(len(prompt), new, kernel)
-        for prompt, new in requests.values())
-    assert stats["dsa_rows_fetched"] > stats["dsa_positions_attended"]
-    # r3's ten steps, r5's ten and r10's six (positions 10 to 15), in
-    # each of two layers (a prompt's first token comes from its prefill)
-    assert stats["dsa_slot_steps_dense"] == 2 * (10 + 10 + 6)
-
-
-def test_slots_beyond_one_group_are_served_group_by_group(params):
-    """Six slots are two groups of `_SLOT_GROUP` (the second padded with
-    rows that drop): the slots that decode are taken first, so a round
-    with five live computes both groups, one with two live the first
-    alone; every token is the reference's best either way."""
-    assert M._SLOT_GROUP == 4
-    rng = np.random.default_rng(12)
-    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 4 + n % 5)
-                for n in (9, 21, 33, 50, 62, 18, 40)}
-    served, decoder = serve(params, requests, name="two-groups", slots=6)
-    for rid, gap in served_gaps(requests, served).items():
-        assert gap < LOGIT_TOLERANCE, (rid, gap)
-    stats = decoder.stats
-    # a step a generated token after the first, in each of two layers
-    assert stats["dsa_slot_steps_dense"] == 2 * 7        # r9: positions 9-15
-    assert stats["dsa_positions_live"] == 2 * sum(
-        n + j for n in (9, 21, 33, 50, 62, 18, 40)
-        for j in range(1, 4 + n % 5))
-
-
-def test_the_counters_of_long_contexts_alone_say_nothing_was_dense(params):
-    rng = np.random.default_rng(9)
-    requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 6)
-                for n in (40, 70)}
-    _, decoder = serve(params, requests, name="long-only")
-    stats = decoder.stats
-    assert stats["dsa_slot_steps_dense"] == 0
-    # five steps a request in two layers, 16 positions each
-    assert stats["dsa_positions_attended"] == 2 * 2 * 5 * 16
-
-
-def test_a_served_token_altered_is_seen(params):
-    rng = np.random.default_rng(8)
-    requests = {"a": (rng.integers(1, 256, size=12).tolist(), 6)}
-    served, _ = serve(params, requests, name="altered")
-    served["a"][2] = (served["a"][2] + 1) % 256
-    assert served_gaps(requests, served)["a"] > 100 * LOGIT_TOLERANCE
-
-
-def test_chunked_extend_equals_one_shot(params):
-    """77 tokens through chunks of 32 and of 16: the same logits' choice,
-    whatever the pieces the prefix was read in."""
-    rng = np.random.default_rng(10)
-    requests = {"c": (rng.integers(1, 256, size=77).tolist(), 5)}
-    wide, _ = serve(params, requests, name="chunks-32")
-    narrow, _ = serve(params, requests, name="chunks-16", chunk=16,
-                      buckets=(8, 16))
-    assert wide == narrow
-
-
-def test_a_slot_reused_reads_nothing_the_longer_request_left(params):
-    """One slot: a request of 90 positions, then one of 20 in the same
-    blocks; the second's indexer sees stale keys past its length and
-    must choose none of them."""
-    rng = np.random.default_rng(11)
-    requests = {"long": (rng.integers(1, 256, size=90).tolist(), 4),
-                "short": (rng.integers(1, 256, size=20).tolist(), 8)}
-    served, _ = serve(params, requests, name="reused", slots=1)
-    for rid, gap in served_gaps(requests, served).items():
-        assert gap < LOGIT_TOLERANCE, (rid, gap)
-
-
 # -- the expert layer: softmax scores, no shared expert, the chip's share --------
-
-def share_layer(layer, first, held):
-    return layer | {"experts": jax.tree.map(
-        lambda w: w[first:first + held], layer["experts"])}
-
 
 @pytest.mark.parametrize("tokens", [24, 200], ids=["decode-block", "tiles"])
 @pytest.mark.parametrize("chips", [8, 2], ids=["eight-chips", "two-chips"])
@@ -637,57 +455,9 @@ def test_the_driver_refuses_keys_it_does_not_compute():
                                  {"indexer_num_kv_heads": 2}})
 
 
-# -- the paths three leaves are not carried through refuse, by name --------------
-
-@pytest.mark.parametrize("kwargs, named", [
-    (dict(paged_kv=False), "dense slot cache"),
-    (dict(kv_cache_dtype="int8"), "int8 KV cache"),
-    (dict(speculate_k=2), "speculative decoding"),
-    (dict(prefix_cache=True), "prefix cache"),
-    (dict(weight_quant=True), "weight-only int8"),
-], ids=["dense", "int8-kv", "speculation", "prefix-cache", "weight-quant"])
-def test_paths_not_carried_refuse_at_construction(params, kwargs, named):
-    kwargs = dict(paged_kv=True, kv_block=8, max_slots=2, max_seq=64,
-                  prefill_chunk=32) | kwargs
-    if kwargs.get("prefix_cache"):
-        kwargs["prefix_cache"] = serving.PrefixKVCache(block_tokens=8)
-    with pytest.raises(ValueError, match=named):
-        ContinuousDecoder(params, model_config(max_seq=64), **kwargs)
-
-
-def test_tensor_parallel_weights_refuse_at_construction(params):
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
-    sharded = dict(params)
-    sharded["lm_head"] = {"w": jax.device_put(
-        params["lm_head"]["w"], NamedSharding(mesh, P(None, "model")))}
-    with pytest.raises(ValueError, match="tensor-parallel"):
-        ContinuousDecoder(sharded, model_config(max_seq=64), paged_kv=True,
-                          kv_block=8, max_slots=2, max_seq=64,
-                          prefill_chunk=32)
-
-
-@pytest.mark.parametrize("path", ["drain", "wire-layout", "install",
-                                  "disagg-client"])
-def test_drain_and_the_kv_wire_refuse_by_name(params, path):
-    decoder = ContinuousDecoder(params, model_config(max_seq=64),
-                                paged_kv=True, kv_block=8, max_slots=2,
-                                max_seq=64, prefill_chunk=32,
-                                name=f"refuse-sparse-gqa-{path}")
-    with pytest.raises(ValueError, match="not carried"):
-        if path == "drain":
-            decoder.drain()
-        elif path == "wire-layout":
-            decoder.kv_wire_layout()
-        elif path == "install":
-            decoder.install_shipped_blocks([1] * 16, 0, [{}])
-        else:
-            from aiko_services_tpu.serving_disagg import PrefillClient
-            PrefillClient(None, decoder)
-
-
 def test_serving_tests_no_models_name():
     for module in ("serving.py", "serving_paged.py"):
         with open(os.path.join(ROOT, "aiko_services_tpu", module)) as f:
             text = f.read()
         assert "sparse_gqa" not in text and "SparseGqa" not in text
+
