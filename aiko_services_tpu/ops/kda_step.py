@@ -76,13 +76,26 @@
 # transpose XLA makes of [S, H, Dk], 49 KB a slot beside 2.2 MB), a head's
 # column read at a static lane and spread over the head's lanes.
 #
+# THE PLAIN DECAYED RULE (Mamba-2, arXiv:2405.21060; ISSUE 45: 64 heads of
+# [128 (state), 64 (head)], B the key and C the query ONE vector for every
+# head of a slot) is a static argument of that body, `plain`, and no third
+# kernel:
+#
+#     S <- exp(g) S + k v^T;   o = S^T q
+#
+# with no `beta (v - S'^T k)` term: the write is v itself, no beta arrives,
+# and k, q and q . k are a slot's (one column each, lanes 0 and 64 of the
+# same [Dk, 128] block, spread over every lane once an item and not a head
+# at a time).
+#
 # Validated where: tests/test_kda_step.py (interpreter, CPU: mixed, none
 # and all slots live, bits of the untouched states, four donated steps in
-# a while_loop, both bodies, unequal head sides); tests/test_chip_compile.py
-# (each cell's whole step compiled for a described v5e: one custom call a
-# recurrent layer, no other operation on a state leaf); chip_smoke.py's
-# hybrid and gated_delta phases and the cells long_doc_open_loop and
-# gdn_decode_saturated on the chip.
+# a while_loop, both bodies, unequal head sides, the plain rule);
+# tests/test_chip_compile.py (each cell's whole step compiled for a described
+# v5e: one custom call a recurrent layer, no other operation on a state
+# leaf); chip_smoke.py's hybrid, gated_delta and ssm_hybrid phases and the
+# cells long_doc_open_loop, gdn_decode_saturated and ssm_chat_open_loop on the
+# chip.
 
 from __future__ import annotations
 
@@ -278,7 +291,9 @@ def kda_live_step(q, k, v, g, beta, state, active, *,
         g [S, H, Dk] (a channel), state [S, H, Dk, Dv] float32, or
         g [S, H] (a head), state [S, Dk, H x Dv] float32: the heads side
         by side on the lanes (`heads_side_by_side` lays it so)
-    -> (o [S, H, Dv], the new state, laid as it came).  Where `active` is
+    -> (o [S, H, Dv], the new state, laid as it came).  `beta` None (a
+    gate a head only) is the PLAIN decayed rule, S <- exp(g) S + k v^T,
+    o = S^T q, with q and k [S, Dk], one vector for every head of a slot.  Where `active` is
     False the state comes back bit for bit (it is never touched: the
     result IS the argument's buffer, `input_output_aliases`) and o is
     zeros; g and beta of such a slot are not read.  Equals
@@ -372,16 +387,19 @@ def heads_apart(state, heads: int):
     return state.reshape(a, dk, heads, lanes // heads).transpose(0, 2, 1, 3)
 
 
-def _head_kernel(count_ref, ids_ref, decay_ref, beta_ref, qk_ref, cols_hbm,
-                 v_ref, state_hbm, o_ref, state_out, ring, cols, arrived,
-                 left, *, heads: int, group: int):
+def _head_kernel(count_ref, ids_ref, decay_ref, *refs, heads: int, group: int,
+                 plain: bool):
     """The body of a gate a head (header).  `state_out` is `state_hbm`'s
-    own buffer; an item is a slot's whole state."""
+    own buffer; an item is a slot's whole state.  `plain`: the decayed rule
+    with no correction, k, q and q . k a slot's (no beta arrives)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    beta_ref = None if plain else refs[0]
+    (qk_ref, cols_hbm, v_ref, state_hbm, o_ref, state_out, ring, cols,
+     arrived, left) = refs[0 if plain else 1:]
     dk, lanes = state_hbm.shape[1:]
     dv = lanes // heads
     wide = group * dv
@@ -412,6 +430,11 @@ def _head_kernel(count_ref, ids_ref, decay_ref, beta_ref, qk_ref, cols_hbm,
                 out = jnp.where(lane >= j * dv, of(j), out)
             return out
 
+        if plain:
+            # the slot's one k and one q, for every head of every group
+            k, q = (jnp.broadcast_to(cols[at, :, base:base + 1], (dk, wide))
+                    for base in (0, _LANES // 2))
+
         # every group written out: a head's column sits at a STATIC lane
         for n in range(heads // group):
             first = n * group
@@ -426,10 +449,21 @@ def _head_kernel(count_ref, ids_ref, decay_ref, beta_ref, qk_ref, cols_hbm,
                     cols[at, :, base + first + j:base + first + j + 1],
                     (dk, wide)))
 
-            k, q = column(0), column(_LANES // 2)
+            if not plain:
+                k, q = column(0), column(_LANES // 2)
             decayed = ring[at, :, here] * scalar(decay_ref)          # [Dk, w]
-            seen = jnp.sum(decayed * k, axis=0, keepdims=True)       # [1, w]
+            if not plain:
+                seen = jnp.sum(decayed * k, axis=0, keepdims=True)   # [1, w]
             asked = jnp.sum(decayed * q, axis=0, keepdims=True)
+            # (the plain rule's v and o are a [1, lanes] slab a slot: a row
+            # at a dynamic sublane read straight into a broadcast, or
+            # written from none, is refused, "dynamic load / store with
+            # unaligned indices")
+            if plain:
+                write = v_ref[slot, :, here]
+                ring[at, :, here] = decayed + k * write
+                o_ref[slot, :, here] = asked + qk_ref[slot] * write
+                continue
             write = scalar(beta_ref) * (v_ref[pl.ds(slot, 1), here] - seen)
             ring[at, :, here] = decayed + k * write
             o_ref[pl.ds(slot, 1), here] = asked + scalar(qk_ref) * write
@@ -450,35 +484,43 @@ def _head_step(q, k, v, g, beta, state, active, *, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    slots, heads, dk = q.shape
-    dv = v.shape[2]
+    slots, heads, dv = v.shape
+    dk = q.shape[-1]
     half = _LANES // 2
+    plain = beta is None
     ids, count = _live_ids(active)
     # k and q as columns, a slot a [Dk, 128] block: k's heads at lanes
-    # [0, 64), q's at [64, 128)
+    # [0, 64), q's at [64, 128) (the plain rule's one k and one q: lanes 0
+    # and 64)
     columns = jnp.concatenate(
-        [jnp.pad(z.transpose(0, 2, 1), ((0, 0), (0, 0), (0, half - heads)))
+        [jnp.pad(z[:, :, None] if plain else z.transpose(0, 2, 1),
+                 ((0, 0), (0, 0), (0, half - (1 if plain else heads))))
          for z in (k, q)], axis=-1)
+    # what the rule reads as scalars: the decay, the delta rule's beta, q . k
+    rule = (jnp.exp(g).reshape(-1),) + (
+        () if plain else (beta.reshape(-1),)) + (
+        (q * k).sum(axis=-1).reshape(-1),)
+    # v and o, whole in VMEM, a row a slot (the plain rule's: a slab)
+    rows = (slots,) + (1,) * plain + (heads * dv,)
     scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out, state = pl.pallas_call(
         functools.partial(_head_kernel, heads=heads,
-                          group=_head_group(heads, dv)),
-        out_shape=(jax.ShapeDtypeStruct((slots, heads * dv), jnp.float32),
+                          group=_head_group(heads, dv), plain=plain),
+        out_shape=(jax.ShapeDtypeStruct(rows, jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        in_specs=[scalars] * 5 + [in_hbm, in_vmem, in_hbm],
+        in_specs=[scalars] * (2 + len(rule)) + [in_hbm, in_vmem, in_hbm],
         out_specs=(in_vmem, in_hbm),
         scratch_shapes=[pltpu.VMEM((_RING, dk, heads * dv), jnp.float32),
                         pltpu.VMEM((_RING, dk, _LANES), jnp.float32),
                         pltpu.SemaphoreType.DMA((_RING,)),
                         pltpu.SemaphoreType.DMA((_RING,))],
-        input_output_aliases={7: 1},
+        input_output_aliases={4 + len(rule): 1},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_HEAD_VMEM_LIMIT),
-        name="gdn_live_step",
+        name="ssm_live_step" if plain else "gdn_live_step",
         interpret=interpret,
-    )(count, ids, jnp.exp(g).reshape(-1), beta.reshape(-1),
-      (q * k).sum(axis=-1).reshape(-1), columns,
-      v.reshape(slots, heads * dv), state)
+    )(count, ids, *rule, columns,
+      v.reshape(rows), state)
     return out.reshape(slots, heads, dv), state

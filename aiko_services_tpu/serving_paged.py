@@ -139,7 +139,11 @@ class PagedModel:
         extend_layer's layer(..., prepared, state) -> (x, rows, state)
     residual_in(config, x), final_norm(params, config, x): what an
         extend does to the embedding before the first layer and instead
-        of the last norm, where the residual is not one stream
+        of the last norm, where the residual is not one stream (or the
+        embedding is scaled, or the norm's epsilon is the model's own)
+    head(params, config, hidden) -> f32 logits of an admit's or a
+        chunk's last position, where the head is not `lm_head` (ISSUE
+        45: the embedding itself, tied)
 
     A layer may keep MORE than two leaves of a token (ISSUE 38: K, V and
     an indexer key): `sides` and `leaves` then have one entry a leaf, and
@@ -159,6 +163,7 @@ class PagedModel:
     block_multiple: int = 1
     residual_in: object = None
     final_norm: object = None
+    head: object = None
 
 
 def reads_own_pool(config) -> bool:
@@ -1124,7 +1129,9 @@ def _paged_admit_fn_for(config, bucket: int, width: int,
             idx = jnp.maximum(true_lens - 1, 0)
             last_hidden = jnp.take_along_axis(
                 hidden, idx[:, None, None], axis=1)[:, 0]
-            last = L.linear_logits(params["lm_head"], last_hidden)
+            last = L.linear_logits(params["lm_head"], last_hidden) \
+                if model.head is None \
+                else model.head(params, config, last_hidden)
             firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
         nbb = tables_rows.shape[1]
         padded_t = nbb * block_tokens
@@ -1241,7 +1248,9 @@ def _paged_extend_fn_for(config, chunk_len: int,
                 else model.final_norm(params, config, x)
             last_hidden = jnp.take_along_axis(
                 x, final_idx[:, None, None], axis=1)[:, 0]
-            last = L.linear_logits(params["lm_head"], last_hidden)
+            last = L.linear_logits(params["lm_head"], last_hidden) \
+                if model.head is None \
+                else model.head(params, config, last_hidden)
             firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
         apply = valid & finish
         tokens = tokens.at[slots].set(

@@ -60,6 +60,21 @@ Phases of the default run:
            vocabulary: prompts admitted whole and prompts that go chunk by
            chunk, decode, every slot served twice; every served token held
            to benchmark/reference/gated_delta_lm.py
+  ssm_hybrid  ops/kda_step.py's PLAIN decayed rule (Mamba-2: one k and one
+           q a slot) against its four-line oracle at the published head
+           sizes (64 heads of [128, 64]; some slots live, none, all) and
+           its time for a step's 36 layers against the memory's speed,
+           then ContinuousDecoder on models/ssm_hybrid.py (Mamba-2 slot
+           state beside a pool whose row is a K/V head's V and K side by
+           side, which the shared paged kernel walks) at the published
+           widths, four layers (three Mamba, one attention) and the whole
+           tied vocabulary: prompts admitted whole and prompts that go
+           chunk by chunk, decode, every slot served twice; every served
+           token held to benchmark/reference/ssm_hybrid_lm.py
+
+--only <phase> [<phase> ...] runs those phases alone (a builder's chip
+minutes; a run that skips a phase never says "ok": it ends with the
+rehearsal's line).
 """
 
 from __future__ import annotations
@@ -1338,6 +1353,195 @@ def phase_gated_delta(shape: dict, seed: int, on_chip: bool,
 
 
 
+def check_plain_live_step(heads: int, width: int, state_lanes: int,
+                          slots: int, layers: int, key,
+                          on_chip: bool) -> bool:
+    """ops.kda_step.kda_live_step's plain rule (beta None: S <- exp(g) S +
+    k v^T, o = S^T q, ONE k and ONE q a slot) against the four lines
+    written out, at `heads` heads of [state_lanes, width] laid side by
+    side; some slots live, none, all.  On the chip also its TIME: `layers`
+    calls in one program (a decode step's Mamba layers) with three slots
+    in four live, against the live states' bytes once in and once out at
+    the memory's speed.  -> whether a decode step takes the kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu.ops import kda_step
+
+    takes = kda_step.moves_live_states(heads, state_lanes, not on_chip,
+                                       value_dim=width, by_head=True)
+    say(f"  kda_live_step, the plain rule, H{heads} [{state_lanes}, "
+        f"{width}]: a decode step takes it {takes}")
+    if not takes:
+        return False
+    keys = jax.random.split(key, 5)
+    q = jax.random.normal(keys[0], (slots, state_lanes))
+    k = jax.random.normal(keys[1], (slots, state_lanes))
+    v = jax.random.normal(keys[2], (slots, heads, width))
+    g = -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], (slots, heads)))
+    state = jax.random.normal(keys[4], (slots, state_lanes, heads * width))
+
+    def plain(q, k, v, g, state, active):
+        return kda_step.kda_live_step(q, k, v, g, None, state, active,
+                                      interpret=not on_chip)
+
+    @jax.jit
+    def oracle(q, k, v, g, state):
+        new = state * jnp.repeat(jnp.exp(g), width, axis=-1)[:, None, :] + \
+            k[:, :, None] * v.reshape(slots, 1, -1)
+        return jnp.einsum("sd,sdl->sl", q, new,
+                          precision=jax.lax.Precision.HIGHEST).reshape(
+                              v.shape), new
+
+    kernel = jax.jit(plain)
+    if on_chip:
+        require(lowered_has_kernel(kernel, q, k, v, g, state,
+                                   jnp.ones((slots,), bool)),
+                "kda_live_step lowered without a tpu_custom_call")
+    want_out, want = oracle(q, k, v, g, state)
+    for label, live in (("some", np.arange(slots) % 3 == 1),
+                        ("none", np.zeros(slots, bool)),
+                        ("all", np.ones(slots, bool))):
+        out, new = kernel(q, k, v, g, state, jnp.asarray(live))
+        scale = float(jnp.abs(want_out).max())
+        worst = max(float(jnp.abs(out - want_out)[live].max(initial=0.0))
+                    / scale,
+                    float(jnp.abs(new - want)[live].max(initial=0.0)))
+        kept = bool(np.array_equal(np.asarray(new)[~live],
+                                   np.asarray(state)[~live])) and \
+            not np.asarray(out)[~live].any()
+        say(f"  kda_live_step, the plain rule, {label} of {slots} slots "
+            f"live: max|kernel-oracle|={worst:.2e}, the others untouched "
+            f"{kept}")
+        # float32 sums of a hundred terms in another order
+        require(worst <= 1e-5 and kept,
+                f"kda_live_step, the plain rule, {label} live off by {worst}")
+    if on_chip:
+        live = np.arange(slots) % 4 != 3
+        active = jnp.asarray(live)
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def step(state):
+            def layer(_, carry):
+                state, total = carry
+                out, state = plain(q, k, v, g * 0.01, state, active)
+                return state, total + out
+            return jax.lax.fori_loop(0, layers, layer,
+                                     (state, jnp.zeros_like(v)))
+
+        held = step(state + 0.0)
+        jax.block_until_ready(held)
+        rounds, start = 10, time.perf_counter()
+        for _ in range(rounds):
+            held = step(held[0])
+        jax.block_until_ready(held)
+        seconds = (time.perf_counter() - start) / rounds
+        moved = 2 * int(live.sum()) * layers * state[0].nbytes
+        say(f"  kda_live_step, the plain rule, {layers} calls with "
+            f"{int(live.sum())} of {slots} slots live: {seconds * 1e3:.3f} "
+            f"ms a step's worth (host clock, one dispatch), {moved / 1e9:.3f}"
+            f" GB in and out, {moved / seconds / 1e9:.0f} GB/s = "
+            f"{100 * moved / seconds / 819e9:.1f}% of 819 GB/s")
+    return True
+
+
+def phase_ssm_hybrid(shape: dict, seed: int, on_chip: bool,
+                     clock: CompileClock) -> None:
+    """models/ssm_hybrid.py through the same decoder: Mamba-2 layers whose
+    state is a slot's (zeroed at admit, carried from chunk to chunk, moved
+    by ops/kda_step's plain rule in the step on the chip) beside attention
+    layers of 64-wide K/V heads whose one pool leaf, a head's V and K side
+    by side, the shared paged kernel walks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu import serving
+    from benchmark import run as bench
+    from benchmark import weights_ssm_hybrid as W
+    from benchmark.reference import ssm_hybrid_lm
+
+    own = shape["ssm_hybrid"]
+    sizes = own["sizes"]
+    dtype = jnp.dtype(shape["llama_dtype"])
+    config = bench.load_module("drivers", sizes["driver"]).model_config(
+        sizes, own["max_seq"], dtype)
+    takes = check_plain_live_step(
+        config.ssm_heads, config.ssm_head_dim, config.ssm_state,
+        own["kernel_slots"], own["kernel_layers"],
+        jax.random.PRNGKey(seed + 45), on_chip)
+    require(takes, "the phase's head sizes do not take the kernel")
+    params = W.decoder_weights(W.key_for(seed), sizes, dtype)
+    rng = np.random.default_rng(seed)
+    requests = {
+        f"r{i}": (rng.integers(1, config.vocab, size=length).tolist(),
+                  shape["new_tokens"])
+        for i, length in enumerate(own["prompt_lengths"])}
+    decoder = serving.ContinuousDecoder(
+        params, config, paged_kv=True, max_slots=own["slots"],
+        max_seq=own["max_seq"], t_block=own["max_seq"],
+        prefill_buckets=own["prefill_buckets"],
+        prefill_chunk=own["prefill_chunk"],
+        prefill_budget=own["prefill_chunk"],
+        steps_per_sync=shape["steps_per_sync"], name="ssm_hybrid")
+    # told nothing, on the chip the step is the kernels' for BOTH reasons
+    # (the walk of the pool's rows, the plain rule over the live slots'
+    # state): the decoder's one flag keeps one meaning (ISSUE 45, route 1)
+    require(decoder.step_kernel == on_chip and
+            decoder._walks_live == on_chip and
+            bool(decoder._model_kernel),
+            f"ssm_hybrid: step_kernel {decoder.step_kernel}, walks live "
+            f"{decoder._walks_live}, on the chip {on_chip}")
+    cold = timed_serve("first pass", decoder, requests, clock)
+    warm = timed_serve("second pass (every slot reused)", decoder,
+                       requests, clock)
+    require(cold == warm, "the same requests served twice differ: a "
+            "slot's state outlived its request")
+    stats, pool = decoder.stats, decoder.pool
+    require(stats["prefill_chunks"] > 0 and stats["prefills"] > 0,
+            "the prompts did not take both the admit and the extend")
+    require(stats["slot_states_zeroed"] == 2 * len(requests),
+            "a request did not start from zeroed state")
+    require(0 < stats["ssm_states_moved"] <= stats["ssm_states_held"],
+            "the recurrence moved no state, or more than the layers hold")
+    attending = [i for i, kind in enumerate(config.layer_types)
+                 if kind == "attention"]
+    require(all((pool.k_pools[i] is not None) == (i in attending)
+                for i in range(config.num_layers)) and not pool.v_pools and
+            pool.block_nbytes == decoder.kv_block * len(attending) * 2 *
+            config.num_kv_heads * config.head_dim *
+            jnp.dtype(config.dtype).itemsize,
+            "a Mamba layer holds pool blocks, or an attention layer none")
+    varied = min(len(set(tokens)) / len(tokens) for tokens in cold.values())
+    say(f"  prefill_chunks={stats['prefill_chunks']} rounds="
+        f"{stats['rounds']} leaves {pool.k_pools[attending[0]].shape} state "
+        f"{decoder.slot_state.nbytes() / 1e6:.1f} MB; states moved "
+        f"{stats['ssm_states_moved']} of {stats['ssm_states_held']} held; "
+        f"distinct tokens a served token, the least {varied:.2f}")
+    require(varied > 0.5, "a request was served the same few tokens over "
+            "and over: the tied head reads the embedding back")
+    # no expert and no group is chosen: a token passes within the dense
+    # cell's own gap in bfloat16, and float32 leaves near-ties alone
+    half = dtype == jnp.bfloat16
+    tolerance = 0.5 if half else 1e-3
+    numbers = ssm_hybrid_lm.check(
+        [{"prompt": prompt, "served": cold[request_id]}
+         for request_id, (prompt, _) in requests.items()],
+        sizes, seed, str(dtype), say=lambda line: say("  " + line))["numbers"]
+    worst = max(numbers["served_token_gap_std"])
+    require(np.isfinite(worst) and worst <= tolerance,
+            f"ssm_hybrid: a served token is {worst:.3f} logit-std below "
+            f"the reference's best (tolerance {tolerance})")
+    for name, limit in sizes["correctness"]["limits"].items():
+        require(not half or max(numbers[name]) <= limit,
+                f"ssm_hybrid: {name} {max(numbers[name]):.4f} over the "
+                f"cell's limit {limit}")
+    say(f"  every token within {tolerance} logit-std of the plain "
+        f"reference's best (worst {worst:.4f}, mean "
+        f"{numbers['served_token_gap_mean_std'][0]:.5f})")
+
+
 # -- four chips --------------------------------------------------------------
 
 def phase_tensor_parallel(shape: dict, seed: int, on_chip: bool,
@@ -1448,6 +1652,19 @@ def shapes(rehearse: bool) -> dict:
             "num_hidden_layers": 4,
             "layer_types": ["linear_attention"] * 3 + ["full_attention"],
             "serving": {"max_seq": max_seq}})
+
+    def ssm_hybrid_sizes(max_seq: int) -> dict:
+        """The Mamba-2 cell's configuration file (its `rehearse` sizes laid
+        over it for a rehearsal) cut to four layers, one of them
+        attention."""
+        sizes = bench.load_json("benchmark", "configs",
+                                "granite-4.0-h-micro.json")
+        if rehearse:
+            sizes = bench.merged(sizes, sizes["rehearse"])
+        return bench.merged(sizes, {
+            "num_hidden_layers": 4,
+            "layer_types": ["mamba", "mamba", "attention", "mamba"],
+            "serving": {"max_seq": max_seq}})
     if rehearse:
         # the CPU rehearsal: same code paths, toy widths
         return {"whisper_preset": "test", "llama_preset": "tiny",
@@ -1468,6 +1685,12 @@ def shapes(rehearse: bool) -> dict:
                     "sizes": gated_delta_sizes(128),
                     "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
                     "prefill_chunk": 32,
+                    "prompt_lengths": (5, 20, 44, 100)},
+                "ssm_hybrid": {
+                    "sizes": ssm_hybrid_sizes(128),
+                    "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
+                    "prefill_chunk": 32, "kernel_slots": 5,
+                    "kernel_layers": 2,
                     "prompt_lengths": (5, 20, 44, 100)},
                 "llama_heads": 4,
                 "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
@@ -1509,6 +1732,16 @@ def shapes(rehearse: bool) -> dict:
                 "max_seq": 1024, "slots": 4, "prefill_buckets": (64, 256),
                 "prefill_chunk": 256,
                 "prompt_lengths": (64, 200, 600, 900)},
+            # the published widths, four layers (three Mamba-2 and an
+            # attention one) and the whole tied vocabulary: 0.9 GB in
+            # bfloat16; the kernel alone at the cell's 32 slots and a
+            # step's 36 layers
+            "ssm_hybrid": {
+                "sizes": ssm_hybrid_sizes(1024),
+                "max_seq": 1024, "slots": 4, "prefill_buckets": (64, 256),
+                "prefill_chunk": 256, "kernel_slots": 32,
+                "kernel_layers": 36,
+                "prompt_lengths": (64, 200, 600, 900)},
             "llama_heads": 16,
             "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,
             "prefill_buckets": (64, 256), "prefill_chunk": 256,
@@ -1526,6 +1759,8 @@ def main(argv=None) -> int:
     parser.add_argument("--rehearse", action="store_true",
                         help="tiny presets on any backend (CPU "
                              "rehearsal); never prints ok")
+    parser.add_argument("--only", nargs="+", metavar="PHASE",
+                        help="run these phases alone; never prints ok")
     args = parser.parse_args(argv)
 
     import faulthandler
@@ -1565,7 +1800,13 @@ def main(argv=None) -> int:
                   "sparse_gqa": functools.partial(phase_sparse_gqa,
                                                   clock=clock),
                   "gated_delta": functools.partial(phase_gated_delta,
-                                                   clock=clock)}
+                                                   clock=clock),
+                  "ssm_hybrid": functools.partial(phase_ssm_hybrid,
+                                                  clock=clock)}
+    if args.only:
+        require(set(args.only) <= set(phases),
+                f"--only names {args.only}; the phases are {list(phases)}")
+        phases = {name: phases[name] for name in args.only}
     for name, phase in phases.items():
         with Phase(name, clock):
             phase(shape, args.seed, on_chip)
@@ -1574,7 +1815,7 @@ def main(argv=None) -> int:
         f"cache_misses={misses} (a second run with the same cache "
         f"directory should show hits and less compile)")
     faulthandler.cancel_dump_traceback_later()
-    if args.rehearse:
+    if args.rehearse or args.only:
         print(json.dumps({"rehearsal": "passed", "device": found}))
     else:
         print(json.dumps({"ok": True, "device": found}))

@@ -75,6 +75,37 @@ def _delta_chunk(tokens, heads, dk, dv, by_head, rows=1):
               f32)])
 
 
+def _ssm_state(slots, heads=64, width=64, state=128):
+    """ops/kda_step.py's plain decayed rule (ISSUE 45: Mamba-2): ONE k and
+    ONE q a slot, the state [N, H x P] with the heads side by side."""
+    from aiko_services_tpu.ops.kda_step import kda_live_step
+    f32 = jnp.float32
+    return (lambda q, k, v, g, memory, active: kda_live_step(
+        q, k, v, g, None, memory, active, interpret=False)[0],
+            [((slots, state), f32), ((slots, state), f32),
+             ((slots, heads, width), f32), ((slots, heads), f32),
+             ((slots, state, heads * width), f32), ((slots,), jnp.bool_)])
+
+
+def _paged_rows(slots, width=1, side=4, t_cap=2048, head_dim=64):
+    """The walk over ONE leaf whose row is a head's V then its K (ISSUE 45:
+    K/V heads of 64, a row of 128 lanes): the query zero over V's lanes,
+    the result the weighted rows' leading lanes, as a latent pool's."""
+    from aiko_services_tpu.ops.paged_attention import \
+        paged_decode_attention
+    nb = t_cap // BLOCK
+    bf16 = jnp.bfloat16
+    return (lambda q, pool, tables, rows, valid, entry:
+            paged_decode_attention(
+                q, pool, None, tables, rows, rows[..., :head_dim], valid,
+                entry, groups=GROUPS, scale=1 / 64, interpret=False),
+            [((slots, HKV, GROUPS * width, 2 * head_dim), bf16),
+             ((slots * nb + 1, HKV, BLOCK, 2 * head_dim), bf16),
+             ((slots, nb + 1), jnp.int32),
+             ((slots, HKV, side, 2 * head_dim), bf16),
+             ((slots, width, side), jnp.bool_), ((slots,), jnp.int32)])
+
+
 MISTRAL = {"slots": 24, "t_cap": 2048, "head_dim": 128}
 
 CASES = {
@@ -127,6 +158,13 @@ CASES = {
     "delta-chunk-gdn-small-heads": lambda: _delta_chunk(128, 8, 8, 16, True),
     "delta-chunk-kda-half-lanes": lambda: _delta_chunk(128, 32, 64, 64,
                                                        False),
+    # granite-4.0-h-micro as ssm_chat_open_loop serves it (ISSUE 45): the
+    # plain decayed rule over 64 heads of [128, 64] that share k and q, 32
+    # slots and a count that is no multiple of 8; and the walk over the
+    # attention layers' one leaf, a row a head's V and K side by side
+    "ssm-state-s32": lambda: _ssm_state(32),
+    "ssm-state-s5": lambda: _ssm_state(5),
+    "paged-rows-v64-k64-step-w1": lambda: _paged_rows(32),
 }
 
 

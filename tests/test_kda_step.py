@@ -230,3 +230,109 @@ def test_a_gate_a_channel_takes_unequal_sides_of_whole_lanes(
         heads, key_dim, value_dim, takes):
     assert kda_step.moves_live_states(heads, key_dim, False,
                                       value_dim=value_dim) is takes
+
+
+# -- the plain decayed rule (ISSUE 45: Mamba-2): S <- exp(g) S + k v^T, o = S^T q,
+# k and q ONE vector for every head of a slot, a static argument of the body
+# of a gate a head ------------------------------------------------------------------
+
+PLAIN = {"6 heads of [16, 8]": (6, 16, 8), "4 heads of [128, 64]": (4, 128, 64)}
+
+
+def plain_inputs(seed, heads, dk, dv, slots=SLOTS):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(keys[0], (slots, dk)),
+            jax.random.normal(keys[1], (slots, dk)),
+            jax.random.normal(keys[2], (slots, heads, dv)),
+            -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], (slots, heads))),
+            jax.random.normal(keys[4], (slots, dk, heads * dv)))
+
+
+def plain_oracle(q, k, v, g, state, active):
+    """The four lines: decay a head over its lanes, the rank-one write, the
+    read; a slot that decodes nothing keeps its state and reads zeros."""
+    slots, heads, dv = v.shape
+    decay = jnp.repeat(jnp.exp(g), dv, axis=-1)[:, None, :]
+    new = state * decay + k[:, :, None] * v.reshape(slots, 1, -1)
+    out = jnp.einsum("sd,sdl->sl", q, new,
+                     precision=jax.lax.Precision.HIGHEST)
+    live = active[:, None, None]
+    return (jnp.where(live, out.reshape(v.shape), 0.0),
+            jnp.where(live, new, state))
+
+
+@pytest.mark.parametrize("shape", sorted(PLAIN))
+@pytest.mark.parametrize("case", sorted(ACTIVE))
+def test_the_plain_rule_moves_the_live_slots_and_no_other(case, shape):
+    q, k, v, g, state = plain_inputs(7, *PLAIN[shape])
+    active = jnp.asarray(ACTIVE[case])
+    out, new = kda_step.kda_live_step(q, k, v, g, None, state, active)
+    want_out, want = plain_oracle(q, k, v, g, state, active)
+    live = np.asarray(active)
+    assert out.shape == v.shape and new.shape == state.shape
+    # a 16- or 128-term sum in another order: a few float32 ulps of outputs
+    # that spread by the square root of their terms (11 at 128)
+    assert np.abs(np.asarray(out - want_out)).max() < \
+        4e-6 * max(1.0, float(np.abs(np.asarray(want_out)).max()))
+    assert np.abs(np.asarray(new - want)).max() < 2e-6
+    if live.any():
+        assert not np.array_equal(np.asarray(new)[live],
+                                  np.asarray(state)[live])
+    assert np.array_equal(np.asarray(new)[~live].view(np.uint32),
+                          np.asarray(state)[~live].view(np.uint32))
+    assert not np.asarray(out)[~live].any()
+
+
+def test_the_plain_rule_never_reads_an_idle_slot():
+    q, k, v, g, state = plain_inputs(8, 6, 16, 8)
+    active = jnp.asarray(ACTIVE["mixed"])
+    clean = kda_step.kda_live_step(q, k, v, g, None, state, active)
+    poison = [jnp.where((~active).reshape((-1,) + (1,) * (z.ndim - 1)),
+                        jnp.nan, z) for z in (q, k, v, g, state)]
+    out, new = kda_step.kda_live_step(*poison[:4], None, poison[4], active)
+    live = np.asarray(active)
+    assert np.array_equal(np.asarray(out)[live], np.asarray(clean[0])[live])
+    assert np.array_equal(np.asarray(new)[live], np.asarray(clean[1])[live])
+    assert np.isnan(np.asarray(new)[~live]).all()
+    assert not np.asarray(out)[~live].any()
+
+
+def test_four_plain_steps_in_a_loop_with_the_state_donated():
+    """As the decode step runs it: inside a `lax.while_loop` under `jit`,
+    the state carried and donated, one slot never live, one that stops."""
+    q, k, v, g, state = plain_inputs(9, 6, 16, 8)
+    steps = 4
+    lives = jnp.asarray([[True, False, True, True, True]] * 2 +
+                        [[True, False, True, False, True]] * 2)
+
+    def run(state):
+        def body(loop):
+            index, state, outs = loop
+            out, state = kda_step.kda_live_step(
+                jnp.roll(q, index, axis=1), k, jnp.roll(v, index, axis=1),
+                g, None, state, lives[index])
+            return index + 1, state, outs.at[index].set(out)
+
+        return jax.lax.while_loop(
+            lambda loop: loop[0] < steps, body,
+            (jnp.int32(0), state, jnp.zeros((steps,) + v.shape)))[1:]
+
+    want, want_outs = state, []
+    for index in range(steps):
+        out, want = plain_oracle(jnp.roll(q, index, axis=1), k,
+                                 jnp.roll(v, index, axis=1), g, want,
+                                 lives[index])
+        want_outs.append(np.asarray(out))
+    kept = np.asarray(state[1])
+    new, outs = jax.jit(run, donate_argnums=(0,))(state + 0.0)
+    assert np.abs(np.asarray(new - want)).max() < 1e-5
+    assert np.abs(np.asarray(outs) - np.stack(want_outs)).max() < 1e-4
+    assert np.array_equal(np.asarray(new[1]), kept)
+
+
+def test_the_published_state_space_heads_take_the_kernel():
+    """64 heads of [128, 64]: 4,096 lanes a slot, pairs of heads a vector,
+    2 MB a slot (`moves_live_states`' own arithmetic, ISSUE 45)."""
+    assert kda_step.moves_live_states(64, 128, value_dim=64, by_head=True)
+    assert kda_step._head_group(64, 64) == 2
+    assert not kda_step.moves_live_states(6, 16, value_dim=8, by_head=True)
