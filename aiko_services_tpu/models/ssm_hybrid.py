@@ -30,7 +30,9 @@
 # and V leaves would fall to that kernel's table body (ISSUE 45, route 1).
 # The decode step's recurrence is ops/kda_step's kernel in its plain rule on
 # the chip (`step_kernel`) and `ssm_step` everywhere else; an admit's and a
-# chunk's is `ssm_chunked`, in XLA.
+# chunk's is ops/ssm_chunk's kernel, one call a layer over the state as the
+# pool keeps it, on the chip where the heads tile (`_scan_kernel`, ISSUE 46)
+# and `ssm_chunked`, in XLA, elsewhere.
 
 from __future__ import annotations
 
@@ -42,6 +44,7 @@ import jax.numpy as jnp
 
 from ..ops.kda_step import kda_live_step, moves_live_states
 from ..ops.paged_attention import paged_decode_attention, walks_live_blocks
+from ..ops.ssm_chunk import scans_ssm_chunks, ssm_chunk_scan
 from . import layers as L
 # what any model with slot state beside a pool without positions shares
 from .gated_delta import _extend_prepare, _rope, _zero_state
@@ -344,7 +347,9 @@ def _mamba_block(layer, config: SsmHybridConfig, x, state, live,
         out = out[:, None]
     else:
         with jax.named_scope(SCOPE_SSM_SCAN):
-            out, memory = ssm_chunked(inputs, dt, b, c, a, memory)
+            scan = ssm_chunk_scan if _scan_kernel(
+                config, jax.default_backend() != "tpu") else ssm_chunked
+            out, memory = scan(inputs, dt, b, c, a, memory)
     return (_mamba_output(mamba, config, out, inputs, gate, x.dtype),
             (memory, tail))
 
@@ -534,6 +539,14 @@ def _state_kernel(config: SsmHybridConfig, interpret: bool) -> bool:
                              value_dim=config.ssm_head_dim, by_head=True)
 
 
+def _scan_kernel(config: SsmHybridConfig, interpret: bool) -> bool:
+    """Whether a prompt's piece runs the chunked form as ops/ssm_chunk's
+    kernel: on the chip, where the heads tile.  Read off the geometry at
+    trace time; the interpreter is never taken unasked."""
+    return not interpret and scans_ssm_chunks(
+        config.ssm_heads, config.ssm_head_dim, config.ssm_state)
+
+
 def _walks(config: SsmHybridConfig, kv_int8: bool,
            interpret: bool) -> str | None:
     """The pool's row is a head's V and K side by side: whole lanes where
@@ -619,6 +632,6 @@ def _paged_model():
         rope=_rope, token_block_argmax=_step_argmax,
         step_attention=_step_attention, prefill=_prefill,
         extend_prepare=_extend_prepare, extend_layer=_extend_layer,
-        walks=_walks, step_kernel=_state_kernel,
+        walks=_walks, step_kernel=_state_kernel, scan_kernel=_scan_kernel,
         counters=SSM_HYBRID_COUNTERS, supports=frozenset(),
         residual_in=_residual_in, final_norm=_final_norm, head=_logits)

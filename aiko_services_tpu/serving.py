@@ -1801,7 +1801,7 @@ class ContinuousDecoder:
                         "recurrence's chunked form as %s",
                         "the pallas kernel, one call a layer"
                         if self._model.scan_kernel(config, not on_tpu)
-                        else "XLA's program, models/delta_rule.chunked")
+                        else "XLA's program, the model's own chunked form")
             from .serving_paged import run_write_form
             self.logger.info(
                 "decode step writes a round's rows to the pool as %s",

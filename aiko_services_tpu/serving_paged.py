@@ -117,9 +117,11 @@ class PagedModel:
         decides, and step_attention's `kernel` that says so
     scan_kernel(config, interpret) -> whether a prompt's piece (an admit,
         an extend) runs the model's recurrence over slot state as a
-        pallas kernel (ISSUE 41: ops/delta_chunk); None where the model
-        has none.  The MODEL decides, at trace time, from what it can
-        observe; the decoder only logs the answer at set-up
+        pallas kernel (ISSUE 41: ops/delta_chunk, the delta rule's WY
+        form; ISSUE 46: ops/ssm_chunk, Mamba-2's decayed rule with B and
+        C shared by every head); None where the model has none.  The
+        MODEL decides, at trace time, from what it can observe; the
+        decoder only logs the answer at set-up
     counters: names of the step's counts, added to decoder.stats
     supports: the serving paths this model's pool is carried through;
         the decoder refuses the others at construction

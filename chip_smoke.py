@@ -63,14 +63,18 @@ Phases of the default run:
   ssm_hybrid  ops/kda_step.py's PLAIN decayed rule (Mamba-2: one k and one
            q a slot) against its four-line oracle at the published head
            sizes (64 heads of [128, 64]; some slots live, none, all) and
-           its time for a step's 36 layers against the memory's speed,
-           then ContinuousDecoder on models/ssm_hybrid.py (Mamba-2 slot
+           its time for a step's 36 layers against the memory's speed;
+           ops/ssm_chunk.py's chunked form against `ssm_chunked` and
+           `ssm_plain` over a 512-token piece and, alone, its time for a
+           piece's 36 layers beside XLA's form (ISSUE 46); then
+           ContinuousDecoder on models/ssm_hybrid.py (Mamba-2 slot
            state beside a pool whose row is a K/V head's V and K side by
            side, which the shared paged kernel walks) at the published
            widths, four layers (three Mamba, one attention) and the whole
            tied vocabulary: prompts admitted whole and prompts that go
-           chunk by chunk, decode, every slot served twice; every served
-           token held to benchmark/reference/ssm_hybrid_lm.py
+           chunk by chunk (both through the chunk kernel on the chip),
+           decode, every slot served twice; every served token held to
+           benchmark/reference/ssm_hybrid_lm.py
 
 --only <phase> [<phase> ...] runs those phases alone (a builder's chip
 minutes; a run that skips a phase never says "ok": it ends with the
@@ -1446,6 +1450,82 @@ def check_plain_live_step(heads: int, width: int, state_lanes: int,
     return True
 
 
+def check_ssm_chunk_scan(heads: int, width: int, state: int, tokens: int,
+                         layers: int, key, on_chip: bool) -> bool:
+    """ops.ssm_chunk.ssm_chunk_scan against models/ssm_hybrid.ssm_chunked
+    (and `ssm_plain` token by token) over a prompt's piece of `tokens` at
+    `heads` heads of `width` that share B and C of `state`, dt and A drawn
+    as the cell's weights make them, from a state that is not zero and a
+    tail of dt = 0 in the second row: output and state to 2e-4 of their
+    scale (float32 at HIGHEST on both sides, sums in another order).  On
+    the chip also the kernel ALONE against XLA's form, one row of `tokens`:
+    the host's clock around one dispatch of `layers` calls.  -> whether a
+    prompt's piece takes the kernel at this geometry on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from aiko_services_tpu.models import ssm_hybrid
+    from aiko_services_tpu.ops import ssm_chunk
+
+    takes = ssm_chunk.scans_ssm_chunks(heads, width, state)
+    say(f"  ssm_chunk_scan, H{heads} x {width}, N {state}: a prompt's piece "
+        f"takes it on the chip {takes}")
+    if on_chip and not takes:
+        return False
+    keys = jax.random.split(key, 6)
+    live = jnp.arange(tokens)[None] < jnp.array([[tokens],
+                                                 [tokens - tokens // 3]])
+    x = jax.random.normal(keys[0], (2, tokens, heads, width))
+    dt = jnp.exp(jax.random.uniform(
+        keys[1], (2, tokens, heads), minval=np.log(1e-3),
+        maxval=np.log(0.1))) * live[..., None]
+    b = jax.random.normal(keys[2], (2, tokens, state))
+    c = jax.random.normal(keys[3], (2, tokens, state))
+    a = -jnp.exp(jax.random.uniform(keys[4], (heads,), minval=0.0,
+                                    maxval=np.log(16.0)))
+    memory = jax.random.normal(keys[5], (2, state, heads * width))
+    kernel = jax.jit(functools.partial(ssm_chunk.ssm_chunk_scan,
+                                       interpret=not on_chip))
+    if on_chip:
+        require(lowered_has_kernel(kernel, x, dt, b, c, a, memory),
+                "ssm_chunk_scan lowered without a tpu_custom_call")
+    out, new = kernel(x, dt, b, c, a, memory)
+    for name, form in (("chunked", ssm_hybrid.ssm_chunked),
+                       ("plain", ssm_hybrid.ssm_plain)):
+        want_out, want = jax.jit(form)(x, dt, b, c, a, memory)
+        worst = max(float(jnp.abs(out - want_out).max() /
+                          jnp.abs(want_out).max()),
+                    float(jnp.abs(new - want).max() / jnp.abs(want).max()))
+        say(f"  ssm_chunk_scan, {tokens} tokens: max|kernel-{name}| of the "
+            f"scale={worst:.2e}")
+        require(worst <= 2e-4, f"ssm_chunk_scan off {name} by {worst}")
+    if on_chip:
+        one = (x[:1], dt[:1], b[:1], c[:1], a)
+        for name, form in (("the kernel", kernel),
+                           ("XLA's ssm_chunked", ssm_hybrid.ssm_chunked)):
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def piece(held, form=form):
+                def layer(_, carry):
+                    held, total = carry
+                    out, held = form(*one, held)
+                    return held, total + out[:, -1]
+                return jax.lax.fori_loop(
+                    0, layers, layer, (held, jnp.zeros_like(x[:1, -1])))
+
+            held = piece(memory[:1] + 0.0)
+            jax.block_until_ready(held)
+            rounds, start = 10, time.perf_counter()
+            for _ in range(rounds):
+                held = piece(held[0])
+            jax.block_until_ready(held)
+            seconds = (time.perf_counter() - start) / rounds
+            say(f"  {name}, {layers} calls over one row of {tokens} tokens: "
+                f"{seconds * 1e3:.3f} ms a piece's worth (host clock, one "
+                f"dispatch), {seconds / layers * 1e6:.0f} us a call")
+    return takes
+
+
 def phase_ssm_hybrid(shape: dict, seed: int, on_chip: bool,
                      clock: CompileClock) -> None:
     """models/ssm_hybrid.py through the same decoder: Mamba-2 layers whose
@@ -1472,6 +1552,13 @@ def phase_ssm_hybrid(shape: dict, seed: int, on_chip: bool,
         own["kernel_slots"], own["kernel_layers"],
         jax.random.PRNGKey(seed + 45), on_chip)
     require(takes, "the phase's head sizes do not take the kernel")
+    # a prompt's pieces run the chunked form as ONE kernel a layer on the
+    # chip (checked against ssm_chunked and timed alone first), XLA's
+    # program in the rehearsal
+    scans = check_ssm_chunk_scan(
+        config.ssm_heads, config.ssm_head_dim, config.ssm_state,
+        own["kernel_tokens"], own["kernel_layers"],
+        jax.random.PRNGKey(seed + 46), on_chip)
     params = W.decoder_weights(W.key_for(seed), sizes, dtype)
     rng = np.random.default_rng(seed)
     requests = {
@@ -1493,6 +1580,11 @@ def phase_ssm_hybrid(shape: dict, seed: int, on_chip: bool,
             bool(decoder._model_kernel),
             f"ssm_hybrid: step_kernel {decoder.step_kernel}, walks live "
             f"{decoder._walks_live}, on the chip {on_chip}")
+    held = prefill_programs_hold(decoder, own["prefill_buckets"][-1],
+                                 own["prefill_chunk"], "ssm_chunk_scan")
+    require(held == (on_chip and scans,) * 2,
+            f"ssm_hybrid: admit and extend hold the chunk kernel {held}, on "
+            f"the chip {on_chip}")
     cold = timed_serve("first pass", decoder, requests, clock)
     warm = timed_serve("second pass (every slot reused)", decoder,
                        requests, clock)
@@ -1690,7 +1782,7 @@ def shapes(rehearse: bool) -> dict:
                     "sizes": ssm_hybrid_sizes(128),
                     "max_seq": 128, "slots": 4, "prefill_buckets": (8, 32),
                     "prefill_chunk": 32, "kernel_slots": 5,
-                    "kernel_layers": 2,
+                    "kernel_layers": 2, "kernel_tokens": 40,
                     "prompt_lengths": (5, 20, 44, 100)},
                 "llama_heads": 4,
                 "llama_dtype": jnp.float32, "max_seq": 128, "slots": 8,
@@ -1734,13 +1826,13 @@ def shapes(rehearse: bool) -> dict:
                 "prompt_lengths": (64, 200, 600, 900)},
             # the published widths, four layers (three Mamba-2 and an
             # attention one) and the whole tied vocabulary: 0.9 GB in
-            # bfloat16; the kernel alone at the cell's 32 slots and a
-            # step's 36 layers
+            # bfloat16; the kernels alone at the cell's 32 slots, a step's
+            # (and a piece's) 36 layers and the extend's 512 tokens
             "ssm_hybrid": {
                 "sizes": ssm_hybrid_sizes(1024),
                 "max_seq": 1024, "slots": 4, "prefill_buckets": (64, 256),
                 "prefill_chunk": 256, "kernel_slots": 32,
-                "kernel_layers": 36,
+                "kernel_layers": 36, "kernel_tokens": 512,
                 "prompt_lengths": (64, 200, 600, 900)},
             "llama_heads": 16,
             "llama_dtype": jnp.bfloat16, "max_seq": 1280, "slots": 8,

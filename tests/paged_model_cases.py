@@ -188,9 +188,11 @@ class PagedModelCases:
 
 
 @contextlib.contextmanager
-def scan_kernel_interpreted(model, scan=True):
-    """`scan`: a prompt's pieces take ops/delta_chunk's kernel in `model`
-    (models/hybrid_sparse or models/gated_delta), in the interpreter.  The
+def scan_kernel_interpreted(model, scan=True, name="delta_chunk_scan"):
+    """`scan`: a prompt's pieces take the chunk kernel that `model` holds
+    under `name` (ops/delta_chunk's in models/hybrid_sparse and
+    models/gated_delta, ops/ssm_chunk's `ssm_chunk_scan` in
+    models/ssm_hybrid), in the interpreter.  The
     model takes it unasked on a chip alone, and the choice is made where
     the admit and the extend are TRACED: so the builders' caches, which
     know nothing of it, are emptied around, and the decoder that serves
@@ -203,10 +205,10 @@ def scan_kernel_interpreted(model, scan=True):
                 serving_paged._paged_extend_fn_for)
     for builder in builders:
         builder.cache_clear()
-    traced, kernel = [], model.delta_chunk_scan
+    traced, kernel = [], getattr(model, name)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(model, "_scan_kernel", lambda config, interpret: True)
-        patch.setattr(model, "delta_chunk_scan",
+        patch.setattr(model, name,
                       lambda *args: traced.append(1) or kernel(*args))
         yield
     for builder in builders:

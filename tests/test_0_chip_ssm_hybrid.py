@@ -1,7 +1,9 @@
 # granite-4.0-h-micro's whole decode step as `ssm_chat_open_loop` runs it
 # (ISSUE 45: Mamba-2 slot state beside a pool whose row is a K/V head's V
-# and K side by side), all 40 layers, compiled for a DESCRIBED v5e
-# (tests/test_chip_compile.py says what that can and cannot show).
+# and K side by side) and its admits and extend (ISSUE 46: the chunked
+# recurrence as one kernel a layer), all 40 layers, compiled for a
+# DESCRIBED v5e (tests/test_chip_compile.py says what that can and cannot
+# show).
 
 import re
 
@@ -92,3 +94,54 @@ def test_ssm_hybrid_step_walks_the_one_leaf_and_not_the_table(
         if found and found.group(1) not in HLO_CARRIES]
     assert made and all(SCOPE_KV_MERGE in line for line in made), \
         [line[:200] for line in made if SCOPE_KV_MERGE not in line]
+
+
+PIECES = {"admit-512": ("admit", 512, 1), "admit-256-two-rows":
+          ("admit", 256, 2), "extend-512": ("extend", 512, 1)}
+
+
+@pytest.mark.parametrize("piece", sorted(PIECES))
+def test_ssm_hybrid_pieces_scan_a_prompt_in_one_kernel_a_layer(cell, piece):
+    """The cell's `jit_admit` (one prompt padded to the bucket of 512; two
+    of 256, where the rows' x is not of the state's shape) and `jit_extend`
+    (a 512-token piece) as a decoder traces them on the chip: the 36 Mamba
+    layers' chunked recurrence is 36
+    `ssm_chunk_scan` custom calls under `aiko.ssm_scan` (ISSUE 46), their
+    state argument aliased to their result, and NO loop is left under that
+    scope: XLA's form of `ssm_chunked` was a `while` of four trips a layer.
+    Under the scope a row's state `f32[rows,128,4096]` is computed by those
+    calls alone: no copy that a failed aliasing would put before one, no
+    fusion that lays the state out for it (what gathers an extend's rows
+    out of the slots' state and scatters them back is the builder's, as in
+    the parent)."""
+    from aiko_services_tpu import serving_paged
+    from aiko_services_tpu.models import ssm_hybrid as M
+    kind, tokens, width = PIECES[piece]
+    config = cell.config
+    builder = getattr(serving_paged, "_paged_%s_fn_for" % kind)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        assert config.paged_model().scan_kernel(config, False) is True
+        builder.cache_clear()
+        compiled = getattr(cell, "lower_" + kind)(tokens, width).compile()
+    builder.cache_clear()
+    text = compiled.as_text()
+    lines = [line.strip() for line in text.splitlines()]
+    scans = [line for line in lines if "tpu_custom_call" in line and
+             M.SCOPE_SSM_SCAN in line]
+    recurrent = sum(kind == "mamba" for kind in config.layer_types)
+    assert len(scans) == recurrent == 36
+    assert all("ssm_chunk_scan" in line and
+               "output_to_operand_aliasing" in line for line in scans)
+    assert not [line[:160] for line in lines
+                if re.search(r" while\(", line) and M.SCOPE_SSM_SCAN in line]
+    made, kinds = made_whole(text, "f32[%d,%d,%d]" % (
+        width, config.ssm_state, config.ssm_inner))
+    under = [(line, kind) for line, kind in zip(made, kinds)
+             if M.SCOPE_SSM_SCAN in line]
+    assert sum(kind == "custom-call" for _, kind in under) == recurrent
+    assert not [line[:200] for line, kind in under
+                if kind in ("copy", "fusion")]
+    # weights 6.38 GB, pool 0.67, slot state 3.06: a piece's own
+    # temporaries under a gigabyte
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9

@@ -12,7 +12,7 @@ import pytest
 import aiko_services_tpu.serving as serving
 from aiko_services_tpu import serving_paged
 from aiko_services_tpu.serving import ContinuousDecoder
-from paged_model_cases import NOT_CARRIED
+from paged_model_cases import NOT_CARRIED, scan_kernel_interpreted
 from test_ssm_hybrid_layers import (CASES, LOGIT_TOLERANCE, SIZES, M,
                                     model_config)
 
@@ -147,13 +147,16 @@ def test_prefill_then_decode_through_pool_and_state_agrees_with_one_forward(
     pool), 64 by two whole chunks; two wait for a slot that another request
     leaves.  All decode 11 tokens; each served token is the reference's
     best at its position to within the tolerance.  `kernel`: the step's
-    kernels asked for (a decoder of its own)."""
+    kernels asked for, and a prompt's pieces through ops/ssm_chunk's (a
+    decoder of its own: one that has traced its admits keeps them).  The
+    whole of it, last: this case empties the builders' caches."""
     rng = np.random.default_rng(7)
     requests = {f"r{n}": (rng.integers(1, 256, size=n).tolist(), 11)
                 for n in (10, 45, 77, 5, 30, 64)}
-    served, stats = serve(
-        decoder_for("ssm-hybrid-kernels", True) if kernel else decoder,
-        requests)
+    with scan_kernel_interpreted(M, kernel, "ssm_chunk_scan"):
+        served, stats = serve(
+            decoder_for("ssm-hybrid-kernels", True) if kernel else decoder,
+            requests)
     assert stats["prefill_chunks"] == 7 and stats["prefills"] == 3
     assert stats["slot_states_zeroed"] == 6
     for rid, gap in served_gaps(requests, served).items():

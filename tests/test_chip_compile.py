@@ -75,6 +75,18 @@ def _delta_chunk(tokens, heads, dk, dv, by_head, rows=1):
               f32)])
 
 
+def _ssm_chunk(tokens, rows=1, heads=64, width=64, state=128):
+    """ops/ssm_chunk.py over a prompt's piece (ISSUE 46: Mamba-2's chunked
+    form): ONE B and ONE C a row, the state [N, H x P] as the pool keeps
+    it."""
+    from aiko_services_tpu.ops.ssm_chunk import ssm_chunk_scan
+    f32 = jnp.float32
+    return (lambda *args: ssm_chunk_scan(*args, interpret=False)[0],
+            [((rows, tokens, heads, width), f32), ((rows, tokens, heads), f32),
+             ((rows, tokens, state), f32), ((rows, tokens, state), f32),
+             ((heads,), f32), ((rows, state, heads * width), f32)])
+
+
 def _ssm_state(slots, heads=64, width=64, state=128):
     """ops/kda_step.py's plain decayed rule (ISSUE 45: Mamba-2): ONE k and
     ONE q a slot, the state [N, H x P] with the heads side by side."""
@@ -165,6 +177,23 @@ CASES = {
     "ssm-state-s32": lambda: _ssm_state(32),
     "ssm-state-s5": lambda: _ssm_state(5),
     "paged-rows-v64-k64-step-w1": lambda: _paged_rows(32),
+    # the same cell's pieces (ISSUE 46): the chunked recurrence as one
+    # kernel a layer at the extend's 512 tokens and at every admit the
+    # cell warms up (buckets of 128, 256 and 512 under a budget of 512
+    # tokens a round: four, two and one row); a piece that is no whole
+    # chunk; and what `scans_ssm_chunks` lets through off the published
+    # widths: sixteen heads of 8 inside one vector, a head of two vectors
+    "ssm-chunk-t512": lambda: _ssm_chunk(512),
+    "ssm-chunk-t128": lambda: _ssm_chunk(128),
+    "ssm-chunk-t128-two-rows": lambda: _ssm_chunk(128, rows=2),
+    "ssm-chunk-t128-four-rows": lambda: _ssm_chunk(128, rows=4),
+    "ssm-chunk-t256": lambda: _ssm_chunk(256),
+    "ssm-chunk-t256-two-rows": lambda: _ssm_chunk(256, rows=2),
+    "ssm-chunk-t200-two-rows": lambda: _ssm_chunk(200, rows=2),
+    "ssm-chunk-small-heads": lambda: _ssm_chunk(128, heads=16, width=8,
+                                                state=16),
+    "ssm-chunk-wide-heads": lambda: _ssm_chunk(128, heads=4, width=256,
+                                               state=64),
 }
 
 
