@@ -104,10 +104,11 @@ class GatedDeltaConfig:
     def slot_state(self) -> tuple:
         """Layer by layer, (shape, dtype) of what a SLOT holds: a
         recurrent layer its state S, the heads side by side, and the
-        convolution's tail; a full layer nothing."""
+        convolution's tail, its conv-1 positions side by side on the lanes
+        (layers.conv_tail); a full layer nothing."""
         gdn = (((self.key_dim, self.gdn_heads * self.value_dim),
                 jnp.float32),
-               ((self.conv_width - 1, self.conv_channels), self.dtype))
+               (((self.conv_width - 1) * self.conv_channels,), self.dtype))
         return tuple(gdn if kind == "gdn" else ()
                      for kind in self.layer_types)
 
@@ -190,13 +191,12 @@ def gated_delta_init(key, config: GatedDeltaConfig):
 # -- the recurrent layer ---------------------------------------------------------
 
 def _gdn_inputs(gdn, config: GatedDeltaConfig, x, tail, live):
-    """x [A, T, dim], tail [A, conv-1, channels] the convolution's inputs
-    before position 0 of x, live [A, T] -> q, k [A, T, H, Dk], v [A, T, H,
-    Dv] f32, g, beta [A, T, H] f32, the output gate [A, T, H x Dv] f32, and
-    the new tail: the inputs of the last conv-1 LIVE positions (live
-    positions lead each row)."""
+    """x [A, T, dim], tail [A, (conv-1) x channels] the convolution's
+    inputs before position 0 of x, live [A, T] -> q, k [A, T, H, Dk], v [A,
+    T, H, Dv] f32, g, beta [A, T, H] f32, the output gate [A, T, H x Dv]
+    f32, and the new tail: the inputs of the last conv-1 LIVE positions
+    (live positions lead each row)."""
     heads, dk, dv = config.gdn_heads, config.key_dim, config.value_dim
-    taps = config.conv_width
     a, t, _ = x.shape
     with jax.named_scope(SCOPE_GDN_PROJ):
         pre = jnp.concatenate([L.linear(gdn[name], x) for name in "qkv"],
@@ -205,11 +205,7 @@ def _gdn_inputs(gdn, config: GatedDeltaConfig, x, tail, live):
         write = L.linear(gdn["b"], x)
         gate = L.linear(gdn["g"], x)
     with jax.named_scope(SCOPE_GDN_CONV):
-        full = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
-        weights = gdn["conv"]["w"].astype(jnp.float32)
-        mixed = jax.nn.silu(sum(
-            full[:, i:i + t].astype(jnp.float32) * weights[i]
-            for i in range(taps)))
+        mixed, tail = L.conv_tail(pre, tail, gdn["conv"]["w"], None, live)
         q, k, v = (z.reshape(a, t, heads, -1) for z in jnp.split(
             mixed, [heads * dk, 2 * heads * dk], axis=-1))
 
@@ -226,10 +222,7 @@ def _gdn_inputs(gdn, config: GatedDeltaConfig, x, tail, live):
         # a position that is not live leaves S as it was: no decay, no write
         g = g * live[:, :, None]
         beta = beta * live[:, :, None]
-        count = live.sum(axis=1).astype(jnp.int32)
-        new_tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-            rows, n, taps - 1, axis=0))(full, count)
-    return q, k, v, g, beta, gate, new_tail.astype(tail.dtype)
+    return q, k, v, g, beta, gate, tail
 
 
 def _gdn_output(gdn, config: GatedDeltaConfig, out, gate, dtype):

@@ -177,11 +177,12 @@ class HybridSparseConfig:
     @property
     def slot_state(self) -> tuple:
         """Layer by layer, (shape, dtype) of what a SLOT holds: a KDA
-        layer its state S and the convolution's tail, a sparse layer
-        the key sum of its open group."""
+        layer its state S and the convolution's tail, its conv-1
+        positions side by side on the lanes (layers.conv_tail), a sparse
+        layer the key sum of its open group."""
         heads, d = self.kda_heads, self.kda_head_dim
         kda = (((heads, d, d), jnp.float32),
-               ((self.conv_width - 1, 3 * heads * d), self.dtype))
+               (((self.conv_width - 1) * 3 * heads * d,), self.dtype))
         sparse = (((self.index_dim,), jnp.float32),)
         return tuple(sparse if kind == "dsa" else kda
                      for kind in self.layer_types)
@@ -374,21 +375,17 @@ def _head_hidden(params, config: HybridSparseConfig, streams):
 # -- KDA -------------------------------------------------------------------------
 
 def _kda_inputs(kda, config: HybridSparseConfig, x, tail, live):
-    """x [A, T, dim] (normed), tail [A, conv-1, 3HD] the convolution's
+    """x [A, T, dim] (normed), tail [A, (conv-1) x 3HD] the convolution's
     inputs before position 0 of x, live [A, T] -> q, k, v, g [A, T, H, D]
     f32, beta [A, T, H] f32, the output gate [A, T, HD] f32, and the new
     tail: the inputs of the last conv-1 LIVE positions (live positions
     lead each row)."""
     heads, d = config.kda_heads, config.kda_head_dim
-    taps = config.conv_width
     a, t, _ = x.shape
     pre = jnp.concatenate([L.linear(kda[name], x) for name in "qkv"],
                           axis=-1)
-    full = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
-    weights = kda["conv"]["w"].astype(jnp.float32)
-    mixed = sum(full[:, i:i + t].astype(jnp.float32) * weights[i]
-                for i in range(taps))
-    mixed = jax.nn.silu(mixed).reshape(a, t, 3, heads, d)
+    mixed, tail = L.conv_tail(pre, tail, kda["conv"]["w"], None, live)
+    mixed = mixed.reshape(a, t, 3, heads, d)
     q, k, v = mixed[:, :, 0], mixed[:, :, 1], mixed[:, :, 2]
 
     def unit(z):
@@ -406,10 +403,7 @@ def _kda_inputs(kda, config: HybridSparseConfig, x, tail, live):
     # a position that is not live leaves S as it was: no decay, no write
     g = g * live[:, :, None, None]
     beta = beta * live[:, :, None]
-    count = live.sum(axis=1).astype(jnp.int32)
-    new_tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
-        rows, n, taps - 1, axis=0))(full, count)
-    return q, k, v, g, beta, gate, new_tail.astype(tail.dtype)
+    return q, k, v, g, beta, gate, tail
 
 
 def _kda_output(kda, config: HybridSparseConfig, out, gate, dtype):

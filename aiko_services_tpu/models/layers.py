@@ -24,7 +24,7 @@ __all__ = [
     "layer_norm_init", "layer_norm", "layer_norm_axes",
     "rms_norm_init", "rms_norm", "rms_norm_axes",
     "embedding_init", "embedding", "embedding_axes",
-    "conv1d_init", "conv1d", "conv1d_axes",
+    "conv1d_init", "conv1d", "conv1d_axes", "conv_tail",
     "mha_init", "mha", "mha_axes", "precompute_kv", "init_kv_cache",
     "update_kv_cache", "quantize_linear", "quantize_linear_tree",
     "quantize_kv_cache", "dequantize_kv_cache",
@@ -217,6 +217,54 @@ def conv1d(params, x, stride: int = 1, padding=None):
 
 def conv1d_axes():
     return {"w": (None, None, "embed"), "b": ("embed",)}
+
+
+def conv_tail(pre, tail, weights, bias, live):
+    """A recurrent layer's short causal convolution a channel over a block
+    of a slot's tokens, and the roll of the slot's tail.  pre [A, T, C] the
+    block's inputs, tail [A, (conv-1) x C] the inputs of the conv-1
+    positions before position 0 of pre, oldest first, side by side on the
+    lanes (C a whole number of lane tiles at the published widths: a tap is
+    an aligned window, where a [conv-1, C] tail is three rows of a tile
+    that XLA lays out anew around every use), weights [conv, C], bias [C]
+    or None, live [A, T] bool (live positions lead each row) ->
+    (silu(conv) f32 [A, T, C], the new tail: the inputs of the last conv-1
+    LIVE positions, in tail's dtype).
+
+    The form is read off pre's SHAPE.  One token (every decode step): the
+    taps are the tail's windows and the token, each its own [A, C] slab,
+    and the roll is one select between the tail shifted by a window and
+    the tail as it was: no [A, conv, C] array, no gather, and a slot that
+    does not decode keeps its tail.  A prompt's piece: the taps are windows
+    of tail and piece laid end to end, and the new tail starts at the row's
+    count of live positions; one row slices once, several a row at a
+    time."""
+    rows, t, channels = pre.shape
+    taps = weights.shape[0]
+    weights = weights.astype(jnp.float32)
+    held = tail.astype(pre.dtype)
+    if t == 1:
+        windows = [held[:, i * channels:(i + 1) * channels][:, None]
+                   for i in range(taps - 1)] + [pre]
+        rolled = jnp.where(live, jnp.concatenate(
+            [held[:, channels:], pre[:, 0]], axis=1), held)
+    else:
+        full = jnp.concatenate(
+            [held.reshape(rows, taps - 1, channels), pre], axis=1)
+        windows = [full[:, i:i + t] for i in range(taps)]
+        count = live.sum(axis=1).astype(jnp.int32)
+        if rows == 1:
+            rolled = jax.lax.dynamic_slice_in_dim(
+                full[0], count[0], taps - 1, axis=0)[None]
+        else:
+            rolled = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
+                row, n, taps - 1, axis=0))(full, count)
+        rolled = rolled.reshape(tail.shape)
+    mixed = sum(window.astype(jnp.float32) * weights[i]
+                for i, window in enumerate(windows))
+    if bias is not None:
+        mixed = mixed + bias.astype(jnp.float32)
+    return jax.nn.silu(mixed), rolled.astype(tail.dtype)
 
 
 # -- attention ---------------------------------------------------------------

@@ -119,9 +119,11 @@ class SsmHybridConfig:
     def slot_state(self) -> tuple:
         """Layer by layer, (shape, dtype) of what a SLOT holds: a Mamba
         layer its state S [N, H x P], the heads side by side, and the
-        convolution's tail; an attention layer nothing."""
+        convolution's tail, its conv-1 positions side by side on the lanes
+        (layers.conv_tail); an attention layer nothing."""
         mamba = (((self.ssm_state, self.ssm_inner), jnp.float32),
-                 ((self.conv_width - 1, self.conv_channels), self.dtype))
+                 (((self.conv_width - 1) * self.conv_channels,),
+                  self.dtype))
         return tuple(mamba if kind == "mamba" else ()
                      for kind in self.layer_types)
 
@@ -282,33 +284,27 @@ def ssm_chunked(x, dt, b, c, a, state, chunk: int = _CHUNK):
 # -- the Mamba layer ---------------------------------------------------------------
 
 def _mamba_inputs(mamba, config: SsmHybridConfig, x, tail, live):
-    """x [A, T, dim], tail [A, conv-1, channels] the convolution's inputs
-    before position 0 of x, live [A, T] -> the heads' inputs [A, T, H, P],
-    dt [A, T, H] (0 where not live), B, C [A, T, N], all f32, the gate z
-    [A, T, H x P] f32, and the new tail: the inputs of the last conv-1 LIVE
-    positions (live positions lead each row)."""
+    """x [A, T, dim], tail [A, (conv-1) x channels] the convolution's
+    inputs before position 0 of x, live [A, T] -> the heads' inputs [A, T,
+    H, P], dt [A, T, H] (0 where not live), B, C [A, T, N], all f32, the
+    gate z [A, T, H x P] f32, and the new tail: the inputs of the last
+    conv-1 LIVE positions (live positions lead each row)."""
     heads, width, n = config.ssm_heads, config.ssm_head_dim, config.ssm_state
-    inner, taps = config.ssm_inner, config.conv_width
+    inner = config.ssm_inner
     rows, t, _ = x.shape
     with jax.named_scope(SCOPE_SSM_PROJ):
         z, pre, rate = jnp.split(L.linear(mamba["in"], x),
                                  [inner, inner + config.conv_channels],
                                  axis=-1)
     with jax.named_scope(SCOPE_SSM_CONV):
-        full = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
-        weights = mamba["conv"]["w"].astype(jnp.float32)
-        mixed = jax.nn.silu(sum(
-            full[:, i:i + t].astype(jnp.float32) * weights[i]
-            for i in range(taps)) + mamba["conv"]["b"].astype(jnp.float32))
+        mixed, tail = L.conv_tail(pre, tail, mamba["conv"]["w"],
+                                  mamba["conv"]["b"], live)
         inputs, b, c = jnp.split(mixed, [inner, inner + n], axis=-1)
         dt = jax.nn.softplus(rate.astype(jnp.float32) +
                              mamba["dt_bias"].astype(jnp.float32)) * live[
             :, :, None]
-        count = live.sum(axis=1).astype(jnp.int32)
-        new_tail = jax.vmap(lambda held, n: jax.lax.dynamic_slice_in_dim(
-            held, n, taps - 1, axis=0))(full, count)
     return (inputs.reshape(rows, t, heads, width), dt, b, c,
-            z.astype(jnp.float32), new_tail.astype(tail.dtype))
+            z.astype(jnp.float32), tail)
 
 
 def _mamba_output(mamba, config: SsmHybridConfig, out, inputs, gate, dtype):

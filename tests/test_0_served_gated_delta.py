@@ -70,7 +70,7 @@ def test_the_pool_holds_rows_for_the_full_layers_alone(decoder):
     # S lies with its heads side by side: [slots, key side, heads x value]
     assert state[0][0].shape == (4, 8, 6 * 16)
     assert state[0][0].dtype == jnp.float32
-    assert state[0][1].shape == (4, 3, 6 * (8 + 8 + 16))
+    assert state[0][1].shape == (4, 3 * 6 * (8 + 8 + 16))
     assert serving_paged.layer_leaves(config)[3] == ((4, 16, 1), (4, 16, 1))
 
 

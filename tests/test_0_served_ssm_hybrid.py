@@ -74,7 +74,7 @@ def test_the_pool_holds_one_leaf_for_the_attention_layers_alone(decoder):
     # S lies with its heads side by side: [slots, state lanes, heads x lanes]
     assert state[0][0].shape == (4, 16, 6 * 8)
     assert state[0][0].dtype == jnp.float32
-    assert state[0][1].shape == (4, 3, 6 * 8 + 2 * 16)
+    assert state[0][1].shape == (4, 3 * (6 * 8 + 2 * 16))
     assert serving_paged.layer_leaves(config)[1] == ((2, 16, 1),)
 
 

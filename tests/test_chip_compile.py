@@ -9,6 +9,7 @@
 # cells' WHOLE programs are compiled in test_0_chip_*.py, a file a
 # configuration (README.md, "Test-suite wall-time budget").
 
+import importlib
 import math
 import os
 
@@ -16,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from paged_model_cases import block_windows, no_copy_of, shaped
+from paged_model_cases import (DescribedCell, block_windows, no_copy_of,
+                               shaped)
 
 
 # Llama-3.2-1B attention geometry, the serving stack's pool block
@@ -204,6 +206,71 @@ def test_kernel_compiles_for_v5e(chip, case):
                         is_leaf=lambda leaf: isinstance(leaf, tuple))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the recurrent layers' convolution (ISSUE 47) -----------------------------------
+
+# a configuration's file -> (its model's module, its benchmark driver, the
+# block of one recurrent layer, the layer's key, the scope its convolution
+# runs under): granite's Mamba layer, olmo-hybrid's Gated-DeltaNet layer,
+# glm's KDA layer
+CONV_LAYERS = {
+    "granite-4.0-h-micro": ("ssm_hybrid", "ssm_hybrid_decoder",
+                            "_mamba_block", "mamba", "aiko.ssm_conv"),
+    "olmo-hybrid-7b-d16": ("gated_delta", "gated_delta_decoder",
+                           "_gdn_block", "gdn", "aiko.gdn_conv"),
+    "glm-5.3-flash-ep8-d5": ("hybrid_sparse", "hybrid_sparse_decoder",
+                             "_kda_block", "kda", "aiko.kda_core"),
+}
+
+
+@pytest.mark.parametrize("block", ["step", "piece"])
+@pytest.mark.parametrize("name", sorted(CONV_LAYERS))
+def test_recurrent_convolution_rolls_its_tail_without_a_gather(chip, name,
+                                                               block):
+    """ONE recurrent layer of each of the three models at its cell's slots
+    and widths, as the chip traces it: the decode step (a token a slot, the
+    state through ops/kda_step's kernel) and a prompt's piece of one row
+    (`jit_extend`, most of `jit_admit`).  layers.conv_tail rolls a slot's
+    tail by one select in the step and one slice in the piece: NO operation
+    under the convolution's scope comes from a `gather` (the `vmap` of
+    `dynamic_slice_in_dim` lowered to a `kCustom` fusion a layer, ISSUE
+    47), the step never lays tail and token end to end as [slots, conv, C],
+    and the tail stays [slots, (conv-1) x C], its positions side by side
+    on the lanes: nothing makes it three rows of a tile again.  Nothing
+    runs and nothing is timed."""
+    module, driver, layer_block, key, scope = CONV_LAYERS[name]
+    M = importlib.import_module("aiko_services_tpu.models." + module)
+    cell = DescribedCell(chip, name + ".json", getattr(M, module + "_init"),
+                         importlib.import_module(driver).model_config)
+    config = cell.config
+    at = next(i for i, layer in enumerate(cell.params["layers"])
+              if key in layer)
+    rows, tokens = (cell.slots, 1) if block == "step" \
+        else (1, cell.serve["prefill_chunk"])
+    state = tuple(cell.shaped((rows,) + leaf.shape[1:], leaf.dtype)
+                  for leaf in cell.state[0][at])
+    taps = config.conv_width
+    channels = state[1].shape[1] // (taps - 1)
+    assert state[1].shape == (rows, (taps - 1) * channels)
+    assert channels % 128 == 0
+
+    def one_layer(layer, x, state, live):
+        return getattr(M, layer_block)(layer, config, x, state, live,
+                                       live_only=block == "step")
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(one_layer).lower(
+            cell.params["layers"][at],
+            cell.shaped((rows, tokens, config.dim), jnp.bfloat16), state,
+            cell.shaped((rows, tokens), bool)).compile().as_text()
+    under = [line.strip() for line in text.splitlines() if scope in line]
+    assert under and "tpu_custom_call" in text
+    assert not [line[:200] for line in under if "gather" in line]
+    if block == "step":
+        for shape in ((rows, taps, channels), (rows, taps - 1, channels)):
+            assert "[%d,%d,%d]" % shape not in text
 
 
 # mistral-7b-v0.3-d16's pool leaf in the benchmark's cells: 24 slots x 64

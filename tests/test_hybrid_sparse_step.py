@@ -174,7 +174,7 @@ def test_the_pool_and_the_state_take_their_geometry_from_the_model():
     state = SlotState(published, 32)
     assert state.arrays[0][0].shape == (32, 64, 128, 128)
     assert state.arrays[0][0].dtype == jnp.float32
-    assert state.arrays[0][1].shape == (32, 3, 24576)
+    assert state.arrays[0][1].shape == (32, 3 * 24576)
     assert state.arrays[4][0].shape == (32, 128)
     assert round(state.nbytes() / 1e9, 2) == 0.56
     # growth and copy walk the leaves that are there
